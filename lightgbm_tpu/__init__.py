@@ -34,6 +34,9 @@ from .callback import (
 from .engine import CVBooster, CVResult, cv, train
 from .models.gbdt import Booster
 from .models.tree import Tree
+from .utils.compile_cache import compile_cache_dir as _compile_cache_dir
+
+_compile_cache_dir()    # one compile-cache rule for train/cv/serve alike
 
 __all__ = [
     "Booster",

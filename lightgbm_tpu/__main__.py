@@ -39,8 +39,10 @@ default ``none`` = unbounded), ``shed_policy=off|depth|deadline``
 (default ``deadline``: reject requests predicted to miss their deadline
 with a typed ``Overloaded`` error instead of letting p99 blow out),
 ``canary_rows`` (post-swap canary batch size, default 8),
-``compile_cache_dir`` (jax persistent compilation cache, so restarts
-serve warm).  The model is ModelBank-backed: ``!swap <model.npz>`` /
+``compile_cache_dir`` (reported only: jax's persistent compilation
+cache, which lets restarts serve warm, lives where
+``JAX_COMPILATION_CACHE_DIR`` says, else in ``<checkout>/.jaxcache``).
+The model is ModelBank-backed: ``!swap <model.npz>`` /
 ``!rollback`` / ``!stats`` request lines are control commands (acks on
 stderr), and SIGTERM drains gracefully — stop admitting, flush
 in-flight, final stats snapshot on stderr.
@@ -395,6 +397,9 @@ def _serve(input_model: str, cfg: Dict[str, str],
         packed = pack_booster(lgb.Booster(model_file=path))
         return bank.deploy(_SERVE_MODEL, packed, raw_score=raw_score)
 
+    if cache_dir is not None:
+        stderr.write(f"[lightgbm_tpu] compile cache in force: "
+                     f"{bank.cache_dir}\n")
     try:
         rep = deploy(input_model)
     except SwapRejected as e:

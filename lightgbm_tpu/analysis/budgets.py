@@ -180,8 +180,7 @@ def serving_predict_counts(bucket: int = 8, stub: bool = False):
     from ..ops import predict as predict_mod
     from ..serving.runtime import PredictorRuntime
 
-    rt = PredictorRuntime(tiny_packed_forest(), max_bucket=max(bucket, 1),
-                          donate=False)
+    rt = PredictorRuntime(tiny_packed_forest(), max_bucket=max(bucket, 1))
     codes = jnp.zeros((bucket, rt.packed.num_feature()), jnp.int32)
     mask = jnp.ones((bucket,), jnp.float32)
     fn = rt._build_fn(raw_score=False)
@@ -207,7 +206,7 @@ def kernels_per_round_summary(e=40, num_leaves=31):
     iters = num_leaves - 1
     model = xla_f + xla_c
     # r4's TPU-measured per-split-iteration launch count at this bucket
-    # shape (PERF.md "Result: 49 fusions + 1 custom-call per split
+    # shape (PERF_HISTORY.md "Result: 49 fusions + 1 custom-call per split
     # iteration"; the "~1,500 kernels/round" exec floor)
     r4_per_iter = 50
     budget = budget_by_name("cv_tpu_model").budget
@@ -273,15 +272,16 @@ class LaunchBudget:
                 "ok": measured <= self.budget, "note": self.note}
 
 
-# Measured on the r7 jax pin: strict (23 unfused / 45 fused-inlined /
-# 5+1 stub), E-batched (21 / 53 / 5+1); E=8 compiles ~5x faster than the
-# production E=40 bucket with IDENTICAL per-iteration body counts
-# (vmapped ops don't multiply with batch size) — verified against E=40
-# when the budget was set.
+# The CPU pins are measured on the installed jax 0.9.0 (r21): strict
+# 43 unfused / 63 fused-inlined, E-batched 44 / 70, serving bucket 17;
+# the stub (TPU-model) counts are 7+1 and 3+1 and keep their budgets.
+# E=8 compiles ~5x faster than the production E=40 bucket with IDENTICAL
+# per-iteration body counts (vmapped ops don't multiply with batch
+# size) — verified against E=40 when the budget was first set.
 LAUNCH_BUDGETS: Tuple[LaunchBudget, ...] = (
-    LaunchBudget("strict_unfused", 29, fuse_split=False,
+    LaunchBudget("strict_unfused", 54, fuse_split=False,
                  note="strict grower, r6 unfused split iteration"),
-    LaunchBudget("strict_fused_cpu", 56,
+    LaunchBudget("strict_fused_cpu", 79,
                  note="interpret-mode Pallas inlined; CPU regression pin"),
     LaunchBudget("strict_tpu_model", 8, stub=True,
                  note="XLA fusions + 1 mega-kernel custom-call = TPU "
@@ -292,19 +292,20 @@ LAUNCH_BUDGETS: Tuple[LaunchBudget, ...] = (
                       "SAME launch ceiling as the full-width strict "
                       "model — screening shrinks kernel shapes and "
                       "payloads, never the launch structure"),
-    LaunchBudget("cv_unfused", 27, fuse_split=False, e=8,
+    LaunchBudget("cv_unfused", 55, fuse_split=False, e=8,
                  note="fused-CV hyper-batch, unfused split iteration"),
-    LaunchBudget("cv_fused_cpu", 66, e=8,
+    LaunchBudget("cv_fused_cpu", 88, e=8,
                  note="interpret-mode Pallas inlined; CPU regression pin"),
     LaunchBudget("cv_tpu_model", 8, e=8, stub=True,
                  note="the r7 tentpole: >=3x drop vs the 50/iter r4 "
                       "TPU-measured baseline"),
-    LaunchBudget("serving_predict_b8", 12, kind="serving_predict",
+    LaunchBudget("serving_predict_b8", 21, kind="serving_predict",
                  bucket=8,
                  note="fused predict bucket program, interpret-mode "
-                      "Pallas inlined; CPU regression pin (measured 10 "
-                      "at the r18 switch to the mega-kernel; the legacy "
-                      "per-node program measured 3 on the r8 pin)"),
+                      "Pallas inlined; CPU regression pin (measured 17 "
+                      "with the r21 sub-chunked kernel; 10 at the r18 "
+                      "switch to the mega-kernel on the jax of the "
+                      "time)"),
     LaunchBudget("serving_predict_tpu_model", 5, kind="serving_predict",
                  bucket=8, stub=True,
                  note="XLA fusions + 1 mega-kernel custom-call per "
@@ -354,8 +355,7 @@ def serving_recompile_sweep(max_bucket: int = 64) -> Dict[str, object]:
     try:
         from ..serving.runtime import PredictorRuntime
 
-        rt = PredictorRuntime(tiny_packed_forest(), max_bucket=max_bucket,
-                              donate=False)
+        rt = PredictorRuntime(tiny_packed_forest(), max_bucket=max_bucket)
         rng = np.random.RandomState(0)
         sizes = sorted({1, 2, 3, max_bucket}
                        | {int(x) for x in rng.randint(1, max_bucket + 1,
@@ -397,7 +397,7 @@ def serving_warm_recompile(max_bucket: int = 16) -> Dict[str, object]:
         meshed = jax.local_device_count() >= 2
         kw = ({"mesh_devices": 2, "shard_policy": "dp"} if meshed else {})
         rt = PredictorRuntime(tiny_packed_forest(), max_bucket=max_bucket,
-                              donate=False, forest_precision="int8", **kw)
+                              forest_precision="int8", **kw)
         warmed = rt.warm(raw_score=False) + rt.warm(raw_score=True)
         keys = len(rt.warmed_keys)
         before = rt.num_compiles
@@ -1332,7 +1332,7 @@ def predict_kernel_time(num_trees: int = 800, node_slots: int = 509,
     """Launch/VMEM/HBM model of one fused predict dispatch.
 
     Reference shape: an 800-tree, 255-leaf (509 node slots) int8 forest
-    serving full 16k buckets of 32 features — the PERF.md serving
+    serving full 16k buckets of 32 features — the PERF_HISTORY.md serving
     reference.  Returns:
 
     * ``launches_fused`` / ``launches_r14_model`` / ``launch_drop_x`` —
@@ -1340,9 +1340,10 @@ def predict_kernel_time(num_trees: int = 800, node_slots: int = 509,
       mega-kernel custom-call per class, depth-independent) vs the r14
       per-node path (its traversal fusion group re-launched every depth
       step);
-    * ``vmem_block_mb`` — peak VMEM of one grid step: the widened f32
-      table tiles, the bins block, and the dominant [Tc, Mp, Rb] one-hot
-      working buffer; must sit under the 16 MB arena;
+    * ``vmem_block_mb`` — peak VMEM of one grid step
+      (``analysis.vmem.predict_forest_bytes``: operand blocks, the
+      widened f32 table scratch and the bounded one-hot working set);
+      must sit under the 16 MB arena;
     * ``hbm_node_table_bytes`` / ``f32_node_table_bytes`` — what the
       resident SoA costs, and how much of it is f32 node data.  For
       int8/bf16 the second number is ZERO — the r18 acceptance that no
@@ -1363,8 +1364,6 @@ def predict_kernel_time(num_trees: int = 800, node_slots: int = 509,
     tp = max(chunk, -(-num_trees // chunk) * chunk)
     mp = max(PREDICT_NODE_PAD,
              -(-node_slots // PREDICT_NODE_PAD) * PREDICT_NODE_PAD)
-    fp = max(8, -(-num_features // 8) * 8)
-    rb = 128
 
     # launches per dispatch: fused = prologue fusions + 1 custom-call per
     # class; r14 = the step fusion group x depth_cap + epilogue, per class
@@ -1372,11 +1371,10 @@ def predict_kernel_time(num_trees: int = 800, node_slots: int = 509,
     launches_r14 = num_class * (R14_PREDICT_STEP_FUSIONS * depth_cap
                                 + R14_PREDICT_EPILOGUE_FUSIONS)
 
-    # VMEM of one grid step (all tiles widened to f32 in-kernel)
-    onehot = chunk * mp * rb * 4            # [Tc, Mp, Rb] working buffer
-    tables = 5 * chunk * mp * 4             # feat/thr/left/right/leaf
-    bins_blk = fp * rb * 4
-    vmem = onehot + tables + bins_blk + chunk * 4 + rb * 4
+    # VMEM of one grid step: the estimator the lint's VMEM gate shares
+    from .vmem import predict_forest_bytes
+
+    vmem = predict_forest_bytes(node_slots, num_features, precision)
 
     node_b = PREDICT_SOA_NODE_BYTES[precision]
     table_bytes = num_class * tp * mp * node_b
@@ -1614,7 +1612,7 @@ def check_serve_slo_budgets(names: Optional[List[str]] = None
 #   CKPT_FIXED_LATENCY_S    — per-checkpoint constant: device->host state
 #       gather dispatch, fsync, rename (~10 ms).
 #   TRAIN_ROWS_PER_S        — measured training throughput (rows/s/round)
-#       at the r5 fused reference (PERF.md); the round denominator is
+#       at the r5 fused reference (PERF_HISTORY.md); the round denominator is
 #       charged from MEASURED wall clock, not the one-hot-matmul flop
 #       model, so the overhead fraction means what it says.
 # ---------------------------------------------------------------------------

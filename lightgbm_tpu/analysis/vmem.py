@@ -74,6 +74,39 @@ def split_iter_bytes(num_features: int, num_bins: int,
     return 2 * io
 
 
+def predict_forest_bytes(node_slots: int, num_features: int,
+                         precision: str = "int8",
+                         row_block: int = 128) -> int:
+    """Estimated peak scoped VMEM of one ``predict_forest_pallas`` grid
+    step: the double-buffered operand blocks (node tables in storage
+    dtype, bins, scale*mask, output), the widened f32 table scratch and
+    leaf scratch, THREE live node one-hot buffers ``[sub, node_chunk,
+    R]`` and one feature one-hot ``[sub, Fp, R]`` — what the chip's
+    compiler keeps of the working set (r21, bisected with
+    ``vmem_limit_bytes`` on the described v5e: the compiler's own
+    figure is between 0.72x and 0.97x of this estimate over 253..2045
+    slots, F=28/136, all three precisions;
+    tests/test_tpu_compile.py holds it inside [0.5x, 1x]).  Reads the
+    kernel's own blocking constants, so a retuned kernel moves the
+    estimate with it."""
+    from ..ops.predict import (PREDICT_NODE_PAD, PREDICT_SUB_TREES,
+                               PREDICT_TREE_CHUNKS, predict_node_chunk)
+    from ..ops.quantize import PACKED_NODE_BYTES
+
+    tc = PREDICT_TREE_CHUNKS[precision]
+    mp = max(PREDICT_NODE_PAD,
+             -(-node_slots // PREDICT_NODE_PAD) * PREDICT_NODE_PAD)
+    fp = max(8, -(-num_features // 8) * 8)
+    # the is_leaf parity byte of the layout contract never enters the kernel
+    table_bytes = tc * mp * (PACKED_NODE_BYTES[precision] - 1)
+    blocks = 2 * (table_bytes + padded_bytes((fp, row_block))
+                  + padded_bytes((tc, 1)) + padded_bytes((1, row_block)))
+    scratch = 5 * tc * mp * 4 + tc * row_block * 4
+    onehot = PREDICT_SUB_TREES * predict_node_chunk(mp) * row_block * 4
+    feat_onehot = PREDICT_SUB_TREES * fp * row_block * 4
+    return blocks + scratch + 3 * onehot + feat_onehot
+
+
 @dataclass(frozen=True)
 class VmemSpec:
     """One kernel at one representative shape vs the 16 MB budget."""
@@ -109,6 +142,13 @@ VMEM_SPECS: Tuple[VmemSpec, ...] = (
     VmemSpec("split_iter_mslr",
              lambda: split_iter_bytes(136, 256, capacity=61),
              note="r7 mega-kernel at the MSLR feature width"),
+    VmemSpec("predict_forest_higgs_f32",
+             lambda: predict_forest_bytes(253, 28, "f32"),
+             note="fused predict, 127-leaf trees (253 slots), Higgs F=28"),
+    VmemSpec("predict_forest_ref_int8",
+             lambda: predict_forest_bytes(509, 32, "int8"),
+             note="fused predict at the serving reference forest: 255-leaf "
+                  "trees (509 slots, two node chunks), int8, F=32"),
 )
 
 

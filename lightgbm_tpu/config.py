@@ -550,7 +550,7 @@ def parse_params(
     # BEATS the oracle (the r3 8.1e-4 gap was entirely the half-tail's
     # departure from strict split order).  The XLA path also sidesteps
     # this worker's known Pallas fault under near-strict invocation
-    # patterns (PERF.md), and strict on the jnp path costs ~2.4 s/round
+    # patterns (PERF_HISTORY.md), and strict on the jnp path costs ~2.4 s/round
     # at 1M rows.  Explicit user keys still win over preset defaults.
     preset = str(merged.pop("preset", "")).lower()
     if preset == "parity":

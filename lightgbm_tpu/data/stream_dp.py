@@ -61,7 +61,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.tree import (
@@ -78,7 +78,6 @@ from ..models.tree import (
 )
 from ..ops.histogram import histogram_merge
 from ..parallel.data_parallel import DATA_AXIS, shard_rows
-from ..utils.compat import shard_map
 from .stream_grow import _grad_stats_fn, _pred_update_fn
 
 _RS_MODES = ("reduce_scatter", "reduce_scatter_ring",

@@ -85,7 +85,7 @@ class _PK:
 
     The strict grower's per-split bookkeeping used to live in 22 separate
     ``[capacity]`` arrays; at small n the fused-cv sweep is bound by KERNEL
-    COUNT, not FLOPs (PERF.md r4 finding 3), and the 15 tiny per-field
+    COUNT, not FLOPs (PERF_HISTORY.md r4 finding 3), and the 15 tiny per-field
     gathers plus ~44 per-field masked scatters per split iteration were
     most of its while-body kernels.  One f32 ``[capacity, NC]`` table makes
     that ONE row gather and THREE row scatters per iteration.  Integer
@@ -606,8 +606,11 @@ def grow_tree(
         cumsum gain scan + argmax + winner gather + packed-table update
         in VMEM) instead of the ~49-fusion XLA body.  Engages only on
         the plain numeric path (no categorical/monotone/extra-trees/
-        interaction/bynode-sampling/feature-parallel); numerics are
-        bitwise identical (tests/test_split_iter_fused.py).
+        interaction/bynode-sampling/feature-parallel).  On the CPU the
+        two paths are bitwise identical (tests/test_split_iter_fused.py);
+        compiled for the chip the kernel's prefix sums add in another
+        order, and ``chip_smoke.py`` holds it to the SAME tree as
+        ``fuse_split=False`` at 1M rows (values within 1e-5).
       hist_merge: how per-shard histogram partials combine under
         ``axis_name`` (see :func:`~lightgbm_tpu.ops.histogram.
         histogram_merge`): ``"psum"`` (full allreduce, the r0 baseline),
@@ -710,9 +713,10 @@ def grow_tree(
     # subset scan, no monotone bounds, no per-node RNG (bynode sampling /
     # extra_trees), no interaction-constraint set recurrence, and no
     # feature sharding (the winner must be globalized OUTSIDE the kernel).
-    # Numerics are bitwise identical to the XLA body by construction (the
-    # shared ops.split.split_gain_scan helper + first-occurrence argmax);
-    # ``fuse_split=False`` keeps the reference XLA body for debugging.
+    # In interpret mode numerics are bitwise identical to the XLA body by
+    # construction (the shared ops.split.split_gain_scan helper + first-
+    # occurrence argmax); ``fuse_split=False`` keeps the reference XLA
+    # body — what tests and chip_smoke.py compare the kernel with.
     fuse_si = (fuse_split and cat_info is None and mono is None
                and not extra_trees and ic_member is None and bynode_off
                and fp_axis is None and not dist_mode)
@@ -1212,7 +1216,7 @@ def grow_tree_frontier(
     strict best-first selection over the realized gains and prunes back
     to ``num_leaves`` (the budget-binding tail is the ONLY place wave and
     strict order diverge, so recovering it recovers strict order at
-    roughly one extra histogram pass — PERF.md r4 gap decomposition).
+    roughly one extra histogram pass — PERF_HISTORY.md r4 gap decomposition).
     """
     n, num_features = bins.shape
     exact = wave_tail == "exact"

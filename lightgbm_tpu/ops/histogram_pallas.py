@@ -290,7 +290,7 @@ def _vmem_blocking(num_features: int, num_bins: int, k: int,
     # 61-64% of the bf16 FLOP model, where a measured chunk sweep peaks
     # at ~4096 (75%; flat beyond).  The trimmed estimate plus the raised
     # 4096 cap lands within ~3% of the measured optimum at the Higgs,
-    # MSLR, and Criteo-root shapes (chunk-sweep table in PERF.md, "r4
+    # MSLR, and Criteo-root shapes (chunk-sweep table in PERF_HISTORY.md, "r4
     # session 2 kernel chunk sweep"); still conservative enough that no
     # shape re-approaches the 16 MB scope.
     out_bytes = f_blk * num_bins * k_pad * 4
@@ -431,17 +431,19 @@ def hist_fused_pallas(
 # ---------------------------------------------------------------------------
 # Split-iteration mega-kernel — the r7 kernel-count attack.
 #
-# PERF.md r4/r5: at fused-cv scale the strict grower's per-split iteration
-# lowered to ~49 XLA fusions + 1 custom-call, and with ~1,500 launches per
-# round at ~9 us each the sweep's floor is DISPATCH, not FLOPs.  Everything
-# between the histogram pass and the next iteration's partition is pure
-# VPU work over VMEM-sized operands ([2, F, 3, B] histograms + the packed
+# PERF_HISTORY.md r4/r5: at fused-cv scale the strict grower's per-split
+# iteration lowered to ~49 XLA fusions + 1 custom-call, and with ~1,500
+# launches per round at ~9 us each the sweep's floor is DISPATCH, not
+# FLOPs.  Everything between the histogram pass and the next iteration's
+# partition is pure VPU work over VMEM-sized operands ([2, F, 3, B]
+# histograms + the packed
 # [capacity, _PK.NC] node table), so the whole tail of the iteration fuses
 # into ONE pallas call:
 #
 #   * cumsum gain scan over both children (shared numeric helper
-#     ``ops.split.split_gain_scan`` — bitwise identical to
-#     find_best_split's XLA scan by construction);
+#     ``ops.split.split_gain_scan`` — in interpret mode bitwise identical
+#     to find_best_split's XLA scan by construction; see the kernel's
+#     docstring for the chip);
 #   * regularized-gain argmax (first-occurrence, matching jnp.argmax's
 #     row-major tie-break) + winner gather, per child;
 #   * the one-row-gather / three-row-scatter node-table update;
@@ -462,9 +464,22 @@ def hist_fused_pallas(
 # ---------------------------------------------------------------------------
 
 
+def _prefix_sum_lanes(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along the minor (lane) axis, as log2(width)
+    shifted adds — Mosaic lowers no ``cumsum``.  The width must be a
+    128 multiple (``split_iter_pallas`` pads the bin axis)."""
+    axis = x.ndim - 1
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    shift = 1
+    while shift < x.shape[axis]:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, axis), 0.0)
+        shift *= 2
+    return x
+
+
 def _split_iter_kernel(hist_ref, tab_ref, fmask_ref, aux_ref, scal_ref,
                        out_tab_ref, out_aux_ref, *, K, num_features: int,
-                       num_bins: int, capacity: int):
+                       num_bins: int, capacity: int, interpret: bool):
     """One whole strict split iteration in VMEM (see block comment above).
 
     Operands:
@@ -478,8 +493,19 @@ def _split_iter_kernel(hist_ref, tab_ref, fmask_ref, aux_ref, scal_ref,
                                    max_delta_step, path_smooth, max_depth,
                                    n_nodes, 0...] (all exact in f32).
     Outputs: updated table + the next iteration's aux row.
+
+    Compiled for the chip, ``hist_ref``'s bin axis arrives zero-padded to
+    a 128-lane multiple (``num_bins`` stays the real count): the prefix
+    sums run as shifted adds and the padded lanes are masked out of the
+    argmax.  Interpret mode keeps ``jnp.cumsum`` on the unpadded axis,
+    the op ``find_best_split`` uses, which is what makes the two paths
+    agree bitwise on the CPU.
     """
     from .split import SplitContext, split_gain_scan, split_stats_valid
+
+    cumsum = (functools.partial(jnp.cumsum, axis=-1) if interpret
+              else _prefix_sum_lanes)
+    lanes = hist_ref.shape[-1]                         # >= num_bins
 
     neg_inf = jnp.float32(-jnp.inf)
     sc = scal_ref[0, :]
@@ -509,15 +535,18 @@ def _split_iter_kernel(hist_ref, tab_ref, fmask_ref, aux_ref, scal_ref,
 
     def score(c, p_out):
         """find_best_split's numeric path for one child (shared helper)."""
-        lg = jnp.cumsum(hist_ref[c, :, 0, :], axis=-1)       # [F, B]
-        lh = jnp.cumsum(hist_ref[c, :, 1, :], axis=-1)
-        lc = jnp.cumsum(hist_ref[c, :, 2, :], axis=-1)
+        lg = cumsum(hist_ref[c, :, 0, :])                    # [F, B]
+        lh = cumsum(hist_ref[c, :, 1, :])
+        lc = cumsum(hist_ref[c, :, 2, :])
         tg, th, tc = lg[:, -1:], lh[:, -1:], lc[:, -1:]      # [F, 1]
         rg, rh, rc = tg - lg, th - lh, tc - lc
         gain, wl, wr = split_gain_scan(lg, lh, lc, rg, rh, rc, tg, th,
                                        ctx, lo, hi, p_out)
         valid = (split_stats_valid(lc, rc, lh, rh, gain, ctx)
                  & (fmask.reshape(num_features, 1) > 0) & depth_ok)
+        if lanes > num_bins:                               # padded bins
+            valid &= lax.broadcasted_iota(jnp.int32, gain.shape,
+                                          1) < num_bins
         gain = jnp.where(valid, gain, neg_inf)
         best = jnp.max(gain)
         # first-occurrence flat argmax: min flat index among the maxima
@@ -631,10 +660,13 @@ def split_iter_pallas(hist2_t: jnp.ndarray, table: jnp.ndarray,
             f"integer range (2^24)")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    if not interpret:
+        hist2_t = jnp.pad(hist2_t, ((0, 0),) * 3
+                          + ((0, -num_bins % 128),))
     return pl.pallas_call(
         functools.partial(_split_iter_kernel, K=pk,
                           num_features=num_features, num_bins=num_bins,
-                          capacity=capacity),
+                          capacity=capacity, interpret=interpret),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 5,
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 2,
         out_shape=[

@@ -48,6 +48,15 @@ PREDICT_ROW_BLOCK = 128
 # minimum legal sublane tile — uint8 thresholds / int8 leaves need 32,
 # an all-i32/f32 forest needs only 8 (pallas_guide.md tiling table).
 PREDICT_TREE_CHUNKS = {"f32": 8, "bf16": 32, "int8": 32}
+# In-kernel working set: a tree chunk is traversed PREDICT_SUB_TREES (one
+# f32 sublane tile) trees at a time and every one-hot gather covers at
+# most PREDICT_NODE_CHUNK node lanes, so the [sub, chunk, R] f32 one-hot
+# is 1 MiB whatever the forest's chunk or node capacity.  The chip's
+# compiler keeps about four such buffers live; unbounded, the int8
+# layout's [32, 256, 128] one-hots alone overran the 16 MiB scoped-VMEM
+# limit (analysis.vmem.predict_forest_bytes models the bounded version).
+PREDICT_SUB_TREES = 8
+PREDICT_NODE_CHUNK = 256
 
 # tools/hlo_counts.py + analysis.budgets flip this to compile the serving
 # predict program with the mega-kernel replaced by a pure_callback, so a
@@ -198,60 +207,93 @@ def pack_forest_soa(split_feature, split_bin, left, right, leaf_value,
         scale=jnp.asarray(scale))
 
 
+def predict_node_chunk(mp: int) -> int:
+    """Node lanes per one-hot gather for a table of ``mp`` padded slots:
+    the largest of 256/128 that divides ``mp`` (always a 128 multiple)."""
+    return PREDICT_NODE_CHUNK if mp % PREDICT_NODE_CHUNK == 0 \
+        else PREDICT_NODE_PAD
+
+
 def _forest_kernel(bins_ref, feat_ref, thr_ref, left_ref, right_ref,
-                   leaf_ref, sm_ref, out_ref, *, depth_cap: int):
+                   leaf_ref, sm_ref, out_ref, tab_ref, lv_ref, *,
+                   depth_cap: int):
     """One (row-block, tree-chunk) grid step of the fused mega-kernel.
 
     Level-synchronous traversal: every row advances one level per
-    iteration across the whole tree chunk at once; leaves self-loop so
+    iteration across a sub-chunk of trees at once; leaves self-loop so
     after ``depth_cap`` steps every lane sits on its leaf.  All gathers
     are one-hot contractions over exact small integers held in f32
     lanes (the repo's histogram-kernel idiom — TPU has no VMEM gather),
     so routing is exact; only the leaf-value accumulation is real f32
     arithmetic.  The tree-chunk grid axis revisits the output block and
-    accumulates (``@pl.when`` zero-init on the first chunk)."""
+    accumulates (``@pl.when`` zero-init on the first chunk).
+
+    The compact tables widen ONCE per grid step into the f32 scratch
+    ``tab_ref`` ``[5, Tc/8, 8, Mp]`` (integers go through int32: Mosaic
+    has no direct u8/i16/i8 -> f32 cast); the traversal then loops over
+    8-tree sub-chunks and, inside every gather, over node chunks, which
+    bounds the one-hot working set (module constants above).  Leaf
+    values land in ``lv_ref`` ``[Tc/8, 8, R]`` and reduce over the whole
+    chunk in one sum, the same reduction the unchunked kernel made."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
+    sub = PREDICT_SUB_TREES
+    tc, mp = feat_ref.shape
+    fp, r = bins_ref.shape
+    n_sub = tc // sub
+    nch = predict_node_chunk(mp)
+
+    for k, ref in enumerate((feat_ref, thr_ref, left_ref, right_ref,
+                             leaf_ref)):
+        x = ref[:]                                # [Tc, Mp] storage dtype
+        if np.issubdtype(ref.dtype, np.integer):  # static: storage dtype
+            x = x.astype(jnp.int32)
+        x = x.astype(jnp.float32)
+        for j in range(n_sub):
+            tab_ref[k, j] = x[j * sub:(j + 1) * sub]
+
     bins = bins_ref[:]                            # [Fp, R] f32 bin codes
-    feat = feat_ref[:].astype(jnp.float32)        # [Tc, Mp]
-    thr = thr_ref[:].astype(jnp.float32)
-    left = left_ref[:].astype(jnp.float32)
-    right = right_ref[:].astype(jnp.float32)
-    leaf = leaf_ref[:].astype(jnp.float32)        # quantized codes/values
-    sm = sm_ref[:]                                # [Tc, 1] scale * round-mask
-    tc, mp = feat.shape
-    fp, r = bins.shape
+    iota_n = lax.broadcasted_iota(jnp.int32, (sub, nch, r), 1)
+    iota_f = lax.broadcasted_iota(jnp.int32, (sub, fp, r), 1)
 
-    iota_m = lax.broadcasted_iota(jnp.int32, (tc, mp, r), 1)
-    iota_f = lax.broadcasted_iota(jnp.float32, (tc, fp, r), 1)
+    def gather(j, node, tables):
+        """Values of ``tables`` (indices into tab_ref) at ``node``
+        ``[sub, R]`` for sub-chunk ``j``: one shared one-hot per node
+        chunk, summed over chunks (exactly one chunk holds the hit)."""
+        def chunk(c, accs):
+            base = pl.multiple_of(c * nch, nch)
+            oh = ((node - base)[:, None, :] == iota_n).astype(jnp.float32)
+            return tuple(
+                a + jnp.sum(
+                    oh * tab_ref[k, j, :, pl.ds(base, nch)][:, :, None],
+                    axis=1)
+                for a, k in zip(accs, tables))
 
-    def onehot(node):                             # [Tc, R] i32 -> f32 3-D
-        return (node[:, None, :] == iota_m).astype(jnp.float32)
+        zero = jnp.zeros((sub, r), jnp.float32)
+        return lax.fori_loop(0, mp // nch, chunk, (zero,) * len(tables))
 
-    def gather(oh, tbl):                          # -> [Tc, R]
-        return jnp.sum(oh * tbl[:, :, None], axis=1)
+    def traverse(j, _):
+        def step(_, node):
+            f_g, t_g, l_g, r_g = gather(j, node, (0, 1, 2, 3))
+            code = jnp.sum(
+                (f_g.astype(jnp.int32)[:, None, :] == iota_f)
+                .astype(jnp.float32) * bins[None, :, :], axis=1)
+            # quantized-space routing: code and threshold are both exact
+            # integers in f32 lanes, so <= is the stored-bin comparison
+            return jnp.where(code <= t_g, l_g, r_g).astype(jnp.int32)
 
-    def step(_, node):
-        oh = onehot(node)
-        f_g = gather(oh, feat)
-        t_g = gather(oh, thr)
-        l_g = gather(oh, left)
-        r_g = gather(oh, right)
-        code = jnp.sum((f_g[:, None, :] == iota_f).astype(jnp.float32)
-                       * bins[None, :, :], axis=1)
-        # quantized-space routing: code and threshold are both exact
-        # integers in f32 lanes, so <= is the stored-bin comparison
-        nxt = jnp.where(code <= t_g, l_g, r_g)
-        return nxt.astype(jnp.int32)
+        node = lax.fori_loop(0, depth_cap, step,
+                             jnp.zeros((sub, r), jnp.int32))
+        (lv_ref[j],) = gather(j, node, (4,))
+        return _
 
-    node = lax.fori_loop(0, depth_cap, step,
-                         jnp.zeros((tc, r), jnp.int32))
-    lv = gather(onehot(node), leaf)               # [Tc, R]
-    out_ref[...] += jnp.sum(lv * sm, axis=0)[None, :]
+    lax.fori_loop(0, n_sub, traverse, 0)
+    lv = lv_ref[:].reshape(tc, r)                 # [Tc, R]
+    out_ref[...] += jnp.sum(lv * sm_ref[:], axis=0)[None, :]
 
 
 def predict_forest_pallas(
@@ -280,7 +322,7 @@ def predict_forest_pallas(
     f32 ``[n]`` — same contract as :func:`predict_forest_binned`.
     """
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+    from jax.experimental.pallas import tpu as pltpu
 
     import functools
 
@@ -320,6 +362,7 @@ def predict_forest_pallas(
     else:
         kernel = functools.partial(_forest_kernel, depth_cap=depth_cap)
         tbl_spec = pl.BlockSpec((tc, mp), lambda r_, c: (c, 0))
+        n_sub = tc // PREDICT_SUB_TREES
         out = pl.pallas_call(
             kernel,
             grid=(n_rb, n_tc),
@@ -330,6 +373,10 @@ def predict_forest_pallas(
             ],
             out_specs=pl.BlockSpec((1, rb), lambda r_, c: (0, r_)),
             out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
+            scratch_shapes=[
+                pltpu.VMEM((5, n_sub, PREDICT_SUB_TREES, mp), jnp.float32),
+                pltpu.VMEM((n_sub, PREDICT_SUB_TREES, rb), jnp.float32),
+            ],
             interpret=interpret,
         )(bins_t, soa.split_feature, soa.split_bin, soa.left,
           soa.right, soa.leaf, sm)

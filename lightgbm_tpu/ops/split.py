@@ -293,7 +293,7 @@ def find_best_split(
         # two child outputs gather separately.  (A [F,B,8] stacked re-pack
         # would be one gather fewer but materializes ~35 MB per call once
         # the frontier grower vmaps this over its wave segments; the
-        # strict sweep path is kernel-count-bound, PERF.md r4.)
+        # strict sweep path is kernel-count-bound, PERF_HISTORY.md r4.)
         win_l = cum[feat, bin_idx]                        # [3] (g, h, c)
         tot = total[feat, 0]                              # [3]
         win_r = tot - win_l

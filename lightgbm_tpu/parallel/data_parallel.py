@@ -36,9 +36,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..utils.compat import shard_map
 
 from ..models.gbdt import HyperScalars, _rebuild_objective
 from ..ops.lookup import lookup_values
@@ -49,27 +48,23 @@ DATA_AXIS = "data"
 
 def make_mesh(n_devices: Optional[int] = None,
               devices=None, axis_name: str = DATA_AXIS) -> Mesh:
-    """1-D row-sharding mesh over the first ``n_devices`` devices.
+    """1-D row-sharding mesh over the first ``n_devices`` devices of the
+    default backend.
 
-    Falls back to the virtual CPU backend when the default platform has
-    fewer than ``n_devices`` chips (the multi-chip dry-run path: only one
-    physical TPU is guaranteed locally, SURVEY.md §4).
+    Asking for more devices than that backend has raises: a mesh is never
+    built from another platform's devices, so "four chips" cannot quietly
+    mean four host threads.  The virtual-mesh tests and dry runs get
+    their devices by making the CPU the default backend
+    (``JAX_PLATFORMS=cpu`` +
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
     """
     if devices is None:
         devices = jax.devices()
-        if n_devices is not None and len(devices) < n_devices:
-            try:
-                cpus = jax.devices("cpu")
-            except RuntimeError:
-                cpus = []
-            if len(cpus) >= n_devices:
-                devices = cpus
     if n_devices is not None:
         if len(devices) < n_devices:
             raise ValueError(
-                f"need {n_devices} devices, have {len(devices)}; set "
-                "XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{n_devices} for a virtual CPU mesh")
+                f"need {n_devices} devices, the {devices[0].platform} "
+                f"backend has {len(devices)}")
         devices = devices[:n_devices]
     import numpy as np
 

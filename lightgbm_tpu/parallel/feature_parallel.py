@@ -29,9 +29,8 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..utils.compat import shard_map
 
 from ..models.gbdt import HyperScalars, _rebuild_objective
 from ..ops.lookup import lookup_values
@@ -95,8 +94,8 @@ def broadcast_feature_column(bins_local, feat_global, axis_name: str,
 
 
 def make_feature_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
-    """1-D feature-sharding mesh (same device fallback logic as
-    data_parallel.make_mesh)."""
+    """1-D feature-sharding mesh (data_parallel.make_mesh under the
+    feature axis name)."""
     from .data_parallel import make_mesh
 
     return make_mesh(n_devices, devices, axis_name=FEATURE_AXIS)
@@ -206,16 +205,10 @@ def make_mesh_2d(n_data: int, n_feature: int, devices=None) -> Mesh:
 
     if devices is None:
         devices = jax.devices()
-        if len(devices) < n_data * n_feature:
-            try:
-                cpus = jax.devices("cpu")
-            except RuntimeError:
-                cpus = []
-            if len(cpus) >= n_data * n_feature:
-                devices = cpus
     need = n_data * n_feature
     if len(devices) < need:
-        raise ValueError(f"need {need} devices, have {len(devices)}")
+        raise ValueError(f"need {need} devices, the {devices[0].platform} "
+                         f"backend has {len(devices)}")
     arr = np.array(devices[:need]).reshape(n_data, n_feature)
     return Mesh(arr, (DATA_AXIS, FEATURE_AXIS))
 

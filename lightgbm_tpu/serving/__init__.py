@@ -16,7 +16,7 @@ drives it at high throughput:
 
 Multi-model tenancy and resilience ride on top:
 
-    bank = ModelBank(warm_on_deploy=True, cache_dir=".jaxcache")
+    bank = ModelBank(warm_on_deploy=True)
     bank.deploy("fraud", "model_v1.npz")      # validate -> warm -> canary -> flip
     mb = bank.batcher("fraud", max_queue_depth=512)   # sheds with Overloaded
     bank.deploy("fraud", "model_v2.npz")      # zero-downtime hot swap
@@ -38,10 +38,12 @@ from .packed import (PACKED_FORMAT_VERSION, PackedForest, PackedForestError,
                      pack_booster)
 from .queue import (SHED_POLICIES, MicroBatcher, Overloaded,
                     PendingPrediction, RequestTimeout)
-from .runtime import PredictorRuntime, bucket_for, enable_persistent_cache
+from .runtime import (DeviceProgramError, PredictorRuntime, bucket_for,
+                      enable_persistent_cache)
 from .stats import ServingStats
 
 __all__ = [
+    "DeviceProgramError",
     "FAULT_SITES",
     "FOREST_PRECISIONS",
     "FaultError",

@@ -16,10 +16,11 @@ Deploys are **validate-then-atomic-flip**:
    survive the swap);
 3. warm — optionally precompile the bucket ladder, with the measured
    (clock-injectable) duration checked against ``compile_timeout_s`` so
-   a stalled compile aborts the swap instead of blocking traffic;
+   a stalled compile aborts the swap instead of blocking traffic, and
+   a program the device's compiler refuses rejects it;
 4. canary — a deterministic batch through the NEW runtime, checked
    finite and cross-checked against the forest's own numpy oracle; a
-   device fault or NaN here rejects the swap;
+   device fault, an unbuildable program or NaN here rejects the swap;
 5. flip — one attribute assignment.  In-flight batches that already
    resolved the old runtime finish on it; the next dispatch resolves the
    new one (``MicroBatcher`` re-resolves its runtime per dispatch).
@@ -67,7 +68,8 @@ from .faults import FaultError
 from .mesh import SHARD_POLICIES
 from .packed import PackedForest, PackedForestError
 from .runtime import (DEFAULT_CACHE_ENTRIES, DEFAULT_MAX_BUCKET,
-                      PredictorRuntime, enable_persistent_cache)
+                      DeviceProgramError, PredictorRuntime,
+                      enable_persistent_cache)
 from .stats import ServingStats
 
 WARM_MANIFEST_VERSION = 1
@@ -104,7 +106,7 @@ class ModelBank:
     """N packed forests resident behind one bucket-ladder configuration.
 
     Args:
-      max_bucket / max_cache_entries / donate: shared PredictorRuntime
+      max_bucket / max_cache_entries: shared PredictorRuntime
         knobs — the one bucket ladder every tenant compiles against.
       warm_on_deploy: precompile the ladder inside every deploy (before
         the flip, so traffic never pays the compiles).
@@ -116,8 +118,9 @@ class ModelBank:
         (``device_predict``) and consulted at ``artifact_load`` and
         ``compile`` during deploys.
       clock: injectable time source for the compile-timeout measurement.
-      cache_dir: enable jax's persistent compilation cache here (see
-        :func:`runtime.enable_persistent_cache`).
+      cache_dir: where the caller expects jax's persistent compilation
+        cache; reported only — the directory in force comes from the one
+        rule in ``utils.compile_cache`` (``self.cache_dir`` holds it).
       mesh_devices / shard_policy / forest_precision: pod-scale runtime
         knobs shared by every tenant, like the bucket ladder (see
         :class:`runtime.PredictorRuntime` and the module docstring's
@@ -126,7 +129,6 @@ class ModelBank:
 
     def __init__(self, max_bucket: int = DEFAULT_MAX_BUCKET,
                  max_cache_entries: int = DEFAULT_CACHE_ENTRIES,
-                 donate: Optional[bool] = None,
                  warm_on_deploy: bool = False,
                  canary_rows: int = 8,
                  canary_tol: float = 1e-5,
@@ -151,16 +153,13 @@ class ModelBank:
         self.mesh_devices = int(mesh_devices)
         self.shard_policy = shard_policy
         self.forest_precision = forest_precision
-        self.donate = donate
         self.warm_on_deploy = bool(warm_on_deploy)
         self.canary_rows = int(canary_rows)
         self.canary_tol = float(canary_tol)
         self.compile_timeout_s = compile_timeout_s
         self.faults = faults
         self.clock = clock
-        self.persistent_cache = (enable_persistent_cache(cache_dir)
-                                 if cache_dir else False)
-        self.cache_dir = cache_dir
+        self.cache_dir = enable_persistent_cache(cache_dir)
         # guards the resident-version table: deploys flip and undeploys
         # delete while reader threads (MicroBatcher resolvers) look up
         self._lock = threading.RLock()
@@ -229,7 +228,7 @@ class ModelBank:
                 rt = PredictorRuntime(
                     packed, max_bucket=self.max_bucket,
                     max_cache_entries=self.max_cache_entries,
-                    donate=self.donate, stats=stats, faults=self.faults,
+                    stats=stats, faults=self.faults,
                     mesh_devices=self.mesh_devices,
                     shard_policy=self.shard_policy,
                     forest_precision=self.forest_precision)
@@ -250,7 +249,8 @@ class ModelBank:
             if entry is not None:
                 entry.history.append(report)
             raise
-        except (PackedForestError, FaultError, OSError) as e:
+        except (PackedForestError, FaultError, DeviceProgramError,
+                OSError) as e:
             msg = f"swap rejected at {report['stage']}: {e}"
             report["error"] = msg
             if entry is not None:
@@ -387,7 +387,7 @@ class ModelBank:
         out = {"models": {}, "bucket_ladder": {
             "max_bucket": self.max_bucket,
             "max_cache_entries": self.max_cache_entries},
-            "persistent_cache": bool(self.persistent_cache)}
+            "compile_cache_dir": self.cache_dir}
         for name in self.names():
             e = self._entries[name]
             out["models"][name] = {

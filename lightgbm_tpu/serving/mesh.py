@@ -145,12 +145,11 @@ def dp_shard(smesh: ServingMesh, fn, check_vma: bool = True):
     checker has no rule for custom kernels.  The contract is unchanged
     — the kernel body is still row-elementwise per shard.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.compat import shard_map
-
     ax = smesh.axis_name
-    return shard_map(fn, smesh.mesh,
+    return shard_map(fn, mesh=smesh.mesh,
                      in_specs=(P(ax), P(ax), P()),
                      out_specs=P(ax), check_vma=check_vma)
 
@@ -234,11 +233,10 @@ def tp_raw_margins_fused(smesh: ServingMesh, soas, trees_per_device: int,
     :func:`pad_soa_for_tp`.
     """
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops.predict import predict_forest_pallas
-    from ..utils.compat import shard_map
 
     ax = smesh.axis_name
 
@@ -251,7 +249,7 @@ def tp_raw_margins_fused(smesh: ServingMesh, soas, trees_per_device: int,
         local = jnp.stack(cols, axis=1) if num_class > 1 else cols[0]
         return lax.psum(local, ax)
 
-    sharded = shard_map(body, smesh.mesh,
+    sharded = shard_map(body, mesh=smesh.mesh,
                         in_specs=(P(ax), P(), P()),
                         out_specs=P(), check_vma=False)
 
@@ -285,12 +283,11 @@ def tp_raw_margins(smesh: ServingMesh, forest, leaf_scale,
     """
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops.predict import predict_forest_binned
     from ..ops.quantize import widen_tree
-    from ..utils.compat import shard_map
 
     ax = smesh.axis_name
     scales = () if leaf_scale is None else (leaf_scale,)
@@ -318,7 +315,7 @@ def tp_raw_margins(smesh: ServingMesh, forest, leaf_scale,
                             scales_loc[0] if scales_loc else None)
         return lax.psum(local, ax)
 
-    sharded = shard_map(body, smesh.mesh,
+    sharded = shard_map(body, mesh=smesh.mesh,
                         in_specs=(P(ax), P(ax), P(), P()),
                         out_specs=P())
 

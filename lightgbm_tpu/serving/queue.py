@@ -31,8 +31,14 @@ Design constraints (Tier-1 testability):
   explicit, deadline misses are not.
 * **Graceful degradation** — when the batched device dispatch raises,
   the batch falls back to the pure-numpy unbatched predictor
-  (``PackedForest.predict_numpy``) per request, so an XLA/device failure
-  degrades throughput instead of erroring the traffic.
+  (``PackedForest.predict_numpy``) per request, so a transient
+  XLA/device failure degrades throughput instead of erroring the
+  traffic.  A program that cannot be BUILT for this device
+  (:class:`~.runtime.DeviceProgramError`, raised by the compile that
+  precedes its first run) is not transient: those requests fail with
+  the error,
+  so a model whose kernel the chip refuses is never served from the
+  host behind a zero exit code.
 * **Hot swap** — ``runtime`` may be a zero-arg callable (e.g. a
   ModelBank resolver); it is re-resolved at every dispatch, so an atomic
   version flip takes effect for queued requests without touching the
@@ -49,6 +55,8 @@ from collections import deque
 from typing import Optional
 
 import numpy as np
+
+from .runtime import DeviceProgramError
 
 SHED_POLICIES = ("off", "depth", "deadline")
 
@@ -327,6 +335,10 @@ class MicroBatcher:
             try:
                 preds = runtime.predict(X, num_iteration=num_it,
                                         raw_score=self.raw_score)
+            except DeviceProgramError as e:
+                for r in group:
+                    r.pending._set(error=e)
+                continue
             except Exception:
                 self._fallback(runtime, group, num_it)
                 continue

@@ -11,8 +11,6 @@ The serving runtime instead:
   ``(bucket, raw_score)`` — the ``ntree_limit`` truncation mask is a
   TRACED argument of every program (the repo's staged-predict contract),
   so changing it never recompiles and never grows the key space;
-* donates the padded input buffer to the program on TPU (the binned batch
-  is dead after dispatch, so XLA can reuse its pages for the output);
 * performs the raw->binned transform on the edge with the packed bin
   bounds (the same dataset.BinMapper search the trainer used, so serving
   and training binning can never diverge);
@@ -58,6 +56,7 @@ counters.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 import time
 from collections import OrderedDict
@@ -82,29 +81,48 @@ def bucket_for(n: int, max_bucket: int) -> int:
     return min(1 << (int(n - 1).bit_length()), max_bucket)
 
 
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Cache EVERY program in jax's persistent compilation cache and
+    return the cache directory in force.
 
-    Best-effort: returns True when the config landed, False when this
-    jax build has no persistent cache (the warm-manifest path still
-    works — restarts then pay compiles, not correctness).  Thresholds
-    are zeroed so even the small bucket programs are cached; a restarted
-    process that re-warms the same ladder then deserializes executables
-    instead of recompiling them.
+    The directory is not chosen here: ``utils.compile_cache`` holds the
+    one rule (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/
+    .jaxcache``), and a ``cache_dir`` that differs from it is ignored
+    with a warning.  What serving adds is the thresholds: zeroed, so
+    even the small bucket programs are cached and a restarted process
+    that re-warms the same ladder deserializes executables instead of
+    recompiling them.
     """
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    except Exception:                          # noqa: BLE001
-        return False
-    for key, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(key, val)
-        except Exception:  # noqa: BLE001  # graftlint: GL011 — older jax
-            pass                               # older jax: defaults apply
-    return True
+    from ..utils.compile_cache import compile_cache_dir
+
+    in_force = compile_cache_dir()
+    if cache_dir and os.path.abspath(str(cache_dir)) != \
+            os.path.abspath(in_force):
+        import warnings
+
+        warnings.warn(
+            f"compile cache directory {str(cache_dir)!r} ignored: the "
+            f"cache in force is {in_force!r} (set JAX_COMPILATION_CACHE_DIR "
+            "to move it)", stacklevel=2)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return in_force
+
+
+class DeviceProgramError(RuntimeError):
+    """A bucket program could not be BUILT for this device.
+
+    Every program is traced, lowered and compiled ahead of its first
+    run (``PredictorRuntime._get_fn``), and a failure there (a kernel
+    the chip's compiler refuses, a shape error) is deterministic: the
+    same program fails the same way for every later batch.  It is
+    therefore never degraded to the host oracle — ``warm()``/deploy
+    raise it, the canary refuses the swap, and ``MicroBatcher`` fails
+    the requests with it.  An error from RUNNING a compiled program is
+    not this error, whether it is the program's first run or not: the
+    queue treats it as a transient device fault."""
 
 
 class PredictorRuntime:
@@ -115,9 +133,7 @@ class PredictorRuntime:
       max_bucket: largest single-dispatch row count (power of two);
         bigger batches are chunked.
       max_cache_entries: LRU bound on live compiled programs.  Eviction
-        drops the jitted callable, so a re-used evicted bucket recompiles.
-      donate: donate the padded input buffer to XLA; default on for TPU
-        backends only (CPU donation is a no-op that warns).
+        drops the executable, so a re-used evicted bucket recompiles.
       faults: optional serving.faults.FaultInjector consulted at the
         ``device_predict`` site before every compiled dispatch — the
         deterministic stand-in for a device error mid-predict.
@@ -133,15 +149,12 @@ class PredictorRuntime:
     def __init__(self, packed: PackedForest,
                  max_bucket: int = DEFAULT_MAX_BUCKET,
                  max_cache_entries: int = DEFAULT_CACHE_ENTRIES,
-                 donate: Optional[bool] = None,
                  stats: Optional[ServingStats] = None,
                  faults=None,
                  mesh_devices: int = 1,
                  shard_policy: str = "auto",
                  forest_precision: str = "f32",
                  clock=time.perf_counter):
-        import jax
-
         if max_bucket < 1 or (max_bucket & (max_bucket - 1)):
             raise ValueError(f"max_bucket must be a power of two, got "
                              f"{max_bucket}")
@@ -162,8 +175,6 @@ class PredictorRuntime:
         self.clock = clock
         self.shard_policy = shard_policy
         self.forest_precision = forest_precision
-        self._donate = (jax.default_backend() == "tpu"
-                        if donate is None else bool(donate))
         self.mesh = (ServingMesh(mesh_devices) if int(mesh_devices) > 1
                      else None)
         # r18: the fused SoA mega-kernel is the default device path;
@@ -350,7 +361,8 @@ class PredictorRuntime:
         contract).  When the ladder exceeds the LRU bound only the
         LARGEST ``max_cache_entries`` buckets are warmed — warming more
         would evict programs just built.  Returns the number of
-        programs compiled.
+        programs compiled.  Raises :class:`DeviceProgramError` when a
+        program cannot be built for this device.
         """
         import jax
         import jax.numpy as jnp
@@ -358,16 +370,13 @@ class PredictorRuntime:
         todo = list(buckets) if buckets is not None else list(self.buckets)
         if len(todo) > self.max_cache_entries:
             todo = todo[-self.max_cache_entries:]
-        bundler = getattr(self.packed.bin_mapper, "bundler", None)
-        n_cols = (bundler.num_columns if bundler is not None
-                  else self.packed.num_feature())
         before = self.num_compiles
         for b in todo:
             key = (b, bool(raw_score), self.route_for(b))
-            fn = self._get_fn(*key)
-            jax.block_until_ready(fn(
-                jnp.zeros((b, n_cols), jnp.uint8),
-                jnp.zeros(b, jnp.float32), jnp.int32(1)))
+            codes, mask, _ = self._program_operands(b)
+            jax.block_until_ready(self._get_fn(*key)(
+                jnp.zeros(codes.shape, codes.dtype),
+                jnp.zeros(mask.shape, mask.dtype), jnp.int32(1)))
             self.warmed_keys.add(key)
         self.warmed_buckets += len(todo)
         return self.num_compiles - before
@@ -390,14 +399,26 @@ class PredictorRuntime:
         mask[:n] = 1.0
         route = self.route_for(bucket)
         fn = self._get_fn(bucket, raw_score, route)
-        out = np.asarray(fn(jnp.asarray(codes), jnp.asarray(mask),
-                            jnp.int32(k)))
+        out = np.asarray(fn(jnp.asarray(codes, jnp.uint8),
+                            jnp.asarray(mask), jnp.int32(k)))
         self.stats.record_dispatch(
             bucket, rows=n, padded=pad,
             latency_s=self.clock() - t0, route=route,
             kernel_launches=self.kernel_launches_per_dispatch,
             fused=self.fused_predict)
         return out[:n]
+
+    def _program_operands(self, bucket: int) -> tuple:
+        """What every program of ``bucket`` takes: the uint8 codes the
+        edge transform produces, the f32 row mask, the round count."""
+        import jax
+
+        bundler = getattr(self.packed.bin_mapper, "bundler", None)
+        n_cols = (bundler.num_columns if bundler is not None
+                  else self.packed.num_feature())
+        return (jax.ShapeDtypeStruct((bucket, n_cols), np.uint8),
+                jax.ShapeDtypeStruct((bucket,), np.float32),
+                jax.ShapeDtypeStruct((), np.int32))
 
     def _get_fn(self, bucket: int, raw_score: bool,
                 route: str = "single"):
@@ -408,7 +429,15 @@ class PredictorRuntime:
             self.stats.record_cache(bucket, hit=True)
             return fn
         self.stats.record_cache(bucket, hit=False)
-        fn = self._build_fn(raw_score, route)
+        # compiled HERE, ahead of the run, so that "cannot be built for
+        # this device" and "failed while running" stay two errors
+        try:
+            fn = self._build_fn(raw_score, route).lower(
+                *self._program_operands(bucket)).compile()
+        except Exception as e:
+            raise DeviceProgramError(
+                f"bucket program {key} cannot be built for this device "
+                f"— {type(e).__name__}: {e}") from e
         self.num_compiles += 1
         self._cache[key] = fn
         while len(self._cache) > self.max_cache_entries:
@@ -540,5 +569,4 @@ class PredictorRuntime:
 
                 fn = dp_shard(self.mesh, fn, check_vma=not fused)
 
-        donate = (0,) if self._donate else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(fn)

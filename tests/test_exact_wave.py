@@ -5,7 +5,7 @@ extraction order over the realized gain tree equals descending pathmin
 order, so pruning an overgrown wave tree to the top-(num_leaves-1)
 expandable nodes by (pathmin desc, id asc) reproduces the STRICT grower's
 tree exactly — the r4 gap decomposition showed split ORDER was the entire
-residual quality gap of wave growth (PERF.md), so exactness here is the
+residual quality gap of wave growth (PERF_HISTORY.md), so exactness here is the
 north-star AUC-parity mechanism.
 """
 
@@ -66,7 +66,8 @@ def test_exact_replay_matches_strict_grower(seed):
 def test_exact_default_overgrow_near_strict():
     """At moderate (1.5x) overgrowth, coverage misses are rare: the
     split multiset differs from strict in at most a few tail splits.
-    (The production default is 2.0x — gap-converged on-chip, PERF.md r5.)"""
+    (The production default is 2.0x — gap-converged on-chip,
+    PERF_HISTORY.md r5.)"""
     from lightgbm_tpu.models.gbdt import _exact_overgrow_target
 
     nl, B = 31, 64

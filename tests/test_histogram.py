@@ -57,9 +57,13 @@ def test_histogram_zero_stats_rows_contribute_nothing(rng):
 
 
 @pytest.mark.parametrize("mode", ["f32", "bf16"])
-def test_fused_pallas_matches_numpy(rng, mode):
+def test_fused_pallas_matches_numpy(mode):
+    # its OWN rng, like the int8 test below: the bf16 tolerance holds for
+    # a given draw, and the session rng's draw depends on which tests
+    # the worker ran before this one
     from lightgbm_tpu.ops.histogram_pallas import hist_fused_pallas
 
+    rng = np.random.default_rng(7)
     n, F, B, K = 1500, 4, 32, 5
     bins = rng.integers(0, B, (n, F)).astype(np.uint8)
     stats = rng.normal(0, 1, (n, 3)).astype(np.float32)

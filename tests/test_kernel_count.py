@@ -1,6 +1,6 @@
 """Kernel-count regression guard (r7 satellite; declarative since r8).
 
-PERF.md's r4/r5 analysis showed the training floor is kernel LAUNCH
+PERF_HISTORY.md's r4/r5 analysis showed the training floor is kernel LAUNCH
 count (~1,500/round in the fused-CV sweep at ~9 us each), so op-count
 regressions must fail tier-1 instead of surfacing rounds later in a
 bench.  The budgets themselves are DECLARATIVE specs in

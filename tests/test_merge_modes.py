@@ -61,7 +61,7 @@ def _grow_pair(f, merge, voting_k=0, wave_width=1, num_leaves=15,
     """(serial tree/rows, distributed tree/rows) for one merge mode."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from lightgbm_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     bins, _y, stats = _make_problem(f, num_bins=num_bins)
     fmask = jnp.ones(f, jnp.float32)
@@ -206,7 +206,7 @@ def test_wire_dtypes_close_and_guarded():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from lightgbm_tpu.ops.histogram import histogram_merge
-    from lightgbm_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     s, f, b = 2, 13, 8
     rng = np.random.RandomState(5)
@@ -337,7 +337,7 @@ def test_histogram_merge_slices_match_psum():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from lightgbm_tpu.ops.histogram import histogram_merge
-    from lightgbm_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     s, f, b = 2, 13, 8
     rng = np.random.RandomState(3)

@@ -80,6 +80,27 @@ def test_dp_matches_unsharded_grower(problem):
     np.testing.assert_array_equal(tree_ref.split_bin, tree8.split_bin)
 
 
+@pytest.mark.parametrize("build", ["make_mesh", "make_mesh_2d"])
+def test_mesh_never_borrows_another_backends_devices(monkeypatch, build):
+    """Asking for more devices than the default backend has raises — a
+    one-chip TPU backend must not quietly become a mesh of host CPU
+    devices (which is what these builders did before r21)."""
+    from lightgbm_tpu.parallel import data_parallel, feature_parallel
+
+    class OneChip:
+        platform = "tpu"
+
+    real = jax.devices
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: real(*a) if a else [OneChip()])
+    assert len(real("cpu")) >= 4             # the devices it used to borrow
+    with pytest.raises(ValueError, match="tpu backend has 1"):
+        if build == "make_mesh":
+            data_parallel.make_mesh(4)
+        else:
+            feature_parallel.make_mesh_2d(2, 2)
+
+
 def test_dryrun_multichip_entrypoint():
     import sys
 

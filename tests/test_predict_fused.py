@@ -224,7 +224,7 @@ def _edge_forest(num_bins=8, edge_bin=3):
 @pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
 def test_runtime_oracle_parity(reg_packed, precision):
     X, pf = reg_packed
-    rt = PredictorRuntime(pf, max_bucket=256, donate=False,
+    rt = PredictorRuntime(pf, max_bucket=256,
                           forest_precision=precision)
     assert rt.fused_predict and rt.cache_info()["fused_path"]
     codes = pf.bin_mapper.transform(np.asarray(X[:200], np.float64))
@@ -235,7 +235,7 @@ def test_runtime_oracle_parity(reg_packed, precision):
 
 def test_runtime_multiclass_parity(mc_packed):
     X, pf = mc_packed
-    rt = PredictorRuntime(pf, max_bucket=128, donate=False,
+    rt = PredictorRuntime(pf, max_bucket=128,
                           forest_precision="int8")
     assert rt.kernel_launches_per_dispatch == 3      # one kernel per class
     codes = pf.bin_mapper.transform(np.asarray(X[:100], np.float64))
@@ -250,7 +250,7 @@ def test_bin_edge_routes_left(precision):
     # code <= threshold goes LEFT; the quantized path compares the SAME
     # stored u8 bin codes, so the edge row lands identically
     pf = _edge_forest(edge_bin=3)
-    rt = PredictorRuntime(pf, max_bucket=16, donate=False,
+    rt = PredictorRuntime(pf, max_bucket=16,
                           forest_precision=precision)
     codes = np.arange(8, dtype=np.uint8)[:, None]
     out = rt.predict_binned(codes, raw_score=True)
@@ -268,7 +268,7 @@ def test_threshold_bound_rejected_at_ingest(reg_packed):
 
     bad = dataclasses.replace(pf, split_bin=bad_bin)
     with pytest.raises(ThresholdBoundError, match="split_bin"):
-        PredictorRuntime(bad, max_bucket=16, donate=False,
+        PredictorRuntime(bad, max_bucket=16,
                          forest_precision="int8")
 
 
@@ -280,7 +280,7 @@ def test_soa_residency_byte_contract(reg_packed):
     for precision, idx_t, thr_t, leaf_t in (
             ("int8", np.int16, np.uint8, jnp.int8),
             ("bf16", np.int16, np.uint8, jnp.bfloat16)):
-        rt = PredictorRuntime(pf, max_bucket=64, donate=False,
+        rt = PredictorRuntime(pf, max_bucket=64,
                               forest_precision=precision)
         (soa,) = rt._soa
         assert soa.split_feature.dtype == idx_t
@@ -323,7 +323,7 @@ def test_cat_forest_falls_back_to_legacy(small_regression):
          "min_data_in_leaf": 5},
         lgb.Dataset(Xc, label=y, categorical_feature=[0]),
         num_boost_round=4)
-    rt = PredictorRuntime(pack_booster(b), max_bucket=32, donate=False)
+    rt = PredictorRuntime(pack_booster(b), max_bucket=32)
     assert not rt.fused_predict
     assert rt.kernel_launches_per_dispatch == 0
     rt.predict(Xc[:10])
@@ -338,7 +338,7 @@ def test_cat_forest_falls_back_to_legacy(small_regression):
 # ---------------------------------------------------------------------------
 def test_stats_count_kernel_launches(mc_packed):
     X, pf = mc_packed
-    rt = PredictorRuntime(pf, max_bucket=64, donate=False,
+    rt = PredictorRuntime(pf, max_bucket=64,
                           forest_precision="int8")
     for n in (5, 40, 64):
         rt.predict(X[:n])
@@ -355,7 +355,7 @@ def test_warm_covers_full_compile_key_quantized_dp(reg_packed):
     # cache must hold the full warmed key set: 8-bucket ladder x 2
     # raw_score settings (the LRU would otherwise evict early warms —
     # documented warm() semantics)
-    rt = PredictorRuntime(pf, max_bucket=128, donate=False,
+    rt = PredictorRuntime(pf, max_bucket=128,
                           forest_precision="int8", mesh_devices=4,
                           shard_policy="dp", max_cache_entries=32)
     for raw in (False, True):

@@ -23,6 +23,7 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.serving import (
+    DeviceProgramError,
     FaultError,
     FaultInjector,
     FaultSpec,
@@ -147,6 +148,104 @@ def test_microbatcher_fallback_on_device_fault(served_models):
     got = np.array([h.result() for h in hs])
     assert np.abs(got - b.predict(X[:4])).max() <= TOL
     assert rt.stats.snapshot()["fallbacks"] == 4
+
+
+def _refuse_to_compile(*_a, **_k):
+    import jax
+
+    def fn(*_args):
+        raise NotImplementedError("Unsupported cast: uint8 -> float32")
+    return jax.jit(fn)
+
+
+def test_unbuildable_program_is_never_served_from_the_host(served_models,
+                                                           monkeypatch):
+    """A program that cannot be built (traced, lowered, compiled) is a
+    deterministic failure: warm() raises, the requests fail with
+    DeviceProgramError, and nothing is answered from the numpy oracle —
+    before r21 the queue served such a model 100% from the host."""
+    X, _, v1, _ = served_models
+    rt = PredictorRuntime(PackedForest.load(v1), max_bucket=16)
+    monkeypatch.setattr(rt, "_build_fn", _refuse_to_compile)
+    with pytest.raises(DeviceProgramError, match="Unsupported cast"):
+        rt.warm()
+    mb = MicroBatcher(rt, max_batch=4, max_delay_ms=0.0, clock=_Clock())
+    hs = [mb.submit(X[i]) for i in range(4)]
+    assert mb.pump() == 1
+    for h in hs:
+        with pytest.raises(DeviceProgramError):
+            h.result()
+    assert rt.stats.snapshot()["fallbacks"] == 0
+
+
+def _dropped_core(*_args):
+    raise RuntimeError("device lost")
+
+
+def test_built_program_degrades_when_it_fails_running(served_models):
+    """... whereas a compiled program that raises while RUNNING degrades
+    to the oracle: that is the transient fault the fallback is for."""
+    X, b, v1, _ = served_models
+    rt = PredictorRuntime(PackedForest.load(v1), max_bucket=16)
+    want = rt.predict(X[:4])
+    (key,) = rt._cache
+    rt._cache[key] = _dropped_core
+    mb = MicroBatcher(rt, max_batch=4, max_delay_ms=0.0, clock=_Clock())
+    hs = [mb.submit(X[i]) for i in range(4)]
+    mb.pump()
+    got = np.array([h.result() for h in hs])
+    assert np.abs(got - want).max() <= TOL
+    assert rt.stats.snapshot()["fallbacks"] == 4
+
+
+def test_first_run_fault_of_a_rebuilt_bucket_degrades_too(served_models,
+                                                          monkeypatch):
+    """The fault may hit a program's very FIRST run — an un-warmed
+    bucket, or one the LRU evicted and rebuilt.  The program compiled,
+    so this is still the transient fault: answered from the oracle, and
+    the next dispatch is back on the device."""
+    X, b, v1, _ = served_models
+    rt = PredictorRuntime(PackedForest.load(v1), max_bucket=16,
+                          max_cache_entries=1)
+    want = rt.predict(X[:4])
+    rt.predict(X[:8])                              # evicts bucket 4
+    assert [k[0] for k in rt._cache] == [8]
+
+    class _CompilesThenDrops:
+        def lower(self, *_shapes):
+            return self
+
+        def compile(self):
+            return _dropped_core
+
+    monkeypatch.setattr(rt, "_build_fn",
+                        lambda *a, **k: _CompilesThenDrops())
+    mb = MicroBatcher(rt, max_batch=4, max_delay_ms=0.0, clock=_Clock())
+    hs = [mb.submit(X[i]) for i in range(4)]
+    mb.pump()
+    got = np.array([h.result() for h in hs])
+    assert np.abs(got - want).max() <= TOL
+    assert rt.stats.snapshot()["fallbacks"] == 4
+    monkeypatch.undo()
+    rt._cache.clear()                              # the core came back
+    assert np.array_equal(rt.predict(X[:4]), want)
+
+
+@pytest.mark.parametrize("warm,stage", [(True, "warm"), (False, "canary")])
+def test_bank_rejects_a_model_whose_program_cannot_compile(
+        served_models, monkeypatch, warm, stage):
+    X, _, v1, v2 = served_models
+    bank = _bank(warm_on_deploy=warm)
+    bank.deploy("m", v1)
+    before = bank.predict("m", X[:4])
+    monkeypatch.setattr(PredictorRuntime, "_build_fn",
+                        lambda self, *a, **k: _refuse_to_compile())
+    with pytest.raises(SwapRejected) as ei:
+        bank.deploy("m", v2)
+    assert ei.value.stage == stage
+    monkeypatch.undo()
+    assert bank.version("m") == "v1"               # old version still serves
+    assert np.array_equal(bank.predict("m", X[:4]), before)
 
 
 # ---------------------------------------------------------------------------
@@ -537,15 +636,36 @@ def test_warm_manifest_version_gate(tmp_path):
         _bank().restore_warm_manifest(p)
 
 
-def test_enable_persistent_cache_configures_jax(tmp_path):
+@pytest.mark.parametrize("env_dir", [None, "/some/dir"])
+def test_one_compile_cache_rule(tmp_path, monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing sets another; unset,
+    the cache is <checkout>/.jaxcache — enable_persistent_cache and
+    ModelBank(cache_dir=) only report the directory in force."""
     import jax
 
-    assert enable_persistent_cache(str(tmp_path / "jaxcache")) is True
+    import lightgbm_tpu
+    from lightgbm_tpu.utils.compile_cache import (ENV_VAR,
+                                                  compile_cache_dir)
+
+    checkout = os.path.dirname(os.path.dirname(lightgbm_tpu.__file__))
+    if env_dir is None:
+        monkeypatch.delenv(ENV_VAR, raising=False)
+        want = os.path.join(checkout, ".jaxcache")
+    else:
+        monkeypatch.setenv(ENV_VAR, env_dir)
+        want = env_dir
+    before = jax.config.jax_compilation_cache_dir
     try:
-        assert jax.config.jax_compilation_cache_dir == \
-            str(tmp_path / "jaxcache")
+        assert compile_cache_dir() == want
+        with pytest.warns(UserWarning, match="ignored"):
+            assert enable_persistent_cache(str(tmp_path / "other")) == want
+        assert _bank().cache_dir == want
+        if env_dir is None:
+            assert jax.config.jax_compilation_cache_dir == want
+        else:   # the variable is JAX's to read: no code path set a dir
+            assert jax.config.jax_compilation_cache_dir == before
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # ---------------------------------------------------------------------------

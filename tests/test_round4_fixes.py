@@ -113,7 +113,7 @@ def test_parity_preset_expands_to_quality_config():
     p = parse_params({"objective": "binary", "preset": "parity"})
     # TRUE-STRICT order + EXACT f32 histograms on the XLA path (strict on
     # jnp is clean on this worker — the intermittent fault follows
-    # strict+pallas; PERF.md "AUC parity — NORTH STAR MET")
+    # strict+pallas; PERF_HISTORY.md "AUC parity — NORTH STAR MET")
     assert p.grow_policy == "leafwise"
     assert p.extra.get("hist_dtype") == "f32"
     assert p.extra.get("hist_impl") == "jnp"

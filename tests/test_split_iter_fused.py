@@ -115,13 +115,10 @@ def _xla_split_iter_ref(P, hist2, ctx, fmask, max_depth, n_nodes, capacity):
     return P
 
 
-def test_kernel_bitmatches_xla_one_iteration():
-    rng = np.random.RandomState(7)
-    F, B, num_leaves = 9, 32, 15
+def _root_iteration(hist2, num_leaves, ctx, fmask):
+    """Operands of the FIRST split iteration for children histograms
+    ``hist2`` [2, F, B, 3]: (packed root table, aux pick, scalars)."""
     cap = 2 * num_leaves - 1
-    ctx = make_ctx()
-    fmask = jnp.ones(F, jnp.float32)
-    hist2 = jnp.asarray((rng.randn(2, F, B, 3).astype(np.float32)) ** 2)
     root_hist = hist2[0] + hist2[1]
     root_tot = jnp.sum(root_hist.sum(0), axis=0)
     root_out = constrained_leaf_output(
@@ -136,15 +133,26 @@ def test_kernel_bitmatches_xla_one_iteration():
                      jnp.isfinite(root_best.gain).astype(jnp.float32),
                      jnp.float32(0), jnp.float32(0), jnp.float32(0),
                      jnp.float32(0)]).reshape(1, 8)
+    scal = jnp.concatenate([jnp.stack([
+        ctx.lambda_l1, ctx.lambda_l2, ctx.min_data_in_leaf,
+        ctx.min_sum_hessian, ctx.min_gain_to_split, ctx.max_delta_step,
+        ctx.path_smooth, jnp.float32(0), jnp.float32(1)]),
+        jnp.zeros(7)]).reshape(1, 16)
+    return tab, aux, scal
+
+
+def test_kernel_bitmatches_xla_one_iteration():
+    rng = np.random.RandomState(7)
+    F, B, num_leaves = 9, 32, 15
+    cap = 2 * num_leaves - 1
+    ctx = make_ctx()
+    fmask = jnp.ones(F, jnp.float32)
+    hist2 = jnp.asarray((rng.randn(2, F, B, 3).astype(np.float32)) ** 2)
+    tab, aux, scal = _root_iteration(hist2, num_leaves, ctx, fmask)
     md = jnp.int32(0)
     n_nodes = jnp.int32(1)
 
     def both():
-        scal = jnp.concatenate([jnp.stack([
-            ctx.lambda_l1, ctx.lambda_l2, ctx.min_data_in_leaf,
-            ctx.min_sum_hessian, ctx.min_gain_to_split, ctx.max_delta_step,
-            ctx.path_smooth, md.astype(jnp.float32),
-            n_nodes.astype(jnp.float32)]), jnp.zeros(7)]).reshape(1, 16)
         Pk, auxk = split_iter_pallas(hist2.transpose(0, 1, 3, 2), tab,
                                      fmask.reshape(1, F), aux, scal, pk=_PK)
         Px = _xla_split_iter_ref(tab, hist2, ctx, fmask, md, n_nodes, cap)
@@ -163,6 +171,45 @@ def test_kernel_bitmatches_xla_one_iteration():
     assert a[1] == Px_np[leaf_n, K.CAND_FEAT]
     assert a[2] == Px_np[leaf_n, K.CAND_BIN]
     assert bool(a[3]) == bool(np.isfinite(gains[leaf_n]))
+
+
+@pytest.mark.parametrize("num_bins", [32, 255])
+def test_chip_branch_matches_interpret_branch(monkeypatch, num_bins):
+    """What the chip compiles (bin axis padded to the lane tile, prefix
+    sums as shifted adds, padded bins masked out of the argmax), run
+    through the interpreter: with integer-valued histograms every prefix
+    sum is exact in any order, so it must make the ``jnp.cumsum``
+    branch's picks exactly and its gains to an ulp (XLA:CPU vectorizes
+    the padded and unpadded gain formulas differently).
+    ``interpret=False`` selects the chip branch; the patched
+    ``pallas_call`` keeps the run on the CPU."""
+    from jax.experimental import pallas as pl
+
+    rng = np.random.RandomState(11)
+    F, num_leaves = 5, 15
+    ctx = make_ctx()
+    fmask = jnp.ones(F, jnp.float32)
+    cnt = rng.randint(0, 40, size=(2, F, num_bins)).astype(np.float32)
+    cnt[:, 1:] = cnt[:, :1][:, :, rng.permutation(num_bins)]  # same totals
+    g = np.round(rng.randn(2, F, num_bins) * 8) * (cnt > 0)
+    hist2 = jnp.asarray(np.stack([g, cnt, cnt], axis=-1), jnp.float32)
+    tab, aux, scal = _root_iteration(hist2, num_leaves, ctx, fmask)
+    args = (hist2.transpose(0, 1, 3, 2), tab, fmask.reshape(1, F), aux, scal)
+
+    want_tab, want_aux = split_iter_pallas(*args, pk=_PK, interpret=True)
+    real_call = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, **k: real_call(*a, **{**k, "interpret": True}))
+    got_tab, got_aux = split_iter_pallas(*args, pk=_PK, interpret=False)
+    got_tab, want_tab = np.asarray(got_tab), np.asarray(want_tab)
+    K = _PK
+    picks = [K.SPLIT_FEAT, K.SPLIT_BIN, K.LEFT, K.RIGHT, K.IS_LEAF,
+             K.CAND_FEAT, K.CAND_BIN, K.COUNT, K.DEPTH]
+    np.testing.assert_array_equal(got_tab[:, picks], want_tab[:, picks])
+    np.testing.assert_allclose(got_tab, want_tab, rtol=5e-7, atol=0.0)
+    np.testing.assert_array_equal(np.asarray(got_aux), np.asarray(want_aux))
+    assert want_tab[0, K.IS_LEAF] == 0                   # the root did split
 
 
 def test_tree_parity_regression_unbatched():

@@ -1,0 +1,222 @@
+"""The main path's kernels, compiled for the v5e without a chip (r21).
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached, so what Mosaic refuses (an unsupported cast or
+primitive, a misaligned slice, too much scoped VMEM) fails HERE instead
+of on the chip.  Interpret-mode tests cannot see any of it: r18's
+``predict_forest_pallas`` and r7's ``split_iter_pallas`` passed every
+CPU test and were both refused by the chip's compiler.
+
+Nothing runs — shapes only, at the real widths (Higgs F=28 / MSLR F=136,
+B=256, 42-segment waves, 1M rows, 127-leaf trees).  The topology is
+described inside a module-scoped fixture of this file and nowhere else:
+only the worker that is handed this file loads the TPU library, and a
+machine that cannot describe the topology skips these tests and no
+others.  The persistent compile cache is off around them (an entry
+compiled for a described chip cannot be read back without one).
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+N_ROWS = 1_000_192            # Higgs-1M padded to the Dataset's 256 rows
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    had_log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:                    # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+        if had_log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def S(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` on shapes placed on the described chip; the compiled
+    program must contain a Mosaic kernel."""
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_hist_fused_bf16_higgs_wave(one_chip):
+    from lightgbm_tpu.ops.histogram_pallas import hist_fused_pallas
+
+    _compile(lambda b, s, g: hist_fused_pallas(
+        b, s, g, 42, 256, hist_dtype="bf16", interpret=False), one_chip,
+        S((N_ROWS, 28), jnp.uint8), S((N_ROWS, 3), jnp.float32),
+        S((N_ROWS,), jnp.int32))
+
+
+@pytest.mark.parametrize("num_features", [28, 136],
+                         ids=["single_block_f28", "multi_block_f136"])
+def test_hist_partition_fused(one_chip, num_features):
+    from lightgbm_tpu.ops.histogram_pallas import (
+        _vmem_blocking, hist_partition_fused_pallas, prepare_wave_operands)
+
+    w, b = 42, 256
+    assert (_vmem_blocking(num_features, b, w * 3, chunk_align=512)[1]
+            > 1) == (num_features == 136)
+
+    def wave(bins, stats, pv, wfeat):
+        bins_t, stats_t, chunk = prepare_wave_operands(bins, stats, b, w)
+        pv_t = jnp.pad(pv, ((0, 0), (0, bins_t.shape[1] - pv.shape[1])))
+        return hist_partition_fused_pallas(
+            bins_t, stats_t, pv_t, w, b, chunk, interpret=False,
+            hist_dtype="bf16", wfeat=wfeat, num_features=num_features)
+
+    _compile(wave, one_chip,
+             S((N_ROWS, num_features), jnp.uint8),
+             S((N_ROWS, 3), jnp.float32), S((8, N_ROWS), jnp.float32),
+             S((w,), jnp.int32))
+
+
+@pytest.mark.parametrize("num_bins", [255, 256])
+def test_split_iter(one_chip, num_bins):
+    # 255 is what max_bin=255 data has: the wrapper pads the bin axis to
+    # the lane tile and the kernel masks the padding
+    from lightgbm_tpu.models.tree import _PK
+    from lightgbm_tpu.ops.histogram_pallas import split_iter_pallas
+
+    cap = 2 * 127 - 1
+    _compile(lambda h, t, fm, aux, sc: split_iter_pallas(
+        h, t, fm, aux, sc, pk=_PK, interpret=False), one_chip,
+        S((2, 28, 3, num_bins), jnp.float32), S((cap, _PK.NC), jnp.float32),
+        S((1, 28), jnp.float32), S((1, 8), jnp.float32),
+        S((1, 16), jnp.float32))
+
+
+def test_kernel_payload_ignores_call_path_and_trace_history(one_chip):
+    """A Pallas kernel is serialized into its program with its source
+    locations and that payload is part of the persistent-cache key, so
+    the one compile-cache rule (``utils/compile_cache.py``) keeps
+    locations out of the IR.  Without it the second lowering below
+    differs twice over: it comes from another call path, and the
+    ``jnp`` helper both kernels use was first traced on another line."""
+    from lightgbm_tpu.models.tree import _PK
+    from lightgbm_tpu.ops.histogram_pallas import (hist_fused_pallas,
+                                                   split_iter_pallas)
+
+    def lowered(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+                for s in shapes]
+        return jax.jit(fn).lower(*args).as_text()
+
+    def split_iter():
+        return lowered(lambda h, t, fm, aux, sc: split_iter_pallas(
+            h, t, fm, aux, sc, pk=_PK, interpret=False),
+            S((2, 28, 3, 256), jnp.float32), S((253, _PK.NC), jnp.float32),
+            S((1, 28), jnp.float32), S((1, 8), jnp.float32),
+            S((1, 16), jnp.float32))
+
+    lowered(lambda b, s, g: hist_fused_pallas(
+        b, s, g, 8, 256, hist_dtype="f32", interpret=False),
+        S((4096, 28), jnp.uint8), S((4096, 3), jnp.float32),
+        S((4096,), jnp.int32))
+    after_another_kernel = split_iter()
+    jax.clear_caches()
+    from_a_fresh_trace = (lambda: split_iter())()
+    assert "tpu_custom_call" in after_another_kernel
+    assert after_another_kernel == from_a_fresh_trace
+
+
+def _soa_shapes(precision, num_trees, node_slots):
+    from lightgbm_tpu.ops.predict import PREDICT_TREE_CHUNKS, ForestSoA
+
+    tc = PREDICT_TREE_CHUNKS[precision]
+    tp = -(-num_trees // tc) * tc
+    mp = -(-node_slots // 128) * 128
+    idx_t, thr_t, leaf_t = {
+        "f32": (jnp.int32, jnp.int32, jnp.float32),
+        "bf16": (jnp.int16, jnp.uint8, jnp.bfloat16),
+        "int8": (jnp.int16, jnp.uint8, jnp.int8)}[precision]
+    tbl = (tp, mp)
+    return ForestSoA(S(tbl, idx_t), S(tbl, thr_t), S(tbl, idx_t),
+                     S(tbl, idx_t), S(tbl, leaf_t), S(tbl, jnp.bool_),
+                     S((tp,), jnp.float32))
+
+
+def _predict(depth_cap):
+    from lightgbm_tpu.ops.predict import predict_forest_pallas
+
+    return lambda soa, bins, num_it: predict_forest_pallas(
+        soa, bins, jnp.float32(0.1), 0.0, num_it, depth_cap,
+        interpret=False)
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+def test_predict_forest(one_chip, precision):
+    # 300 trees of 127 leaves at the bucket ladder's ends, then the
+    # serving reference forest (255 leaves -> two node chunks, F=136)
+    for bucket in (8, 16384):
+        _compile(_predict(14), one_chip, _soa_shapes(precision, 300, 253),
+                 S((bucket, 28), jnp.uint8), S((), jnp.int32))
+    _compile(_predict(12), one_chip, _soa_shapes(precision, 800, 509),
+             S((16384, 136), jnp.uint8), S((), jnp.int32))
+
+
+@pytest.mark.parametrize("precision,node_slots,num_features",
+                         [("f32", 253, 28), ("int8", 253, 28),
+                          ("int8", 509, 136)])
+def test_predict_vmem_estimate_brackets_the_compiler(
+        one_chip, monkeypatch, precision, node_slots, num_features):
+    """``analysis.vmem.predict_forest_bytes`` against the compiler's own
+    scoped-VMEM accounting: the kernel compiles inside the estimate and
+    is refused inside half of it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from lightgbm_tpu.analysis.vmem import (VMEM_BUDGET_BYTES,
+                                            predict_forest_bytes)
+
+    est = predict_forest_bytes(node_slots, num_features, precision)
+    assert est <= VMEM_BUDGET_BYTES
+    real_call = pl.pallas_call
+
+    def compile_with_limit(limit):
+        monkeypatch.setattr(
+            pl, "pallas_call", lambda *a, **k: real_call(
+                *a, compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=int(limit)), **k))
+        try:
+            _compile(_predict(12), one_chip,
+                     _soa_shapes(precision, 64, node_slots),
+                     S((4096, num_features), jnp.uint8), S((), jnp.int32))
+        finally:
+            monkeypatch.setattr(pl, "pallas_call", real_call)
+
+    compile_with_limit(est)
+    with pytest.raises(Exception, match="vmem"):
+        compile_with_limit(est // 2)

@@ -1,4 +1,6 @@
-"""Training chaos bench: kill/resume parity, fault absorption, overhead.
+"""Virtual-mesh, CPU only: training chaos bench — kill/resume parity,
+fault absorption, overhead (the CPU platform is pinned below, so nothing
+here is a chip number).
 
 Drives the r13 fault-tolerant training stack through the failure menu
 the issue gates on and writes ``BENCH_CHAOS_r13.json`` with the

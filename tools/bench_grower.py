@@ -110,7 +110,7 @@ def artifact(path):
     out["mslr_f136_round_ms_fused_partition"] = round(
         mslr_round_ms(fuse_partition=True), 1)
     out["note_kernels"] = (
-        "kernels/split-iter: r4 TPU-measured baseline 50 (PERF.md '49 "
+        "kernels/split-iter: r4 TPU-measured baseline 50 (PERF_HISTORY.md '49 "
         "fusions + 1 custom-call'); tpu_model = CPU compile with the "
         "mega-kernel as one custom-call (tools/hlo_counts.py stub); "
         "fused_cpu_inlined is interpret-mode Pallas inlined by XLA:CPU "

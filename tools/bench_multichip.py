@@ -1,4 +1,6 @@
-"""MULTICHIP round artifact: dryrun + merge-mode timings + comm model.
+"""Virtual-mesh, CPU only: dryrun + merge-mode timings + comm model
+(the MULTICHIP round artifact; both children pin the CPU platform, so
+nothing here is a chip number).
 
 Extends the driver's {n_devices, rc, ok, skipped, tail} schema (see
 MULTICHIP_r0X.json) with the r9/r10 tentpole evidence:

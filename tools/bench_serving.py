@@ -4,8 +4,7 @@ Measures PredictorRuntime dispatch throughput (rows/sec, warm) at every
 power-of-two batch bucket 2^0 .. 2^14, plus the compile-cache hit rate of
 a mixed-size workload, and writes the artifact the issue asks for
 (``BENCH_SERVE_r06.json``).  Runs on CPU JAX by default so the artifact is
-reproducible without an accelerator; on TPU the same script measures the
-donated-buffer path.
+reproducible without an accelerator.
 
 Usage: python tools/bench_serving.py [n_trees] [out.json]
 """

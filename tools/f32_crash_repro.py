@@ -1,7 +1,7 @@
 """Minimal repro harness for the f32 (hi/lo) kernel-mode worker crash.
 
-PERF.md "Known issue": the f32 two-pass histogram mode intermittently
-crashes the remote TPU worker at the 1M-row Higgs shape after a few
+PERF_HISTORY.md "Known issue": the f32 two-pass histogram mode intermittently
+crashed the TPU worker (r3-r5 hardware) at the 1M-row Higgs shape after a few
 hundred kernel invocations; bf16/int8 have run thousands clean and f32 is
 stable at <=200k rows.  VERDICT r3 #7 asks for a shape/pressure bisect and
 a checked-in repro.
@@ -21,9 +21,7 @@ import sys
 from pathlib import Path
 
 CELL = r"""
-import os, sys, json
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/lightgbm_tpu_jaxcache")
+import sys, json
 import numpy as np, jax, jax.numpy as jnp
 sys.path.insert(0, {repo!r})
 from lightgbm_tpu.ops.histogram_pallas import hist_fused_pallas

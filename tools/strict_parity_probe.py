@@ -1,9 +1,9 @@
 """Probe: does TRUE strict best-first order close the parity AUC gap?
 
-PERF.md r4 located the remaining 8.1e-4 parity gap in "grower semantics"
+PERF_HISTORY.md r4 located the remaining 8.1e-4 parity gap in "grower semantics"
 (half-tail residual departure from strict order + tie-breaks) but could
 not isolate the strict term because strict+pallas crashes the worker.
-The crash follows the PALLAS kernel (PERF.md fault pattern), and the
+The crash follows the PALLAS kernel (PERF_HISTORY.md fault pattern), and the
 parity preset already pins hist_impl=jnp — so strict on the jnp path is
 measurable.  This probe times it, then measures the paired AUC gap.
 

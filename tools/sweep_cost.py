@@ -1,6 +1,6 @@
 """XLA cost analysis + measured slope of one fused-cv bucket round.
 
-The 108-config sweep is per-op-bound (PERF.md r4 finding 3): ~30-70 ms
+The 108-config sweep is per-op-bound (PERF_HISTORY.md r4 finding 3): ~30-70 ms
 per while-loop round for ~0.3 ms of FLOPs.  This tool compiles one
 bucket's ``run_segment`` at the exact sweep shape and prints the
 compiled program's cost_analysis (bytes accessed, flops) plus a
