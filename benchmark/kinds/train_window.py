@@ -1,0 +1,234 @@
+"""Traffic kind ``train_window``: boosting rounds for ``--seconds``.
+
+Set-up makes the rows from the seed, bins them through ``lgb.Dataset``,
+builds ONE ``Booster`` and drives it through its first ``checked_rounds``
+by the window's own call (which also compiles the round program); the
+window then goes on with that same object.  Every call is
+``Booster.update_many(rounds_per_call)`` ended by ``block_until_ready``,
+so the window closes on a dispatch boundary and its real length divides
+the work.
+
+``correct`` comes from ``benchmark/reference/gbdt_check.py`` once the
+window has closed and the program's device state is freed: the first
+rounds' trees (as ``Booster.dump_model()`` hands them to a user) and
+training scores against exact statistics of the raw rows, the splits
+they chose against the reference's own search of the same nodes, their
+split order against leaf-wise growth, the leaf count of every tree of
+the run, and the final scores of a sample of rows against a walk over
+every tree of the run.  Nothing may compile inside the window.
+"""
+
+from __future__ import annotations
+
+import time
+
+import numpy as np
+
+from .. import datagen
+from ..reference import gbdt_check
+
+# Faults the CPU tests plant to see ``correct`` come out false; nothing on
+# the command line or in the environment sets this.
+FAULT = None
+
+# Faults that are a path of the program's own, switched on by a parameter:
+# a grower that stops at half the leaves, a split scan over half of the
+# features, wave growth without the replay of strict best-first order.
+PARAM_FAULTS = {
+    "fewer_leaves": lambda p: {"num_leaves": (int(p["num_leaves"]) + 1) // 2},
+    "restricted_features": lambda p: {"feature_fraction": 0.5},
+    "greedy_tail": lambda p: {"wave_tail": "greedy"},
+}
+
+
+class Cell:
+    def __init__(self, config: dict, traffic: dict, seed: int, devices):
+        self.config, self.traffic, self.seed = config, traffic, int(seed)
+        self.devices = devices
+        self.rows = int(config["rows"])
+        self.features = int(config["features"])
+        self.rounds_per_call = int(traffic.get("rounds_per_call", 1))
+        self.checked_rounds = int(traffic.get("checked_rounds", 3))
+        self.sample_rows = int(traffic.get("sample_rows", 100_000))
+        self.split_nodes = int(traffic.get("split_nodes", 0))
+        self.order_leaves = int(traffic.get("order_leaves", 0))
+        self.counters = {}
+        from ..device import CompileMeter
+
+        self.meter = CompileMeter()
+
+    # -- set-up ------------------------------------------------------------
+    def setup(self) -> None:
+        self.make_inputs()
+        self.build()
+
+    def make_inputs(self) -> None:
+        """Rows and labels from the seed, binned through ``lgb.Dataset``."""
+        import jax
+
+        import lightgbm_tpu as lgb
+
+        t0 = time.perf_counter()
+        self.X, self.y = datagen.higgs_like(
+            self.rows, self.features, self.seed)
+        t1 = time.perf_counter()
+        self.dataset = lgb.Dataset(self.X, label=self.y, free_raw_data=True)
+        self.dataset.construct()
+        jax.block_until_ready(self.dataset.X_binned)
+        self.counters.update(datagen_s=t1 - t0,
+                             binning_s=time.perf_counter() - t1)
+
+    def share_inputs(self, other: "Cell") -> None:
+        """Another run on the same rows (``benchmark/readings.py``)."""
+        self.X, self.y, self.dataset = other.X, other.y, other.dataset
+        self.counters.update(datagen_s=0.0, binning_s=0.0)
+
+    def build(self) -> None:
+        """ONE booster, driven through its first rounds by the window's
+        own call; the window goes on with the same object."""
+        import jax
+
+        import lightgbm_tpu as lgb
+
+        ds = self.dataset
+        t2 = time.perf_counter()
+        params = dict(self.config["params"])
+        if FAULT in PARAM_FAULTS:
+            params.update(PARAM_FAULTS[FAULT](params))
+        self.booster = lgb.Booster(params, ds)
+        if FAULT == "half_batch":
+            # half of the batch left out, the statistics taken over the rest
+            half = np.ones(int(ds.row_mask.shape[0]), np.float32)
+            half[1::2] = 0.0
+            self.booster._bag = self.booster._bag * jax.numpy.asarray(half)
+        self.program_init = float(self.booster.init_score_)
+        self.scores_after = []
+        for _ in range(self.checked_rounds):
+            self._call()
+            self.scores_after.append(
+                np.asarray(self.booster._pred_train)[:self.rows])
+        t3 = time.perf_counter()
+        self.counters.update(
+            first_rounds_s=t3 - t2,
+            rows=self.rows, features=self.features,
+            rows_padded=int(ds.row_mask.shape[0]),
+            code_bytes=int(ds.X_binned.dtype.itemsize))
+
+    def _call(self) -> None:
+        """The window's call and feed, used by set-up's checked rounds too."""
+        import jax
+
+        before = self.booster.current_iteration()
+        if FAULT != "state_unchanged":
+            with jax.profiler.TraceAnnotation("bench.update_many"):
+                self.booster.update_many(self.rounds_per_call)
+        with jax.profiler.TraceAnnotation("bench.block_until_ready"):
+            jax.block_until_ready(self.booster._pred_train)
+        if FAULT == "altered_answer" and before == 1:
+            # one answer altered where it is produced: a leaf of round 2
+            tree = self.booster.trees[1]
+            i = int(np.flatnonzero(np.asarray(tree.is_leaf))[0])
+            self.booster.trees[1] = tree._replace(
+                leaf_value=tree.leaf_value.at[i].multiply(1.5))
+
+    # -- the measured window -----------------------------------------------
+    def window(self, seconds: float) -> dict:
+        import jax
+
+        calls = 0
+        with self.meter.measure(), \
+                jax.profiler.TraceAnnotation("bench.window"):
+            t0 = time.perf_counter()
+            while True:
+                self._call()
+                calls += 1
+                elapsed = time.perf_counter() - t0
+                if elapsed >= seconds:
+                    break
+        rounds = calls * self.rounds_per_call
+        self.counters.update(window_rounds=rounds, window_calls=calls,
+                             window_compiles=self.meter.programs)
+        return {
+            "window_s": elapsed,
+            "attempted": calls,
+            "failed": 0,
+            "metrics": {
+                "train_rows_rounds_per_s": self.rows * rounds / elapsed,
+            },
+        }
+
+    # -- after the window --------------------------------------------------
+    def release(self) -> None:
+        """Take the program's answer to the host and free its device state."""
+        b = self.booster
+        total = b.current_iteration()
+        dump = b.dump_model()
+        self.trees = [gbdt_check.flatten_tree(t["tree_structure"])
+                      for t in dump["tree_info"]]
+        rng = np.random.default_rng(self.seed)
+        self.sample = np.sort(rng.choice(
+            self.rows, size=min(self.sample_rows, self.rows), replace=False))
+        self.final_sample_scores = np.asarray(b._pred_train)[self.sample]
+        self.counters["total_rounds"] = total
+        self.counters.update(self.round_memory())
+        del self.booster, self.dataset, b
+
+    def round_memory(self) -> dict:
+        """The compiled round's own account of its device memory (XLA's
+        ``memory_analysis``), to set beside the runtime's counters that
+        ``memory_peak_bytes`` is read from.  Read after the peak, so the
+        second copy of the program that this loads is not in it."""
+        try:
+            fn, args = self.booster._fused_segment(self.rounds_per_call)
+            ma = fn.lower(*args).compile().memory_analysis()
+            return {"round_temp_bytes": int(ma.temp_size_in_bytes),
+                    "round_argument_bytes": int(ma.argument_size_in_bytes),
+                    "round_output_bytes": int(ma.output_size_in_bytes)}
+        except Exception as e:       # the reading is a counter, not a check
+            return {"round_memory_error": 1, "round_memory_why": repr(e)}
+
+    def check(self) -> list:
+        """``[(name, value, limit)]``: a value over its limit is a fault."""
+        limits = self.config["limits"]
+        hyper = self.config["reference"]
+        k = self.checked_rounds
+        out = []
+        expected = k + self.counters.get("window_rounds", 0)
+        out.append(("trees_missing",
+                    float(abs(expected - len(self.trees))), 0.0))
+        out.append(("compiles_in_window",
+                    float(self.counters.get("window_compiles", 0)), 0.0))
+        if self.trees and "leaves_off" in limits:
+            leaves = [int((t["feature"] < 0).sum()) for t in self.trees]
+            out.append(("leaves_off", float(max(
+                abs(n - int(hyper["num_leaves"])) for n in leaves)),
+                limits["leaves_off"]))
+        if len(self.trees) >= k:
+            search = "split_gain_short" in limits or "order_excess" in limits
+            r = gbdt_check.check_rounds(
+                self.X, self.y, self.trees[:k], self.scores_after,
+                self.program_init, hyper, seed=self.seed,
+                split_nodes=self.split_nodes if search else 0,
+                order_leaves=self.order_leaves if search else 0)
+            for name in ("leaf_value_worst", "leaf_count_off", "score_abs",
+                         "split_gain_short", "order_excess"):
+                if name in limits:
+                    worst = max(rd[name] for rd in r["rounds"])
+                    out.append((name, float(worst), limits[name]))
+            if search:
+                self.counters["split_checks"] = [
+                    [rd["nodes_checked"], rd["leaves_checked"]]
+                    for rd in r["rounds"]]
+            # read, not compared: no control or fault reads far enough
+            # above the sound runs (PERF.md, section 2)
+            self.counters["leaf_value_rms"] = max(
+                rd["leaf_value_rms"] for rd in r["rounds"])
+            self.counters["reference_loss"] = [
+                rd["loss"] for rd in r["rounds"]]
+        if self.trees and "final_score_abs" in limits:
+            gap = gbdt_check.check_sample(
+                self.X[self.sample], self.trees,
+                gbdt_check.init_score(self.y),
+                float(hyper["learning_rate"]), self.final_sample_scores)
+            out.append(("final_score_abs", gap, limits["final_score_abs"]))
+        return out
