@@ -654,19 +654,6 @@ def bench_higgs_parity_auc(n=1_000_000, n_rounds=100, num_leaves=127):
 def main() -> None:
     import sys
 
-    if "--profile" in sys.argv:
-        from lightgbm_tpu.utils.datasets import make_higgs_like
-        from lightgbm_tpu.utils.profiling import profile_training
-
-        X, y = make_higgs_like(500_000)
-        rep = profile_training(
-            {"objective": "binary", "num_leaves": 127, "verbosity": -1},
-            X, y, num_boost_round=10)
-        for k, v in rep.items():
-            print(f"  {k:>18}: {v:.6g}" if isinstance(v, float)
-                  else f"  {k:>18}: {v}")
-        return
-
     if "--section" in sys.argv:          # dev: one section, full timeout
         expr = sys.argv[sys.argv.index("--section") + 1]
         print(json.dumps(_in_subprocess(expr, 3600)))

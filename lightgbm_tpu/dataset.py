@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from .config import Params, parse_params
+from .utils.profiling import span
 
 ROW_PAD_MULTIPLE = 256  # lane-friendly and shard-friendly (divides by 2,4,8 devices)
 
@@ -561,8 +562,6 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._constructed:
             return self
-        import jax.numpy as jnp  # deferred so Dataset import stays cheap
-
         if isinstance(self.raw_data, str):
             # a path: reload a save_binary() artifact (LightGBM's
             # Dataset('train.bin') contract)
@@ -572,8 +571,19 @@ class Dataset:
             self._load_binary(path)
             return self
 
+        with span("lgbtpu.dataset.construct") as fields:
+            self._construct_from_rows()
+            fields.update(rows=self.num_data_, features=self.num_feature_)
+        return self
+
+    def _construct_from_rows(self) -> None:
+        """Edges, codes, bundle and the copy to the device, each under its
+        own span (``lgbtpu.dataset.*``); the copy is not waited for here."""
+        import jax.numpy as jnp  # deferred so Dataset import stays cheap
+
         p = parse_params(self.params, warn_unknown=False)
-        X = _to_2d_float_array(self.raw_data)
+        with span("lgbtpu.dataset.to_float"):
+            X = _to_2d_float_array(self.raw_data)
         n, num_features = X.shape
         self.num_data_ = n
         self.num_feature_ = num_features
@@ -585,37 +595,44 @@ class Dataset:
             self.bin_mapper = self._reference.bin_mapper
         codes = None
         if self.bin_mapper is None:
-            self.bin_mapper = BinMapper.fit(
-                X, max_bin=p.max_bin, min_data_in_bin=p.min_data_in_bin,
-                categorical=cat_idx, seed=p.data_random_seed)
-            raw_codes = self.bin_mapper._transform_unbundled(X)
+            with span("lgbtpu.dataset.edges"):
+                self.bin_mapper = BinMapper.fit(
+                    X, max_bin=p.max_bin, min_data_in_bin=p.min_data_in_bin,
+                    categorical=cat_idx, seed=p.data_random_seed)
+            with span("lgbtpu.dataset.codes"):
+                raw_codes = self.bin_mapper._transform_unbundled(X)
             if p.enable_bundle:
-                self.bin_mapper.bundler = FeatureBundler.fit(
-                    raw_codes, self.bin_mapper.n_bins,
-                    max_conflict_rate=p.max_conflict_rate,
-                    exclude=self.bin_mapper.is_categorical)
-            b = self.bin_mapper.bundler
-            codes = raw_codes if b is None else b.merge(raw_codes)
+                with span("lgbtpu.dataset.bundle"):
+                    self.bin_mapper.bundler = FeatureBundler.fit(
+                        raw_codes, self.bin_mapper.n_bins,
+                        max_conflict_rate=p.max_conflict_rate,
+                        exclude=self.bin_mapper.is_categorical)
+                    b = self.bin_mapper.bundler
+                    codes = raw_codes if b is None else b.merge(raw_codes)
+            else:
+                codes = raw_codes
         if codes is None:
-            codes = self.bin_mapper.transform(X)
+            with span("lgbtpu.dataset.codes"):
+                codes = self.bin_mapper.transform(X)
         self.raw_num_feature_ = num_features
         if self.bin_mapper.bundler is not None:
             num_features = codes.shape[1]
             self.num_feature_ = num_features
 
-        n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
-        pad = n_pad - n
-        if pad:
-            codes = np.concatenate([codes, np.zeros((pad, num_features), np.uint8)], axis=0)
-        self.X_binned = jnp.asarray(codes)
-        mask = np.zeros(n_pad, dtype=np.float32)
-        mask[:n] = 1.0
-        self.row_mask = jnp.asarray(mask)
-        self._device_put_targets()
+        with span("lgbtpu.dataset.put"):
+            n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
+            pad = n_pad - n
+            if pad:
+                codes = np.concatenate(
+                    [codes, np.zeros((pad, num_features), np.uint8)], axis=0)
+            self.X_binned = jnp.asarray(codes)
+            mask = np.zeros(n_pad, dtype=np.float32)
+            mask[:n] = 1.0
+            self.row_mask = jnp.asarray(mask)
+            self._device_put_targets()
         self._constructed = True
         if self.free_raw_data:
             self.raw_data = None
-        return self
 
     def _device_put_targets(self) -> None:
         import jax.numpy as jnp

@@ -31,7 +31,9 @@ from ..objectives import Objective, create_objective
 from ..ops.lookup import lookup_values
 from ..ops.predict import predict_forest_binned, predict_tree_binned
 from ..ops.split import SplitContext
-from .tree import Tree, grow_tree, pad_tree, renew_leaf_values
+from ..utils import profiling
+from .tree import (Tree, decode_wave_width, grow_tree, pad_tree,
+                   renew_leaf_values)
 
 
 class HyperScalars(NamedTuple):
@@ -502,9 +504,10 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
     @jax.jit
     def round_fn(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
                  key):
-        g, h = obj.grad_hess(pred, y, w)
-        stats = jnp.stack([g * bag, h * bag, (bag > 0).astype(jnp.float32)],
-                          axis=-1)
+        with jax.named_scope("lgbtpu.grad"):
+            g, h = obj.grad_hess(pred, y, w)
+            stats = jnp.stack(
+                [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
         tree, row_leaf = grow_tree(
             bins, stats, feature_mask, hyper.ctx(), num_leaves, num_bins,
             hyper.max_depth, ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode),
@@ -517,8 +520,10 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
             rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
             tree = renew_leaf_values(tree, row_leaf, y - pred, rw,
                                      renew_alpha)
-        shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
-        new_pred = pred + shrink * lookup_values(row_leaf, tree.leaf_value)
+        with jax.named_scope("lgbtpu.pred_update"):
+            shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
+            new_pred = pred + shrink * lookup_values(row_leaf,
+                                                     tree.leaf_value)
         return tree, new_pred
 
     return round_fn
@@ -581,7 +586,8 @@ def _multi_round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
                 fmask = jnp.ones(num_features, jnp.float32)
             rkey = jax.random.fold_in(round_key, i)
             cat_info = _build_cat_info(cat_key, bins.shape[1])
-            g, h = obj.grad_hess(pred, y, w)
+            with jax.named_scope("lgbtpu.grad"):
+                g, h = obj.grad_hess(pred, y, w)
             if goss_k is not None:
                 tree, new_pred = _goss_compact_round(
                     bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
@@ -591,8 +597,10 @@ def _multi_round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
                     renew_scale=renew_scale, ic_member=ic_member,
                     bynode_off=bynode_off)
                 return (new_pred, bag), tree
-            stats = jnp.stack(
-                [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
+            with jax.named_scope("lgbtpu.grad"):
+                stats = jnp.stack(
+                    [g * bag, h * bag, (bag > 0).astype(jnp.float32)],
+                    axis=-1)
             tree, row_leaf = grow_tree(
                 bins, stats, fmask, hyper.ctx(), num_leaves, num_bins,
                 hyper.max_depth, ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode),
@@ -609,8 +617,9 @@ def _multi_round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
             if is_rf:
                 new_pred = pred
             else:
-                new_pred = pred + hyper.learning_rate * \
-                    lookup_values(row_leaf, tree.leaf_value)
+                with jax.named_scope("lgbtpu.pred_update"):
+                    new_pred = pred + hyper.learning_rate * \
+                        lookup_values(row_leaf, tree.leaf_value)
             return (new_pred, bag), tree
 
         (pred, bag), trees = lax.scan(
@@ -866,6 +875,7 @@ class Booster:
             return self.params.num_class
         return 1
 
+    @profiling.span("lgbtpu.train.setup")
     def _setup_training(self) -> None:
         ds = self.train_set
         ds.construct()
@@ -1892,6 +1902,7 @@ class Booster:
         return base if base is not None else jnp.ones(n_cols, jnp.float32)
 
     # -- round step ------------------------------------------------------
+    @profiling.span("lgbtpu.train.update")
     def update(self, train_set: Optional[Dataset] = None, fobj=None) -> bool:
         """Run one boosting round (LightGBM Booster.update)."""
         if train_set is not None and train_set is not self.train_set:
@@ -2198,38 +2209,47 @@ class Booster:
         """
         if k <= 0:
             return
-        if not self.can_fuse_rounds():
-            for _ in range(k):
-                self.update()
-            return
-        ds = self.train_set
-        p = self.params
-        # default segment length scales inversely with row count so one
-        # dispatch stays a few device-seconds at most; big data pays
-        # per-dispatch overhead rarely anyway — compute dominates there.
-        # TINY shapes (rows x features <= 2^20 cells — the diamonds
-        # regime) fuse up to 200 rounds into ONE dispatch: device time
-        # stays well under a second, and per-dispatch round trips are the
-        # entire wall-clock story there
-        n_pad = int(ds.row_mask.shape[0])
-        cells = n_pad * max(int(ds.X_binned.shape[1]), 1)
-        if cells <= (1 << 20):
-            seg_default = max(1, min(200, (1 << 24) // max(n_pad, 1)))
-        else:
-            seg_default = max(1, min(25, (1 << 22) // max(n_pad, 1)))
-        seg = max(1, int(p.extra.get("fused_segment_rounds", seg_default)))
-        while k > 0:
-            n_rounds = min(k, seg)
-            fn, args = self._fused_segment(n_rounds)
-            pred, bag, trees = fn(*args)
-            self._pred_train = pred
-            self._bag = bag
-            if not isinstance(self.trees, _TreeStore):
-                self.trees = _TreeStore(self.trees)   # e.g. loaded model
-            self.trees.append_stacked(trees, n_rounds)
-            self._iter += n_rounds
-            self._forest_cache = None
-            k -= n_rounds
+        with profiling.span("lgbtpu.train.update_many", rounds=k):
+            if not self.can_fuse_rounds():
+                for _ in range(k):
+                    self.update()
+                return
+            ds = self.train_set
+            p = self.params
+            # default segment length scales inversely with row count so
+            # one dispatch stays a few device-seconds at most; big data
+            # pays per-dispatch overhead rarely anyway — compute dominates
+            # there.  TINY shapes (rows x features <= 2^20 cells — the
+            # diamonds regime) fuse up to 200 rounds into ONE dispatch:
+            # device time stays well under a second, and per-dispatch
+            # round trips are the entire wall-clock story there
+            n_pad = int(ds.row_mask.shape[0])
+            cells = n_pad * max(int(ds.X_binned.shape[1]), 1)
+            if cells <= (1 << 20):
+                seg_default = max(1, min(200, (1 << 24) // max(n_pad, 1)))
+            else:
+                seg_default = max(1, min(25, (1 << 22) // max(n_pad, 1)))
+            seg = max(1, int(p.extra.get("fused_segment_rounds",
+                                         seg_default)))
+            while k > 0:
+                n_rounds = min(k, seg)
+                # the three phases of a dispatch, as the host sees them: the
+                # program lookup and its operands, the call (JAX's build
+                # seconds land here when it compiles), the bookkeeping
+                with profiling.span("lgbtpu.train.segment"):
+                    fn, args = self._fused_segment(n_rounds)
+                with profiling.span("lgbtpu.train.dispatch"):
+                    pred, bag, trees = fn(*args)
+                with profiling.span("lgbtpu.train.commit"):
+                    self._pred_train = pred
+                    self._bag = bag
+                    if not isinstance(self.trees, _TreeStore):
+                        # e.g. a loaded model's plain list
+                        self.trees = _TreeStore(self.trees)
+                    self.trees.append_stacked(trees, n_rounds)
+                    self._iter += n_rounds
+                    self._forest_cache = None
+                k -= n_rounds
 
     def _fused_segment(self, n_rounds: int):
         """``(fn, args)``: the jitted ``n_rounds``-round program and its
@@ -2245,12 +2265,23 @@ class Booster:
             goss_k = (int(p.top_rate * ds.num_data_),
                       int(p.other_rate * ds.num_data_))
             eff_rows = goss_k[0] + goss_k[1]
+        hist_dtype = resolve_hist_dtype(p, eff_rows)
+        wave_width = resolve_wave_width(p, eff_rows)
+        # what the pass that runs was decided to be: the shapes a roofline
+        # counts its work from (the benchmark's named metrics read them)
+        width, tail, overgrow = decode_wave_width(wave_width)
+        for fact, value in (
+                ("wave_width", min(width, (overgrow or p.num_leaves) - 1)),
+                ("wave_tail", tail), ("overgrow_leaves", overgrow),
+                ("hist_dtype", hist_dtype), ("rows_padded", eff_rows),
+                ("num_bins", self._num_bins),
+                ("features", int(ds.X_binned.shape[1]))):
+            profiling.note("train." + fact, value)
         fn = _multi_round_fn(
             self._obj_key, p.num_leaves, self._num_bins,
             p.extra.get("hist_impl", "auto"),
             int(p.extra.get("row_chunk", 131072)), p.boosting == "rf",
-            resolve_hist_dtype(p, eff_rows),
-            resolve_wave_width(p, eff_rows), n_rounds,
+            hist_dtype, wave_width, n_rounds,
             p.bagging_freq if use_bagging else 0,
             p.feature_fraction < 1.0,
             self._cat_key, goss_k, self._mono_key, p.extra_trees,

@@ -46,6 +46,16 @@ from ..ops.split import (
 # custom-call but inlines under interpret mode).  Never set in production.
 _SPLIT_ITER_OPCOUNT_STUB = False
 
+# The ROLE a histogram pass plays in a round, given to whichever Pallas
+# kernel serves it as its name in the compiled program and the device
+# trace; a PR that changes what implements a role keeps the name, so the
+# benchmark's named metrics still find it.  The stages of a round are
+# ``jax.named_scope``s of one vocabulary in both growers: lgbtpu.root,
+# lgbtpu.wave.{rank,hist,sibling,scan,commit}, lgbtpu.replay (and
+# lgbtpu.grad, lgbtpu.pred_update in models/gbdt.py).
+HIST_ROOT = "lgbtpu_hist_root"
+HIST_WAVE = "lgbtpu_hist_wave"
+
 
 class Tree(NamedTuple):
     """One tensorized decision tree (node arrays of length 2*num_leaves-1).
@@ -137,8 +147,9 @@ def decode_wave_width(wave_width: int):
 
     SINGLE SOURCE for the encoding produced by ``gbdt.resolve_wave_width``
     (negative = greedy tail; >= 1024 = exact tail, ``overgrow_leaves *
-    1024 + width``; else half) — the grower, the profiling report, and
-    the bench FLOP model all decode through here.
+    1024 + width``; else half) — the grower, the facts
+    ``Booster._fused_segment`` notes for the trace's metrics, and the
+    bench FLOP model all decode through here.
     """
     if wave_width < 0:
         return -wave_width, "greedy", None
@@ -735,14 +746,15 @@ def grow_tree(
         return _rand_bins_for_node(key, node_id, num_features, num_bins,
                                    col_bins)
 
-    def hist_fn(seg_id, num_segments):
+    def hist_fn(seg_id, num_segments, role):
         # custom-vmap op: under fold/config/class batching, calls sharing
         # this binned matrix collapse into ONE wide-matmul pass instead of
-        # per-element skinny matmuls (memory-bound otherwise)
+        # per-element skinny matmuls (memory-bound otherwise).  ``role``
+        # names the kernel for the trace (HIST_ROOT / HIST_WAVE).
         from ..ops.histogram import batched_histogram_op
 
         op = batched_histogram_op(num_segments, num_bins, row_chunk,
-                                  hist_impl, hist_dtype)
+                                  hist_impl, hist_dtype, role)
         h = op(bins, stats, seg_id)
         if hist_merge == "voting":
             return h       # local partials; the scorer merges candidates
@@ -753,41 +765,43 @@ def grow_tree(
     # ---- root -------------------------------------------------------------
     # under rs the merged root_hist is this shard's [F_pad/D, B, 3] slice;
     # under voting the LOCAL unmerged partial
-    root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1)[0]          # [F, B, 3]
-    if dist_mode:
-        # global totals without the full histogram: stats rows sum to the
-        # histogram totals by construction, so one [3]-element psum
-        # replaces reading bins of feature 0 from a (now sliced) histogram
-        root_tot = lax.psum(jnp.sum(stats, axis=0), axis_name)
-    else:
-        root_tot = jnp.sum(root_hist[0], axis=0)                 # (g, h, c)
-    # root output: unsmoothed (no parent), but still max_delta_step-capped
-    root_out = constrained_leaf_output(
-        root_tot[0], root_tot[1], root_tot[2],
-        ctx._replace(path_smooth=jnp.float32(0.0)),
-        jnp.float32(-jnp.inf), jnp.float32(jnp.inf), jnp.float32(0.0))
-    if ic_member is not None:
-        ng = ic_member.shape[0]
-        root_sets = jnp.ones((ng,), bool)
-        root_mask = node_feature_mask(0) * _ic_allowed(root_sets, ic_member)
-    else:
-        root_mask = node_feature_mask(0)
-    # LightGBM convention: max_depth <= 0 means unlimited, so the root
-    # (depth 0) is always splittable — if a limit exists it is >= 1.
-    if dist_mode:
-        rb0 = node_rand_bins(0)
-        root_best = jax.tree.map(lambda x: x[0], score_dist(
-            root_hist[None], root_mask[None], jnp.ones((1,), bool),
-            jnp.full((1,), -jnp.inf, jnp.float32),
-            jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
-            None if rb0 is None else rb0[None]))
-    else:
-        root_best = find_best_split(root_hist, ctx, root_mask,
-                                    jnp.bool_(True), cat_info, mono=mono,
-                                    parent_out=root_out,
-                                    rand_bins=node_rand_bins(0))
-    if fp_axis is not None:
-        root_best = _fp_reduce_best(root_best, fp_axis, num_features)
+    with jax.named_scope("lgbtpu.root"):
+        root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1,
+                            HIST_ROOT)[0]                    # [F, B, 3]
+        if dist_mode:
+            # global totals without the full histogram: stats rows sum to the
+            # histogram totals by construction, so one [3]-element psum
+            # replaces reading bins of feature 0 from a (now sliced) histogram
+            root_tot = lax.psum(jnp.sum(stats, axis=0), axis_name)
+        else:
+            root_tot = jnp.sum(root_hist[0], axis=0)                 # (g, h, c)
+        # root output: unsmoothed (no parent), but still max_delta_step-capped
+        root_out = constrained_leaf_output(
+            root_tot[0], root_tot[1], root_tot[2],
+            ctx._replace(path_smooth=jnp.float32(0.0)),
+            jnp.float32(-jnp.inf), jnp.float32(jnp.inf), jnp.float32(0.0))
+        if ic_member is not None:
+            ng = ic_member.shape[0]
+            root_sets = jnp.ones((ng,), bool)
+            root_mask = node_feature_mask(0) * _ic_allowed(root_sets, ic_member)
+        else:
+            root_mask = node_feature_mask(0)
+        # LightGBM convention: max_depth <= 0 means unlimited, so the root
+        # (depth 0) is always splittable — if a limit exists it is >= 1.
+        if dist_mode:
+            rb0 = node_rand_bins(0)
+            root_best = jax.tree.map(lambda x: x[0], score_dist(
+                root_hist[None], root_mask[None], jnp.ones((1,), bool),
+                jnp.full((1,), -jnp.inf, jnp.float32),
+                jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
+                None if rb0 is None else rb0[None]))
+        else:
+            root_best = find_best_split(root_hist, ctx, root_mask,
+                                        jnp.bool_(True), cat_info, mono=mono,
+                                        parent_out=root_out,
+                                        rand_bins=node_rand_bins(0))
+        if fp_axis is not None:
+            root_best = _fp_reduce_best(root_best, fp_axis, num_features)
 
     K = _PK
     st = _GrowState(
@@ -828,44 +842,46 @@ def grow_tree(
             feat = aux[0, 1].astype(jnp.int32)
             thr = aux[0, 2].astype(jnp.int32)
             active = aux[0, 3] > 0
-            nl, nr = n_nodes, n_nodes + 1
-            # partition + segment select stay in XLA (they touch the [n]
-            # row axis); everything table-sized moves into the kernel
-            col = jnp.take(bins_i32, feat, axis=1)
-            go_left = col <= thr
-            new_rl = jnp.where(row_leaf_c == leaf,
-                               jnp.where(go_left, nl, nr), row_leaf_c)
-            row_leaf2 = jnp.where(active, new_rl, row_leaf_c)
-            seg = jnp.where(row_leaf2 == nl, 0,
-                            jnp.where(row_leaf2 == nr, 1, 2)).astype(
-                                jnp.int32)
-            hist2 = hist_fn(seg, 2)                      # [2, F, B, 3]
-            scal = jnp.stack([
-                jnp.asarray(ctx.lambda_l1, f32),
-                jnp.asarray(ctx.lambda_l2, f32),
-                jnp.asarray(ctx.min_data_in_leaf, f32),
-                jnp.asarray(ctx.min_sum_hessian, f32),
-                jnp.asarray(ctx.min_gain_to_split, f32),
-                jnp.asarray(ctx.max_delta_step, f32),
-                jnp.asarray(ctx.path_smooth, f32),
-                md_f, n_nodes.astype(f32),
-                zero, zero, zero, zero, zero, zero, zero]).reshape(1, 16)
-            if _SPLIT_ITER_OPCOUNT_STUB:
-                # op-count probe (tools/hlo_counts.py): swap the kernel
-                # for a pure_callback so a CPU compile shows the same
-                # launch structure a TPU build has — XLA-side fusions
-                # plus ONE custom-call (interpret mode would inline the
-                # kernel instead).  Compile-only; never executed.
-                P2, aux2 = jax.pure_callback(
-                    lambda h, p, a: (p, a),
-                    (jax.ShapeDtypeStruct(P.shape, P.dtype),
-                     jax.ShapeDtypeStruct(aux.shape, aux.dtype)),
-                    hist2.transpose(0, 1, 3, 2), P, aux,
-                    vmap_method="legacy_vectorized")
-            else:
-                P2, aux2 = split_iter_pallas(
-                    hist2.transpose(0, 1, 3, 2), P, fmask_row, aux, scal,
-                    pk=_PK)
+            with jax.named_scope("lgbtpu.wave.hist"):
+                nl, nr = n_nodes, n_nodes + 1
+                # partition + segment select stay in XLA (they touch the [n]
+                # row axis); everything table-sized moves into the kernel
+                col = jnp.take(bins_i32, feat, axis=1)
+                go_left = col <= thr
+                new_rl = jnp.where(row_leaf_c == leaf,
+                                   jnp.where(go_left, nl, nr), row_leaf_c)
+                row_leaf2 = jnp.where(active, new_rl, row_leaf_c)
+                seg = jnp.where(row_leaf2 == nl, 0,
+                                jnp.where(row_leaf2 == nr, 1, 2)).astype(
+                                    jnp.int32)
+                hist2 = hist_fn(seg, 2, HIST_WAVE)           # [2, F, B, 3]
+            with jax.named_scope("lgbtpu.wave.scan"):
+                scal = jnp.stack([
+                    jnp.asarray(ctx.lambda_l1, f32),
+                    jnp.asarray(ctx.lambda_l2, f32),
+                    jnp.asarray(ctx.min_data_in_leaf, f32),
+                    jnp.asarray(ctx.min_sum_hessian, f32),
+                    jnp.asarray(ctx.min_gain_to_split, f32),
+                    jnp.asarray(ctx.max_delta_step, f32),
+                    jnp.asarray(ctx.path_smooth, f32),
+                    md_f, n_nodes.astype(f32),
+                    zero, zero, zero, zero, zero, zero, zero]).reshape(1, 16)
+                if _SPLIT_ITER_OPCOUNT_STUB:
+                    # op-count probe (tools/hlo_counts.py): swap the kernel
+                    # for a pure_callback so a CPU compile shows the same
+                    # launch structure a TPU build has — XLA-side fusions
+                    # plus ONE custom-call (interpret mode would inline the
+                    # kernel instead).  Compile-only; never executed.
+                    P2, aux2 = jax.pure_callback(
+                        lambda h, p, a: (p, a),
+                        (jax.ShapeDtypeStruct(P.shape, P.dtype),
+                         jax.ShapeDtypeStruct(aux.shape, aux.dtype)),
+                        hist2.transpose(0, 1, 3, 2), P, aux,
+                        vmap_method="legacy_vectorized")
+                else:
+                    P2, aux2 = split_iter_pallas(
+                        hist2.transpose(0, 1, 3, 2), P, fmask_row, aux, scal,
+                        pk=_PK)
             grew = jnp.where(active, 1, 0).astype(jnp.int32)
             return (P2, row_leaf2, n_nodes + 2 * grew, n_leaves + grew,
                     aux2)
@@ -877,119 +893,123 @@ def grow_tree(
 
     def body(_, st: _GrowState) -> _GrowState:
         P = st.nodes
-        # 1. pick the active leaf with the best cached gain (best-first).
-        gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
-        leaf = jnp.argmax(gains).astype(jnp.int32)
-        gain = gains[leaf]
-        active = (~st.done) & jnp.isfinite(gain)
+        with jax.named_scope("lgbtpu.wave.rank"):
+            # 1. pick the active leaf with the best cached gain (best-first).
+            gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
+            leaf = jnp.argmax(gains).astype(jnp.int32)
+            gain = gains[leaf]
+            active = (~st.done) & jnp.isfinite(gain)
 
-        nl = st.n_nodes
-        nr = st.n_nodes + 1
-        row = P[leaf]                       # [NC] — ONE gather for every
-        feat = row[K.CAND_FEAT].astype(jnp.int32)   # cached scalar below
-        thr = row[K.CAND_BIN].astype(jnp.int32)
+            nl = st.n_nodes
+            nr = st.n_nodes + 1
+            row = P[leaf]                       # [NC] — ONE gather for every
+            feat = row[K.CAND_FEAT].astype(jnp.int32)   # cached scalar below
+            thr = row[K.CAND_BIN].astype(jnp.int32)
 
-        # 2. partition rows of the split leaf (gather, no pointer chasing).
-        if fp_axis is not None:
-            col = _fp_column(bins_i32, feat, fp_axis, num_features)
-        else:
-            col = jnp.take(bins_i32, feat, axis=1)
-        if cat_info is None:
-            go_left = col <= thr
-        else:
-            go_left = jnp.where(row[K.CAND_CAT] > 0.5,
-                                st.cand_catmask[leaf][col], col <= thr)
-        new_rl = jnp.where(
-            st.row_leaf == leaf, jnp.where(go_left, nl, nr), st.row_leaf)
-        row_leaf = jnp.where(active, new_rl, st.row_leaf)
+        with jax.named_scope("lgbtpu.wave.hist"):
+            # 2. partition rows of the split leaf (gather, no pointer chasing).
+            if fp_axis is not None:
+                col = _fp_column(bins_i32, feat, fp_axis, num_features)
+            else:
+                col = jnp.take(bins_i32, feat, axis=1)
+            if cat_info is None:
+                go_left = col <= thr
+            else:
+                go_left = jnp.where(row[K.CAND_CAT] > 0.5,
+                                    st.cand_catmask[leaf][col], col <= thr)
+            new_rl = jnp.where(
+                st.row_leaf == leaf, jnp.where(go_left, nl, nr), st.row_leaf)
+            row_leaf = jnp.where(active, new_rl, st.row_leaf)
 
-        # 3. both children's histograms in one pass (others -> segment 2).
-        seg = jnp.where(row_leaf == nl, 0,
-                        jnp.where(row_leaf == nr, 1, 2)).astype(jnp.int32)
-        hist2 = hist_fn(seg, 2)                                  # [2, F, B, 3]
+            # 3. both children's histograms in one pass (others -> segment 2).
+            seg = jnp.where(row_leaf == nl, 0,
+                            jnp.where(row_leaf == nr, 1, 2)).astype(jnp.int32)
+            hist2 = hist_fn(seg, 2, HIST_WAVE)               # [2, F, B, 3]
 
-        # 4. child output bounds (monotone basic method).
-        wl_v, wr_v = row[K.CAND_WL], row[K.CAND_WR]
-        lo, hi = row[K.BOUND_LO], row[K.BOUND_HI]
-        lo_l, hi_l, lo_r, hi_r = _mono_child_bounds(mono, feat, wl_v, wr_v,
-                                                    lo, hi)
+        with jax.named_scope("lgbtpu.wave.scan"):
+            # 4. child output bounds (monotone basic method).
+            wl_v, wr_v = row[K.CAND_WL], row[K.CAND_WR]
+            lo, hi = row[K.BOUND_LO], row[K.BOUND_HI]
+            lo_l, hi_l, lo_r, hi_r = _mono_child_bounds(mono, feat, wl_v, wr_v,
+                                                        lo, hi)
 
-        # 5. candidate splits for the children (each child samples its own
-        # per-node feature subset when feature_fraction_bynode < 1).
-        child_depth = row[K.DEPTH] + 1.0
-        depth_ok = (max_depth <= 0) | \
-            (child_depth < max_depth.astype(jnp.float32))
-        child_masks = jnp.stack([node_feature_mask(nl), node_feature_mask(nr)])
-        if ic_member is not None:
-            child_sets = st.ic_sets[leaf] & ic_member[:, feat]   # [NG]
-            child_masks = child_masks * _ic_allowed(child_sets,
-                                                    ic_member)[None, :]
-        child_lo = jnp.stack([lo_l, lo_r])
-        child_hi = jnp.stack([hi_l, hi_r])
-        child_out = jnp.stack([wl_v, wr_v])
-        if dist_mode:
-            child_rand = (jnp.stack([node_rand_bins(nl), node_rand_bins(nr)])
-                          if extra_trees else None)
-            bs = score_dist(hist2, child_masks, jnp.stack([depth_ok,
-                                                           depth_ok]),
-                            child_lo, child_hi, child_out, child_rand)
-        elif extra_trees:
-            child_rand = jnp.stack([node_rand_bins(nl), node_rand_bins(nr)])
+            # 5. candidate splits for the children (each child samples its own
+            # per-node feature subset when feature_fraction_bynode < 1).
+            child_depth = row[K.DEPTH] + 1.0
+            depth_ok = (max_depth <= 0) | \
+                (child_depth < max_depth.astype(jnp.float32))
+            child_masks = jnp.stack([node_feature_mask(nl), node_feature_mask(nr)])
+            if ic_member is not None:
+                child_sets = st.ic_sets[leaf] & ic_member[:, feat]   # [NG]
+                child_masks = child_masks * _ic_allowed(child_sets,
+                                                        ic_member)[None, :]
+            child_lo = jnp.stack([lo_l, lo_r])
+            child_hi = jnp.stack([hi_l, hi_r])
+            child_out = jnp.stack([wl_v, wr_v])
+            if dist_mode:
+                child_rand = (jnp.stack([node_rand_bins(nl), node_rand_bins(nr)])
+                              if extra_trees else None)
+                bs = score_dist(hist2, child_masks, jnp.stack([depth_ok,
+                                                               depth_ok]),
+                                child_lo, child_hi, child_out, child_rand)
+            elif extra_trees:
+                child_rand = jnp.stack([node_rand_bins(nl), node_rand_bins(nr)])
 
-            def score(h, m, lo_, hi_, po, rb):
-                return find_best_split(h, ctx, m, depth_ok, cat_info, mono,
-                                       lo_, hi_, po, rb)
+                def score(h, m, lo_, hi_, po, rb):
+                    return find_best_split(h, ctx, m, depth_ok, cat_info, mono,
+                                           lo_, hi_, po, rb)
 
-            bs: BestSplit = jax.vmap(score)(hist2, child_masks, child_lo,
-                                            child_hi, child_out, child_rand)
-        else:
+                bs: BestSplit = jax.vmap(score)(hist2, child_masks, child_lo,
+                                                child_hi, child_out, child_rand)
+            else:
 
-            def score(h, m, lo_, hi_, po):
-                return find_best_split(h, ctx, m, depth_ok, cat_info, mono,
-                                       lo_, hi_, po)
+                def score(h, m, lo_, hi_, po):
+                    return find_best_split(h, ctx, m, depth_ok, cat_info, mono,
+                                           lo_, hi_, po)
 
-            bs = jax.vmap(score)(hist2, child_masks, child_lo, child_hi,
-                                 child_out)
-        if fp_axis is not None:
-            bs = jax.vmap(
-                lambda b: _fp_reduce_best(b, fp_axis, num_features))(bs)
+                bs = jax.vmap(score)(hist2, child_masks, child_lo, child_hi,
+                                     child_out)
+            if fp_axis is not None:
+                bs = jax.vmap(
+                    lambda b: _fp_reduce_best(b, fp_axis, num_features))(bs)
 
-        # 6. three packed row writes: the split leaf becomes internal, the
-        # two children arrive with their cached candidate splits.
-        leaf_row = row.at[jnp.array([
-            K.SPLIT_FEAT, K.SPLIT_BIN, K.LEFT, K.RIGHT, K.IS_LEAF,
-            K.SPLIT_GAIN])].set(jnp.stack([
-                feat.astype(jnp.float32), thr.astype(jnp.float32),
-                nl.astype(jnp.float32), nr.astype(jnp.float32),
-                jnp.float32(0.0), gain]))
-        two = lambda a, b: jnp.stack([a, b])
-        child_rows = jnp.stack([
-            jnp.full((2,), -1.0),                        # SPLIT_FEAT
-            jnp.zeros((2,)),                             # SPLIT_BIN
-            jnp.full((2,), -1.0),                        # LEFT
-            jnp.full((2,), -1.0),                        # RIGHT
-            two(wl_v, wr_v),                             # LEAF_VALUE
-            jnp.ones((2,)),                              # IS_LEAF
-            two(row[K.CAND_LC], row[K.CAND_RC]),         # COUNT
-            jnp.zeros((2,)),                             # SPLIT_GAIN
-            jnp.full((2,), child_depth),                 # DEPTH
-            bs.gain,                                     # CAND_GAIN
-            bs.feature.astype(jnp.float32),              # CAND_FEAT
-            bs.bin.astype(jnp.float32),                  # CAND_BIN
-            bs.left_g, bs.left_h, bs.left_c,
-            bs.right_g, bs.right_h, bs.right_c,
-            bs.left_out,                                 # CAND_WL
-            bs.right_out,                                # CAND_WR
-            two(lo_l, lo_r),                             # BOUND_LO
-            two(hi_l, hi_r),                             # BOUND_HI
-            (bs.cat.astype(jnp.float32) if cat_info is not None
-             else jnp.zeros((2,))),                      # CAND_CAT
-            jnp.minimum(row[K.PM], bs.gain),             # PM
-        ], axis=-1)                                      # [2, NC]
-        oob = jnp.int32(capacity)
-        P = P.at[jnp.where(active, leaf, oob)].set(leaf_row, mode="drop")
-        kid_idx = jnp.where(active, jnp.stack([nl, nr]), oob)
-        P = P.at[kid_idx].set(child_rows, mode="drop")
+        with jax.named_scope("lgbtpu.wave.commit"):
+            # 6. three packed row writes: the split leaf becomes internal, the
+            # two children arrive with their cached candidate splits.
+            leaf_row = row.at[jnp.array([
+                K.SPLIT_FEAT, K.SPLIT_BIN, K.LEFT, K.RIGHT, K.IS_LEAF,
+                K.SPLIT_GAIN])].set(jnp.stack([
+                    feat.astype(jnp.float32), thr.astype(jnp.float32),
+                    nl.astype(jnp.float32), nr.astype(jnp.float32),
+                    jnp.float32(0.0), gain]))
+            two = lambda a, b: jnp.stack([a, b])
+            child_rows = jnp.stack([
+                jnp.full((2,), -1.0),                        # SPLIT_FEAT
+                jnp.zeros((2,)),                             # SPLIT_BIN
+                jnp.full((2,), -1.0),                        # LEFT
+                jnp.full((2,), -1.0),                        # RIGHT
+                two(wl_v, wr_v),                             # LEAF_VALUE
+                jnp.ones((2,)),                              # IS_LEAF
+                two(row[K.CAND_LC], row[K.CAND_RC]),         # COUNT
+                jnp.zeros((2,)),                             # SPLIT_GAIN
+                jnp.full((2,), child_depth),                 # DEPTH
+                bs.gain,                                     # CAND_GAIN
+                bs.feature.astype(jnp.float32),              # CAND_FEAT
+                bs.bin.astype(jnp.float32),                  # CAND_BIN
+                bs.left_g, bs.left_h, bs.left_c,
+                bs.right_g, bs.right_h, bs.right_c,
+                bs.left_out,                                 # CAND_WL
+                bs.right_out,                                # CAND_WR
+                two(lo_l, lo_r),                             # BOUND_LO
+                two(hi_l, hi_r),                             # BOUND_HI
+                (bs.cat.astype(jnp.float32) if cat_info is not None
+                 else jnp.zeros((2,))),                      # CAND_CAT
+                jnp.minimum(row[K.PM], bs.gain),             # PM
+            ], axis=-1)                                      # [2, NC]
+            oob = jnp.int32(capacity)
+            P = P.at[jnp.where(active, leaf, oob)].set(leaf_row, mode="drop")
+            kid_idx = jnp.where(active, jnp.stack([nl, nr]), oob)
+            P = P.at[kid_idx].set(child_rows, mode="drop")
 
         return st._replace(
             nodes=P,
@@ -1297,11 +1317,11 @@ def grow_tree_frontier(
         return _rand_bins_for_node(key, node_id, num_features, num_bins,
                                    col_bins)
 
-    def hist_fn(seg_id, num_segments):
+    def hist_fn(seg_id, num_segments, role):
         from ..ops.histogram import batched_histogram_op
 
         op = batched_histogram_op(num_segments, num_bins, row_chunk,
-                                  hist_impl, hist_dtype)
+                                  hist_impl, hist_dtype, role)
         h = op(bins, stats, seg_id)
         if hist_merge == "voting":
             return h       # local partials; the scorer merges candidates
@@ -1310,42 +1330,44 @@ def grow_tree_frontier(
                                n_chunks=merge_chunks)
 
     # ---- root -------------------------------------------------------------
-    root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1)[0]      # [f_hist, B, 3]
-    if dist_mode:
-        # global totals from the stats rows (they sum to the histogram
-        # totals by construction) — one [3]-element psum instead of
-        # reading feature 0's bins from a sliced/unmerged histogram
-        root_tot = lax.psum(jnp.sum(stats, axis=0), axis_name)
-    else:
-        root_tot = jnp.sum(root_hist[0], axis=0)                 # (g, h, c)
-    root_out = constrained_leaf_output(
-        root_tot[0], root_tot[1], root_tot[2],
-        ctx._replace(path_smooth=jnp.float32(0.0)),
-        jnp.float32(-jnp.inf), jnp.float32(jnp.inf), jnp.float32(0.0))
-    if ic_member is not None:
-        root_mask_f = (node_feature_mask(0)
-                       * _ic_allowed(jnp.ones((ic_member.shape[0],), bool),
-                                     ic_member))
-    else:
-        root_mask_f = node_feature_mask(0)
-    if dist_mode:
-        rb0 = node_rand_bins(0)
-        root_best = jax.tree.map(lambda x: x[0], score_dist(
-            root_hist[None], root_mask_f[None], jnp.ones((1,), bool),
-            jnp.full((1,), -jnp.inf, jnp.float32),
-            jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
-            None if rb0 is None else rb0[None]))
-    else:
-        root_best = find_best_split(root_hist, ctx, root_mask_f,
-                                    jnp.bool_(True), cat_info, mono=mono,
-                                    parent_out=root_out,
-                                    rand_bins=node_rand_bins(0))
-    if fp_axis is not None:
-        # feature-parallel: each shard scanned its own column slice; one
-        # tiny all_gather + argmax globalizes the winner (the same split
-        # exchange the strict grower uses — upstream's
-        # FeatureParallelTreeLearner, SURVEY.md §2C)
-        root_best = _fp_reduce_best(root_best, fp_axis, num_features)
+    with jax.named_scope("lgbtpu.root"):
+        root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1,
+                            HIST_ROOT)[0]               # [f_hist, B, 3]
+        if dist_mode:
+            # global totals from the stats rows (they sum to the histogram
+            # totals by construction) — one [3]-element psum instead of
+            # reading feature 0's bins from a sliced/unmerged histogram
+            root_tot = lax.psum(jnp.sum(stats, axis=0), axis_name)
+        else:
+            root_tot = jnp.sum(root_hist[0], axis=0)                 # (g, h, c)
+        root_out = constrained_leaf_output(
+            root_tot[0], root_tot[1], root_tot[2],
+            ctx._replace(path_smooth=jnp.float32(0.0)),
+            jnp.float32(-jnp.inf), jnp.float32(jnp.inf), jnp.float32(0.0))
+        if ic_member is not None:
+            root_mask_f = (node_feature_mask(0)
+                           * _ic_allowed(jnp.ones((ic_member.shape[0],), bool),
+                                         ic_member))
+        else:
+            root_mask_f = node_feature_mask(0)
+        if dist_mode:
+            rb0 = node_rand_bins(0)
+            root_best = jax.tree.map(lambda x: x[0], score_dist(
+                root_hist[None], root_mask_f[None], jnp.ones((1,), bool),
+                jnp.full((1,), -jnp.inf, jnp.float32),
+                jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
+                None if rb0 is None else rb0[None]))
+        else:
+            root_best = find_best_split(root_hist, ctx, root_mask_f,
+                                        jnp.bool_(True), cat_info, mono=mono,
+                                        parent_out=root_out,
+                                        rand_bins=node_rand_bins(0))
+        if fp_axis is not None:
+            # feature-parallel: each shard scanned its own column slice; one
+            # tiny all_gather + argmax globalizes the winner (the same split
+            # exchange the strict grower uses — upstream's
+            # FeatureParallelTreeLearner, SURVEY.md §2C)
+            root_best = _fp_reduce_best(root_best, fp_axis, num_features)
 
     def full(val, dtype):
         return jnp.full((capacity,), val, dtype)
@@ -1396,310 +1418,316 @@ def grow_tree_frontier(
     def body(st: _WaveState) -> _WaveState:
         m = capacity
         P = st.nodes
-        # 1. rank active leaves by cached candidate gain (desc, stable).
-        # Exact mode ranks by PATHMIN instead: priority-first extraction
-        # order on a tree IS descending pathmin (see _exact_prune), so
-        # pm-ordered waves expand nodes in the same order strict growth
-        # would — the overgrown tree then CONTAINS the strict selection
-        # (no coverage misses at the replay), instead of greedy-by-gain
-        # overgrowth hoping to have covered it.
-        gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
-        sel_key = (jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.PM], neg_inf)
-                   if exact else gains)
-        order = jnp.argsort(-sel_key, stable=True)        # [M]
-        rank = jnp.zeros(m, jnp.int32).at[order].set(
-            lax.iota(jnp.int32, m))
-        budget = grow_leaves - st.n_leaves
-        n_cand = jnp.sum(jnp.isfinite(gains)).astype(jnp.int32)
-        # Wave size: every histogram pass costs the same (the one-hot
-        # matmul pads the segment lanes to a full MXU tile), so wave count
-        # IS tree cost.  Greedy (s = min(budget, W)) closes a 127-leaf tree
-        # in 8 passes; spending at most HALF the remaining budget per wave
-        # allocates the tail splits near-strict-best-first at ~5 extra
-        # passes.  The tail refinement is what preserves strict-growth
-        # quality when the leaf budget nearly saturates the data (small-n /
-        # large-num_leaves); ``wave_tail`` picks the tradeoff.  "exact"
-        # overgrows with the greedy schedule (the post-hoc replay, not the
-        # wave order, is what restores strict allocation).
-        if wave_tail == "half":
-            alloc = jnp.maximum(jnp.int32(1), budget // 2)
-        else:  # "greedy" / "exact"
-            alloc = budget
-        s = jnp.minimum(jnp.minimum(n_cand, alloc),
-                        jnp.int32(w_width))               # splits this wave
-        sel = jnp.isfinite(gains) & (rank < s)            # [M]
+        with jax.named_scope("lgbtpu.wave.rank"):
+            # 1. rank active leaves by cached candidate gain (desc, stable).
+            # Exact mode ranks by PATHMIN instead: priority-first extraction
+            # order on a tree IS descending pathmin (see _exact_prune), so
+            # pm-ordered waves expand nodes in the same order strict growth
+            # would — the overgrown tree then CONTAINS the strict selection
+            # (no coverage misses at the replay), instead of greedy-by-gain
+            # overgrowth hoping to have covered it.
+            gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
+            sel_key = (jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.PM], neg_inf)
+                       if exact else gains)
+            order = jnp.argsort(-sel_key, stable=True)        # [M]
+            rank = jnp.zeros(m, jnp.int32).at[order].set(
+                lax.iota(jnp.int32, m))
+            budget = grow_leaves - st.n_leaves
+            n_cand = jnp.sum(jnp.isfinite(gains)).astype(jnp.int32)
+            # Wave size: every histogram pass costs the same (the one-hot
+            # matmul pads the segment lanes to a full MXU tile), so wave count
+            # IS tree cost.  Greedy (s = min(budget, W)) closes a 127-leaf tree
+            # in 8 passes; spending at most HALF the remaining budget per wave
+            # allocates the tail splits near-strict-best-first at ~5 extra
+            # passes.  The tail refinement is what preserves strict-growth
+            # quality when the leaf budget nearly saturates the data (small-n /
+            # large-num_leaves); ``wave_tail`` picks the tradeoff.  "exact"
+            # overgrows with the greedy schedule (the post-hoc replay, not the
+            # wave order, is what restores strict allocation).
+            if wave_tail == "half":
+                alloc = jnp.maximum(jnp.int32(1), budget // 2)
+            else:  # "greedy" / "exact"
+                alloc = budget
+            s = jnp.minimum(jnp.minimum(n_cand, alloc),
+                            jnp.int32(w_width))               # splits this wave
+            sel = jnp.isfinite(gains) & (rank < s)            # [M]
 
-        # 2. partition rows of all splitting leaves at once.  Per-row state
-        # comes from ONE one-hot-matmul table lookup (ops.lookup): XLA's
-        # native [n]-from-[capacity] gathers cost ~7 ms each at 1M rows on
-        # TPU, and this block needs six of them — more than the histogram
-        # kernel itself.
-        parent_r = order[:w_width]                        # [W] node ids
-        active_r = iota_w < s
-        prow = P[parent_r]            # [W, NC] — ONE gather for all the
-        direct_left = prow[:, K.CAND_LC] <= prow[:, K.CAND_RC]  # per-parent
-        nl_r = st.n_nodes + 2 * iota_w                          # scalars
-        nr_r = nl_r + 1
-        dl_of = _scatter(full(m, jnp.bool_), parent_r, direct_left,
-                         active_r)                        # node -> direct side
-        p = st.row_leaf
-        f32 = jnp.float32
-        if fuse_part:
-            # 2+3 FUSED: one transposed per-row lookup of the wave's node
-            # fields, then the pallas kernel routes rows AND builds the
-            # direct-child histograms in a single pass (phase-1 feature
-            # select + phase-2 folded dots — _fused_part_kernel).  The
-            # one-hot compares against the W SPLITTING PARENTS only, not
-            # the full node table (rows in any other leaf produce an
-            # all-zero column = sel 0, exactly the wanted semantics) —
-            # the full-table compare was ~6 ms/wave at 11M rows.  Table
-            # values (sel/feat/thr/rank2/dl) are all <= 256 under the
-            # single-f-block gate, so the dot stays bf16-exact.
-            zw = jnp.zeros(w_width)
-            tbl_w = jnp.stack([active_r.astype(f32),
-                               prow[:, K.CAND_FEAT], prow[:, K.CAND_BIN],
-                               (2 * iota_w).astype(f32),
-                               direct_left.astype(f32), zw, zw, zw],
-                              axis=1)                        # [W, 8]
-            oh_w = (parent_r[:, None] == p[None, :])         # [W, n]
-            pv_t = lax.dot_general(
-                tbl_w.astype(f32).T, oh_w.astype(f32),
+        with jax.named_scope("lgbtpu.wave.hist"):
+            # 2. partition rows of all splitting leaves at once.  Per-row state
+            # comes from ONE one-hot-matmul table lookup (ops.lookup): XLA's
+            # native [n]-from-[capacity] gathers cost ~7 ms each at 1M rows on
+            # TPU, and this block needs six of them — more than the histogram
+            # kernel itself.
+            parent_r = order[:w_width]                        # [W] node ids
+            active_r = iota_w < s
+            prow = P[parent_r]            # [W, NC] — ONE gather for all the
+            direct_left = prow[:, K.CAND_LC] <= prow[:, K.CAND_RC]  # per-parent
+            nl_r = st.n_nodes + 2 * iota_w                          # scalars
+            nr_r = nl_r + 1
+            dl_of = _scatter(full(m, jnp.bool_), parent_r, direct_left,
+                             active_r)                        # node -> direct side
+            p = st.row_leaf
+            f32 = jnp.float32
+            if fuse_part:
+                # 2+3 FUSED: one transposed per-row lookup of the wave's node
+                # fields, then the pallas kernel routes rows AND builds the
+                # direct-child histograms in a single pass (phase-1 feature
+                # select + phase-2 folded dots — _fused_part_kernel).  The
+                # one-hot compares against the W SPLITTING PARENTS only, not
+                # the full node table (rows in any other leaf produce an
+                # all-zero column = sel 0, exactly the wanted semantics) —
+                # the full-table compare was ~6 ms/wave at 11M rows.  Table
+                # values (sel/feat/thr/rank2/dl) are all <= 256 under the
+                # single-f-block gate, so the dot stays bf16-exact.
+                zw = jnp.zeros(w_width)
+                tbl_w = jnp.stack([active_r.astype(f32),
+                                   prow[:, K.CAND_FEAT], prow[:, K.CAND_BIN],
+                                   (2 * iota_w).astype(f32),
+                                   direct_left.astype(f32), zw, zw, zw],
+                                  axis=1)                        # [W, 8]
+                oh_w = (parent_r[:, None] == p[None, :])         # [W, n]
+                pv_t = lax.dot_general(
+                    tbl_w.astype(f32).T, oh_w.astype(f32),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=lax.Precision.DEFAULT)             # [8, n]
+                if n_pad_rows != n:
+                    pv_t = jnp.pad(pv_t, ((0, 0), (0, n_pad_rows - n)))
+                direct_hist, enc = hist_partition_fused_pallas(
+                    bins_t_prep, stats_t_prep, pv_t, w_width, num_bins,
+                    part_chunk,
+                    hist_dtype=("f32" if hist_dtype in ("f32", "f32x")
+                                else "bf16"),
+                    # multi-f-block routing gathers the wave split features'
+                    # code rows; ignored on single-block shapes
+                    wfeat=prow[:, K.CAND_FEAT].astype(jnp.int32),
+                    num_features=num_features, name=HIST_WAVE)
+                # the kernel's direct_hist is the LOCAL pre-merge [W, F, B, 3]
+                # partial, so every merge topology applies after it unchanged
+                # (voting keeps it unmerged for the scorer's candidate union)
+                if hist_merge != "voting":
+                    direct_hist = histogram_merge(direct_hist, axis_name,
+                                                  mode=hist_merge,
+                                                  n_shards=n_shards,
+                                                  wire_dtype=hist_wire,
+                                                  n_chunks=merge_chunks)
+                enc = enc[:n]
+                row_leaf = jnp.where(enc > 0, st.n_nodes + enc - 1, p)
+            else:
+                # child ids ride as WAVE-RELATIVE offsets (2*rank <= 2W <=
+                # 256), not absolute node ids: absolute ids exceed 256
+                # whenever the (overgrown) capacity does, which would force
+                # the HIGHEST-precision dot below.  child = n_nodes + offset
+                # reconstructs the absolute id after the lookup.
+                cols = [sel.astype(f32), P[:, K.CAND_FEAT],
+                        P[:, K.CAND_BIN], (2 * rank).astype(f32),
+                        dl_of.astype(f32)]
+                if cat_info is not None:
+                    cols.append(P[:, K.CAND_CAT])
+                # DEFAULT precision (native-rate bf16 dot) is exact only while
+                # every table value is an integer <= 256 (bf16 has an 8-bit
+                # significand); feature ids beyond 256 need the full-precision
+                # dot or rows partition on corrupted ids.  (The one-hot INDEX
+                # side is exact at any capacity — only table VALUES are
+                # constrained.)  Under feature sharding the table carries
+                # GLOBAL feature ids whose range this shard cannot bound
+                # statically — always exact there.
+                exact_in_bf16 = (fp_axis is None
+                                 and max(num_features, 2 * w_width,
+                                         num_bins) <= 256)
+                pv = lookup_rows(p, jnp.stack(cols, axis=1),
+                                 precision=(lax.Precision.DEFAULT
+                                            if exact_in_bf16
+                                            else lax.Precision.HIGHEST))
+                psel = pv[:, 0] > 0
+                feat_r = pv[:, 1].astype(jnp.int32)
+                thr_r = pv[:, 2]
+                # per-row split value WITHOUT take_along_axis (same gather
+                # problem): masked lane-reduction over the feature axis.
+                # Under feature sharding the ids are global: match against
+                # this shard's global column range and psum — the owning
+                # shard contributes the codes (the [n] bitmap exchange of
+                # upstream's feature-parallel split, batched over the wave)
+                if fp_axis is not None:
+                    gids = (lax.axis_index(fp_axis) * num_features
+                            + lax.iota(jnp.int32, num_features))
+                    fmatch = feat_r[:, None] == gids[None, :]
+                    v = lax.psum(
+                        jnp.sum(jnp.where(fmatch, bins_i32, 0), axis=1),
+                        fp_axis)
+                else:
+                    fmatch = (feat_r[:, None]
+                              == lax.iota(jnp.int32, num_features)[None, :])
+                    v = jnp.sum(jnp.where(fmatch, bins_i32, 0), axis=1)
+                if cat_info is None:
+                    go_left = v.astype(f32) <= thr_r
+                else:
+                    # category-subset membership: one-hot lookup of the row's
+                    # mask row, then select bit v — both stay fused
+                    mrow = lookup_rows(p, st.cand_catmask.astype(f32),
+                                       precision=lax.Precision.DEFAULT)
+                    bit = jnp.sum(
+                        jnp.where(v[:, None]
+                                  == lax.iota(jnp.int32, num_bins)[None, :],
+                                  mrow, 0.0), axis=1)
+                    go_left = jnp.where(pv[:, 5] > 0, bit > 0,
+                                        v.astype(f32) <= thr_r)
+                rank2_r = pv[:, 3].astype(jnp.int32)
+                child = st.n_nodes + rank2_r + jnp.where(go_left, 0, 1)
+                row_leaf = jnp.where(psel, child, p)
+
+                # 3. one histogram pass over the SMALLER child of every
+                # split: a row participates iff its leaf splits this wave AND
+                # it went to the direct (smaller) side; its segment is the
+                # leaf's wave rank.
+                to_direct = psel & (go_left == (pv[:, 4] > 0))
+                seg_id = jnp.where(to_direct, rank2_r >> 1, w_width)
+                direct_hist = hist_fn(seg_id, w_width,
+                                      HIST_WAVE)      # [W, F, B, 3]
+
+        with jax.named_scope("lgbtpu.wave.sibling"):
+            # 4. sibling = parent - child (the subtraction trick).  The cache
+            # gather and update are ONE-HOT MATMULS, not gather/scatter ops:
+            # the r5 trace showed XLA materializing wholesale copies of the
+            # [grow_leaves, F, B, 3] cache around the scatter (two ~59 ms
+            # async copies per wave at the 11M o2.0 shape, co-critical with
+            # the kernel stream), while the matmul form reads the cache once
+            # and commits a pure += the while-carry can alias in place.
+            # Exactness: one-hot factors are exact at every precision and
+            # HIGHEST keeps the f32 cache values bit-exact.
+            fb3 = f_hist * num_bins * 3
+            cache_flat = st.hist_cache.reshape(grow_leaves, fb3)
+            parent_slot = st.node_slot[parent_r]              # [W]
+            oh_p = (parent_slot[:, None]
+                    == lax.iota(jnp.int32, grow_leaves)[None, :])
+            parent_hist = lax.dot_general(
+                oh_p.astype(f32), cache_flat,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-                precision=lax.Precision.DEFAULT)             # [8, n]
-            if n_pad_rows != n:
-                pv_t = jnp.pad(pv_t, ((0, 0), (0, n_pad_rows - n)))
-            direct_hist, enc = hist_partition_fused_pallas(
-                bins_t_prep, stats_t_prep, pv_t, w_width, num_bins,
-                part_chunk,
-                hist_dtype=("f32" if hist_dtype in ("f32", "f32x")
-                            else "bf16"),
-                # multi-f-block routing gathers the wave split features'
-                # code rows; ignored on single-block shapes
-                wfeat=prow[:, K.CAND_FEAT].astype(jnp.int32),
-                num_features=num_features)
-            # the kernel's direct_hist is the LOCAL pre-merge [W, F, B, 3]
-            # partial, so every merge topology applies after it unchanged
-            # (voting keeps it unmerged for the scorer's candidate union)
-            if hist_merge != "voting":
-                direct_hist = histogram_merge(direct_hist, axis_name,
-                                              mode=hist_merge,
-                                              n_shards=n_shards,
-                                              wire_dtype=hist_wire,
-                                              n_chunks=merge_chunks)
-            enc = enc[:n]
-            row_leaf = jnp.where(enc > 0, st.n_nodes + enc - 1, p)
-        else:
-            # child ids ride as WAVE-RELATIVE offsets (2*rank <= 2W <=
-            # 256), not absolute node ids: absolute ids exceed 256
-            # whenever the (overgrown) capacity does, which would force
-            # the HIGHEST-precision dot below.  child = n_nodes + offset
-            # reconstructs the absolute id after the lookup.
-            cols = [sel.astype(f32), P[:, K.CAND_FEAT],
-                    P[:, K.CAND_BIN], (2 * rank).astype(f32),
-                    dl_of.astype(f32)]
-            if cat_info is not None:
-                cols.append(P[:, K.CAND_CAT])
-            # DEFAULT precision (native-rate bf16 dot) is exact only while
-            # every table value is an integer <= 256 (bf16 has an 8-bit
-            # significand); feature ids beyond 256 need the full-precision
-            # dot or rows partition on corrupted ids.  (The one-hot INDEX
-            # side is exact at any capacity — only table VALUES are
-            # constrained.)  Under feature sharding the table carries
-            # GLOBAL feature ids whose range this shard cannot bound
-            # statically — always exact there.
-            exact_in_bf16 = (fp_axis is None
-                             and max(num_features, 2 * w_width,
-                                     num_bins) <= 256)
-            pv = lookup_rows(p, jnp.stack(cols, axis=1),
-                             precision=(lax.Precision.DEFAULT
-                                        if exact_in_bf16
-                                        else lax.Precision.HIGHEST))
-            psel = pv[:, 0] > 0
-            feat_r = pv[:, 1].astype(jnp.int32)
-            thr_r = pv[:, 2]
-            # per-row split value WITHOUT take_along_axis (same gather
-            # problem): masked lane-reduction over the feature axis.
-            # Under feature sharding the ids are global: match against
-            # this shard's global column range and psum — the owning
-            # shard contributes the codes (the [n] bitmap exchange of
-            # upstream's feature-parallel split, batched over the wave)
+                precision=lax.Precision.HIGHEST,
+            ).reshape(w_width, f_hist, num_bins, 3)
+            other_hist = parent_hist - direct_hist
+            dl = direct_left[:, None, None, None]
+            left_hist = jnp.where(dl, direct_hist, other_hist)
+            right_hist = jnp.where(dl, other_hist, direct_hist)
+
+            left_slot = parent_slot                           # reuse parent slot
+            right_slot = st.n_leaves + iota_w
+            # mask-and-add: zero the overwritten rows, matmul-add the EXACT
+            # new values (a delta formulation would set left = parent +
+            # (left - parent), off by ~ulp(parent) in f32 — an error the old
+            # scatter never had, compounding through future subtractions)
+            slot2 = jnp.concatenate([left_slot, right_slot])  # [2W]
+            act2w = jnp.concatenate([active_r, active_r])
+            slot2m = jnp.where(act2w, slot2, -1)
+            q = (lax.iota(jnp.int32, grow_leaves)[:, None]
+                 == slot2m[None, :])                          # [L, 2W]
+            keep = 1.0 - jnp.any(q, axis=1).astype(f32)       # [L]
+            newvals = jnp.concatenate([left_hist, right_hist])
+            cache = (cache_flat * keep[:, None] + lax.dot_general(
+                q.astype(f32), newvals.reshape(2 * w_width, fb3),
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=lax.Precision.HIGHEST,
+            )).reshape(st.hist_cache.shape)
+            node_slot = _scatter(st.node_slot, nl_r, left_slot, active_r)
+            node_slot = _scatter(node_slot, nr_r, right_slot, active_r)
+
+        with jax.named_scope("lgbtpu.wave.scan"):
+            # 5. child output bounds (monotone basic method, per splitting leaf).
+            pf = prow[:, K.CAND_FEAT].astype(jnp.int32)
+            wl_w, wr_w = prow[:, K.CAND_WL], prow[:, K.CAND_WR]       # [W]
+            lo_w, hi_w = prow[:, K.BOUND_LO], prow[:, K.BOUND_HI]
+            lo_l, hi_l, lo_r, hi_r = _mono_child_bounds(mono, pf, wl_w, wr_w,
+                                                        lo_w, hi_w)
+
+            # 6. score candidates for all 2W fresh children from the cache.
+            child_nodes = jnp.concatenate([nl_r, nr_r])       # [2W]
+            child_hists = jnp.concatenate([left_hist, right_hist])
+            child_depth1 = prow[:, K.DEPTH] + 1.0             # [W]
+            child_depth = jnp.concatenate([child_depth1, child_depth1])
+            depth_ok = (max_depth <= 0) | \
+                (child_depth < max_depth.astype(jnp.float32))
+            child_masks = jax.vmap(node_feature_mask)(child_nodes)
+            if ic_member is not None:
+                child_sets = (st.ic_sets[parent_r]
+                              & ic_member[:, pf].T)              # [W, NG]
+                allowed_w = _ic_allowed(child_sets, ic_member)   # [W, F]
+                child_masks = child_masks * jnp.concatenate(
+                    [allowed_w, allowed_w])
+            child_lo = jnp.concatenate([lo_l, lo_r])
+            child_hi = jnp.concatenate([hi_l, hi_r])
+            child_vals = jnp.concatenate([wl_w, wr_w])        # actual outputs
+            if dist_mode:
+                child_rand = (jax.vmap(node_rand_bins)(child_nodes)
+                              if extra_trees else None)
+                bs = score_dist(child_hists, child_masks, depth_ok, child_lo,
+                                child_hi, child_vals, child_rand)
+            elif extra_trees:
+                child_rand = jax.vmap(node_rand_bins)(child_nodes)
+
+                def score(h, m, d, lo_, hi_, po, rb):
+                    return find_best_split(h, ctx, m, d, cat_info, mono,
+                                           lo_, hi_, po, rb)
+
+                bs: BestSplit = jax.vmap(score)(
+                    child_hists, child_masks, depth_ok, child_lo, child_hi,
+                    child_vals, child_rand)
+            else:
+
+                def score(h, m, d, lo_, hi_, po):
+                    return find_best_split(h, ctx, m, d, cat_info, mono,
+                                           lo_, hi_, po)
+
+                bs = jax.vmap(score)(child_hists, child_masks, depth_ok,
+                                     child_lo, child_hi, child_vals)
             if fp_axis is not None:
-                gids = (lax.axis_index(fp_axis) * num_features
-                        + lax.iota(jnp.int32, num_features))
-                fmatch = feat_r[:, None] == gids[None, :]
-                v = lax.psum(
-                    jnp.sum(jnp.where(fmatch, bins_i32, 0), axis=1),
-                    fp_axis)
-            else:
-                fmatch = (feat_r[:, None]
-                          == lax.iota(jnp.int32, num_features)[None, :])
-                v = jnp.sum(jnp.where(fmatch, bins_i32, 0), axis=1)
-            if cat_info is None:
-                go_left = v.astype(f32) <= thr_r
-            else:
-                # category-subset membership: one-hot lookup of the row's
-                # mask row, then select bit v — both stay fused
-                mrow = lookup_rows(p, st.cand_catmask.astype(f32),
-                                   precision=lax.Precision.DEFAULT)
-                bit = jnp.sum(
-                    jnp.where(v[:, None]
-                              == lax.iota(jnp.int32, num_bins)[None, :],
-                              mrow, 0.0), axis=1)
-                go_left = jnp.where(pv[:, 5] > 0, bit > 0,
-                                    v.astype(f32) <= thr_r)
-            rank2_r = pv[:, 3].astype(jnp.int32)
-            child = st.n_nodes + rank2_r + jnp.where(go_left, 0, 1)
-            row_leaf = jnp.where(psel, child, p)
+                # globalize all 2W child winners in one batched all_gather
+                bs = jax.vmap(
+                    lambda b: _fp_reduce_best(b, fp_axis, num_features))(bs)
+            active_2 = jnp.concatenate([active_r, active_r])
 
-            # 3. one histogram pass over the SMALLER child of every
-            # split: a row participates iff its leaf splits this wave AND
-            # it went to the direct (smaller) side; its segment is the
-            # leaf's wave rank.
-            to_direct = psel & (go_left == (pv[:, 4] > 0))
-            seg_id = jnp.where(to_direct, rank2_r >> 1, w_width)
-            direct_hist = hist_fn(seg_id, w_width)        # [W, F, B, 3]
-
-        # 4. sibling = parent - child (the subtraction trick).  The cache
-        # gather and update are ONE-HOT MATMULS, not gather/scatter ops:
-        # the r5 trace showed XLA materializing wholesale copies of the
-        # [grow_leaves, F, B, 3] cache around the scatter (two ~59 ms
-        # async copies per wave at the 11M o2.0 shape, co-critical with
-        # the kernel stream), while the matmul form reads the cache once
-        # and commits a pure += the while-carry can alias in place.
-        # Exactness: one-hot factors are exact at every precision and
-        # HIGHEST keeps the f32 cache values bit-exact.
-        fb3 = f_hist * num_bins * 3
-        cache_flat = st.hist_cache.reshape(grow_leaves, fb3)
-        parent_slot = st.node_slot[parent_r]              # [W]
-        oh_p = (parent_slot[:, None]
-                == lax.iota(jnp.int32, grow_leaves)[None, :])
-        parent_hist = lax.dot_general(
-            oh_p.astype(f32), cache_flat,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        ).reshape(w_width, f_hist, num_bins, 3)
-        other_hist = parent_hist - direct_hist
-        dl = direct_left[:, None, None, None]
-        left_hist = jnp.where(dl, direct_hist, other_hist)
-        right_hist = jnp.where(dl, other_hist, direct_hist)
-
-        left_slot = parent_slot                           # reuse parent slot
-        right_slot = st.n_leaves + iota_w
-        # mask-and-add: zero the overwritten rows, matmul-add the EXACT
-        # new values (a delta formulation would set left = parent +
-        # (left - parent), off by ~ulp(parent) in f32 — an error the old
-        # scatter never had, compounding through future subtractions)
-        slot2 = jnp.concatenate([left_slot, right_slot])  # [2W]
-        act2w = jnp.concatenate([active_r, active_r])
-        slot2m = jnp.where(act2w, slot2, -1)
-        q = (lax.iota(jnp.int32, grow_leaves)[:, None]
-             == slot2m[None, :])                          # [L, 2W]
-        keep = 1.0 - jnp.any(q, axis=1).astype(f32)       # [L]
-        newvals = jnp.concatenate([left_hist, right_hist])
-        cache = (cache_flat * keep[:, None] + lax.dot_general(
-            q.astype(f32), newvals.reshape(2 * w_width, fb3),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        )).reshape(st.hist_cache.shape)
-        node_slot = _scatter(st.node_slot, nl_r, left_slot, active_r)
-        node_slot = _scatter(node_slot, nr_r, right_slot, active_r)
-
-        # 5. child output bounds (monotone basic method, per splitting leaf).
-        pf = prow[:, K.CAND_FEAT].astype(jnp.int32)
-        wl_w, wr_w = prow[:, K.CAND_WL], prow[:, K.CAND_WR]       # [W]
-        lo_w, hi_w = prow[:, K.BOUND_LO], prow[:, K.BOUND_HI]
-        lo_l, hi_l, lo_r, hi_r = _mono_child_bounds(mono, pf, wl_w, wr_w,
-                                                    lo_w, hi_w)
-
-        # 6. score candidates for all 2W fresh children from the cache.
-        child_nodes = jnp.concatenate([nl_r, nr_r])       # [2W]
-        child_hists = jnp.concatenate([left_hist, right_hist])
-        child_depth1 = prow[:, K.DEPTH] + 1.0             # [W]
-        child_depth = jnp.concatenate([child_depth1, child_depth1])
-        depth_ok = (max_depth <= 0) | \
-            (child_depth < max_depth.astype(jnp.float32))
-        child_masks = jax.vmap(node_feature_mask)(child_nodes)
-        if ic_member is not None:
-            child_sets = (st.ic_sets[parent_r]
-                          & ic_member[:, pf].T)              # [W, NG]
-            allowed_w = _ic_allowed(child_sets, ic_member)   # [W, F]
-            child_masks = child_masks * jnp.concatenate(
-                [allowed_w, allowed_w])
-        child_lo = jnp.concatenate([lo_l, lo_r])
-        child_hi = jnp.concatenate([hi_l, hi_r])
-        child_vals = jnp.concatenate([wl_w, wr_w])        # actual outputs
-        if dist_mode:
-            child_rand = (jax.vmap(node_rand_bins)(child_nodes)
-                          if extra_trees else None)
-            bs = score_dist(child_hists, child_masks, depth_ok, child_lo,
-                            child_hi, child_vals, child_rand)
-        elif extra_trees:
-            child_rand = jax.vmap(node_rand_bins)(child_nodes)
-
-            def score(h, m, d, lo_, hi_, po, rb):
-                return find_best_split(h, ctx, m, d, cat_info, mono,
-                                       lo_, hi_, po, rb)
-
-            bs: BestSplit = jax.vmap(score)(
-                child_hists, child_masks, depth_ok, child_lo, child_hi,
-                child_vals, child_rand)
-        else:
-
-            def score(h, m, d, lo_, hi_, po):
-                return find_best_split(h, ctx, m, d, cat_info, mono,
-                                       lo_, hi_, po)
-
-            bs = jax.vmap(score)(child_hists, child_masks, depth_ok,
-                                 child_lo, child_hi, child_vals)
-        if fp_axis is not None:
-            # globalize all 2W child winners in one batched all_gather
-            bs = jax.vmap(
-                lambda b: _fp_reduce_best(b, fp_axis, num_features))(bs)
-        active_2 = jnp.concatenate([active_r, active_r])
-
-        # 7. commit with TWO packed row scatters: the W split parents
-        # become internal (their rows keep every cached field and gain
-        # the split bookkeeping), the 2W fresh children arrive with
-        # their scored candidate splits.
-        parent_rows = prow.at[:, jnp.array([
-            K.SPLIT_FEAT, K.SPLIT_BIN, K.LEFT, K.RIGHT, K.IS_LEAF,
-            K.SPLIT_GAIN])].set(jnp.stack([
-                prow[:, K.CAND_FEAT], prow[:, K.CAND_BIN],
-                nl_r.astype(jnp.float32), nr_r.astype(jnp.float32),
-                jnp.zeros(w_width), gains[parent_r]], axis=-1))
-        child_rows = jnp.stack([
-            jnp.full((2 * w_width,), -1.0),              # SPLIT_FEAT
-            jnp.zeros((2 * w_width,)),                   # SPLIT_BIN
-            jnp.full((2 * w_width,), -1.0),              # LEFT
-            jnp.full((2 * w_width,), -1.0),              # RIGHT
-            child_vals,                                  # LEAF_VALUE
-            jnp.ones((2 * w_width,)),                    # IS_LEAF
-            jnp.concatenate([prow[:, K.CAND_LC],
-                             prow[:, K.CAND_RC]]),       # COUNT
-            jnp.zeros((2 * w_width,)),                   # SPLIT_GAIN
-            child_depth,                                 # DEPTH
-            bs.gain,                                     # CAND_GAIN
-            bs.feature.astype(jnp.float32),              # CAND_FEAT
-            bs.bin.astype(jnp.float32),                  # CAND_BIN
-            bs.left_g, bs.left_h, bs.left_c,
-            bs.right_g, bs.right_h, bs.right_c,
-            bs.left_out,                                 # CAND_WL
-            bs.right_out,                                # CAND_WR
-            child_lo,                                    # BOUND_LO
-            child_hi,                                    # BOUND_HI
-            (bs.cat.astype(jnp.float32) if cat_info is not None
-             else jnp.zeros((2 * w_width,))),            # CAND_CAT
-            jnp.minimum(jnp.concatenate([prow[:, K.PM], prow[:, K.PM]]),
-                        bs.gain),                        # PM
-        ], axis=-1)                                      # [2W, NC]
-        oob = jnp.int32(capacity)
-        P2 = P.at[jnp.where(active_r, parent_r, oob)].set(
-            parent_rows, mode="drop")
-        kid_idx = jnp.where(active_2, child_nodes, oob)
-        P2 = P2.at[kid_idx].set(child_rows, mode="drop")
+        with jax.named_scope("lgbtpu.wave.commit"):
+            # 7. commit with TWO packed row scatters: the W split parents
+            # become internal (their rows keep every cached field and gain
+            # the split bookkeeping), the 2W fresh children arrive with
+            # their scored candidate splits.
+            parent_rows = prow.at[:, jnp.array([
+                K.SPLIT_FEAT, K.SPLIT_BIN, K.LEFT, K.RIGHT, K.IS_LEAF,
+                K.SPLIT_GAIN])].set(jnp.stack([
+                    prow[:, K.CAND_FEAT], prow[:, K.CAND_BIN],
+                    nl_r.astype(jnp.float32), nr_r.astype(jnp.float32),
+                    jnp.zeros(w_width), gains[parent_r]], axis=-1))
+            child_rows = jnp.stack([
+                jnp.full((2 * w_width,), -1.0),              # SPLIT_FEAT
+                jnp.zeros((2 * w_width,)),                   # SPLIT_BIN
+                jnp.full((2 * w_width,), -1.0),              # LEFT
+                jnp.full((2 * w_width,), -1.0),              # RIGHT
+                child_vals,                                  # LEAF_VALUE
+                jnp.ones((2 * w_width,)),                    # IS_LEAF
+                jnp.concatenate([prow[:, K.CAND_LC],
+                                 prow[:, K.CAND_RC]]),       # COUNT
+                jnp.zeros((2 * w_width,)),                   # SPLIT_GAIN
+                child_depth,                                 # DEPTH
+                bs.gain,                                     # CAND_GAIN
+                bs.feature.astype(jnp.float32),              # CAND_FEAT
+                bs.bin.astype(jnp.float32),                  # CAND_BIN
+                bs.left_g, bs.left_h, bs.left_c,
+                bs.right_g, bs.right_h, bs.right_c,
+                bs.left_out,                                 # CAND_WL
+                bs.right_out,                                # CAND_WR
+                child_lo,                                    # BOUND_LO
+                child_hi,                                    # BOUND_HI
+                (bs.cat.astype(jnp.float32) if cat_info is not None
+                 else jnp.zeros((2 * w_width,))),            # CAND_CAT
+                jnp.minimum(jnp.concatenate([prow[:, K.PM], prow[:, K.PM]]),
+                            bs.gain),                        # PM
+            ], axis=-1)                                      # [2W, NC]
+            oob = jnp.int32(capacity)
+            P2 = P.at[jnp.where(active_r, parent_r, oob)].set(
+                parent_rows, mode="drop")
+            kid_idx = jnp.where(active_2, child_nodes, oob)
+            P2 = P2.at[kid_idx].set(child_rows, mode="drop")
 
         return st._replace(
             nodes=P2,
@@ -1719,8 +1747,9 @@ def grow_tree_frontier(
 
     st = lax.while_loop(cond, body, st)
     if exact:
-        newP, new_cat, row_leaf_new, n_leaves_f = _exact_prune(
-            st.nodes, st.cand_catmask, st.row_leaf, num_leaves, cat_info)
+        with jax.named_scope("lgbtpu.replay"):
+            newP, new_cat, row_leaf_new, n_leaves_f = _exact_prune(
+                st.nodes, st.cand_catmask, st.row_leaf, num_leaves, cat_info)
         return (_tree_from_packed(newP, n_leaves_f, cat_info, new_cat),
                 row_leaf_new)
     tree = _tree_from_packed(st.nodes, st.n_leaves, cat_info,

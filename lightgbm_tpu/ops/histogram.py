@@ -152,6 +152,7 @@ def compute_histograms(
     row_chunk: int = DEFAULT_ROW_CHUNK,
     impl: str = "auto",
     hist_dtype: str = "f32",
+    name: Optional[str] = None,
 ) -> jnp.ndarray:
     """Histogram of per-row statistics over (segment, feature, bin).
 
@@ -164,10 +165,14 @@ def compute_histograms(
         ``[0, num_segments)`` contribute nothing.
       num_segments: static segment count (e.g. 2 for the two fresh children).
       num_bins: static bin-axis size.
+      name: the role this pass plays for the caller, given to the Pallas
+        kernel that serves it in place of the kernel's own name (what the
+        device trace shows); the XLA path has no kernel to name.
 
     Returns:
       f32 ``[num_segments, F, num_bins, S]``.
     """
+    named = {} if name is None else {"name": name}
     # "f32x" = EXPLICIT f32 request (resolve_hist_dtype): a contract for
     # exactness, so auto-routing may not swap in the fused kernel's hi/lo
     # bf16 approximation (~1e-5 relative) — only a forced hist_impl=
@@ -187,7 +192,7 @@ def compute_histograms(
         from . import histogram_pallas
         return histogram_pallas.hist_fused_pallas(
             bins, stats, seg_id, num_segments, num_bins,
-            hist_dtype=hist_dtype)
+            hist_dtype=hist_dtype, **named)
 
     num_features = bins.shape[1]
     s = stats.shape[1]
@@ -207,6 +212,7 @@ def compute_histograms_batched(
     row_chunk: int = DEFAULT_ROW_CHUNK,
     impl: str = "auto",
     hist_dtype: str = "f32",
+    name: Optional[str] = None,
 ) -> jnp.ndarray:
     """Batched histograms with a SHARED binned matrix: the key memory-bound
     optimization for vmapped training (fused cv over configs x folds,
@@ -223,6 +229,7 @@ def compute_histograms_batched(
     e, n, s = stats.shape
     num_features = bins.shape[1]
     k_inner = e * num_segments * s
+    named = {} if name is None else {"name": name}
     exact = hist_dtype == "f32x"          # see compute_histograms
     if exact:
         hist_dtype = "f32"
@@ -242,7 +249,8 @@ def compute_histograms_batched(
         # Mosaic-fragility zone (r4: k=6 blocks faulted the TPU worker).
         from .histogram_pallas import hist_fused_pallas_batched
         return hist_fused_pallas_batched(bins, stats, seg_id, num_segments,
-                                         num_bins, hist_dtype=hist_dtype)
+                                         num_bins, hist_dtype=hist_dtype,
+                                         **named)
     segstats = _segstats(stats, seg_id, num_segments)      # [E, n, K*S]
     segstats = jnp.moveaxis(segstats, 0, 1).reshape(n, k_inner)
     # int8 never enters the segstats kernel: it has no quantization path
@@ -255,7 +263,7 @@ def compute_histograms_batched(
                                  and jax.default_backend() == "tpu")):
         from .histogram_pallas import hist_from_segstats_pallas
         hists = hist_from_segstats_pallas(bins, segstats, num_bins,
-                                          hist_dtype=hist_dtype)
+                                          hist_dtype=hist_dtype, **named)
     else:
         hists = _hist_from_segstats(bins, segstats, num_bins, row_chunk,
                                     hist_dtype)
@@ -266,7 +274,8 @@ def compute_histograms_batched(
 @functools.lru_cache(maxsize=None)
 def batched_histogram_op(num_segments: int, num_bins: int,
                          row_chunk: int = DEFAULT_ROW_CHUNK,
-                         impl: str = "auto", hist_dtype: str = "f32"):
+                         impl: str = "auto", hist_dtype: str = "f32",
+                         name: Optional[str] = None):
     """compute_histograms wrapped with a custom vmap rule.
 
     Under `jax.vmap` (fold/config/class batching of the tree grower), calls
@@ -278,7 +287,8 @@ def batched_histogram_op(num_segments: int, num_bins: int,
     @custom_vmap
     def op(bins, stats, seg_id):
         return compute_histograms(bins, stats, seg_id, num_segments,
-                                  num_bins, row_chunk, impl, hist_dtype)
+                                  num_bins, row_chunk, impl, hist_dtype,
+                                  name)
 
     @op.def_vmap
     def _rule(axis_size, in_batched, bins, stats, seg_id):
@@ -288,7 +298,7 @@ def batched_histogram_op(num_segments: int, num_bins: int,
             out = jax.vmap(
                 lambda b, st, sg: compute_histograms(
                     b, st, sg, num_segments, num_bins, row_chunk, impl,
-                    hist_dtype)
+                    hist_dtype, name)
             )(bins,
               stats if stats_b else jnp.broadcast_to(
                   stats, (axis_size,) + stats.shape),
@@ -305,7 +315,7 @@ def batched_histogram_op(num_segments: int, num_bins: int,
             seg_ = seg_id
         out = compute_histograms_batched(bins, stats_, seg_, num_segments,
                                          num_bins, row_chunk, impl,
-                                         hist_dtype)
+                                         hist_dtype, name)
         return out, True
 
     return op

@@ -80,6 +80,7 @@ def hist_from_segstats_pallas(
     chunk: Optional[int] = None,
     interpret: bool | None = None,
     hist_dtype: str = "f32",
+    name: str = "lgbtpu_hist_segstats",
 ) -> jnp.ndarray:
     """Kernel core: bins [n,F] x segstats [n,K] -> f32 [F, num_bins, K].
 
@@ -134,6 +135,7 @@ def hist_from_segstats_pallas(
         out_shape=jax.ShapeDtypeStruct((num_features, num_bins, k),
                                        jnp.float32),
         interpret=interpret,
+        name=name,
     )(bins, segstats)
 
 
@@ -310,10 +312,13 @@ def hist_fused_pallas(
     chunk: Optional[int] = None,
     interpret: bool | None = None,
     hist_dtype: str = "f32",
+    name: str = "lgbtpu_hist_fused",
 ) -> jnp.ndarray:
     """Fused drop-in for ``histogram.compute_histograms``:
     bins u8/i32 [n, F] x stats f32 [n, S] x seg_id i32 [n]
-    -> f32 [num_segments, F, num_bins, S]."""
+    -> f32 [num_segments, F, num_bins, S].  ``name`` is the kernel's name
+    in the compiled program and the device trace (letters, digits and
+    ``_``): a grower passes the ROLE the pass plays for it."""
     n, num_features = bins.shape
     s = stats.shape[1]
     k = num_segments * s
@@ -411,6 +416,7 @@ def hist_fused_pallas(
                 (n_fblk * f_blk, num_bins, k),
                 jnp.int32 if mode == "int8" else jnp.float32),
             interpret=interpret,
+            name=name,
         )(bins_t, stats_arr, seg_row)
 
     if hist_dtype == "f32":
@@ -634,7 +640,8 @@ def _split_iter_kernel(hist_ref, tab_ref, fmask_ref, aux_ref, scal_ref,
 def split_iter_pallas(hist2_t: jnp.ndarray, table: jnp.ndarray,
                       fmask: jnp.ndarray, aux: jnp.ndarray,
                       scal: jnp.ndarray, *, pk,
-                      interpret: bool | None = None):
+                      interpret: bool | None = None,
+                      name: str = "lgbtpu_split_iter"):
     """One strict split iteration in one pallas call (_split_iter_kernel).
 
     Args:
@@ -674,6 +681,7 @@ def split_iter_pallas(hist2_t: jnp.ndarray, table: jnp.ndarray,
             jax.ShapeDtypeStruct((1, 8), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(hist2_t, table, fmask, aux, scal)
 
 
@@ -870,6 +878,7 @@ def hist_partition_fused_pallas(
     hist_dtype: str = "bf16",
     wfeat: jnp.ndarray | None = None,   # [W] i32 wave split features
     num_features: int | None = None,    # nominal F (bins_t may be f-padded)
+    name: str = "lgbtpu_hist_partition_fused",
 ):
     """Fused wave pass: histogram over the direct children PLUS the row
     partition (see _fused_part_kernel).  Returns
@@ -930,6 +939,7 @@ def hist_partition_fused_pallas(
                     jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
                 ],
                 interpret=interpret,
+                name=name,
             )(bins_t, stats_arr, pv_t)
     else:
         if wfeat is None:
@@ -971,6 +981,7 @@ def hist_partition_fused_pallas(
                     jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
                 ],
                 interpret=interpret,
+                name=name,
             )(bins_t, stats_arr, pv_t, wbins_t)
 
     if hist_dtype in ("f32", "f32x"):
@@ -993,6 +1004,7 @@ def hist_fused_pallas_batched(
     chunk: Optional[int] = None,
     interpret: bool | None = None,
     hist_dtype: str = "f32",
+    name: str = "lgbtpu_hist_fused",
 ) -> jnp.ndarray:
     """Batched fused histograms: -> f32 [E, num_segments, F, num_bins, S].
 
@@ -1067,6 +1079,7 @@ def hist_fused_pallas_batched(
             out_shape=jax.ShapeDtypeStruct(
                 (e * n_fblk * f_blk, num_bins, k), jnp.float32),
             interpret=interpret,
+            name=name,
         )(bins_t, stats_arr, seg_flat)
 
     if hist_dtype == "f32":

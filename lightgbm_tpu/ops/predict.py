@@ -306,6 +306,7 @@ def predict_forest_pallas(
     start_iteration: jnp.ndarray = 0,
     row_block: int = PREDICT_ROW_BLOCK,
     interpret: Optional[bool] = None,
+    name: str = "lgbtpu_predict_forest",
 ) -> jnp.ndarray:
     """Fused forest predict: ONE Pallas kernel launch per forest.
 
@@ -378,6 +379,7 @@ def predict_forest_pallas(
                 pltpu.VMEM((n_sub, PREDICT_SUB_TREES, rb), jnp.float32),
             ],
             interpret=interpret,
+            name=name,
         )(bins_t, soa.split_feature, soa.split_bin, soa.left,
           soa.right, soa.leaf, sm)
 

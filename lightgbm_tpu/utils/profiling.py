@@ -1,147 +1,139 @@
-"""Per-phase profiling counters (SURVEY.md §5 "Tracing / profiling").
+"""One in-process recorder of the program's host spans, facts and counts.
 
-The reference's only instrumentation is ``system.time`` wall clocks
-(r/gridsearchCV.R:57,70); LightGBM's C++ has internal chrono counters around
-bin construction / histogram / split / partition.  Here the round step is one
-fused XLA program, so phases cannot be timed from the host inside a real
-round — instead ``profile_training`` times each phase as its own jitted
-program on the actual data (same shapes, same dtypes, same kernels), plus
-the fused whole-round program, and reports rows/sec/chip.
+``span(name, **fields)`` opens a ``jax.profiler.TraceAnnotation``, so that
+under a profiler session the span lies in the ``.xplane.pb`` on the device
+trace's clock, and records ``(id, parent, name, start, end, fields)`` in
+memory; the parent is the innermost open span of the thread.  Kept: a
+bounded ring of the last spans and, per name, ``count``, ``total_s``,
+``self_s`` (duration minus what child spans cover), ``max_s``, ``build_s``
+and ``builds``: the seconds JAX spent tracing, lowering and compiling (or
+reading the persistent cache) while the span was the thread's innermost,
+which says which step recompiled.  ``@span(name)`` on a function opens one
+per call.  ``note(name, value)`` keeps a fact the
+program decided, ``add(name, n)`` a count; ``snapshot()`` is all of it as
+a plain dict, ``reset()`` forgets it.
 
-Timing is host-fetch honest: ``np.asarray`` of a value that depends on the
-computation ends every timed region.
-
-``jax.profiler`` integration: pass ``trace_dir`` to wrap the timed section
-in ``jax.profiler.trace`` for TensorBoard/XProf inspection.
+Always on: it has to see set-up, which no profiler session covers.  With
+no session a span costs two clock reads, one locked dict update and an
+annotation that does nothing, so spans belong around dispatches and
+phases, never around a row or a request.  The open-span stack is per
+thread and everything shared is written under one lock.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import itertools
+import threading
 import time
-from typing import Any, Dict, Optional
 
-import numpy as np
+import jax
 
-
-def _timeit(fn, *args, reps: int = 3) -> float:
-    """Median seconds per call, compile excluded, value-fetch honest."""
-    import jax
-
-    out = fn(*args)
-    np.asarray(jax.tree.leaves(out)[0])  # compile + fetch
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        np.asarray(jax.tree.leaves(out)[0])
-        times.append(time.perf_counter() - t0)
-    return sorted(times)[len(times) // 2]
+RING_SPANS = 4096
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_BUILD_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                 _BACKEND_COMPILE)
 
 
-def profile_training(params: Dict[str, Any], X, y,
-                     num_boost_round: int = 20,
-                     trace_dir: Optional[str] = None) -> Dict[str, Any]:
-    """Phase breakdown + throughput for one training configuration.
+class Recorder:
+    def __init__(self, clock=time.perf_counter, ring: int = RING_SPANS):
+        self._clock = clock
+        self._ring_size = int(ring)
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self._ids = itertools.count(1)
+        self._listening = False
+        self.reset()
 
-    Returns a dict with seconds per phase (one execution each):
-      bin_construct   host-side quantile binning of X (one-time cost)
-      histogram_pass  one (grad,hess,count) histogram over all rows
-      split_scan      one full split-gain scan over (segments,features,bins)
-      partition       one row->leaf partition update (gather)
-      tree_grow       one full tree (all trips/waves)
-      round           one boosting round from the fused path
-      train_total     num_boost_round rounds via update_many
-      rows_per_s      training throughput over train_total
-    """
-    import jax
-    import jax.numpy as jnp
+    def reset(self) -> None:
+        with self._lock:
+            self._ring = collections.deque(maxlen=self._ring_size)
+            self._spans, self._facts = {}, {}
+            self._counts = collections.Counter()
 
-    import lightgbm_tpu as lgb
-    from ..models.gbdt import HyperScalars, resolve_hist_dtype, \
-        resolve_wave_width
-    from ..models.tree import grow_tree
-    from ..ops.histogram import batched_histogram_op
-    from ..ops.split import find_best_split
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
-    report: Dict[str, Any] = {}
+    def _listen(self) -> None:
+        if self._listening:
+            return
+        with self._lock:
+            first, self._listening = not self._listening, True
+        if first:
+            jax.monitoring.register_event_time_span_listener(self._on_build)
 
-    t0 = time.perf_counter()
-    ds = lgb.Dataset(X, label=y)
-    ds.construct()
-    report["bin_construct_s"] = time.perf_counter() - t0
+    def _on_build(self, event, start_time, end_time, **_) -> None:
+        """JAX's own events, on the thread that builds: an inner ``jit``
+        traced inside an outer one reports first and lies inside the
+        outer's interval, which then replaces it."""
+        stack = getattr(self._local, "stack", None)
+        if not stack or event not in _BUILD_EVENTS:
+            return
+        open_span = stack[-1]
+        built = open_span["built"]
+        while built and built[-1][0] >= start_time:
+            built.pop()
+        built.append((start_time, end_time))
+        open_span["builds"] += event == _BACKEND_COMPILE
 
-    p = lgb.config.parse_params(params)
-    n_pad = int(ds.row_mask.shape[0])
-    hd = resolve_hist_dtype(p, n_pad)
-    ww = resolve_wave_width(p, n_pad)
-    hyper = HyperScalars.from_params(p)
-    stats = jnp.stack([ds.y, jnp.ones_like(ds.y), ds.row_mask], axis=-1)
-    # real rows -> segment 0; padding -> out-of-range (contributes nothing)
-    seg = jnp.where(ds.row_mask > 0.5, 0, 2).astype(jnp.int32)
+    @contextlib.contextmanager
+    def span(self, name: str, **fields):
+        self._listen()
+        stack = self._stack()
+        rec = {"id": next(self._ids), "name": name, "fields": fields,
+               "parent": stack[-1]["id"] if stack else None,
+               "child_s": 0.0, "built": [], "builds": 0}
+        stack.append(rec)
+        with jax.profiler.TraceAnnotation(name):
+            start = self._clock()
+            try:
+                yield fields
+            finally:
+                end = self._clock()
+                stack.pop()
+                if stack:
+                    stack[-1]["child_s"] += end - start
+                self._close(rec, start, end)
 
-    hist_op = batched_histogram_op(2, ds.num_bins,
-                                   int(p.extra.get("row_chunk", 131072)),
-                                   p.extra.get("hist_impl", "auto"), hd)
-    report["histogram_pass_s"] = _timeit(
-        jax.jit(lambda b, s, g: hist_op(b, s, g)), ds.X_binned, stats, seg)
+    def _close(self, rec: dict, start: float, end: float) -> None:
+        total = end - start
+        build_s = sum(e - s for s, e in rec["built"])
+        with self._lock:
+            self._ring.append((rec["id"], rec["parent"], rec["name"], start,
+                               end, rec["fields"]))
+            agg = self._spans.setdefault(rec["name"], {
+                "count": 0, "total_s": 0.0, "self_s": 0.0, "max_s": 0.0,
+                "build_s": 0.0, "builds": 0})
+            agg["count"] += 1
+            agg["total_s"] += total
+            agg["self_s"] += total - rec["child_s"]
+            agg["max_s"] = max(agg["max_s"], total)
+            agg["build_s"] += build_s
+            agg["builds"] += rec["builds"]
 
-    hist = jax.jit(lambda b, s, g: hist_op(b, s, g))(ds.X_binned, stats, seg)
-    fmask = jnp.ones(ds.num_feature_, jnp.float32)
-    report["split_scan_s"] = _timeit(
-        jax.jit(lambda h: jax.vmap(
-            find_best_split, in_axes=(0, None, None, None))(
-                h, hyper.ctx(), fmask, jnp.bool_(True))), hist)
+    def note(self, name: str, value) -> None:
+        with self._lock:
+            self._facts[name] = value
 
-    col = ds.X_binned[:, 0].astype(jnp.int32)
-    report["partition_s"] = _timeit(
-        jax.jit(lambda c, rl: jnp.where(
-            rl == 0, jnp.where(c <= 17, 1, 2), rl)),
-        col, jnp.zeros(n_pad, jnp.int32))
+    def add(self, name: str, n=1) -> None:
+        with self._lock:
+            self._counts[name] += n
 
-    report["tree_grow_s"] = _timeit(
-        jax.jit(lambda b, s: grow_tree(
-            b, s, fmask, hyper.ctx(), p.num_leaves, ds.num_bins,
-            p.max_depth, hist_dtype=hd, wave_width=ww)),
-        ds.X_binned, stats)
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {
+                "spans": {k: dict(v) for k, v in self._spans.items()},
+                "facts": dict(self._facts),
+                "counts": dict(self._counts),
+                "ring": [dict(zip(("id", "parent", "name", "start", "end",
+                                   "fields"), r)) for r in self._ring]}
 
-    def train_rounds(k):
-        b = lgb.Booster(p.copy(), ds)
-        b.update_many(k)
-        return b
 
-    ctx = None
-    if trace_dir:
-        import jax.profiler
-        ctx = jax.profiler.trace(trace_dir)
-        ctx.__enter__()
-    b = train_rounds(1)  # compile
-    _ = np.asarray(b._pred_train[:4])
-    t0 = time.perf_counter()
-    b = train_rounds(1)
-    _ = np.asarray(b._pred_train[:4])
-    report["round_s"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    b = train_rounds(num_boost_round)
-    _ = np.asarray(b._pred_train[:4])
-    report["train_total_s"] = time.perf_counter() - t0
-    if ctx is not None:
-        ctx.__exit__(None, None, None)
-
-    report["num_boost_round"] = num_boost_round
-    report["rows"] = ds.num_data_
-    report["rows_per_s"] = ds.num_data_ * num_boost_round / \
-        report["train_total_s"]
-    # "f32x" is the internal explicit-f32 routing token — report the
-    # user-facing name
-    report["hist_dtype"] = "f32" if hd == "f32x" else hd
-    # the tail policy rides in the ENCODING of the static width — surface
-    # it as named fields, not the raw encoded int (ADVICE r3); decoded
-    # through the single shared helper (code review r5)
-    from ..models.tree import decode_wave_width
-
-    w_dec, tail, over = decode_wave_width(ww)
-    report["wave_width"] = w_dec
-    report["wave_tail"] = tail
-    if over is not None:
-        report["wave_overgrow_leaves"] = over
-    return report
+_process = Recorder()
+span, note, add = _process.span, _process.note, _process.add
+snapshot, reset = _process.snapshot, _process.reset

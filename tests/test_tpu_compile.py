@@ -220,3 +220,94 @@ def test_predict_vmem_estimate_brackets_the_compiler(
     compile_with_limit(est)
     with pytest.raises(Exception, match="vmem"):
         compile_with_limit(est // 2)
+
+
+def _named_cases():
+    """``(case, build)``: ``build(name)`` gives ``(fn, shapes)`` of one
+    wrapper at a small size, with the wrapper's own name (``{}``) or the
+    role a grower passes."""
+    from lightgbm_tpu.models.tree import _PK
+    from lightgbm_tpu.ops import histogram_pallas as hp
+    from lightgbm_tpu.ops.predict import predict_forest_pallas
+
+    n, f, b, w = 4096, 28, 256, 8
+    rows = (S((n, f), jnp.uint8), S((n, 3), jnp.float32),
+            S((n,), jnp.int32))
+
+    def partition(num_features):
+        def build(named):
+            def wave(bins, stats, pv, wfeat):
+                bins_t, stats_t, chunk = hp.prepare_wave_operands(
+                    bins, stats, b, 42)
+                pv_t = jnp.pad(pv, ((0, 0),
+                                    (0, bins_t.shape[1] - pv.shape[1])))
+                return hp.hist_partition_fused_pallas(
+                    bins_t, stats_t, pv_t, 42, b, chunk, interpret=False,
+                    hist_dtype="bf16", wfeat=wfeat,
+                    num_features=num_features, **named)
+            return wave, (S((n, num_features), jnp.uint8),
+                          S((n, 3), jnp.float32), S((8, n), jnp.float32),
+                          S((42,), jnp.int32))
+        return build
+
+    return {
+        "hist_fused": lambda named: (
+            lambda bi, st, sg: hp.hist_fused_pallas(
+                bi, st, sg, w, b, hist_dtype="bf16", interpret=False,
+                **named), rows),
+        "hist_fused_batched": lambda named: (
+            lambda bi, st, sg: hp.hist_fused_pallas_batched(
+                bi, st, sg, 42, b, hist_dtype="bf16", interpret=False,
+                **named),
+            (rows[0], S((2, n, 3), jnp.float32), S((2, n), jnp.int32))),
+        "hist_partition_fused": partition(28),
+        "hist_partition_fused_mb": partition(136),
+        # two features and a small chunk: this kernel unrolls one matmul
+        # per feature and is not on the main path (28 compile for minutes)
+        "hist_segstats": lambda named: (
+            lambda bi, ss: hp.hist_from_segstats_pallas(
+                bi, ss, b, chunk=256, hist_dtype="bf16", interpret=False,
+                **named),
+            (S((1024, 2), jnp.uint8), S((1024, 3 * w), jnp.float32))),
+        "split_iter": lambda named: (
+            lambda h, t, fm, aux, sc: hp.split_iter_pallas(
+                h, t, fm, aux, sc, pk=_PK, interpret=False, **named),
+            (S((2, f, 3, b), jnp.float32), S((253, _PK.NC), jnp.float32),
+             S((1, f), jnp.float32), S((1, 8), jnp.float32),
+             S((1, 16), jnp.float32))),
+        "predict_forest": lambda named: (
+            lambda soa, bi, it: predict_forest_pallas(
+                soa, bi, jnp.float32(0.1), 0.0, it, 12, interpret=False,
+                **named),
+            (_soa_shapes("f32", 64, 253), S((n, f), jnp.uint8),
+             S((), jnp.int32))),
+    }
+
+
+@pytest.mark.parametrize("case,name,instruction", [
+    ("hist_fused", None, "%lgbtpu_hist_fused"),
+    ("hist_fused_batched", None, "%lgbtpu_hist_fused"),
+    ("hist_partition_fused", None, "%lgbtpu_hist_partition_fused"),
+    ("hist_partition_fused_mb", None, "%lgbtpu_hist_partition_fused"),
+    ("hist_segstats", None, "%lgbtpu_hist_segstats"),
+    ("split_iter", None, "%lgbtpu_split_iter"),
+    ("predict_forest", None, "%lgbtpu_predict_forest"),
+    ("hist_fused", "lgbtpu_hist_root", "%lgbtpu_hist_root"),
+    ("hist_fused", "lgbtpu_hist_wave", "%lgbtpu_hist_wave"),
+    ("hist_partition_fused", "lgbtpu_hist_wave", "%lgbtpu_hist_wave"),
+    ("hist_partition_fused_mb", "lgbtpu_hist_wave", "%lgbtpu_hist_wave"),
+])
+def test_kernel_instruction_names(one_chip, case, name, instruction):
+    """The compiled Mosaic call's HLO instruction is named by the program:
+    the kernel's own name by default, the ROLE where a grower passes one
+    (what the device trace shows and the benchmark's metrics match)."""
+    import re
+
+    fn, shapes = _named_cases()[case]({} if name is None else {"name": name})
+    text = _compile(fn, one_chip, *shapes).as_text()
+    calls = re.findall(
+        r"^\s*(%[^ ]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text, re.M)
+    assert calls and all(
+        re.fullmatch(re.escape(instruction) + r"(\.\d+)?", c)
+        for c in calls), calls
