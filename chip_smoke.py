@@ -344,8 +344,19 @@ def phase_train(lgb, seed: int, meter, device):
     import jax
 
     jax.block_until_ready(ds.X_binned)
+    binning_s = time.perf_counter() - t0
+    # a float32 table of this size is coded on the chip: the same bytes as
+    # the host's loop gives
+    from lightgbm_tpu.utils import profiling
+
+    host_codes = ds.bin_mapper._transform_unbundled(X)
+    same = np.asarray(ds.X_binned)[:HIGGS_ROWS].tobytes() == host_codes.tobytes()
+    path = profiling.snapshot()["facts"]["dataset.codes_path"]
     say("bin", rows=HIGGS_ROWS, features=X.shape[1], num_bins=ds.num_bins,
-        datagen_s=gen_s, binning_s=time.perf_counter() - t0)
+        datagen_s=gen_s, binning_s=binning_s, codes_path=path,
+        bit_identical_to_host=same)
+    check(path == "device" and same,
+          "bin codes assigned on the chip are the host's bytes")
 
     params = {"objective": "binary", "num_leaves": 127, "verbosity": -1}
     rounds = 10

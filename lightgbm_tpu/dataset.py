@@ -8,23 +8,36 @@ feature, and is reusable across many trainings (the reference reuses one
 TPU-first design (SURVEY.md §7): the binned matrix is a device-resident
 ``uint8[rows_padded, features]`` with rows padded to a lane-friendly multiple
 so it can later be row-sharded over a ``jax.sharding.Mesh`` without reshapes.
-Labels/weights ride alongside as f32.  Binning itself (a one-time, per-feature
-quantile sketch) runs on host in numpy — it is O(n log n) scalar work that XLA
-has no advantage on — and produces the bin-upper-bound table used both for
-training data and for mapping validation/prediction inputs into the same bins.
+Labels/weights ride alongside as f32.  The bin EDGES (a one-time, per-feature
+quantile sketch of a 200k-row sample) are found on host in numpy — O(n log n)
+scalar work that XLA has no advantage on — and give the bin-upper-bound table
+used both for training data and for mapping validation/prediction inputs into
+the same bins.  The bin CODES of a large dense float32 table are assigned on
+the device, in fixed-size row blocks (:func:`device_bin_codes`); every other
+table is coded by the host loop (:meth:`BinMapper._transform_unbundled`).  The
+two give the same bytes.
 """
 
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import Any, Dict, List, Optional, Sequence, Union
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .config import Params, parse_params
+from .utils import profiling
 from .utils.profiling import span
 
 ROW_PAD_MULTIPLE = 256  # lane-friendly and shard-friendly (divides by 2,4,8 devices)
+# Values of one row block of the device's bin-code pass: 32 MiB of float32 a
+# transfer, two in flight.  A table of fewer rows than one block is coded by
+# the host loop, which costs it under a second (0.1 us a value) where the
+# device's program would first have to be built for its shape.
+CODE_BLOCK_VALUES = 1 << 23
 
 
 class FeatureBundler:
@@ -50,6 +63,8 @@ class FeatureBundler:
     squeezed out).  Conflicting rows (two members non-default — allowed up
     to ``max_conflict_rate``) keep the LAST member's value.
     """
+
+    SAMPLE_ROWS = 50_000   # leading rows whose codes :meth:`fit` reads
 
     def __init__(self, groups: List[List[int]], member_bins: np.ndarray,
                  default_bins: np.ndarray):
@@ -119,7 +134,7 @@ class FeatureBundler:
     @staticmethod
     def fit(codes: np.ndarray, n_bins: np.ndarray,
             max_conflict_rate: float = 0.0, max_merged_bins: int = 256,
-            sparse_threshold: float = 0.8, sample: int = 50_000,
+            sparse_threshold: float = 0.8, sample: int = SAMPLE_ROWS,
             exclude: Optional[np.ndarray] = None
             ) -> Optional["FeatureBundler"]:
         """Greedy conflict-bounded bundling (upstream FindGroups).
@@ -254,7 +269,15 @@ class BinMapper:
     For each feature stores ascending ``upper_bounds`` such that raw value v
     maps to bin ``searchsorted(upper_bounds, v, side='left')``; the last bound
     is +inf.  NaN maps to the dedicated last bin (index ``n_bins-1``) when the
-    feature has missing values, else NaN never occurs.
+    feature has missing values, else to the bin of 0.0.
+
+    Two implementations assign codes, and give the same bytes.  The host loop
+    (:meth:`_transform_unbundled`: one float64 ``searchsorted`` a feature)
+    takes any table, and is what ``transform`` (predict, serving,
+    ``from_blocks``) runs.  ``Dataset.construct`` hands a float32 table of at
+    least one row block (``CODE_BLOCK_VALUES``) whose columns are all numeric
+    and form no EFB bundle to :func:`device_bin_codes`, which counts on the
+    device the bounds below each value, from :meth:`device_tables`.
     """
 
     def __init__(self, upper_bounds: List[np.ndarray], nan_bin: np.ndarray,
@@ -371,6 +394,36 @@ class BinMapper:
             out[:, f] = codes.astype(np.uint8)
         return out
 
+    def device_tables(self):
+        """``(edge_keys i32[F, E], nan_code i32[F])`` for
+        :func:`device_bin_codes`; numeric features only.
+
+        A float32 ``x`` is above a float64 bound ``u`` iff it is above
+        ``d(u)``, ``u`` rounded to float32 toward -inf, and two float32
+        order as their :func:`_order_keys` do, so ``searchsorted(u, x,
+        "left")`` is the count of ``key(x) > key(d(u_j))``.  Rows are padded
+        to the widest edge count with the largest key, which nothing is
+        above.  ``nan_code`` is where :meth:`_transform_unbundled` sends
+        NaN: the NaN bin, or the bin of 0.0 where fit saw no NaN."""
+        assert not self.is_categorical.any()
+        width = max([len(ub) for ub in self.upper_bounds] + [1])
+        edge_keys = np.full((self.num_features, width),
+                            np.iinfo(np.int32).max, np.int32)
+        nan_code = np.asarray(self.nan_bin, np.int32).copy()
+        for f, ub in enumerate(self.upper_bounds):
+            ub = np.asarray(ub, np.float64)
+            with np.errstate(over="ignore"):
+                down = ub.astype(np.float32)
+            above = down.astype(np.float64) > ub
+            down[above] = np.nextafter(down[above], np.float32(-np.inf))
+            # a NaN bound is above every value on the host too
+            edge_keys[f, :len(ub)] = np.where(
+                np.isnan(ub), edge_keys[f, :len(ub)],
+                _order_keys(down.view(np.int32)))
+            if nan_code[f] < 0:
+                nan_code[f] = np.searchsorted(ub, 0.0, side="left")
+        return edge_keys, nan_code
+
     def bin_upper_bound(self, feature: int, bin_idx: int) -> float:
         """Raw-value threshold corresponding to `bin <= bin_idx` (for model dump)."""
         ub = self.upper_bounds[feature]
@@ -390,8 +443,70 @@ class BinMapper:
         return mapper_from_dict(d)
 
 
-def _to_2d_float_array(data: Any) -> np.ndarray:
-    """Accept numpy / pandas / list-of-lists; return f64 ndarray [n, F]."""
+_F32_MAGNITUDE = 0x7FFFFFFF
+_F32_INF = 0x7F800000
+
+
+def _order_keys(bits):
+    """float32 bit patterns (int32, numpy or jax) -> int32 that order as the
+    floats do: sign and magnitude to two's complement, so -0.0 and +0.0
+    share key 0 and denormals keep their place whatever the device does to
+    them in float arithmetic.  NaNs lie beyond both infinities."""
+    mag = bits & _F32_MAGNITUDE
+    return (bits >> 31 ^ mag) - (bits >> 31)
+
+
+def code_block_rows(num_features: int) -> int:
+    """Rows of one block of :func:`device_bin_codes`."""
+    rows = CODE_BLOCK_VALUES // max(int(num_features), 1)
+    return max(rows // ROW_PAD_MULTIPLE, 1) * ROW_PAD_MULTIPLE
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_code_block(codes, bits, at, edge_keys, nan_code):
+    """Codes of one row block (``bits[B, F]``: the bit patterns of its
+    float32 values) written into rows ``at...`` of ``codes``.  Integer
+    compares only; rows lie on the minor axis while the edges are counted."""
+    key = _order_keys(bits).T                                   # [F, B]
+    above = key[:, None, :] > edge_keys[:, :, None]
+    code = jnp.sum(above, axis=1, dtype=jnp.int32)
+    is_nan = (key > _F32_INF) | (key < -_F32_INF)
+    code = jnp.where(is_nan, nan_code[:, None], code)
+    codes = jax.lax.dynamic_update_slice(
+        codes, code.T.astype(jnp.uint8), (at, 0))
+    return codes, at
+
+
+def device_bin_codes(X: np.ndarray, mapper: BinMapper, n_pad: int):
+    """``uint8[n_pad, F]`` on the device: the bytes of
+    ``mapper._transform_unbundled(X)`` above ``n_pad - len(X)`` rows of
+    zeros, for a float32 ``X`` of at least one block's rows.
+
+    One program for one block shape; the last block ends at the last row
+    and so overlaps the one before it.  A block's transfer is in flight
+    while the block before it is coded, and a third is not sent before the
+    first is done, so the table is never whole on the device.  Returns the
+    array (not waited for) and the number of blocks."""
+    n, num_features = X.shape
+    rows = code_block_rows(num_features)
+    assert X.dtype == np.float32 and X.flags.c_contiguous and n >= rows
+    edge_keys, nan_code = map(jnp.asarray, mapper.device_tables())
+    codes = jnp.zeros((n_pad, num_features), jnp.uint8)
+    starts = list(range(0, n - rows, rows)) + [n - rows]
+    done = []        # a token of each block in flight, two at most
+    for at in starts:
+        if len(done) == 2:
+            done.pop(0).block_until_ready()
+        bits = X[at:at + rows].view(np.int32)
+        codes, token = _write_code_block(codes, jax.device_put(bits), at,
+                                         edge_keys, nan_code)
+        done.append(token)
+    return codes, len(starts)
+
+
+def _to_2d_float_array(data: Any, keep_float32: bool = False) -> np.ndarray:
+    """Accept numpy / pandas / list-of-lists; return f64 ndarray [n, F]
+    (a float32 table as it is, if ``keep_float32``)."""
     if hasattr(data, "to_numpy"):  # pandas DataFrame/Series
         data = data.to_numpy()
     arr = np.asarray(data)
@@ -401,7 +516,9 @@ def _to_2d_float_array(data: Any) -> np.ndarray:
         arr = arr[:, None]
     if arr.ndim != 2:
         raise ValueError(f"data must be 2-D, got shape {arr.shape}")
-    return np.ascontiguousarray(arr, dtype=np.float64)
+    dtype = (np.float32 if keep_float32 and arr.dtype == np.float32
+             else np.float64)
+    return np.ascontiguousarray(arr, dtype=dtype)
 
 
 def _to_1d_float_array(x: Any) -> np.ndarray:
@@ -578,12 +695,13 @@ class Dataset:
 
     def _construct_from_rows(self) -> None:
         """Edges, codes, bundle and the copy to the device, each under its
-        own span (``lgbtpu.dataset.*``); the copy is not waited for here."""
-        import jax.numpy as jnp  # deferred so Dataset import stays cheap
-
+        own span (``lgbtpu.dataset.*``).  The codes are assigned on the
+        device where the table allows it (:func:`device_bin_codes`), and
+        then waited for inside ``.codes``; the host's codes are copied in
+        ``.put``, which is not waited for here."""
         p = parse_params(self.params, warn_unknown=False)
         with span("lgbtpu.dataset.to_float"):
-            X = _to_2d_float_array(self.raw_data)
+            X = _to_2d_float_array(self.raw_data, keep_float32=True)
         n, num_features = X.shape
         self.num_data_ = n
         self.num_feature_ = num_features
@@ -593,39 +711,51 @@ class Dataset:
         if self.bin_mapper is None and self._reference is not None:
             self._reference.construct()
             self.bin_mapper = self._reference.bin_mapper
-        codes = None
         if self.bin_mapper is None:
             with span("lgbtpu.dataset.edges"):
                 self.bin_mapper = BinMapper.fit(
                     X, max_bin=p.max_bin, min_data_in_bin=p.min_data_in_bin,
                     categorical=cat_idx, seed=p.data_random_seed)
-            with span("lgbtpu.dataset.codes"):
-                raw_codes = self.bin_mapper._transform_unbundled(X)
             if p.enable_bundle:
                 with span("lgbtpu.dataset.bundle"):
+                    # whether a bundle forms is read from the leading rows
+                    head = X[:FeatureBundler.SAMPLE_ROWS]
                     self.bin_mapper.bundler = FeatureBundler.fit(
-                        raw_codes, self.bin_mapper.n_bins,
+                        self.bin_mapper._transform_unbundled(head),
+                        self.bin_mapper.n_bins,
                         max_conflict_rate=p.max_conflict_rate,
                         exclude=self.bin_mapper.is_categorical)
-                    b = self.bin_mapper.bundler
-                    codes = raw_codes if b is None else b.merge(raw_codes)
-            else:
-                codes = raw_codes
-        if codes is None:
-            with span("lgbtpu.dataset.codes"):
-                codes = self.bin_mapper.transform(X)
+        mapper = self.bin_mapper
         self.raw_num_feature_ = num_features
-        if self.bin_mapper.bundler is not None:
-            num_features = codes.shape[1]
+        if mapper.bundler is not None:
+            num_features = mapper.bundler.num_columns
             self.num_feature_ = num_features
 
+        n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
+        # on a CPU backend the device is the host, and its loop the faster
+        # program for it: XLA:CPU counts edges at a tenth of the loop's rate
+        on_device = (X.dtype == np.float32 and mapper.bundler is None
+                     and not mapper.is_categorical.any()
+                     and n >= code_block_rows(num_features)
+                     and jax.default_backend() != "cpu")
+        path = "device" if on_device else "host"
+        profiling.note("dataset.codes_path", path)
+        with span("lgbtpu.dataset.codes", path=path, blocks=0) as fields:
+            if on_device:
+                self.X_binned, fields["blocks"] = device_bin_codes(
+                    X, mapper, n_pad)
+                jax.block_until_ready(self.X_binned)
+                profiling.add("dataset.codes.device_rows", n)
+            else:
+                codes = mapper.transform(X)
         with span("lgbtpu.dataset.put"):
-            n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
-            pad = n_pad - n
-            if pad:
-                codes = np.concatenate(
-                    [codes, np.zeros((pad, num_features), np.uint8)], axis=0)
-            self.X_binned = jnp.asarray(codes)
+            if not on_device:
+                pad = n_pad - n
+                if pad:
+                    codes = np.concatenate(
+                        [codes, np.zeros((pad, num_features), np.uint8)],
+                        axis=0)
+                self.X_binned = jnp.asarray(codes)
             mask = np.zeros(n_pad, dtype=np.float32)
             mask[:n] = 1.0
             self.row_mask = jnp.asarray(mask)
@@ -635,8 +765,6 @@ class Dataset:
             self.raw_data = None
 
     def _device_put_targets(self) -> None:
-        import jax.numpy as jnp
-
         n, n_pad = self.num_data_, int(self.row_mask.shape[0]) if self.row_mask is not None else None
         if n_pad is None:
             return
@@ -694,7 +822,6 @@ class Dataset:
         earlier streamed or in-memory Dataset (must be constructed, no
         EFB bundling).
         """
-        import jax.numpy as jnp
         from .data import BlockStore, StreamingBinMapperBuilder
 
         if callable(blocks):
@@ -932,8 +1059,6 @@ class Dataset:
         return sub
 
     def _from_codes(self, codes: np.ndarray) -> None:
-        import jax.numpy as jnp
-
         n, num_features = codes.shape
         self.num_data_ = n
         self.num_feature_ = num_features

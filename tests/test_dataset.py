@@ -1,9 +1,13 @@
 """Binner + Dataset container tests."""
 
+import jax
 import numpy as np
 import pytest
 
-from lightgbm_tpu.dataset import BinMapper, Dataset, ROW_PAD_MULTIPLE
+from lightgbm_tpu import dataset as dataset_mod
+from lightgbm_tpu.dataset import (BinMapper, Dataset, ROW_PAD_MULTIPLE,
+                                  code_block_rows, device_bin_codes)
+from lightgbm_tpu.utils import profiling
 
 
 def test_binner_few_distinct_values_get_own_bins():
@@ -104,3 +108,182 @@ def test_categorical_binning():
     codes = bm.transform(X)
     assert codes[:, 0].tolist() == [0, 1, 2, 2, 3]
     assert bm.is_categorical[0]
+
+
+# -- bin codes assigned on the device ---------------------------------------
+F32 = np.float32
+TINY = np.finfo(F32).smallest_subnormal
+BLOCK = ROW_PAD_MULTIPLE       # rows of a block in these tests
+
+
+def _mapper(bounds, nan_bins=None):
+    """A numeric mapper of the given float64 bounds; ``nan_bins[f]`` true
+    gives feature ``f`` a NaN bin."""
+    bounds = [np.asarray(b, np.float64) for b in bounds]
+    nan_bins = nan_bins or [False] * len(bounds)
+    nan_bin = np.array([len(b) + 1 if has else -1
+                        for b, has in zip(bounds, nan_bins)], np.int32)
+    n_bins = np.array([len(b) + 1 + has for b, has in zip(bounds, nan_bins)],
+                      np.int32)
+    return BinMapper(bounds, nan_bin, n_bins)
+
+
+def _table(columns, n):
+    """float32 ``[n, F]``: each column's planted values first, then normal
+    draws spread over the float32 range's middle."""
+    rng = np.random.default_rng(len(columns) * 1000 + n)
+    X = (rng.standard_normal((n, len(columns)))
+         * 10.0 ** rng.integers(-3, 4, (n, 1))).astype(F32)
+    for f, planted in enumerate(columns):
+        planted = np.asarray(planted, F32)
+        X[:len(planted), f] = planted
+    return X
+
+
+def _around(values):
+    """Each value as float32 (rounded to nearest), and its float32
+    neighbours on both sides."""
+    with np.errstate(over="ignore"):
+        v = np.asarray(values, np.float64).astype(F32)
+    return np.concatenate([v, np.nextafter(v, F32(np.inf)),
+                           np.nextafter(v, F32(-np.inf))])
+
+
+SPECIALS = np.array([0.0, -0.0, TINY, -TINY, 1e-40, -1e-40, np.inf, -np.inf,
+                     np.finfo(F32).max, np.finfo(F32).min, np.nan], F32)
+NANS = np.array([0x7FC00000, 0xFFC00000, 0x7F800001, 0xFFFFFFFF],
+                np.uint32).view(F32)
+REPRESENTABLE = [-1.5, 0.25, 3.0, 1024.0]
+NOT_REPRESENTABLE = [-1.0 / 3.0, 0.1, 0.1 + 1e-12, 16777217.0]
+EDGES_254 = np.sort(np.random.default_rng(27).standard_normal(254))
+
+
+def _planted_cases():
+    wide = _around(EDGES_254[::16])
+    return {
+        "value_equal_to_bound_and_neighbours": (
+            [REPRESENTABLE], [_around(REPRESENTABLE)], BLOCK),
+        "bounds_not_float32_representable": (
+            [NOT_REPRESENTABLE], [_around(NOT_REPRESENTABLE)], BLOCK),
+        "signed_zeros": (
+            [[0.0], [-0.0], [-1.0, 1.0]], [SPECIALS] * 3, BLOCK),
+        "bounds_below_the_denormals": (
+            [[-1e-50, 1e-50], [-1e-46, 0.0, 1e-46]], [SPECIALS] * 2, BLOCK),
+        "denormal_bounds_and_values": (
+            [[-3e-39, -float(TINY), float(TINY), 1e-40, 1.00001e-40, 3e-39]],
+            [np.concatenate([SPECIALS, _around([1e-40, -3e-39, 3e-39])])],
+            BLOCK),
+        "infinite_values_and_huge_bounds": (
+            [[-1e300, float(np.finfo(F32).min), float(np.finfo(F32).max),
+              1e300], [-np.inf, 0.0, np.inf]],
+            [np.concatenate([SPECIALS, _around([np.finfo(F32).max])])] * 2,
+            BLOCK),
+        "nan_with_nan_bin": (
+            [[-1.0, 1.0], EDGES_254[:253]], [NANS, NANS], BLOCK, [True, True]),
+        "nan_without_nan_bin_takes_zeros_bin": (
+            [[-1.0, 1.0], [0.0], [0.5, 2.0], [-2.0, -0.5]], [NANS] * 4, BLOCK),
+        "constant_column_has_no_edges": (
+            [[], [0.0], []], [SPECIALS] * 3, BLOCK, [False, False, True]),
+        "different_edge_counts": (
+            [[0.0], EDGES_254[:7], EDGES_254, []], [SPECIALS, wide, wide, wide],
+            BLOCK, [True, False, False, False]),
+        "rows_not_a_multiple_of_block_or_pad": (
+            [EDGES_254, REPRESENTABLE], [wide, SPECIALS], 2 * BLOCK + 77),
+        "two_blocks": ([EDGES_254, REPRESENTABLE], [wide, SPECIALS],
+                       2 * BLOCK),
+        "last_block_overlaps_by_all_but_one_row": (
+            [EDGES_254, REPRESENTABLE], [wide, SPECIALS], BLOCK + 1),
+    }
+
+
+@pytest.mark.parametrize("case", list(_planted_cases()))
+def test_device_codes_are_the_hosts_bytes(case, monkeypatch):
+    bounds, planted, n, *nan_bins = _planted_cases()[case]
+    mapper = _mapper(bounds, *nan_bins)
+    X = _table(planted, n)
+    monkeypatch.setattr(dataset_mod, "CODE_BLOCK_VALUES", BLOCK * len(bounds))
+    assert code_block_rows(len(bounds)) == BLOCK
+    n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
+    codes, blocks = device_bin_codes(X, mapper, n_pad)
+    codes = np.asarray(codes)
+    host = mapper._transform_unbundled(X)
+    assert codes.dtype == np.uint8 and codes.shape == (n_pad, len(bounds))
+    assert codes[:n].tobytes() == host.tobytes()
+    assert not codes[n:].any()
+    assert blocks == -(-n // BLOCK)
+    # the planted values reach more than one bin wherever there is an edge
+    for f, b in enumerate(bounds):
+        assert len(np.unique(host[:, f])) > (len(b) > 0)
+
+
+def test_device_codes_of_a_fitted_mapper_with_missing_values(monkeypatch):
+    rng = np.random.default_rng(5)
+    X = rng.standard_normal((3 * BLOCK + 5, 5)).astype(F32)
+    X[rng.random(X.shape) < 0.05] = np.nan
+    X[:, 3] = np.round(X[:, 3])            # few distinct values: midpoints
+    X[:, 4] = 7.0
+    mapper = BinMapper.fit(X, max_bin=255, min_data_in_bin=1)
+    assert (mapper.nan_bin[:4] >= 0).all()
+    monkeypatch.setattr(dataset_mod, "CODE_BLOCK_VALUES", BLOCK * 5)
+    codes, blocks = device_bin_codes(X, mapper, 4 * BLOCK)
+    assert blocks == 4
+    assert np.asarray(codes)[:len(X)].tobytes() == \
+        mapper._transform_unbundled(X).tobytes()
+
+
+def _dense(n, num_features, dtype):
+    return np.random.default_rng(n).standard_normal(
+        (n, num_features)).astype(dtype)
+
+
+def _bundling(n, num_features, dtype):
+    X = np.zeros((n, num_features), dtype)
+    for f in range(num_features):          # mutually exclusive, 95 % zeros
+        X[f::20, f] = 1.0 + (np.arange(len(X[f::20])) % 7)
+    return X
+
+
+@pytest.mark.parametrize("case,make,kwargs,backend,patched,path", [
+    ("float64", _dense, {}, "tpu", True, "host"),
+    ("categorical_column", _dense, {"categorical_feature": [1]}, "tpu", True,
+     "host"),
+    ("bundling_sparse", _bundling, {}, "tpu", True, "host"),
+    ("bundling_off_sparse", _bundling, {"params": {"enable_bundle": False}},
+     "tpu", True, "device"),
+    ("small_like_a_diamonds_fold", _dense, {}, "tpu", False, "host"),
+    ("cpu_backend", _dense, {}, "cpu", True, "host"),
+    ("large_dense_float32", _dense, {}, "tpu", True, "device"),
+])
+def test_codes_path_is_chosen_from_the_table(case, make, kwargs, backend,
+                                             patched, path, monkeypatch):
+    n, num_features = (45_900, 6) if not patched else (3 * BLOCK + 9, 6)
+    X = make(n, num_features, np.float64 if case == "float64" else F32)
+    # the choice asks which backend is the default; the tests' is the CPU
+    assert jax.default_backend() == "cpu"
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if patched:      # three blocks and a part of this table; else as shipped
+        monkeypatch.setattr(dataset_mod, "CODE_BLOCK_VALUES",
+                            BLOCK * num_features)
+    profiling.reset()
+    ds = Dataset(X, label=np.zeros(n), **kwargs).construct()
+    snap = profiling.snapshot()
+    assert snap["facts"]["dataset.codes_path"] == path
+    (codes_span,) = [r for r in snap["ring"]
+                     if r["name"] == "lgbtpu.dataset.codes"]
+    assert codes_span["fields"]["path"] == path
+    assert codes_span["fields"]["blocks"] == (4 if path == "device" else 0)
+    assert snap["counts"].get("dataset.codes.device_rows", 0) == \
+        (n if path == "device" else 0)
+    assert (ds.bin_mapper.bundler is not None) == (case == "bundling_sparse")
+    # whichever path: the codes the host's mapper gives, above zero rows
+    got = np.asarray(ds.X_binned)
+    assert got[:n].tobytes() == ds.bin_mapper.transform(
+        X.astype(np.float64)).tobytes()
+    assert got.shape[0] % ROW_PAD_MULTIPLE == 0 and not got[n:].any()
+
+
+def test_code_block_is_sized_in_values():
+    assert dataset_mod.CODE_BLOCK_VALUES == 1 << 23
+    assert code_block_rows(28) == 299_520
+    assert code_block_rows(700) == 11_776
+    assert code_block_rows(1 << 30) == ROW_PAD_MULTIPLE
