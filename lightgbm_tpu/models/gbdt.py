@@ -198,9 +198,18 @@ def resolve_wave_width(p: Params, n_rows: int) -> int:
     #     tail costs ~6e-2 NDCG@10 on the MSLR bench).
     #   "greedy" — whole remaining budget per wave, fewest passes.
     #     Default only for mid-size pointwise tasks whose budget is far
-    #     from saturating the rows — r4 measured the diamonds shape
-    #     (46k rows, nl=31, ~1.5k rows/leaf) quality-NEUTRAL across
-    #     half/greedy/strict while greedy is 1.44x faster.
+    #     from saturating the rows AND whose tree closes before the wave
+    #     width binds (num_leaves - 1 <= width: every wave but the last
+    #     splits every leaf that can split) — r4 measured the diamonds
+    #     shape (46k rows, nl=31, ~1.5k rows/leaf) quality-NEUTRAL across
+    #     half/greedy/strict while greedy is 1.44x faster.  Where the
+    #     width binds, a wave takes the 42 best leaves it HAS and strict
+    #     order would have taken their children: at 400,000 x 2,000, 255
+    #     leaves (1,568 rows a leaf, which this rule sent to greedy until
+    #     PR 28) the benchmark's reference read a best-first excess of
+    #     0.12 and 0.37 of a split's gain on two seeds against -0.0007
+    #     and 0.002 under "exact" (limit 0.04; chip, PR 28), as it had at
+    #     10.5M x 28 (0.056-0.17, PR 25).
     #   "half"   — at most half the remaining budget per wave
     #     (near-strict tail, r3's compromise; kept for compatibility).
     # Encoding (static width int, rides all existing plumbing): negative
@@ -211,7 +220,8 @@ def resolve_wave_width(p: Params, n_rows: int) -> int:
     # tail's ~6e-2 NDCG cost) — classify it conservatively (ADVICE r4)
     pointwise = p.objective not in ("lambdarank", "rank_xendcg", "none")
     default_tail = ("greedy" if pointwise and rows_per_leaf >= 1024
-                    and n_rows < (1 << 19) else "exact")
+                    and n_rows < (1 << 19) and p.num_leaves - 1 <= width
+                    else "exact")
     tail = str(p.extra.get("wave_tail", default_tail))
     if tail == "greedy":
         width = -width
@@ -2270,12 +2280,28 @@ class Booster:
         # what the pass that runs was decided to be: the shapes a roofline
         # counts its work from (the benchmark's named metrics read them)
         width, tail, overgrow = decode_wave_width(wave_width)
+        segments = min(width, (overgrow or p.num_leaves) - 1)
+        features = int(ds.X_binned.shape[1])
+        # ... and what the kernels' VMEM blocking made of a wave pass at
+        # this width: feature blocks, the feature rows they cover (the
+        # last block is padded), rows per grid step, and kernel calls per
+        # pass (two under the hi/lo split that serves "f32")
+        from ..ops.histogram_pallas import _vmem_blocking
+        f_blk, n_fblk, _, chunk = _vmem_blocking(features, self._num_bins,
+                                                 3 * segments)
         for fact, value in (
-                ("wave_width", min(width, (overgrow or p.num_leaves) - 1)),
+                ("wave_width", segments),
                 ("wave_tail", tail), ("overgrow_leaves", overgrow),
                 ("hist_dtype", hist_dtype), ("rows_padded", eff_rows),
                 ("num_bins", self._num_bins),
-                ("features", int(ds.X_binned.shape[1]))):
+                ("features", features),
+                ("feature_blocks", n_fblk),
+                ("features_padded", n_fblk * f_blk),
+                ("chunk_rows", chunk),
+                ("hist_calls_per_pass",
+                 2 if hist_dtype == "f32" or (
+                     hist_dtype == "f32x"
+                     and p.extra.get("hist_impl") == "pallas") else 1)):
             profiling.note("train." + fact, value)
         fn = _multi_round_fn(
             self._obj_key, p.num_leaves, self._num_bins,

@@ -1163,7 +1163,8 @@ def _exact_prune(P, cand_catmask, row_leaf, num_leaves: int,
 class _WaveState(NamedTuple):
     nodes: jnp.ndarray          # f32[M, _PK.NC] packed per-node table
     # frontier extras
-    hist_cache: jnp.ndarray     # f32[num_leaves, F, B, 3] per-active-leaf
+    hist_cache: jnp.ndarray     # f32[num_leaves, 3*F*B] per-active-leaf
+                                #   planes [3, F, B], flat (bins minor)
     node_slot: jnp.ndarray      # i32[M] node id -> hist_cache slot
     # dynamic growth state
     row_leaf: jnp.ndarray
@@ -1220,7 +1221,9 @@ def grow_tree_frontier(
         lanes below 128 are padded anyway, so batching W splits into one
         pass costs roughly the same as one strict trip);
       * the sibling histogram is ``parent − child`` from a per-leaf
-        histogram cache (f32 ``[num_leaves, F, B, 3]``);
+        histogram cache (f32 ``[num_leaves, 3*F*B]``: each leaf's three
+        planes ``[3, F, B]``, flat, because both uses of the cache are
+        matmuls over that view);
       * fresh children get their candidate splits scored from the cached
         histograms with no extra data pass.
 
@@ -1259,13 +1262,23 @@ def grow_tree_frontier(
     route_pallas = (hist_impl == "pallas"
                     or (hist_impl == "auto" and not exact_dtype
                         and jax.default_backend() == "tpu"))
+    from ..ops.histogram_pallas import _vmem_blocking
+
+    # more than one VMEM feature block: the kernel routes rows by wave rank
+    # from the gathered code rows of the wave's split features and never
+    # reads a feature id from the per-row table
+    wave_f_blk, wave_f_blocks = _vmem_blocking(num_features, num_bins,
+                                               3 * w_width)[:2]
+    multi_block = wave_f_blocks > 1
     fuse_part = (fuse_partition and fp_axis is None and cat_info is None
                  and hist_dtype != "int8" and route_pallas
                  and w_width > 1
                  # the per-row field lookup runs at bf16 DEFAULT
-                 # precision — every table value (feature id, bin,
-                 # 2*rank child offset) must be an exact bf16 integer
-                 and max(num_features, 2 * w_width, num_bins) <= 256)
+                 # precision — every table value (bin, 2*rank child
+                 # offset, and the feature id where one block routes by
+                 # it) must be an exact bf16 integer
+                 and max(2 * w_width, num_bins) <= 256
+                 and (multi_block or num_features <= 256))
     max_depth = jnp.asarray(max_depth, jnp.int32)
     neg_inf = jnp.float32(-jnp.inf)
     if key is None:
@@ -1322,24 +1335,68 @@ def grow_tree_frontier(
 
         op = batched_histogram_op(num_segments, num_bins, row_chunk,
                                   hist_impl, hist_dtype, role)
-        h = op(bins, stats, seg_id)
+        return to_planes(merge(op(bins, stats, seg_id)))
+
+    def merge(h):
+        """``[S, F, B, 3]`` partials -> what this shard scores."""
         if hist_merge == "voting":
             return h       # local partials; the scorer merges candidates
         return histogram_merge(h, axis_name, mode=hist_merge,
                                n_shards=n_shards, wire_dtype=hist_wire,
                                n_chunks=merge_chunks)
 
+    # Per-node histograms live in this grower as PLANES ``[S, 3, F, B]``,
+    # bins minor, from the kernel's output to the split scan: the chip
+    # tiles an array's two minor axes by (8, 128) in HBM, so a 3-wide
+    # minor axis is stored 128 wide (hist_partition_fused_pallas).  The
+    # merges across shards and the distributed scorer keep ``[S, F, B,
+    # 3]``, the layout of everything outside this grower.
+    def to_planes(h):
+        return jnp.moveaxis(h, -1, 1)
+
+    def from_planes(h):
+        return jnp.moveaxis(h, 1, -1)
+
+    if fuse_part:
+        # loop-invariant kernel operands prepared ONCE a tree (the in-call
+        # pad/convert re-ran per wave, ~2.7 ms each at 11M — r5 trace); the
+        # root pass reads them too, so the tree keeps one 4-byte transposed
+        # copy of the codes and not one per row padding
+        from ..ops.histogram_pallas import (hist_fused_prepared,
+                                            hist_partition_fused_pallas,
+                                            prepare_wave_operands)
+
+        stats_prep_src = stats
+        if hist_dtype == "bf16sr":
+            # the opt-in SR variant must quantize here too — the fused
+            # path bypasses compute_histograms where SR normally applies
+            from ..ops.histogram import sr_round_bf16
+
+            stats_prep_src = sr_round_bf16(stats)
+        bins_t_prep, stats_t_prep, part_chunk = prepare_wave_operands(
+            bins, stats_prep_src, num_bins, w_width)
+        n_pad_rows = bins_t_prep.shape[1]
+        kernel_dtype = "f32" if hist_dtype in ("f32", "f32x") else "bf16"
+
     # ---- root -------------------------------------------------------------
     with jax.named_scope("lgbtpu.root"):
-        root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1,
-                            HIST_ROOT)[0]               # [f_hist, B, 3]
+        if fuse_part:
+            # every row in segment 0 (the rows that pad carry no statistics)
+            root_hist = to_planes(merge(hist_fused_prepared(
+                bins_t_prep, stats_t_prep,
+                jnp.zeros((1, n_pad_rows), jnp.int32), 1, num_bins,
+                part_chunk, wave_f_blk, num_features,
+                hist_dtype=kernel_dtype, name=HIST_ROOT)))[0]
+        else:
+            root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1,
+                                HIST_ROOT)[0]           # [3, f_hist, B]
         if dist_mode:
             # global totals from the stats rows (they sum to the histogram
             # totals by construction) — one [3]-element psum instead of
             # reading feature 0's bins from a sliced/unmerged histogram
             root_tot = lax.psum(jnp.sum(stats, axis=0), axis_name)
         else:
-            root_tot = jnp.sum(root_hist[0], axis=0)                 # (g, h, c)
+            root_tot = jnp.sum(root_hist[:, 0], axis=1)              # (g, h, c)
         root_out = constrained_leaf_output(
             root_tot[0], root_tot[1], root_tot[2],
             ctx._replace(path_smooth=jnp.float32(0.0)),
@@ -1353,7 +1410,8 @@ def grow_tree_frontier(
         if dist_mode:
             rb0 = node_rand_bins(0)
             root_best = jax.tree.map(lambda x: x[0], score_dist(
-                root_hist[None], root_mask_f[None], jnp.ones((1,), bool),
+                from_planes(root_hist[None]), root_mask_f[None],
+                jnp.ones((1,), bool),
                 jnp.full((1,), -jnp.inf, jnp.float32),
                 jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
                 None if rb0 is None else rb0[None]))
@@ -1361,7 +1419,8 @@ def grow_tree_frontier(
             root_best = find_best_split(root_hist, ctx, root_mask_f,
                                         jnp.bool_(True), cat_info, mono=mono,
                                         parent_out=root_out,
-                                        rand_bins=node_rand_bins(0))
+                                        rand_bins=node_rand_bins(0),
+                                        bins_minor=True)
         if fp_axis is not None:
             # feature-parallel: each shard scanned its own column slice; one
             # tiny all_gather + argmax globalizes the winner (the same split
@@ -1373,11 +1432,18 @@ def grow_tree_frontier(
         return jnp.full((capacity,), val, dtype)
 
     K = _PK
+    fb3 = 3 * f_hist * num_bins
     st = _WaveState(
         nodes=_packed_root_table(capacity, root_out, root_tot, root_best,
                                  cat_info),
-        hist_cache=jnp.zeros((grow_leaves, f_hist, num_bins, 3),
-                             jnp.float32).at[0].set(root_hist),
+        # slot 0 holds the root; written as a select over the whole cache,
+        # because a one-row update of a cache the compiler keeps leaf-minor
+        # goes through a [1, 3*F*B] array whose 1-wide axis is stored 128
+        # lanes wide (783 MB at 2,000 features)
+        hist_cache=jnp.where(
+            lax.iota(jnp.int32, grow_leaves)[:, None] == 0,
+            jnp.broadcast_to(root_hist.reshape(fb3), (grow_leaves, fb3)),
+            jnp.float32(0.0)),
         node_slot=full(0, jnp.int32),
         row_leaf=jnp.zeros(n, jnp.int32),
         n_nodes=jnp.int32(1),
@@ -1393,27 +1459,22 @@ def grow_tree_frontier(
     bins_i32 = bins.astype(jnp.int32)
     iota_w = lax.iota(jnp.int32, w_width)
 
-    if fuse_part:
-        # loop-invariant kernel operands prepared ONCE (the in-call
-        # pad/convert re-ran per wave, ~2.7 ms each at 11M — r5 trace)
-        from ..ops.histogram_pallas import (hist_partition_fused_pallas,
-                                            prepare_wave_operands)
-
-        stats_prep_src = stats
-        if hist_dtype == "bf16sr":
-            # the opt-in SR variant must quantize here too — the fused
-            # path bypasses compute_histograms where SR normally applies
-            from ..ops.histogram import sr_round_bf16
-
-            stats_prep_src = sr_round_bf16(stats)
-        bins_t_prep, stats_t_prep, part_chunk = prepare_wave_operands(
-            bins, stats_prep_src, num_bins, w_width)
-        n_pad_rows = bins_t_prep.shape[1]
+    # The exact tail's overgrowth target is wave-aligned
+    # (gbdt._exact_overgrow_target): full waves land on it.  A leaf of the
+    # doubling waves with no split to offer leaves the count ONE short,
+    # and the loop then bought that one node of a heuristic margin of
+    # hundreds with a whole pass over the rows (one round in six at
+    # 400,000 x 2,000: 13.08 s for 12.37; the "one more 112 ms pass" of a
+    # Higgs seed).  So once the tree is past num_leaves, a pass has to
+    # have room for an eighth of a wave.
+    min_budget = max(1, w_width // 8) if exact else 1
 
     def cond(st: _WaveState):
         P = st.nodes
         gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
-        return (st.n_leaves < grow_leaves) & jnp.any(jnp.isfinite(gains))
+        budget = grow_leaves - st.n_leaves
+        return (((budget >= min_budget) | (st.n_leaves <= num_leaves))
+                & (budget > 0) & jnp.any(jnp.isfinite(gains)))
 
     def body(st: _WaveState) -> _WaveState:
         m = capacity
@@ -1481,7 +1542,9 @@ def grow_tree_frontier(
                 # single-f-block gate, so the dot stays bf16-exact.
                 zw = jnp.zeros(w_width)
                 tbl_w = jnp.stack([active_r.astype(f32),
-                                   prow[:, K.CAND_FEAT], prow[:, K.CAND_BIN],
+                                   (zw if multi_block
+                                    else prow[:, K.CAND_FEAT]),
+                                   prow[:, K.CAND_BIN],
                                    (2 * iota_w).astype(f32),
                                    direct_left.astype(f32), zw, zw, zw],
                                   axis=1)                        # [W, 8]
@@ -1495,22 +1558,17 @@ def grow_tree_frontier(
                     pv_t = jnp.pad(pv_t, ((0, 0), (0, n_pad_rows - n)))
                 direct_hist, enc = hist_partition_fused_pallas(
                     bins_t_prep, stats_t_prep, pv_t, w_width, num_bins,
-                    part_chunk,
-                    hist_dtype=("f32" if hist_dtype in ("f32", "f32x")
-                                else "bf16"),
+                    part_chunk, hist_dtype=kernel_dtype,
                     # multi-f-block routing gathers the wave split features'
                     # code rows; ignored on single-block shapes
                     wfeat=prow[:, K.CAND_FEAT].astype(jnp.int32),
                     num_features=num_features, name=HIST_WAVE)
-                # the kernel's direct_hist is the LOCAL pre-merge [W, F, B, 3]
-                # partial, so every merge topology applies after it unchanged
-                # (voting keeps it unmerged for the scorer's candidate union)
-                if hist_merge != "voting":
-                    direct_hist = histogram_merge(direct_hist, axis_name,
-                                                  mode=hist_merge,
-                                                  n_shards=n_shards,
-                                                  wire_dtype=hist_wire,
-                                                  n_chunks=merge_chunks)
+                # the kernel's direct_hist is the LOCAL pre-merge partial,
+                # planes [W, 3, F, B]: every merge topology applies after it
+                # unchanged (voting keeps it unmerged for the scorer's
+                # candidate union), in the layout the merges keep
+                if axis_name is not None and hist_merge != "voting":
+                    direct_hist = to_planes(merge(from_planes(direct_hist)))
                 enc = enc[:n]
                 row_leaf = jnp.where(enc > 0, st.n_nodes + enc - 1, p)
             else:
@@ -1583,7 +1641,7 @@ def grow_tree_frontier(
                 to_direct = psel & (go_left == (pv[:, 4] > 0))
                 seg_id = jnp.where(to_direct, rank2_r >> 1, w_width)
                 direct_hist = hist_fn(seg_id, w_width,
-                                      HIST_WAVE)      # [W, F, B, 3]
+                                      HIST_WAVE)      # [W, 3, F, B]
 
         with jax.named_scope("lgbtpu.wave.sibling"):
             # 4. sibling = parent - child (the subtraction trick).  The cache
@@ -1595,17 +1653,15 @@ def grow_tree_frontier(
             # and commits a pure += the while-carry can alias in place.
             # Exactness: one-hot factors are exact at every precision and
             # HIGHEST keeps the f32 cache values bit-exact.
-            fb3 = f_hist * num_bins * 3
-            cache_flat = st.hist_cache.reshape(grow_leaves, fb3)
             parent_slot = st.node_slot[parent_r]              # [W]
             oh_p = (parent_slot[:, None]
                     == lax.iota(jnp.int32, grow_leaves)[None, :])
             parent_hist = lax.dot_general(
-                oh_p.astype(f32), cache_flat,
+                oh_p.astype(f32), st.hist_cache,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=lax.Precision.HIGHEST,
-            ).reshape(w_width, f_hist, num_bins, 3)
+            ).reshape(w_width, 3, f_hist, num_bins)
             other_hist = parent_hist - direct_hist
             dl = direct_left[:, None, None, None]
             left_hist = jnp.where(dl, direct_hist, other_hist)
@@ -1624,12 +1680,11 @@ def grow_tree_frontier(
                  == slot2m[None, :])                          # [L, 2W]
             keep = 1.0 - jnp.any(q, axis=1).astype(f32)       # [L]
             newvals = jnp.concatenate([left_hist, right_hist])
-            cache = (cache_flat * keep[:, None] + lax.dot_general(
+            cache = st.hist_cache * keep[:, None] + lax.dot_general(
                 q.astype(f32), newvals.reshape(2 * w_width, fb3),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST,
-            )).reshape(st.hist_cache.shape)
+                precision=lax.Precision.HIGHEST)
             node_slot = _scatter(st.node_slot, nl_r, left_slot, active_r)
             node_slot = _scatter(node_slot, nr_r, right_slot, active_r)
 
@@ -1661,14 +1716,15 @@ def grow_tree_frontier(
             if dist_mode:
                 child_rand = (jax.vmap(node_rand_bins)(child_nodes)
                               if extra_trees else None)
-                bs = score_dist(child_hists, child_masks, depth_ok, child_lo,
-                                child_hi, child_vals, child_rand)
+                bs = score_dist(from_planes(child_hists), child_masks,
+                                depth_ok, child_lo, child_hi, child_vals,
+                                child_rand)
             elif extra_trees:
                 child_rand = jax.vmap(node_rand_bins)(child_nodes)
 
                 def score(h, m, d, lo_, hi_, po, rb):
                     return find_best_split(h, ctx, m, d, cat_info, mono,
-                                           lo_, hi_, po, rb)
+                                           lo_, hi_, po, rb, bins_minor=True)
 
                 bs: BestSplit = jax.vmap(score)(
                     child_hists, child_masks, depth_ok, child_lo, child_hi,
@@ -1677,7 +1733,7 @@ def grow_tree_frontier(
 
                 def score(h, m, d, lo_, hi_, po):
                     return find_best_split(h, ctx, m, d, cat_info, mono,
-                                           lo_, hi_, po)
+                                           lo_, hi_, po, bins_minor=True)
 
                 bs = jax.vmap(score)(child_hists, child_masks, depth_ok,
                                      child_lo, child_hi, child_vals)

@@ -186,15 +186,36 @@ def compute_histograms_pallas(
 #           8 mantissa bits (relative histogram error ~2e-3; AUC-parity
 #           validated by the Higgs bench and tests).
 #   "f32"   TWO native-rate passes via a hi/lo bfloat16 split of the stats
-#           (stats = hi + lo exactly to ~16 mantissa bits; one-hot exact),
-#           f32 accumulation — ~1e-5 relative error at half the cost of the
+#           (split_hi_lo: stats = hi + lo exactly, hi exact in bfloat16, lo
+#           rounded to it in the kernel: ~15 mantissa bits; one-hot exact),
+#           f32 accumulation — ~3e-5 relative error at half the cost of the
 #           6-pass HIGHEST decomposition the XLA path uses.
 # ---------------------------------------------------------------------------
 
 
+def split_hi_lo(x: jnp.ndarray):
+    """``(hi, lo)`` with ``x == hi + lo`` exactly, ``hi`` representable in
+    bfloat16 and ``|lo| < 2**-7 |x|``: the two operands of the "f32"
+    mode's two kernel passes.
+
+    ``hi`` is ``x`` with the low 16 bits of its pattern cleared: an
+    integer mask, not ``x.astype(bfloat16).astype(float32)``.  That round
+    trip is one the compiler may take out (XLA's excess-precision rule),
+    and it did in some passes and not in others: a kernel called by itself
+    read the same as bf16 to every digit (``lo`` all zero), and in a round
+    the root's and the waves' passes disagreed, which ``parent - child``
+    hands to one sibling whole, so "f32" read WORSE than bf16 (PERF.md,
+    PR 28).  A masked ``hi`` is exact in bfloat16 whatever rounds it
+    afterwards, and no rule removes it."""
+    bits = lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+    hi = lax.bitcast_convert_type(bits, jnp.float32)
+    return hi, x - hi
+
+
 def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *,
                   num_features: int, num_bins: int, num_segments: int,
-                  hist_dtype: str, chunk_dim: int = 1):
+                  hist_dtype: str, chunk_dim: int = 1,
+                  bins_minor: bool = False):
     @pl.when(pl.program_id(chunk_dim) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
@@ -246,11 +267,17 @@ def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *,
     # features iterate via fori_loop (NOT a static unroll: compile time must
     # stay flat in F — MSLR has 136 features); bins arrive TRANSPOSED
     # [F_blk, chunk] so the dynamic per-feature slice is on the major dim
+    # ``bins_minor``: the same NT dot with its operands exchanged, so the
+    # accumulator is [F_blk, K, B] and the K statistics lie on sublanes (K
+    # <= 8: one tile) with the bins on the lanes.  A narrow pass (the root's
+    # K = 3) otherwise leaves the chip an [F, B, 3] array that HBM stores
+    # 128 lanes wide: 264 MB at 2,000 features.
     def body(f, _):
         codes_t = bins_ref[pl.dslice(f, 1), :]             # [1, chunk] i32
         onehot_t = (iota_bt == codes_t).astype(oh_t)
+        lhs, rhs = (operand, onehot_t) if bins_minor else (onehot_t, operand)
         tile = lax.dot_general(
-            onehot_t, operand,
+            lhs, rhs,
             dimension_numbers=(((1,), (1,)), ((), ())),     # NT: both on
             preferred_element_type=acc_t)                   # the chunk dim
         out_ref[pl.dslice(f, 1), :, :] += tile[None]
@@ -367,9 +394,6 @@ def hist_fused_pallas(
         stats = jnp.pad(stats, ((0, pad), (0, 0)))
         seg_id = jnp.pad(seg_id, ((0, pad),), constant_values=-1)
 
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-
     scales = None
     if hist_dtype == "int8":
         # per-channel symmetric quantization to [-127, 127] with
@@ -392,14 +416,54 @@ def hist_fused_pallas(
                          -127.0, 127.0)
 
     # row axis on the 128-lane MINOR dim (see _fused_kernel layout note)
-    stats_t = stats.T                                       # [S, n]
-    seg_row = seg_id.reshape(1, -1)                         # [1, n]
+    out = hist_fused_prepared(
+        bins_t, stats.T, seg_id.reshape(1, -1), num_segments, num_bins,
+        chunk, f_blk, num_features, interpret=interpret,
+        hist_dtype=hist_dtype, name=name)
+    if scales is not None:
+        out = out * scales[None, None, None, :]
+    return out
+
+
+def hist_fused_prepared(
+    bins_t: jnp.ndarray,         # [n_fblk * f_blk, n_pad] i32 codes
+    stats_t: jnp.ndarray,        # [S, n_pad] f32
+    seg_row: jnp.ndarray,        # [1, n_pad] i32, -1 = in no segment
+    num_segments: int,
+    num_bins: int,
+    chunk: int,
+    f_blk: int,
+    num_features: int,
+    interpret: bool | None = None,
+    hist_dtype: str = "bf16",
+    name: str = "lgbtpu_hist_fused",
+) -> jnp.ndarray:
+    """:func:`hist_fused_pallas` on operands that are already transposed,
+    widened and padded to whole blocks (its own preparation, or
+    :func:`prepare_wave_operands`: the frontier grower's root pass reads
+    the table its wave passes read, so a tree keeps ONE 4-byte transposed
+    copy of the codes, 3.2 GB at 400,000 x 2,000, and not one per
+    padding).  ``hist_dtype``: "bf16", "f32" (hi/lo) or "int8" (statistics
+    quantized by the caller, who also applies the scales).  Returns f32
+    ``[num_segments, F, num_bins, S]``."""
+    f_rows, n_pad = bins_t.shape
+    s = stats_t.shape[0]
+    k = num_segments * s
+    n_fblk, n_chunks = f_rows // f_blk, n_pad // chunk
+    assert (n_fblk * f_blk, n_chunks * chunk) == (f_rows, n_pad)
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+
+    # a pass of at most 8 statistics columns (the root: 3) writes its
+    # accumulator bins-minor (see _fused_kernel); int8 keeps [F, B, K]
+    bins_minor = k <= 8 and hist_dtype != "int8"
+    acc_dims = (k, num_bins) if bins_minor else (num_bins, k)
 
     def one_pass(stats_arr, mode):
         return pl.pallas_call(
             functools.partial(_fused_kernel, num_features=num_features,
                               num_bins=num_bins, num_segments=num_segments,
-                              hist_dtype=mode),
+                              hist_dtype=mode, bins_minor=bins_minor),
             grid=(n_fblk, n_chunks),
             in_specs=[
                 pl.BlockSpec((f_blk, chunk), lambda fb, c: (fb, c),
@@ -409,28 +473,29 @@ def hist_fused_pallas(
                 pl.BlockSpec((1, chunk), lambda fb, c: (0, c),
                              memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((f_blk, num_bins, k),
+            out_specs=pl.BlockSpec((f_blk,) + acc_dims,
                                    lambda fb, c: (fb, 0, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct(
-                (n_fblk * f_blk, num_bins, k),
+                (n_fblk * f_blk,) + acc_dims,
                 jnp.int32 if mode == "int8" else jnp.float32),
             interpret=interpret,
             name=name,
         )(bins_t, stats_arr, seg_row)
 
     if hist_dtype == "f32":
-        # exact-to-~16-bit hi/lo bf16 split realized as TWO whole-kernel
+        # the hi/lo split (split_hi_lo) realized as TWO whole-kernel
         # passes over the identical single-dot program (a two-dot kernel
         # body crashed the TPU runtime intermittently)
-        hi = stats_t.astype(jnp.bfloat16).astype(jnp.float32)
-        out = one_pass(hi, "bf16") + one_pass(stats_t - hi, "bf16")
+        hi, lo = split_hi_lo(stats_t)
+        out = one_pass(hi, "bf16") + one_pass(lo, "bf16")
     else:
         out = one_pass(stats_t, hist_dtype)
-    out = out[:num_features]
+    out = out[:num_features].astype(jnp.float32)
+    if bins_minor:
+        out = out.reshape(num_features, num_segments, s, num_bins)
+        return out.transpose(1, 0, 3, 2)
     out = out.reshape(num_features, num_bins, num_segments, s)
-    if scales is not None:
-        out = out.astype(jnp.float32) * scales[None, None, None, :]
     return out.transpose(2, 0, 1, 3)
 
 
@@ -882,7 +947,15 @@ def hist_partition_fused_pallas(
 ):
     """Fused wave pass: histogram over the direct children PLUS the row
     partition (see _fused_part_kernel).  Returns
-    (hist f32 [num_segments, F, num_bins, S], enc i32 [n_pad]).
+    (hist f32 [num_segments, S, F, num_bins], enc i32 [n_pad]).
+
+    The histograms leave as PLANES, bins minor: the kernel's ``[F, B,
+    W*S]`` accumulator is turned once, as a 2-D transpose, and nothing
+    after it has the S-wide statistics axis as its minor one.  In HBM the
+    chip tiles the two minor axes by (8, 128), so ``[W, F, B, 3]`` is
+    stored as ``[W, F, B, 128]``: 42.7x its size, 22 GB for the 2W children
+    of one wave at 2,000 features (and 308 MB a copy at 28, which was most
+    of that round's temporaries).
 
     Single VMEM feature block: the r5 kernel routes from the resident
     bins tile.  Multiple blocks (F > ~45, r7): the W wave split
@@ -985,14 +1058,14 @@ def hist_partition_fused_pallas(
             )(bins_t, stats_arr, pv_t, wbins_t)
 
     if hist_dtype in ("f32", "f32x"):
-        hi = stats_t.astype(jnp.bfloat16).astype(jnp.float32)
+        hi, lo = split_hi_lo(stats_t)
         h1, enc = one_pass(hi)
-        h2, _ = one_pass(stats_t - hi)
+        h2, _ = one_pass(lo)
         out = h1 + h2
     else:
         out, enc = one_pass(stats_t)
-    out = out[:num_features].reshape(num_features, num_bins, num_segments, s)
-    return out.transpose(2, 0, 1, 3), enc[0]
+    out = out[:num_features].transpose(2, 0, 1)          # [W*S, F, B]
+    return out.reshape(num_segments, s, num_features, num_bins), enc[0]
 
 
 def hist_fused_pallas_batched(
@@ -1083,8 +1156,8 @@ def hist_fused_pallas_batched(
         )(bins_t, stats_arr, seg_flat)
 
     if hist_dtype == "f32":
-        hi = stats_flat.astype(jnp.bfloat16).astype(jnp.float32)
-        out = one_pass(hi, "bf16") + one_pass(stats_flat - hi, "bf16")
+        hi, lo = split_hi_lo(stats_flat)
+        out = one_pass(hi, "bf16") + one_pass(lo, "bf16")
     else:
         out = one_pass(stats_flat, hist_dtype)
     out = out.reshape(e, n_fblk * f_blk, num_bins, k)[:, :num_features]

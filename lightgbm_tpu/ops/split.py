@@ -223,11 +223,17 @@ def find_best_split(
     bound_hi=None,
     parent_out=None,
     rand_bins=None,
+    bins_minor: bool = False,
 ) -> BestSplit:
     """Scan one leaf's histogram for the best (feature, bin) split.
 
     Args:
-      hist: f32 ``[F, B, 3]`` per-(feature, bin) sums of (grad, hess, count).
+      hist: f32 ``[F, B, 3]`` per-(feature, bin) sums of (grad, hess, count);
+        with ``bins_minor`` the same sums as three planes ``[3, F, B]``, the
+        layout the frontier grower keeps its per-node histograms in.  The
+        scan itself always runs on the planes: the bin axis it sums along
+        is then the array's minor axis, where the chip's tiled HBM layout
+        pads 255 bins to 256 lanes instead of 3 statistics to 128.
       ctx: regularization scalars.
       feature_mask: f32/bool ``[F]`` — 1 for usable features this tree
         (feature_fraction sampling; SURVEY.md §2C "Stochasticity").
@@ -255,10 +261,12 @@ def find_best_split(
     Returns BestSplit with child statistics AND constrained child outputs so
     the grower can update node state without touching the histogram again.
     """
-    cum = jnp.cumsum(hist, axis=1)                 # [F, B, 3] inclusive prefix
-    total = cum[:, -1:, :]                         # [F, 1, 3]
-    lg, lh, lc = cum[..., 0], cum[..., 1], cum[..., 2]
-    tg, th, tc = total[..., 0], total[..., 1], total[..., 2]
+    if not bins_minor:
+        hist = jnp.moveaxis(hist, -1, 0)
+    cum = jnp.cumsum(hist, axis=2)                 # [3, F, B] inclusive prefix
+    total = cum[:, :, -1:]                         # [3, F, 1]
+    lg, lh, lc = cum[0], cum[1], cum[2]
+    tg, th, tc = total[0], total[1], total[2]
     rg, rh, rc = tg - lg, th - lh, tc - lc
 
     lo = jnp.float32(-jnp.inf) if bound_lo is None else bound_lo
@@ -277,7 +285,7 @@ def find_best_split(
         m = mono[:, None].astype(wl.dtype)         # [F, 1]
         valid &= (m == 0) | (m * (wr - wl) >= 0)
     if rand_bins is not None:
-        pos_b = jnp.arange(hist.shape[1])[None, :]
+        pos_b = jnp.arange(hist.shape[2])[None, :]
         valid &= pos_b == rand_bins[:, None]
     gain = jnp.where(valid, gain, NEG_INF)
 
@@ -287,21 +295,28 @@ def find_best_split(
         flat_idx = jnp.argmax(gain.reshape(-1))
         feat = (flat_idx // num_bins).astype(jnp.int32)
         bin_idx = (flat_idx % num_bins).astype(jnp.int32)
-        # 4 gathers instead of 10, with NO materialized re-pack: the left
-        # (g,h,c) triple comes straight out of the existing cumsum tensor
-        # in one gather, the right triple is total - left, and only the
-        # two child outputs gather separately.  (A [F,B,8] stacked re-pack
-        # would be one gather fewer but materializes ~35 MB per call once
-        # the frontier grower vmaps this over its wave segments; the
-        # strict sweep path is kernel-count-bound, PERF_HISTORY.md r4.)
-        win_l = cum[feat, bin_idx]                        # [3] (g, h, c)
-        tot = total[feat, 0]                              # [3]
+        # The winner's numbers are picked by MASK and sum, not gathered:
+        # exactly one cell is kept, so the sum is that cell's value to the
+        # bit, and the pick fuses into one more read of the planes.  A
+        # gather of the (g, h, c) triple made XLA re-lay the whole batch of
+        # cumulative planes with the 3-wide axis minor once the frontier
+        # grower vmaps this over its wave (308 MB a wave at Higgs's width,
+        # 22 GB at 2,000 features).  The right triple is total - left.
+        hit = ((jnp.arange(num_features)[:, None] == feat)
+               & (jnp.arange(num_bins)[None, :] == bin_idx))      # [F, B]
+
+        def pick(x):
+            return jnp.sum(jnp.where(hit, x, 0.0), axis=(-2, -1))
+
+        win_l = pick(cum)                                 # [3] (g, h, c)
+        tot = jnp.sum(jnp.where(jnp.arange(num_features) == feat,
+                                total[:, :, 0], 0.0), axis=1)      # [3]
         win_r = tot - win_l
         return BestSplit(
             gain=jnp.max(gain), feature=feat, bin=bin_idx,
             left_g=win_l[0], left_h=win_l[1], left_c=win_l[2],
             right_g=win_r[0], right_h=win_r[1], right_c=win_r[2],
-            left_out=wl[feat, bin_idx], right_out=wr[feat, bin_idx])
+            left_out=pick(wl), right_out=pick(wr))
 
     is_cat = cat_info.is_cat
     # Fisher ordering: bins ranked by grad/(hess + cat_smooth); empty bins
@@ -310,7 +325,7 @@ def find_best_split(
     # child.  Upstream scans ASCENDING and DESCENDING (each prefix capped
     # at max_cat_threshold), which together reach small-subset partitions
     # on either end of the ordering.
-    g_, h_, c_ = hist[..., 0], hist[..., 1], hist[..., 2]
+    g_, h_, c_ = hist[0], hist[1], hist[2]
     raw_score = g_ / (h_ + cat_info.cat_smooth)
     pos = jnp.arange(num_bins)[None, :]
     ctx_cat = ctx._replace(lambda_l2=ctx.lambda_l2 + cat_info.cat_l2)
@@ -318,9 +333,9 @@ def find_best_split(
                  else parent_out)
 
     def scan_direction(order):
-        hist_s = jnp.take_along_axis(hist, order[..., None], axis=1)
-        cum_s = jnp.cumsum(hist_s, axis=1)
-        slg, slh, slc = cum_s[..., 0], cum_s[..., 1], cum_s[..., 2]
+        hist_s = jnp.take_along_axis(hist, order[None], axis=2)
+        cum_s = jnp.cumsum(hist_s, axis=2)
+        slg, slh, slc = cum_s[0], cum_s[1], cum_s[2]
         srg, srh, src = tg - slg, th - slh, tc - slc
         gain_c, swl, swr = split_gain_scan(slg, slh, slc, srg, srh, src,
                                            tg, th, ctx_cat, lo, hi,

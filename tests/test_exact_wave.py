@@ -142,6 +142,14 @@ def test_resolve_wave_width_exact_encoding():
     assert width == 42
     p2 = parse_params({"objective": "regression", "num_leaves": 31})
     assert resolve_wave_width(p2, 46000) < 0     # mid-size pointwise greedy
+    # ... only while the tree closes before the wave width binds: 255
+    # leaves at 1,568 rows a leaf grow with the exact tail (PR 28: greedy
+    # read a best-first excess of 0.12 and 0.37 there, limit 0.04)
+    wide = parse_params({"objective": "binary", "num_leaves": 255})
+    assert resolve_wave_width(wide, 400_128) // 1024 > 255
+    assert resolve_wave_width(wide, 400_128) % 1024 == 42
+    p43 = parse_params({"objective": "binary", "num_leaves": 43})
+    assert resolve_wave_width(p43, 400_128) == -42
     p3 = parse_params({"objective": "lambdarank", "num_leaves": 63})
     assert resolve_wave_width(p3, 100000) >= 1024   # ranking -> exact
     p4 = parse_params({"objective": "binary", "num_leaves": 127,

@@ -124,3 +124,25 @@ def test_fused_pallas_int8_quantized():
     sigma_q = F * np.sqrt(n / K / 12.0)
     np.testing.assert_allclose(tg, wg, rtol=5e-3,
                                atol=float(scale.max()) * 4 * sigma_q)
+
+
+def test_split_hi_lo_is_exact_and_hi_is_bfloat16():
+    """The two operands of the "f32" mode's passes: they add up to the
+    statistics exactly, the first is a bfloat16 value whatever rounds it
+    afterwards, the second is under 2**-7 of it."""
+    import jax
+
+    from lightgbm_tpu.ops.histogram_pallas import split_hi_lo
+
+    rng = np.random.RandomState(5)
+    x = np.concatenate([rng.randn(4096), [0.5003, -0.4997, 0.0, -0.0,
+                                          1e-30, -3e38, 2.0 ** -126]]
+                       ).astype(np.float32)
+    hi, lo = jax.jit(split_hi_lo)(jnp.asarray(x))
+    hi, lo = np.asarray(hi), np.asarray(lo)
+    np.testing.assert_array_equal(hi + lo, x)
+    np.testing.assert_array_equal(
+        np.asarray(jnp.asarray(hi).astype(jnp.bfloat16).astype(jnp.float32)),
+        hi)
+    assert np.all(np.abs(lo) <= np.abs(x) * 2.0 ** -7)
+    assert np.any(lo != 0)

@@ -60,7 +60,8 @@ def _reference(bins, stats, leaf, wfeat, wthr, wdl):
         rows = np.flatnonzero(seg == w)
         for f in range(F):
             np.add.at(hist[w, f], (bins[rows, f],), stats[rows])
-    return hist, enc
+    # the fused pass hands its histograms over as planes [W, S, F, B]
+    return hist.transpose(0, 3, 1, 2), enc
 
 
 def run_fused(bins, stats, pv, wfeat):

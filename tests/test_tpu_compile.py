@@ -17,6 +17,7 @@ compiled for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import pytest
 
@@ -301,8 +302,6 @@ def test_kernel_instruction_names(one_chip, case, name, instruction):
     """The compiled Mosaic call's HLO instruction is named by the program:
     the kernel's own name by default, the ROLE where a grower passes one
     (what the device trace shows and the benchmark's metrics match)."""
-    import re
-
     fn, shapes = _named_cases()[case]({} if name is None else {"name": name})
     text = _compile(fn, one_chip, *shapes).as_text()
     calls = re.findall(
@@ -339,3 +338,117 @@ def test_bin_code_block_program(one_chip, num_features, rows_padded):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes > 0          # codes written in place
     assert memory.temp_size_in_bytes < 128 << 20
+
+
+# -- the whole round (PR 28) -------------------------------------------------
+
+_HLO_SHAPE = re.compile(r"(f32)\[([0-9,]+)\]\{([0-9,]+):T\(([0-9,]+)\)")
+
+
+def _narrow_minor_f32(text, least_bytes):
+    """``[(padded bytes, shape)]`` of the float32 arrays in a compiled
+    program's text whose MINOR axis (the first of ``minor_to_major``) is
+    under 8 wide and whose tiled size is over ``least_bytes``: the chip
+    stores the two minor axes in (8, 128) tiles, so such an array takes
+    up to 128x its bytes (``f32[84,2000,256,3]``: 22 GB for 0.5)."""
+    found = set()
+    for m in _HLO_SHAPE.finditer(text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        order = [int(d) for d in m.group(3).split(",")]
+        tile = [int(d) for d in m.group(4).split(",")]
+        if len(order) != len(dims) or dims[order[0]] >= 8:
+            continue
+        phys = [dims[i] for i in reversed(order)]          # major .. minor
+        for j, t in enumerate(reversed(tile)):
+            if j < len(phys):
+                phys[-1 - j] = -(-phys[-1 - j] // t) * t
+        size = 4
+        for d in phys:
+            size *= d
+        if size > least_bytes:
+            found.add((size, m.group(0)))
+    return sorted(found, reverse=True)
+
+
+def _round_compiled(one_chip, monkeypatch, rows_padded, num_features,
+                    **params):
+    """``Booster._fused_segment(1)`` lowered for the described chip with
+    the row axis set to ``rows_padded``: a booster on a small table of the
+    real width (255 bins, 255 leaves), its dataset's row count replaced
+    by a shape so the program resolves its precision, wave tail and
+    blocking as at the real size, ``jax.default_backend`` patched so the
+    round takes the chip's route (the Pallas kernels)."""
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.utils import profiling
+
+    rng = np.random.default_rng(0)
+    X = rng.standard_normal((2048, num_features)).astype(np.float32)
+    y = (rng.random(2048) < 0.5).astype(np.float32)
+    booster = lgb.Booster(
+        dict(objective="binary", num_leaves=255, learning_rate=0.1,
+             max_bin=255, min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0,
+             verbosity=-1, **params), lgb.Dataset(X, label=y))
+    ds = booster.train_set
+    small = int(ds.row_mask.shape[0])
+    ds.row_mask = jax.ShapeDtypeStruct((rows_padded,), ds.row_mask.dtype)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = booster._fused_segment(1)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            tuple(rows_padded if d == small else d for d in a.shape),
+            a.dtype, sharding=one_chip), args)
+    compiled = fn.lower(*shapes).compile()
+    return compiled, dict(profiling.snapshot()["facts"])
+
+
+# (rows padded, features, parameters, facts the program resolves to, the most
+# temporaries).  Wide: LightGBM's GPU benchmark on Epsilon, 400,000 x 2,000:
+# what the program resolves to by itself (hi/lo float32, the exact tail: a
+# cache of 526 leaves, 3.2 GB, which the update writes a second time), then
+# the control's precision and the other tail; the parent stopped here with
+# RESOURCE_EXHAUSTED (22.0 GB for one f32[84,2000,3,256]).  Narrow:
+# Higgs-1M, where the parent's round took 1,457,935,872 B of temporaries,
+# most of it the same lane padding.
+_ROUNDS = {
+    "epsilon_default": (400_128, 2000, {},
+                        {"hist_dtype": "f32", "wave_tail": "exact"},
+                        13 << 30),
+    "epsilon_bf16": (400_128, 2000, {"hist_dtype": "bf16"},
+                     {"hist_dtype": "bf16", "wave_tail": "exact"}, 13 << 30),
+    "epsilon_greedy_tail": (400_128, 2000, {"wave_tail": "greedy"},
+                            {"hist_dtype": "f32", "wave_tail": "greedy"},
+                            11 << 30),
+    "higgs_1m": (N_ROWS, 28, {}, {"hist_dtype": "bf16",
+                                  "wave_tail": "exact"}, 1_457_935_872),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROUNDS))
+def test_whole_round(one_chip, monkeypatch, case):
+    rows_padded, num_features, params, resolved, most_temp = _ROUNDS[case]
+    compiled, facts = _round_compiled(one_chip, monkeypatch, rows_padded,
+                                      num_features, **params)
+    for fact, value in resolved.items():
+        assert facts["train." + fact] == value
+    assert facts["train.rows_padded"] == rows_padded
+    assert facts["train.features"] == num_features
+    text = compiled.as_text()
+    assert "%lgbtpu_hist_root" in text and "%lgbtpu_hist_wave" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= most_temp
+    assert _narrow_minor_f32(text, 64 << 20) == []
+    if num_features == 2000:
+        # the blocking of _vmem_blocking as the facts state it
+        assert (facts["train.feature_blocks"], facts["train.features_padded"],
+                facts["train.chunk_rows"]) == (63, 2016, 3072)
+        assert facts["train.hist_calls_per_pass"] == (
+            2 if resolved["hist_dtype"] == "f32" else 1)
+
+
+def test_narrow_minor_reader_sees_the_parents_buffer():
+    line = ("%copy.342 = f32[84,2000,3,256]{2,3,1,0:T(8,128)} copy(%x), "
+            "f32[84,3,2000,255]{3,2,1,0:T(8,128)} f32[8,3]{1,0:T(8,128)}")
+    assert _narrow_minor_f32(line, 64 << 20) == [
+        (84 * 2000 * 256 * 128 * 4, "f32[84,2000,3,256]{2,3,1,0:T(8,128)")]
