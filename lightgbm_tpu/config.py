@@ -313,7 +313,9 @@ _FRAMEWORK_KEYS = {
     "wave_width",          # frontier grower: max splits per histogram pass
     "wave_tail",           # "exact" (strict order via overgrow+replay) |
                            # "greedy" (fewest passes) | "half" (near-strict)
-    "wave_overgrow",       # exact tail: overgrowth factor (default 2.0)
+    "wave_overgrow",       # exact tail: CAP of the overgrowth as a factor
+                           # of num_leaves (default 2.0); a tree stops
+                           # earlier once its replay is certified exact
     "linear_k",            # linear_tree: max path features per leaf model
     "histogram_merge",     # dp merge topology override: "psum" |
                            # "reduce_scatter" | "reduce_scatter_ring" |

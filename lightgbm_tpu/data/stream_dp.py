@@ -363,7 +363,7 @@ def _grow_wave_dp(shards, mesh, stats, feature_mask, ctx, num_leaves,
     multi = shards[0].num_blocks > 1
     # host sync once per wave, same GL002-baselined predicate as the
     # serial streamed driver (the block loop is a host loop)
-    while bool(cond(Ptbl, n_leaves)):
+    while bool(cond(Ptbl, n_leaves, num_leaves)):
         tbl = plan(Ptbl, n_leaves)
         acc = None
         for off, bins_g in dp_block_rounds(shards, mesh):

@@ -140,7 +140,7 @@ def _grow_wave(store, stats, feature_mask, ctx, num_leaves, num_bins,
     # host sync once per wave: the wave count is data-dependent and the
     # block loop is a host loop, so the while predicate must come back to
     # the host (graftlint GL002 — baselined with this justification)
-    while bool(cond(P, n_leaves)):
+    while bool(cond(P, n_leaves, num_leaves)):
         tbl = plan(P, n_leaves)
         acc = None
         for off, bins_b in store.device_blocks():

@@ -135,10 +135,12 @@ def check_int8_row_limit(p: Params, n_rows: int, n_shards: int = 1) -> None:
 
 
 def _exact_overgrow_target(num_leaves: int, width: int, over: float) -> int:
-    """Wave-aligned overgrowth target for the exact tail.
+    """Wave-aligned overgrowth CAP for the exact tail (the leaf count a
+    tree grows to when its replay is never certified earlier:
+    ``tree._replay_certified``).
 
     Every histogram pass costs the same whether it retires 2 or ``width``
-    splits, so an overgrowth target that lands mid-wave buys its last few
+    splits, so a cap that lands mid-wave buys its last few
     candidate nodes at the price of a full pass.  Walk the greedy wave
     schedule (same recurrence as the grower: wave size = min(frontier
     doubling, width)) and pick the wave boundary closest to
@@ -186,10 +188,14 @@ def resolve_wave_width(p: Params, n_rows: int) -> int:
     width = max(1, min(width, 512))
     # wave_tail — how the wave schedule spends the tail of the leaf
     # budget, where wave and strict best-first order can diverge:
-    #   "exact"  — overgrow greedily ~2x past num_leaves, then replay
+    #   "exact"  — overgrow past num_leaves in pathmin order until the
+    #     replay is provably the strict tree (models/tree.py
+    #     _replay_certified; at most to the ~2x cap below), then replay
     #     strict best-first selection over the realized gains and prune
-    #     (models/tree.py _exact_prune).  LightGBM-exact split ORDER at
-    #     ~one extra histogram pass over greedy; r4's gap decomposition
+    #     (_exact_prune).  LightGBM-exact split ORDER at the larger of
+    #     greedy's pass count and the strict tree's depth (a pass grows
+    #     one level): 11-16 passes at 255 leaves, width 42, for greedy's
+    #     11 and the cap's 17 (PERF.md PR 29); r4's gap decomposition
     #     proved split order was the ENTIRE residual quality gap of the
     #     old near-strict tail (PERF_HISTORY.md), so this is the default
     #     wherever order can matter: large data (the AUC-parity north
@@ -226,11 +232,12 @@ def resolve_wave_width(p: Params, n_rows: int) -> int:
     if tail == "greedy":
         width = -width
     elif tail == "exact":
-        # default overgrowth 2.0: the r5 on-chip gap-vs-overgrow sweep
-        # converged at ~2x (Higgs-1M: 1.5x -> +8.6e-4 vs oracle, 2.0x ->
-        # +0.3..2.1e-4 across oracle draws, 2.5x no better), and at 2x
-        # the 11M throughput still clears the 5x north star with the
-        # partition-fused kernel (PERF_HISTORY.md r5)
+        # wave_overgrow is the CAP of the overgrowth, for trees whose
+        # replay is not certified earlier.  Default 2.0: history sized
+        # it, when every tree ran to it (the r5 on-chip gap-vs-overgrow
+        # sweep converged at ~2x: Higgs-1M 1.5x -> +8.6e-4 vs oracle,
+        # 2.0x -> +0.3..2.1e-4 across oracle draws, 2.5x no better;
+        # PERF_HISTORY.md r5)
         over = float(p.extra.get("wave_overgrow", 2.0))
         l_over = _exact_overgrow_target(p.num_leaves, width, over)
         width = l_over * 1024 + width
@@ -2278,7 +2285,8 @@ class Booster:
         hist_dtype = resolve_hist_dtype(p, eff_rows)
         wave_width = resolve_wave_width(p, eff_rows)
         # what the pass that runs was decided to be: the shapes a roofline
-        # counts its work from (the benchmark's named metrics read them)
+        # counts its work from (the benchmark's named metrics read them);
+        # overgrow_leaves is the exact tail's cap, not what each tree grows to
         width, tail, overgrow = decode_wave_width(wave_width)
         segments = min(width, (overgrow or p.num_leaves) - 1)
         features = int(ds.X_binned.shape[1])

@@ -652,10 +652,11 @@ def grow_tree(
       * NEGATIVE — "greedy" tail (spend the whole remaining leaf budget
         per wave, fewest histogram passes);
       * ``>= 1024`` — "exact" mode, encoded ``overgrow_leaves * 1024 +
-        width``: overgrow greedily to ``overgrow_leaves``, then replay
-        strict best-first selection over the realized gains and prune
-        back to ``num_leaves`` (LightGBM-exact split ORDER at near-greedy
-        pass counts — see :func:`_exact_prune`);
+        width``: overgrow until the replay is certified, at most to
+        ``overgrow_leaves``, then replay strict best-first selection over
+        the realized gains and prune back to ``num_leaves``
+        (LightGBM-exact split ORDER at near-greedy pass counts — see
+        :func:`_exact_prune`, :func:`_replay_certified`);
       * otherwise — "half" tail (near-strict tail ordering).
     """
     raw_wave_width = wave_width
@@ -1061,10 +1062,11 @@ def _exact_prune(P, cand_catmask, row_leaf, num_leaves: int,
     stays a leaf and its budget goes to the next-best candidate — the
     only divergence from true strict order.  The overgrowth waves
     select by PATHMIN (= priority-first extraction order between
-    distinct priorities), which expands nodes in near-strict order and
-    makes misses rare at the ~2x default overgrowth (validated vs the strict
-    grower in tests/test_exact_wave.py; quality impact measured in the
-    bench's parity section).
+    distinct priorities) and stop as soon as :func:`_replay_certified`
+    proves there is no such node, so a miss can only happen in a tree
+    that reached the overgrowth CAP (``wave_overgrow``, ~2x) uncertified
+    (identity with the strict grower and with the uncertified loop:
+    tests/test_exact_wave.py).
 
     Returns (packed table [2*num_leaves-1, NC], pruned cand_catmask,
     remapped row_leaf, n_leaves).
@@ -1092,8 +1094,9 @@ def _exact_prune(P, cand_catmask, row_leaf, num_leaves: int,
     # available candidate gains -> keep -> activate children), all on
     # [m_over]-sized arrays (~6 tiny fused kernels per trip; a few ms per
     # round at production shapes).  Overgrown leaves with no scored
-    # children (coverage misses — rare under pathmin-ordered overgrowth)
-    # are skipped in favor of the next-best candidate.
+    # children (coverage misses — only in a tree that reached the cap
+    # uncertified, see _replay_certified) are skipped in favor of the
+    # next-best candidate.
     gain_c = P[:, K.CAND_GAIN]
     avail0 = jnp.zeros(m_over, bool).at[0].set(True)
     kept0 = jnp.zeros(m_over, bool)
@@ -1158,6 +1161,33 @@ def _exact_prune(P, cand_catmask, row_leaf, num_leaves: int,
         precision=(lax.Precision.DEFAULT if capacity <= 256
                    else lax.Precision.HIGHEST)).astype(jnp.int32)
     return newP, new_cat, row_leaf_new, n_kept + 1
+
+
+def _replay_certified(P, num_leaves: int):
+    """True once :func:`_exact_prune`'s replay over the packed node table
+    ``P`` is provably the strict best-first tree, whatever further
+    overgrowth would add.
+
+    Strict extraction takes every node of pathmin ``p`` before any node
+    of pathmin ``q < p`` (before the weakest ancestor of the second can
+    be taken, an ancestor-or-self of the first, all of gain ``>= p``, is
+    available and beats it).  ``T`` is the largest pathmin of a leaf that
+    still has a candidate split: nothing that is not expanded yet, and
+    nothing that could ever grow under it, has a pathmin above ``T``.  So
+    with ``num_leaves - 1`` EXPANDED nodes strictly above ``T`` the
+    replay's ``num_leaves - 1`` extractions lie inside the tree that
+    exists, and more passes change neither the kept splits nor their
+    statistics.  A tie with ``T`` is not counted (inside a pathmin tie
+    group strict order dives by gain): the test errs towards one more
+    pass, and is otherwise tight: with fewer, strict order's next pick
+    IS that unexpanded leaf.  Node-table-sized reductions only.
+    """
+    K = _PK
+    pm = P[:, K.PM]
+    cand = (P[:, K.IS_LEAF] > 0.5) & jnp.isfinite(P[:, K.CAND_GAIN])
+    t = jnp.max(jnp.where(cand, pm, -jnp.inf))
+    above = (P[:, K.LEFT] >= 0) & (pm > t)
+    return jnp.sum(above.astype(jnp.int32)) >= num_leaves - 1
 
 
 class _WaveState(NamedTuple):
@@ -1235,11 +1265,14 @@ def grow_tree_frontier(
     favor of higher-gain fresh children.  Predictive quality is equivalent
     in practice (tests compare both modes); LightGBM-exact split order
     needs either the strict grower or ``wave_tail="exact"`` — overgrow
-    greedily to ``overgrow_leaves``, then :func:`_exact_prune` replays
-    strict best-first selection over the realized gains and prunes back
-    to ``num_leaves`` (the budget-binding tail is the ONLY place wave and
-    strict order diverge, so recovering it recovers strict order at
-    roughly one extra histogram pass — PERF_HISTORY.md r4 gap decomposition).
+    in pathmin order until :func:`_replay_certified` proves the replay
+    (at most to ``overgrow_leaves``, the cap), then :func:`_exact_prune`
+    replays strict best-first selection over the realized gains and
+    prunes back to ``num_leaves`` (the budget-binding tail is the ONLY
+    place wave and strict order diverge, so recovering it recovers strict
+    order; a certified tree costs the larger of greedy's pass count and
+    the strict tree's depth — PERF.md PR 29; PERF_HISTORY.md r4 gap
+    decomposition).
     """
     n, num_features = bins.shape
     exact = wave_tail == "exact"
@@ -1459,8 +1492,10 @@ def grow_tree_frontier(
     bins_i32 = bins.astype(jnp.int32)
     iota_w = lax.iota(jnp.int32, w_width)
 
-    # The exact tail's overgrowth target is wave-aligned
-    # (gbdt._exact_overgrow_target): full waves land on it.  A leaf of the
+    # The exact tail's overgrowth cap is wave-aligned
+    # (gbdt._exact_overgrow_target): full waves land on it.  A tree whose
+    # replay is certified earlier (_replay_certified) stops there; one
+    # that is not runs on to the cap.  A leaf of the
     # doubling waves with no split to offer leaves the count ONE short,
     # and the loop then bought that one node of a heuristic margin of
     # hundreds with a whole pass over the rows (one round in six at
@@ -1473,8 +1508,9 @@ def grow_tree_frontier(
         P = st.nodes
         gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
         budget = grow_leaves - st.n_leaves
-        return (((budget >= min_budget) | (st.n_leaves <= num_leaves))
-                & (budget > 0) & jnp.any(jnp.isfinite(gains)))
+        go = (((budget >= min_budget) | (st.n_leaves <= num_leaves))
+              & (budget > 0) & jnp.any(jnp.isfinite(gains)))
+        return go & ~_replay_certified(P, num_leaves) if exact else go
 
     def body(st: _WaveState) -> _WaveState:
         m = capacity
@@ -1484,9 +1520,9 @@ def grow_tree_frontier(
             # Exact mode ranks by PATHMIN instead: priority-first extraction
             # order on a tree IS descending pathmin (see _exact_prune), so
             # pm-ordered waves expand nodes in the same order strict growth
-            # would — the overgrown tree then CONTAINS the strict selection
-            # (no coverage misses at the replay), instead of greedy-by-gain
-            # overgrowth hoping to have covered it.
+            # would — the overgrown tree soon CONTAINS the strict selection
+            # (cond stops the loop once _replay_certified proves it),
+            # instead of greedy-by-gain overgrowth hoping to have covered it.
             gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
             sel_key = (jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.PM], neg_inf)
                        if exact else gains)
@@ -2159,10 +2195,12 @@ def _stream_wave_fns(capacity: int, w_width: int, grow_leaves: int,
         P2 = P2.at[kid_idx].set(child_rows, mode="drop")
         return (P2, cache, node_slot2, n_nodes + 2 * s, n_leaves + s)
 
-    @jax.jit
-    def cond(P, n_leaves):
+    @functools.partial(jax.jit, static_argnames=("num_leaves",))
+    def cond(P, n_leaves, num_leaves):
         gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
-        return (n_leaves < grow_leaves) & jnp.any(jnp.isfinite(gains))
+        go = (n_leaves < grow_leaves) & jnp.any(jnp.isfinite(gains))
+        # exact tail: a saved pass is a saved re-stream of the store
+        return go & ~_replay_certified(P, num_leaves) if exact else go
 
     return plan, update, cond
 

@@ -208,3 +208,161 @@ def test_partition_fused_kernel_matches_unfused():
         np.testing.assert_allclose(np.asarray(t_u.leaf_value),
                                    np.asarray(t_f.leaf_value),
                                    rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The certified replay (PR 29): the exact tail stops overgrowing once
+# _replay_certified proves the replay is the strict best-first tree.
+# ---------------------------------------------------------------------------
+
+
+def _table(expanded_pm, leaves):
+    """Hand-made packed node table: one expanded node per pathmin of
+    ``expanded_pm``, one unexpanded leaf per ``(pathmin, cand_gain)``."""
+    from lightgbm_tpu.models.tree import _PK as K, _empty_packed_table
+
+    P = np.array(_empty_packed_table(2 * (len(expanded_pm) + len(leaves))))
+    for i, pm in enumerate(expanded_pm):
+        P[i, [K.LEFT, K.RIGHT, K.IS_LEAF, K.PM, K.CAND_GAIN]] = (
+            1, 2, 0.0, pm, pm)
+    for j, (pm, gain) in enumerate(leaves, start=len(expanded_pm)):
+        P[j, [K.IS_LEAF, K.PM, K.CAND_GAIN]] = (1.0, pm, gain)
+    return jnp.asarray(P)
+
+
+_NEG = -np.inf
+
+
+@pytest.mark.parametrize("name,expanded_pm,leaves,fires", [
+    # T = 2.0 (the best unexpanded candidate's pathmin), num_leaves = 5
+    ("exactly_num_leaves_minus_1_above", [9., 7., 5., 3., 1.],
+     [(2., 4.), (.5, .5)], True),
+    ("one_fewer", [9., 7., 5., 1.5, 1.], [(2., 4.), (.5, .5)], False),
+    ("tie_with_T_is_not_counted", [9., 7., 5., 2., 1.],
+     [(2., 4.), (.5, .5)], False),
+    # a leaf that can never split has no say, whatever its pathmin reads
+    ("dead_leaf_does_not_set_T", [9., 7., 5., 3.],
+     [(8., _NEG), (2., 4.)], True),
+    ("no_candidate_left", [9., 7., 5., 3.], [(8., _NEG), (2., _NEG)], True),
+    ("all_stump", [], [(6., 6.)], False),
+    ("all_stump_dead_root", [], [(_NEG, _NEG)], False),
+])
+def test_replay_certified_on_hand_made_tables(name, expanded_pm, leaves,
+                                              fires):
+    from lightgbm_tpu.models.tree import _replay_certified
+
+    assert bool(_replay_certified(_table(expanded_pm, leaves), 5)) is fires
+
+
+def _rank_stats(seed, n_queries=300, docs=16, F=8, B=64):
+    """Bin codes and lambdarank (grad, hess, 1) of a zero score."""
+    from lightgbm_tpu.config import parse_params
+    from lightgbm_tpu.ranking import LambdaRank
+
+    rng = np.random.default_rng(seed)
+    n = n_queries * docs
+    X = rng.integers(0, B, (n, F)).astype(np.uint8)
+    u = X[:, 0] * 0.05 + np.sin(X[:, 1] * 0.2) + rng.normal(0, 0.7, n)
+    y = np.clip(np.floor((u - u.min()) / (np.ptp(u) + 1e-9) * 5), 0, 4)
+    obj = LambdaRank(parse_params({"objective": "lambdarank"}))
+    obj.set_group(np.full(n_queries, docs), y, n)
+    g, h = obj.grad_hess(jnp.zeros(n, jnp.float32),
+                         jnp.asarray(y, jnp.float32), jnp.ones(n, jnp.float32))
+    return jnp.asarray(X), jnp.stack([g, h, jnp.ones(n)], axis=-1)
+
+
+# name -> (bins, stats, ctx, num_leaves, width)
+_IDENTITY_CASES = {
+    # no leaf runs out of rows: every wave is full
+    "dense": lambda: (*_make(0, n=20000, F=10), _ctx(), 63, 16),
+    # PR 28's saturating repro (40,000 x 200: leaves of the doubling waves
+    # with no split to offer) at a CPU size: growth ends under the cap
+    "saturating": lambda: (*_make(2, n=4000, F=20), _ctx(min_data=60),
+                           31, 8),
+    # growth stalls under num_leaves: the certificate has no say
+    "stalled": lambda: (*_make(1, n=4000, F=20), _ctx(min_data=200), 31, 8),
+    "lambdarank": lambda: (*_rank_stats(3), _ctx(min_data=5), 31, 8),
+}
+
+
+def _grow_exact(case, monkeypatch, never_certified=False):
+    """Exact-tail tree of a case, and the overgrown table its replay saw."""
+    from lightgbm_tpu.models import tree as T
+    from lightgbm_tpu.models.gbdt import _exact_overgrow_target
+
+    bins, stats, ctx, nl, width = _IDENTITY_CASES[case]()
+    cap = _exact_overgrow_target(nl, width, 2.0)
+    seen = {}
+    prune = T._exact_prune
+
+    def spy(P, *args):
+        seen["P"] = np.asarray(P)
+        return prune(P, *args)
+
+    with monkeypatch.context() as m:
+        m.setattr(T, "_exact_prune", spy)
+        if never_certified:
+            m.setattr(T, "_replay_certified",
+                      lambda P, num_leaves: jnp.bool_(False))
+        t, rl = grow_tree(bins, stats, jnp.ones(bins.shape[1], jnp.float32),
+                          ctx, nl, 64, -1, wave_width=cap * 1024 + width,
+                          hist_impl="jnp")
+    return t, np.asarray(rl), seen["P"], (bins, stats, ctx, nl, width, cap)
+
+
+def _overgrown_leaves(P):
+    from lightgbm_tpu.models.tree import _PK as K
+
+    return int((P[:, K.IS_LEAF] > 0.5).sum())
+
+
+@pytest.mark.parametrize("case", sorted(_IDENTITY_CASES))
+def test_certified_stop_grows_the_same_tree(case, monkeypatch):
+    """Stopping at the certificate changes nothing but the passes: the
+    tree and every row's leaf equal, array for array, those of the loop
+    that runs on to the cap (the helper patched to never fire); and where
+    the table is certified, the splits are the strict grower's."""
+    from lightgbm_tpu.models.tree import _replay_certified, tree_to_arrays
+
+    t_c, rl_c, P_c, (bins, stats, ctx, nl, _, cap) = _grow_exact(
+        case, monkeypatch)
+    t_n, rl_n, P_n, _ = _grow_exact(case, monkeypatch, never_certified=True)
+    assert _overgrown_leaves(P_c) <= _overgrown_leaves(P_n) <= cap
+    a_c, a_n = tree_to_arrays(t_c), tree_to_arrays(t_n)
+    assert a_c.keys() == a_n.keys()
+    for field in a_c:
+        np.testing.assert_array_equal(a_c[field], a_n[field], err_msg=field)
+    np.testing.assert_array_equal(rl_c, rl_n)
+    if bool(_replay_certified(jnp.asarray(P_c), nl)):
+        t_s, rl_s = grow_tree(bins, stats,
+                              jnp.ones(bins.shape[1], jnp.float32), ctx, nl,
+                              64, -1, wave_width=1, hist_impl="jnp")
+        assert int(t_s.num_leaves) == int(t_c.num_leaves)
+        assert _splits(t_s) == _splits(t_c)
+        np.testing.assert_allclose(
+            np.asarray(lookup_values(rl_s, t_s.leaf_value)),
+            np.asarray(lookup_values(jnp.asarray(rl_c), t_c.leaf_value)),
+            rtol=2e-4, atol=2e-6)
+    else:
+        assert case == "stalled"
+
+
+def _passes_to(leaves, width):
+    """Trips of the wave schedule (full waves) that end on ``leaves``."""
+    n, cand, passes = 1, 1, 0
+    while n < leaves:
+        s = min(cand, width)
+        n, cand, passes = n + s, min(2 * cand, n + s), passes + 1
+    assert n == leaves, (n, leaves)
+    return passes
+
+
+def test_certified_stop_makes_fewer_passes(monkeypatch):
+    """On a table whose waves are all full the certificate fires before
+    the cap: the loop ends on an earlier wave boundary of the schedule."""
+    _, _, P_c, (_, _, _, nl, width, cap) = _grow_exact("dense", monkeypatch)
+    _, _, P_n, _ = _grow_exact("dense", monkeypatch, never_certified=True)
+    assert _overgrown_leaves(P_n) == cap
+    assert nl <= _overgrown_leaves(P_c) < cap
+    assert (_passes_to(_overgrown_leaves(P_c), width)
+            < _passes_to(cap, width))
