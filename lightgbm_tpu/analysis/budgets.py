@@ -435,16 +435,16 @@ def fused_train_step_recompiles(n_hyper_batches: int = 3
     from ..config import parse_params
     from ..models.fused import _fused_cv_fn
     from ..models.gbdt import HyperScalars, _objective_static_key
+    from ..models.spec import GrowSpec
     from ..objectives import create_objective
 
     p = parse_params({"objective": "regression"}, warn_unknown=False)
     obj = create_objective(p)
     n, num_features, num_bins, num_leaves = 256, 4, 16, 7
     run_segment, init_carry, _ = _fused_cv_fn(
-        _objective_static_key(obj, p), num_leaves, num_bins,
+        _objective_static_key(obj, p), GrowSpec(num_leaves, num_bins),
         "l2", float(p.alpha), float(p.tweedie_variance_power),
-        t_max=6, bagging_freq=0, n_configs=1, n_folds=1,
-        hist_impl="auto", row_chunk=131072)
+        t_max=6, bagging_freq=0, n_configs=1, n_folds=1)
 
     rng = np.random.RandomState(0)
     bins = jnp.asarray(rng.randint(0, num_bins, size=(n, num_features)),

@@ -304,7 +304,9 @@ _METRIC_ALIASES: Dict[str, str] = {
 # TPU-framework-specific knobs (not LightGBM vocabulary): ride in
 # Params.extra without an unknown-parameter warning.
 _FRAMEWORK_KEYS = {
-    "hist_dtype",          # "f32" (default) | "bf16" MXU histogram inputs
+    "hist_dtype",          # "auto" (default: bf16 from 2**19 rows, else the
+                           # hi/lo f32 split) | "f32" (exact) | "bf16" |
+                           # "int8" | "bf16sr": models/spec.py
     "hist_impl",           # "auto" | "jnp" | "pallas"
     "row_chunk",           # histogram row-chunk size
     "cv_segment_rounds",   # fused-cv rounds per device dispatch

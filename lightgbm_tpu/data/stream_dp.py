@@ -56,6 +56,7 @@ and the compacted kernel shapes carry the whole change.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -64,17 +65,18 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..models.spec import GrowSpec, WaveSchedule
 from ..models.tree import (
     _stream_root_block_fn,
     _stream_strict_block_fn,
     _stream_wave_block_fn,
     _stream_wave_fns,
     _tree_from_packed,
-    decode_wave_width,
     stream_exact_prune,
     stream_strict_init,
     stream_strict_update,
     stream_wave_init,
+    wave_extent,
 )
 from ..ops.histogram import histogram_merge
 from ..parallel.data_parallel import DATA_AXIS, shard_rows
@@ -269,20 +271,17 @@ def _accumulate(acc, h, multi: bool):
 
 def stream_dp_grow_tree(shards, mesh, stats, feature_mask, ctx,
                         num_leaves: int, num_bins: int, max_depth,
-                        wave_width: int, hist_impl: str, hist_dtype: str,
+                        wave: WaveSchedule, hist_impl: str, hist_dtype: str,
                         merge_mode: str, wire_dtype: str,
                         merge_chunks: int):
     """Grow one tree streamed across the dp mesh; returns
     ``(tree [replicated], row_leaf [row-sharded])``."""
-    width, tail, overgrow = decode_wave_width(wave_width)
     args = (shards, mesh, stats, feature_mask, ctx, num_leaves, num_bins,
             max_depth, hist_impl, hist_dtype, merge_mode, wire_dtype,
             merge_chunks)
-    if width <= 1:
+    if wave.width <= 1:
         return _grow_strict_dp(*args)
-    return _grow_wave_dp(*args[:5], num_leaves, num_bins, max_depth,
-                         width, tail, overgrow, hist_impl, hist_dtype,
-                         merge_mode, wire_dtype, merge_chunks)
+    return _grow_wave_dp(*args, wave)
 
 
 def _dp_root_hist(shards, mesh, stats, num_bins, hist_impl, hist_dtype,
@@ -336,13 +335,11 @@ def _grow_strict_dp(shards, mesh, stats, feature_mask, ctx, num_leaves,
 
 
 def _grow_wave_dp(shards, mesh, stats, feature_mask, ctx, num_leaves,
-                  num_bins, max_depth, width, tail, overgrow, hist_impl,
-                  hist_dtype, merge_mode, wire_dtype, merge_chunks):
-    exact = tail == "exact"
-    grow_leaves = (max(num_leaves + 1, int(overgrow or 0)) if exact
-                   else num_leaves)
+                  num_bins, max_depth, hist_impl, hist_dtype, merge_mode,
+                  wire_dtype, merge_chunks, wave):
+    grow_leaves, w_width = wave_extent(wave, num_leaves)
+    exact = wave.tail == "exact"
     capacity = 2 * grow_leaves - 1
-    w_width = min(int(width), grow_leaves - 1)
     num_features = shards[0].num_features
     block_rows = shards[0].block_rows
     acc = _dp_root_hist(shards, mesh, stats, num_bins, hist_impl,
@@ -354,9 +351,9 @@ def _grow_wave_dp(shards, mesh, stats, feature_mask, ctx, num_leaves,
     n_nodes = jnp.int32(1)
     n_leaves = jnp.int32(1)
     plan, _, cond = _stream_wave_fns(capacity, w_width, grow_leaves,
-                                     num_features, num_bins, tail)
+                                     num_features, num_bins, wave.tail)
     upd = _dp_wave_update_fn(capacity, w_width, grow_leaves,
-                             num_features, num_bins, tail)
+                             num_features, num_bins, wave.tail)
     step = _dp_wave_block_step(mesh, w_width, num_bins, num_features,
                                block_rows, hist_impl, hist_dtype,
                                merge_mode, wire_dtype, merge_chunks)
@@ -386,19 +383,18 @@ def _grow_wave_dp(shards, mesh, stats, feature_mask, ctx, num_leaves,
 
 
 def stream_dp_plain_round(shards, mesh, obj_key: tuple, y, w, bag, pred,
-                          fmask, hyper, num_leaves: int, num_bins: int,
-                          hist_impl: str, hist_dtype: str,
-                          wave_width: int, is_rf: bool, merge_mode: str,
-                          wire_dtype: str, merge_chunks: int):
+                          fmask, hyper, spec: GrowSpec, is_rf: bool,
+                          merge_mode: str, wire_dtype: str,
+                          merge_chunks: int):
     """One plain gbdt/rf round streamed across the dp mesh — the
     streamed-dp restatement of ``stream_grow.stream_plain_round`` with
     the SAME jitted gradient/update functions (row-sharded residents
     partition elementwise, so per-row arithmetic is unchanged)."""
     _, _, stats = _grad_stats_fn(obj_key)(pred, y, w, bag)
     tree, row_leaf = stream_dp_grow_tree(
-        shards, mesh, stats, fmask, hyper.ctx(), num_leaves, num_bins,
-        hyper.max_depth, wave_width, hist_impl, hist_dtype, merge_mode,
-        wire_dtype, merge_chunks)
+        shards, mesh, stats, fmask, hyper.ctx(), spec.num_leaves,
+        spec.num_bins, hyper.max_depth, spec.wave, spec.hist_impl,
+        spec.hist_dtype, merge_mode, wire_dtype, merge_chunks)
     new_pred = _pred_update_fn(is_rf)(pred, hyper.learning_rate,
                                       row_leaf, tree.leaf_value)
     return tree, new_pred
@@ -427,9 +423,7 @@ def _dp_goss_pred_block_step(mesh, block_rows: int):
 def stream_dp_goss_round(shards, mesh, obj_key: tuple, y, w, bag, pred,
                          fmask, hyper, key, goss_k_shard,
                          top_rate: float, other_rate: float, seed: int,
-                         num_leaves: int, num_bins: int, hist_impl: str,
-                         hist_dtype: str, wave_width: int,
-                         merge_mode: str, wire_dtype: str,
+                         spec: GrowSpec, merge_mode: str, wire_dtype: str,
                          merge_chunks: int):
     """One GOSS round with PER-SHARD host sampling before transfer —
     the GOSS×wire compounding round.
@@ -507,8 +501,8 @@ def stream_dp_goss_round(shards, mesh, obj_key: tuple, y, w, bag, pred,
     bins_g = shard_rows(mesh, jnp.asarray(np.concatenate(bins_parts)))
     stats_g = shard_rows(mesh, jnp.asarray(np.concatenate(stats_parts)))
     grow = make_dp_grow_step(
-        mesh, num_leaves, num_bins, hist_impl, shards[0].block_rows,
-        wave_width, hist_dtype, merge_mode, 0, wire_dtype, merge_chunks)
+        mesh, dataclasses.replace(spec, row_chunk=shards[0].block_rows),
+        merge_mode, 0, wire_dtype, merge_chunks)
     tree, _ = grow(bins_g, stats_g, fmask, hyper, key)
 
     # train-score update: one full streamed sharded traversal pass

@@ -35,25 +35,27 @@ histograms shrink together.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.spec import GrowSpec, WaveSchedule
 from ..models.tree import (
     _stream_root_block_fn,
     _stream_strict_block_fn,
     _stream_wave_block_fn,
     _stream_wave_fns,
     _tree_from_packed,
-    decode_wave_width,
-    grow_tree,
+    grower_from_spec,
     renew_leaf_values,
     stream_exact_prune,
     stream_strict_init,
     stream_strict_update,
     stream_wave_init,
+    wave_extent,
 )
 from ..ops.lookup import lookup_values
 from ..ops.predict import predict_tree_binned
@@ -76,21 +78,19 @@ def _root_hist(store, stats, num_bins, hist_impl, hist_dtype):
 
 
 def stream_grow_tree(store, stats, feature_mask, ctx, num_leaves: int,
-                     num_bins: int, max_depth, wave_width: int,
+                     num_bins: int, max_depth, wave: WaveSchedule,
                      hist_impl: str = "auto", hist_dtype: str = "f32"):
     """Grow one tree from a BlockStore (plain numeric path).
 
-    Mirrors ``grow_tree``'s strict/wave dispatch on the encoded
-    ``wave_width``; returns ``(tree, row_leaf)`` like the in-memory
-    grower, with ``row_leaf`` sized ``store.padded_rows``.
+    Mirrors ``grow_tree``'s strict/wave dispatch on ``wave``; returns
+    ``(tree, row_leaf)`` like the in-memory grower, with ``row_leaf``
+    sized ``store.padded_rows``.
     """
-    width, tail, overgrow = decode_wave_width(wave_width)
-    if width <= 1:
+    if wave.width <= 1:
         return _grow_strict(store, stats, feature_mask, ctx, num_leaves,
                             num_bins, max_depth, hist_impl, hist_dtype)
     return _grow_wave(store, stats, feature_mask, ctx, num_leaves,
-                      num_bins, max_depth, width, tail, overgrow,
-                      hist_impl, hist_dtype)
+                      num_bins, max_depth, wave, hist_impl, hist_dtype)
 
 
 def _grow_strict(store, stats, feature_mask, ctx, num_leaves, num_bins,
@@ -119,12 +119,10 @@ def _grow_strict(store, stats, feature_mask, ctx, num_leaves, num_bins,
 
 
 def _grow_wave(store, stats, feature_mask, ctx, num_leaves, num_bins,
-               max_depth, width, tail, overgrow, hist_impl, hist_dtype):
-    exact = tail == "exact"
-    grow_leaves = (max(num_leaves + 1, int(overgrow or 0)) if exact
-                   else num_leaves)
+               max_depth, wave, hist_impl, hist_dtype):
+    grow_leaves, w_width = wave_extent(wave, num_leaves)
+    exact = wave.tail == "exact"
     capacity = 2 * grow_leaves - 1
-    w_width = min(int(width), grow_leaves - 1)
     num_features = store.num_features
     root_hist = _root_hist(store, stats, num_bins, hist_impl, hist_dtype)
     P, cache, node_slot = stream_wave_init(root_hist, ctx, feature_mask,
@@ -133,7 +131,8 @@ def _grow_wave(store, stats, feature_mask, ctx, num_leaves, num_bins,
     n_nodes = jnp.int32(1)
     n_leaves = jnp.int32(1)
     plan, update, cond = _stream_wave_fns(capacity, w_width, grow_leaves,
-                                          num_features, num_bins, tail)
+                                          num_features, num_bins,
+                                          wave.tail)
     blk = _stream_wave_block_fn(w_width, num_bins, num_features,
                                 store.block_rows, hist_impl, hist_dtype)
     multi = store.num_blocks > 1
@@ -184,17 +183,13 @@ def _grad_stats_fn(obj_key: tuple):
 
 
 @functools.lru_cache(maxsize=None)
-def _goss_grow_fn(num_leaves: int, num_bins: int, hist_impl: str,
-                  row_chunk: int, hist_dtype: str, wave_width: int):
+def _goss_grow_fn(spec: GrowSpec):
     """Jitted in-memory grower over the GOSS-compacted [k, F] matrix."""
+    grow = grower_from_spec(spec, fuse_partition=True)
 
     @jax.jit
     def fn(bins_c, stats, fmask, ctx, max_depth, key):
-        return grow_tree(bins_c, stats, fmask, ctx, num_leaves, num_bins,
-                         max_depth, ff_bynode=None, key=key,
-                         hist_impl=hist_impl, row_chunk=row_chunk,
-                         hist_dtype=hist_dtype, wave_width=wave_width,
-                         fuse_partition=True)
+        return grow(bins_c, stats, fmask, ctx, max_depth, None, key)
 
     return fn
 
@@ -239,15 +234,14 @@ def _pred_update_fn(is_rf: bool):
 
 
 def stream_plain_round(store, obj_key: tuple, y, w, bag, pred, fmask,
-                       hyper, num_leaves: int, num_bins: int,
-                       hist_impl: str, hist_dtype: str, wave_width: int,
-                       is_rf: bool, renew_alpha=None, renew_scale=None):
+                       hyper, spec: GrowSpec, is_rf: bool,
+                       renew_alpha=None, renew_scale=None):
     """One plain gbdt/rf boosting round over a BlockStore — the streamed
     restatement of gbdt's serial ``round_fn``."""
     _, _, stats = _grad_stats_fn(obj_key)(pred, y, w, bag)
     tree, row_leaf = stream_grow_tree(
-        store, stats, fmask, hyper.ctx(), num_leaves, num_bins,
-        hyper.max_depth, wave_width, hist_impl, hist_dtype)
+        store, stats, fmask, hyper.ctx(), spec.num_leaves, spec.num_bins,
+        hyper.max_depth, spec.wave, spec.hist_impl, spec.hist_dtype)
     if renew_alpha is not None:
         rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
         tree = renew_leaf_values(tree, row_leaf, y - pred, rw, renew_alpha)
@@ -258,10 +252,8 @@ def stream_plain_round(store, obj_key: tuple, y, w, bag, pred, fmask,
 
 def stream_goss_round(store, obj_key: tuple, y, w, bag, pred, fmask,
                       hyper, key, goss_k, top_rate: float,
-                      other_rate: float, seed: int, num_leaves: int,
-                      num_bins: int, hist_impl: str, hist_dtype: str,
-                      wave_width: int, renew_alpha=None,
-                      renew_scale=None):
+                      other_rate: float, seed: int, spec: GrowSpec,
+                      renew_alpha=None, renew_scale=None):
     """One GOSS round with host-side sampling before transfer.
 
     Selection runs on host copies of |g| and the bag (deliberate host
@@ -312,8 +304,8 @@ def stream_goss_round(store, obj_key: tuple, y, w, bag, pred, fmask,
     live = (bag[idx] > 0).astype(jnp.float32) * (wt > 0)
     wt = wt * live
     stats = jnp.stack([g[idx] * wt, h[idx] * wt, live], axis=-1)
-    grow = _goss_grow_fn(num_leaves, num_bins, hist_impl,
-                         store.block_rows, hist_dtype, wave_width)
+    grow = _goss_grow_fn(
+        dataclasses.replace(spec, row_chunk=store.block_rows))
     tree, rl_c = grow(bins_c, stats, fmask, hyper.ctx(), hyper.max_depth,
                       key)
     if renew_alpha is not None:

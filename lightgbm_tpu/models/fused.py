@@ -34,6 +34,7 @@ program.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import List, NamedTuple, Optional, Sequence
 
@@ -46,7 +47,9 @@ from ..config import Params, default_metric_for_objective
 from ..metrics import get_metric
 from .gbdt import HyperScalars, _objective_static_key, _rebuild_objective
 from ..ops.lookup import lookup_values
-from .tree import grow_tree
+from .spec import (STRICT, GrowSpec, WaveSchedule, resolve_grow_spec,
+                   resolve_wave)
+from .tree import grower_from_spec
 
 
 class FusedCVCarry(NamedTuple):
@@ -74,15 +77,14 @@ from .feature_mask import compose_tree_mask as _sample_features_within
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_cv_fn(obj_key: tuple, num_leaves: int, num_bins: int,
+def _fused_cv_fn(obj_key: tuple, spec: GrowSpec,
                  metric_name: str, metric_alpha: float,
                  metric_rho: float, t_max: int,
                  bagging_freq: int, n_configs: int, n_folds: int,
-                 hist_impl: str, row_chunk: int, hist_dtype: str = "f32",
-                 cat_key: Optional[tuple] = None, num_class: int = 1,
-                 wave_width: int = 1, bynode_off: bool = False):
+                 num_class: int = 1):
     """Build the jitted fused-cv program for one static configuration."""
     obj = _rebuild_objective(obj_key)
+    grow = grower_from_spec(spec)
     metric = get_metric(metric_name,
                         Params(alpha=metric_alpha,
                                tweedie_variance_power=metric_rho))
@@ -96,8 +98,6 @@ def _fused_cv_fn(obj_key: tuple, num_leaves: int, num_bins: int,
         ``pred`` is [n] (single-output) or [n, K] (multiclass — K trees
         grown simultaneously, the class axis vmapped over the grower
         exactly like the host loop's round_fn_mc)."""
-        from .gbdt import _build_cat_info
-
         num_features = bins.shape[1]
         g, h = obj.grad_hess(pred, y, w)
         fmask = _sample_features_within(jax.random.fold_in(key, 1), ff,
@@ -105,15 +105,8 @@ def _fused_cv_fn(obj_key: tuple, num_leaves: int, num_bins: int,
 
         def grow_one(gc, hc, kc):
             stats = jnp.stack([gc * bag, hc * bag, bag], axis=-1)
-            return grow_tree(
-                bins, stats, fmask, hyper.ctx(), num_leaves, num_bins,
-                hyper.max_depth,
-                ff_bynode=(None if bynode_off
-                           else hyper.feature_fraction_bynode),
-                key=kc, hist_impl=hist_impl,
-                row_chunk=row_chunk, hist_dtype=hist_dtype,
-                wave_width=wave_width,
-                cat_info=_build_cat_info(cat_key, num_features))
+            return grow(bins, stats, fmask, hyper.ctx(), hyper.max_depth,
+                        hyper.feature_fraction_bynode, kc)
 
         if num_class > 1:
             from .gbdt import mc_round_update
@@ -205,8 +198,9 @@ def _fused_cv_fn(obj_key: tuple, num_leaves: int, num_bins: int,
     return run_segment, init_carry, finalize
 
 
-def _fused_wave_width(p: Params, n_pad: int, hist_dtype: str) -> int:
-    """Wave width for the BATCHED regime: strict growth below ~2^19 rows.
+def _fused_wave_width(p: Params, n_pad: int,
+                      hist_dtype: str) -> WaveSchedule:
+    """Wave schedule for the BATCHED regime: strict growth below ~2^19 rows.
 
     With the configs x folds batch axis already amortizing per-pass fixed
     costs, waves' extra FLOPs and per-wave partition work LOSE at small n
@@ -222,9 +216,8 @@ def _fused_wave_width(p: Params, n_pad: int, hist_dtype: str) -> int:
                 or int(p.extra.get("wave_width", 0)) != 0)
     if not explicit and (n_pad < (1 << 19)
                         or hist_dtype in ("f32x", "int8")):
-        return 1
-    from .gbdt import resolve_wave_width
-    return resolve_wave_width(p, n_pad)
+        return STRICT
+    return resolve_wave(p, n_pad)
 
 
 def fused_cv_eligible(p: Params, feval, callbacks, train_set=None) -> bool:
@@ -356,23 +349,21 @@ class FusedCVProgram:
         self._num_class = num_class
         self._init_score = init
 
-        from .gbdt import resolve_hist_dtype
-
         cats = np.flatnonzero(train_set.col_is_categorical)
         cat_key = ((tuple(int(c) for c in cats), float(p0.cat_smooth),
                     float(p0.cat_l2), int(p0.max_cat_threshold))
                    if len(cats) else None)
-        hd = resolve_hist_dtype(p0, n_pad)
-        self._run_segment, self._init_carry, self._finalize = _fused_cv_fn(
-            _objective_static_key(obj, p0), p0.num_leaves,
-            train_set.num_bins, self.metric_name, float(p0.alpha),
-            float(p0.tweedie_variance_power), num_boost_round,
-            int(bagging_freq), n_configs, n_folds,
-            p0.extra.get("hist_impl", "auto"),
-            int(p0.extra.get("row_chunk", 131072)),
-            hd, cat_key, num_class, _fused_wave_width(p0, n_pad, hd),
+        spec = resolve_grow_spec(p0, n_pad, train_set.num_bins,
+                                 cat_key=cat_key)
+        spec = dataclasses.replace(
+            spec, wave=_fused_wave_width(p0, n_pad, spec.hist_dtype),
             bynode_off=all(p.feature_fraction_bynode >= 1.0
                            for p in param_list))
+        self._run_segment, self._init_carry, self._finalize = _fused_cv_fn(
+            _objective_static_key(obj, p0), spec, self.metric_name,
+            float(p0.alpha), float(p0.tweedie_variance_power),
+            num_boost_round, int(bagging_freq), n_configs, n_folds,
+            num_class)
 
         self._tm_d = jnp.asarray(tm)
         self._args = (

@@ -32,8 +32,9 @@ from ..ops.lookup import lookup_values
 from ..ops.predict import predict_forest_binned, predict_tree_binned
 from ..ops.split import SplitContext
 from ..utils import profiling
-from .tree import (Tree, decode_wave_width, grow_tree, pad_tree,
-                   renew_leaf_values)
+from .spec import GrowSpec, check_int8_row_limit, resolve_grow_spec
+from .tree import (Tree, grower_from_spec, pad_tree, renew_leaf_values,
+                   wave_extent)
 
 
 class HyperScalars(NamedTuple):
@@ -83,169 +84,6 @@ class HyperScalars(NamedTuple):
         )
 
 
-def resolve_hist_dtype(p: Params, n_rows: int) -> str:
-    """Histogram matmul precision (static).
-
-    "auto" picks bf16 one-hot matmuls (full-rate MXU, f32 accumulation) once
-    the data is large enough that (a) the histogram pass dominates wall time
-    and (b) per-bin sums average over enough rows that the ~0.4% bf16
-    quantization of per-row grad/hess washes out of the split scores
-    (validated against f32 AUC on the Higgs bench).  Small data under
-    "auto" resolves to "f32", which the fused TPU kernel serves as a hi/lo
-    bf16 split (2 passes, ~1e-5 relative).  An EXPLICIT
-    ``hist_dtype="f32"`` request is a contract for exactness (ADVICE r3):
-    it resolves to "f32x", which bypasses the fused kernel for the true
-    Precision.HIGHEST path unless ``hist_impl="pallas"`` is also forced.
-    """
-    if p.use_quantized_grad:
-        # upstream's quantized-gradient training: reduced-precision
-        # histogram accumulation.  bf16 MXU inputs are the FAST reduced
-        # mode on this chip: a true int8 path exists (hist_dtype="int8",
-        # stochastic rounding + exact int32 accumulation) but Mosaic's
-        # int8 relayouts force a 4x smaller row chunk and it measured
-        # 17.8 ms/pass vs bf16's 10.5 at the Higgs shape
-        return "bf16"
-    d = p.extra.get("hist_dtype", "auto")
-    if d != "auto":
-        return "f32x" if d == "f32" else d
-    return "bf16" if n_rows >= (1 << 19) else "f32"
-
-
-def check_int8_row_limit(p: Params, n_rows: int, n_shards: int = 1) -> None:
-    """Fail fast when ``hist_dtype='int8'`` cannot accumulate exactly.
-
-    The kernel-level guard (``hist_fused_pallas``) catches this too, but
-    only at trace time inside the compiled round — by which point the
-    user has paid dataset binning and sharding.  This check runs once per
-    ``update()`` with the Booster's own shard count, so oversized int8
-    configs die with a clear message before any lowering.
-    """
-    if resolve_hist_dtype(p, n_rows) != "int8":
-        return
-    from ..ops.histogram_pallas import INT8_ACC_ROW_LIMIT
-
-    per_shard = -(-n_rows // max(int(n_shards), 1))
-    if per_shard > INT8_ACC_ROW_LIMIT:
-        raise ValueError(
-            f"hist_dtype='int8' with {per_shard:,} rows per device shard "
-            f"(n={n_rows:,} over {n_shards} shard(s)) exceeds the exact "
-            f"int32 accumulation limit of {INT8_ACC_ROW_LIMIT:,} rows — "
-            f"histograms would silently wrap.  Use hist_dtype='bf16' or "
-            f"train on more devices.")
-
-
-def _exact_overgrow_target(num_leaves: int, width: int, over: float) -> int:
-    """Wave-aligned overgrowth CAP for the exact tail (the leaf count a
-    tree grows to when its replay is never certified earlier:
-    ``tree._replay_certified``).
-
-    Every histogram pass costs the same whether it retires 2 or ``width``
-    splits, so a cap that lands mid-wave buys its last few
-    candidate nodes at the price of a full pass.  Walk the greedy wave
-    schedule (same recurrence as the grower: wave size = min(frontier
-    doubling, width)) and pick the wave boundary closest to
-    ``num_leaves * over`` in log space, bounded to (num_leaves, 2.5x].
-    """
-    import math
-
-    target = max(num_leaves * over, num_leaves + 1)
-    leaves, cand = 1, 1
-    best = None
-    while leaves < 2.5 * num_leaves:
-        s = min(cand, width)
-        leaves += s
-        cand = min(cand * 2, leaves)
-        if leaves > num_leaves:
-            if best is None or (abs(math.log(leaves / target))
-                                < abs(math.log(best / target))):
-                best = leaves
-    return best or int(math.ceil(target))
-
-
-def resolve_wave_width(p: Params, n_rows: int) -> int:
-    """Pick the grower's splits-per-histogram-pass (static).
-
-    ``grow_policy="leafwise"`` forces strict best-first (1) — use it when
-    LightGBM-exact split ORDER matters (wave growth picks each wave's split
-    set before scoring that wave's children, which can allocate the leaf
-    budget differently when it binds mid-wave; predictive quality is
-    equivalent in tests).  "frontier" forces wave growth.  "auto" defaults
-    to waves for any non-toy workload (>= 4096 rows and >= 16 leaves):
-    every histogram pass has a large fixed cost on the TPU runtime, and a
-    wave retires up to ``width`` splits per pass instead of one (the strict
-    grower's ``num_leaves - 1`` passes are the round-time ceiling — VERDICT
-    r1 item 3).  Default width 42 keeps the segment-folded one-hot matmul
-    at 3*42=126 lanes — inside one 128-lane MXU tile, so a wave costs about
-    the same as a single strict trip.
-    """
-    if p.grow_policy == "leafwise":
-        return 1
-    width = int(p.extra.get("wave_width", 0)) or min(42, p.num_leaves - 1)
-    # clamp below the exact-mode encoding base (1024): an unclamped user
-    # width would collide with the overgrow_leaves*1024 encoding and
-    # silently misroute the grower (code review r5); >512 lanes is far
-    # past the MXU tile sweet spot anyway
-    width = max(1, min(width, 512))
-    # wave_tail — how the wave schedule spends the tail of the leaf
-    # budget, where wave and strict best-first order can diverge:
-    #   "exact"  — overgrow past num_leaves in pathmin order until the
-    #     replay is provably the strict tree (models/tree.py
-    #     _replay_certified; at most to the ~2x cap below), then replay
-    #     strict best-first selection over the realized gains and prune
-    #     (_exact_prune).  LightGBM-exact split ORDER at the larger of
-    #     greedy's pass count and the strict tree's depth (a pass grows
-    #     one level): 11-16 passes at 255 leaves, width 42, for greedy's
-    #     11 and the cap's 17 (PERF.md PR 29); r4's gap decomposition
-    #     proved split order was the ENTIRE residual quality gap of the
-    #     old near-strict tail (PERF_HISTORY.md), so this is the default
-    #     wherever order can matter: large data (the AUC-parity north
-    #     star), budget-saturating small data, and every ranking
-    #     objective (rank lambdas are tail-order-sensitive: the greedy
-    #     tail costs ~6e-2 NDCG@10 on the MSLR bench).
-    #   "greedy" — whole remaining budget per wave, fewest passes.
-    #     Default only for mid-size pointwise tasks whose budget is far
-    #     from saturating the rows AND whose tree closes before the wave
-    #     width binds (num_leaves - 1 <= width: every wave but the last
-    #     splits every leaf that can split) — r4 measured the diamonds
-    #     shape (46k rows, nl=31, ~1.5k rows/leaf) quality-NEUTRAL across
-    #     half/greedy/strict while greedy is 1.44x faster.  Where the
-    #     width binds, a wave takes the 42 best leaves it HAS and strict
-    #     order would have taken their children: at 400,000 x 2,000, 255
-    #     leaves (1,568 rows a leaf, which this rule sent to greedy until
-    #     PR 28) the benchmark's reference read a best-first excess of
-    #     0.12 and 0.37 of a split's gain on two seeds against -0.0007
-    #     and 0.002 under "exact" (limit 0.04; chip, PR 28), as it had at
-    #     10.5M x 28 (0.056-0.17, PR 25).
-    #   "half"   — at most half the remaining budget per wave
-    #     (near-strict tail, r3's compromise; kept for compatibility).
-    # Encoding (static width int, rides all existing plumbing): negative
-    # = greedy; >= 1024 = exact (overgrow_leaves * 1024 + width).
-    rows_per_leaf = n_rows // max(p.num_leaves, 1)
-    # objective "none" = user-supplied fobj whose tail-order sensitivity
-    # is unknown (a custom ranking loss would silently eat the greedy
-    # tail's ~6e-2 NDCG cost) — classify it conservatively (ADVICE r4)
-    pointwise = p.objective not in ("lambdarank", "rank_xendcg", "none")
-    default_tail = ("greedy" if pointwise and rows_per_leaf >= 1024
-                    and n_rows < (1 << 19) and p.num_leaves - 1 <= width
-                    else "exact")
-    tail = str(p.extra.get("wave_tail", default_tail))
-    if tail == "greedy":
-        width = -width
-    elif tail == "exact":
-        # wave_overgrow is the CAP of the overgrowth, for trees whose
-        # replay is not certified earlier.  Default 2.0: history sized
-        # it, when every tree ran to it (the r5 on-chip gap-vs-overgrow
-        # sweep converged at ~2x: Higgs-1M 1.5x -> +8.6e-4 vs oracle,
-        # 2.0x -> +0.3..2.1e-4 across oracle draws, 2.5x no better;
-        # PERF_HISTORY.md r5)
-        over = float(p.extra.get("wave_overgrow", 2.0))
-        l_over = _exact_overgrow_target(p.num_leaves, width, over)
-        width = l_over * 1024 + width
-    if p.grow_policy == "frontier":
-        return width
-    return width if (n_rows >= 4096 and p.num_leaves >= 16) else 1
-
-
 def _objective_static_key(obj: Objective, p: Params) -> tuple:
     """Hashable key identifying the objective for the jit-compile cache.
 
@@ -276,23 +114,6 @@ def _objective_static_key(obj: Objective, p: Params) -> tuple:
     )
 
 
-def _build_cat_info(cat_key, num_features: int):
-    """Static cat_key -> traced CatInfo (None passthrough).
-
-    cat_key = (tuple of categorical column indices, cat_smooth, cat_l2,
-    max_cat_threshold) — static so the compiled program specializes on
-    WHICH columns take subset splits.
-    """
-    if cat_key is None:
-        return None
-    from ..ops.split import CatInfo
-
-    idx, smooth, l2, mct = cat_key
-    is_cat = jnp.zeros(num_features, bool).at[jnp.asarray(idx)].set(True)
-    return CatInfo(is_cat=is_cat, cat_smooth=jnp.float32(smooth),
-                   cat_l2=jnp.float32(l2), max_cat_threshold=int(mct))
-
-
 def _rebuild_objective(key: tuple) -> Objective:
     if key and key[0] == "__group_objective__":
         return key[1]
@@ -313,14 +134,9 @@ def _rebuild_objective(key: tuple) -> Objective:
     return obj
 
 
-def _goss_compact_round(bins, y, w, bag, pred, fmask, hyper: HyperScalars,
-                        key, g, h, goss_k, num_leaves, num_bins, hist_impl,
-                        row_chunk, hist_dtype, wave_width, cat_info,
-                        renew_alpha, axis_name=None, sample_key=None,
-                        mono=None, extra_trees=False, col_bins=None,
-                        renew_scale=None, ic_member=None,
-                        bynode_off=False, hist_merge="psum", n_shards=1,
-                        voting_k=0, hist_wire="f32", merge_chunks=4):
+def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
+                        hyper: HyperScalars, key, g, h, goss_k,
+                        renew_alpha, sample_key=None, renew_scale=None):
     """One compacted GOSS round (shared by the per-round and scanned paths
     — the two MUST stay in RNG lockstep for fused == host training).
 
@@ -328,7 +144,9 @@ def _goss_compact_round(bins, y, w, bag, pred, fmask, hyper: HyperScalars,
     costs the same for masked rows as for live ones — so the sampled subset
     is GATHERED into a dense [k_top + k_other, F] matrix and the tree grown
     on that, cutting histogram cost by ~(top_rate + other_rate).  Train
-    scores for ALL rows then come from one traversal pass."""
+    scores for ALL rows then come from one traversal pass.  ``grow`` is
+    the caller's ``tree.grower_from_spec`` closure (the dp learner's
+    carries its mesh placement)."""
     from ..ops.sampling import approx_top_mask
 
     k_top, k_other = goss_k
@@ -366,15 +184,8 @@ def _goss_compact_round(bins, y, w, bag, pred, fmask, hyper: HyperScalars,
     wt = wt * live
     bins_c = jnp.take(bins, idx, axis=0)
     stats = jnp.stack([g[idx] * wt, h[idx] * wt, live], axis=-1)
-    tree, rl_c = grow_tree(
-        bins_c, stats, fmask, hyper.ctx(), num_leaves, num_bins,
-        hyper.max_depth, ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode), key=key,
-        hist_impl=hist_impl, row_chunk=row_chunk, hist_dtype=hist_dtype,
-        wave_width=wave_width, cat_info=cat_info, axis_name=axis_name,
-        mono=mono, extra_trees=extra_trees, col_bins=col_bins,
-        ic_member=ic_member, fuse_partition=True, hist_merge=hist_merge,
-        n_shards=n_shards, voting_k=voting_k, hist_wire=hist_wire,
-        merge_chunks=merge_chunks)
+    tree, rl_c = grow(bins_c, stats, fmask, hyper.ctx(), hyper.max_depth,
+                      hyper.feature_fraction_bynode, key)
     if renew_alpha is not None:
         rw = w[idx] * wt
         if renew_scale is not None:
@@ -407,32 +218,17 @@ def mc_round_update(grow_one, g, h, keys, pred, learning_rate):
 
 
 @functools.lru_cache(maxsize=None)
-def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
-              hist_impl: str, row_chunk: int, is_rf: bool,
-              num_class: int = 1, hist_dtype: str = "f32",
-              wave_width: int = 1, goss_k: Optional[Tuple[int, int]] = None,
-              cat_key: Optional[tuple] = None,
-              mono_key: Optional[tuple] = None, extra_trees: bool = False,
-              nbins_key: Optional[tuple] = None,
-              linear_k: Optional[int] = None,
-              ic_key: Optional[tuple] = None,
-              bynode_off: bool = False):
+def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
+              goss_k: Optional[Tuple[int, int]], linear_k: Optional[int]):
     """goss_k: static (k_top, k_other) row counts enabling the compacted
-    GOSS path; None = plain gbdt/rf.  cat_key: static categorical-split
-    configuration (see _build_cat_info).  mono_key: static per-feature
-    monotone constraints tuple (upstream ``monotone_constraints``).
-    bynode_off: statically true when feature_fraction_bynode == 1.0 — the
-    growers then skip the per-node threefry draw entirely (kernel-count
-    savings at small shapes)."""
+    GOSS path; None = plain gbdt/rf.  No defaults: ``lru_cache`` keys on
+    the call as written, so every caller spells all six."""
     obj = _rebuild_objective(obj_key)
     is_goss = goss_k is not None
     renew_alpha = getattr(obj, "renew_alpha", None)
     renew_scale = getattr(obj, "renew_scale", None)
-    mono_arr = (None if mono_key is None
-                else jnp.asarray(mono_key, jnp.int32))
-    colb = (None if nbins_key is None
-            else jnp.asarray(nbins_key, jnp.int32))
-    ic_member = (None if ic_key is None else jnp.asarray(ic_key, bool))
+    # the class axis vmaps over the grower: no in-kernel partition there
+    grow = grower_from_spec(spec, fuse_partition=num_class == 1)
 
     def goss_bag(key, g, bag, hyper):
         """GOSS as row re-weighting (multiclass path): top-|g| keep +
@@ -455,15 +251,9 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
             def grow_one(gc, hc, kc):
                 stats = jnp.stack([gc * bag, hc * bag,
                                    (bag > 0).astype(jnp.float32)], axis=-1)
-                return grow_tree(
-                    bins, stats, feature_mask, hyper.ctx(), num_leaves,
-                    num_bins, hyper.max_depth,
-                    ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode), key=kc,
-                    hist_impl=hist_impl, row_chunk=row_chunk,
-                    hist_dtype=hist_dtype, wave_width=wave_width,
-                    cat_info=_build_cat_info(cat_key, bins.shape[1]),
-                    mono=mono_arr, extra_trees=extra_trees, col_bins=colb,
-                    ic_member=ic_member)
+                return grow(bins, stats, feature_mask, hyper.ctx(),
+                            hyper.max_depth, hyper.feature_fraction_bynode,
+                            kc)
 
             return mc_round_update(grow_one, g, h,
                                    jax.random.split(key, num_class), pred,
@@ -478,13 +268,8 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
                           hyper: HyperScalars, key):
             g, h = obj.grad_hess(pred, y, w)
             return _goss_compact_round(
-                bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
-                goss_k, num_leaves, num_bins, hist_impl, row_chunk,
-                hist_dtype, wave_width,
-                _build_cat_info(cat_key, bins.shape[1]), renew_alpha,
-                mono=mono_arr, extra_trees=extra_trees, col_bins=colb,
-                renew_scale=renew_scale, ic_member=ic_member,
-                bynode_off=bynode_off)
+                grow, bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
+                goss_k, renew_alpha, renew_scale=renew_scale)
 
         return round_fn_goss
 
@@ -501,18 +286,12 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
             g, h = obj.grad_hess(pred, y, w)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
-            tree, row_leaf = grow_tree(
-                bins, stats, feature_mask, hyper.ctx(), num_leaves,
-                num_bins, hyper.max_depth,
-                ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode),
-                key=key, hist_impl=hist_impl, row_chunk=row_chunk,
-                hist_dtype=hist_dtype, wave_width=wave_width,
-                cat_info=_build_cat_info(cat_key, bins.shape[1]),
-                mono=mono_arr, extra_trees=extra_trees, col_bins=colb,
-                ic_member=ic_member, fuse_partition=True)
+            tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
+                                  hyper.max_depth,
+                                  hyper.feature_fraction_bynode, key)
             tree, delta = fit_linear_leaves(
                 tree, row_leaf, xraw, g, h, bag, hyper.linear_lambda,
-                linear_k, row_chunk)
+                linear_k, spec.row_chunk)
             new_pred = pred + hyper.learning_rate * delta
             return tree, new_pred
 
@@ -525,14 +304,9 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
             g, h = obj.grad_hess(pred, y, w)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
-        tree, row_leaf = grow_tree(
-            bins, stats, feature_mask, hyper.ctx(), num_leaves, num_bins,
-            hyper.max_depth, ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode),
-            key=key, hist_impl=hist_impl, row_chunk=row_chunk,
-            hist_dtype=hist_dtype, wave_width=wave_width,
-            cat_info=_build_cat_info(cat_key, bins.shape[1]),
-            mono=mono_arr, extra_trees=extra_trees, col_bins=colb,
-            ic_member=ic_member, fuse_partition=True)
+        tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
+                              hyper.max_depth, hyper.feature_fraction_bynode,
+                              key)
         if renew_alpha is not None:
             rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
             tree = renew_leaf_values(tree, row_leaf, y - pred, rw,
@@ -547,17 +321,9 @@ def _round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _multi_round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
-                    hist_impl: str, row_chunk: int, is_rf: bool,
-                    hist_dtype: str, wave_width: int, n_rounds: int,
-                    bagging_freq: int, use_ff: bool,
-                    cat_key: Optional[tuple] = None,
-                    goss_k: Optional[Tuple[int, int]] = None,
-                    mono_key: Optional[tuple] = None,
-                    extra_trees: bool = False,
-                    nbins_key: Optional[tuple] = None,
-                    ic_key: Optional[tuple] = None,
-                    bynode_off: bool = False):
+def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
+                    n_rounds: int, bagging_freq: int, use_ff: bool,
+                    goss_k: Optional[Tuple[int, int]] = None):
     """``n_rounds`` boosting rounds as ONE device program (`lax.scan`).
 
     The host round loop pays a dispatch round-trip per boosting round,
@@ -572,11 +338,7 @@ def _multi_round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
     obj = _rebuild_objective(obj_key)
     renew_alpha = getattr(obj, "renew_alpha", None)
     renew_scale = getattr(obj, "renew_scale", None)
-    mono_arr = (None if mono_key is None
-                else jnp.asarray(mono_key, jnp.int32))
-    colb = (None if nbins_key is None
-            else jnp.asarray(nbins_key, jnp.int32))
-    ic_member = (None if ic_key is None else jnp.asarray(ic_key, bool))
+    grow = grower_from_spec(spec, fuse_partition=True)
 
     @jax.jit
     def multi(bins, y, w, bag0, pred0, hyper: HyperScalars, round_key,
@@ -602,30 +364,20 @@ def _multi_round_fn(obj_key: tuple, num_leaves: int, num_bins: int,
             else:
                 fmask = jnp.ones(num_features, jnp.float32)
             rkey = jax.random.fold_in(round_key, i)
-            cat_info = _build_cat_info(cat_key, bins.shape[1])
             with jax.named_scope("lgbtpu.grad"):
                 g, h = obj.grad_hess(pred, y, w)
             if goss_k is not None:
                 tree, new_pred = _goss_compact_round(
-                    bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
-                    goss_k, num_leaves, num_bins, hist_impl, row_chunk,
-                    hist_dtype, wave_width, cat_info, renew_alpha,
-                    mono=mono_arr, extra_trees=extra_trees, col_bins=colb,
-                    renew_scale=renew_scale, ic_member=ic_member,
-                    bynode_off=bynode_off)
+                    grow, bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
+                    goss_k, renew_alpha, renew_scale=renew_scale)
                 return (new_pred, bag), tree
             with jax.named_scope("lgbtpu.grad"):
                 stats = jnp.stack(
                     [g * bag, h * bag, (bag > 0).astype(jnp.float32)],
                     axis=-1)
-            tree, row_leaf = grow_tree(
-                bins, stats, fmask, hyper.ctx(), num_leaves, num_bins,
-                hyper.max_depth, ff_bynode=(None if bynode_off else hyper.feature_fraction_bynode),
-                key=rkey, hist_impl=hist_impl,
-                row_chunk=row_chunk, hist_dtype=hist_dtype,
-                wave_width=wave_width,
-                cat_info=cat_info, mono=mono_arr, extra_trees=extra_trees,
-                col_bins=colb, ic_member=ic_member, fuse_partition=True)
+            tree, row_leaf = grow(bins, stats, fmask, hyper.ctx(),
+                                  hyper.max_depth,
+                                  hyper.feature_fraction_bynode, rkey)
             if renew_alpha is not None:
                 rw = (w * bag if renew_scale is None
                       else w * bag * renew_scale(y))
@@ -957,6 +709,7 @@ class Booster:
             self._nbins_key = tuple(int(x) for x in colb)
         else:
             self._nbins_key = None
+        self._grow_specs = {}
         self._streamed = bool(getattr(ds, "is_streamed", False))
         if self._streamed:
             self._check_streamed_scope()
@@ -1002,6 +755,20 @@ class Booster:
             self._maybe_setup_fp()
         elif p.tree_learner in ("data", "voting"):
             self._maybe_setup_dp()
+
+    def _grow_spec(self, eff_rows: int) -> GrowSpec:
+        """The static decisions of this booster's trees grown on
+        ``eff_rows`` rows (GOSS grows on its ``k_top + k_other`` sample),
+        resolved once: the round builders key their program caches on the
+        value, so a dispatch must not re-derive it.  ``_setup_training``
+        and ``reset_parameter`` drop the memo."""
+        spec = self._grow_specs.get(eff_rows)
+        if spec is None:
+            spec = self._grow_specs[eff_rows] = resolve_grow_spec(
+                self.params, eff_rows, self._num_bins,
+                cat_key=self._cat_key, mono_key=self._mono_key,
+                nbins_key=self._nbins_key, ic_key=self._ic_key)
+        return spec
 
     def _check_streamed_scope(self) -> None:
         """Out-of-core training covers the PLAIN numeric path (ISSUE 7):
@@ -1962,6 +1729,7 @@ class Booster:
         check_int8_row_limit(
             p, eff_rows,
             int(_dp_m.shape["data"]) if _dp_m is not None else 1)
+        spec = self._grow_spec(eff_rows)
         round_key = jax.random.fold_in(self._key, i)
         if getattr(self, "_streamed", False):
             from ..data.stream_grow import (stream_goss_round,
@@ -1972,9 +1740,6 @@ class Booster:
 
             renew_alpha = getattr(self.obj, "renew_alpha", None)
             renew_scale = getattr(self.obj, "renew_scale", None)
-            hist_impl = p.extra.get("hist_impl", "auto")
-            hist_dtype = resolve_hist_dtype(p, eff_rows)
-            wave_width = resolve_wave_width(p, eff_rows)
             store = ds.block_store
             if active_ids is not None:
                 # screened round out-of-core: only the active columns
@@ -2009,17 +1774,14 @@ class Booster:
                         self._bag, self._pred_train, fmask, self._hyper,
                         round_key, goss_k_shard, float(p.top_rate),
                         float(p.other_rate), p.seed * 1_000_003 + i,
-                        p.num_leaves, self._num_bins, hist_impl,
-                        hist_dtype, wave_width, merge_mode, wire_dtype,
-                        merge_chunks)
+                        spec, merge_mode, wire_dtype, merge_chunks)
                 else:
                     tree, new_pred = stream_dp_plain_round(
                         shards, self._dp_mesh,
                         self._obj_key, self._dp_y, self._dp_w,
                         self._bag, self._pred_train, fmask, self._hyper,
-                        p.num_leaves, self._num_bins, hist_impl,
-                        hist_dtype, wave_width, p.boosting == "rf",
-                        merge_mode, wire_dtype, merge_chunks)
+                        spec, p.boosting == "rf", merge_mode, wire_dtype,
+                        merge_chunks)
                 drain_shard_odometers(ds.block_store,
                                       self._stream_shards)
             elif goss_k is not None:
@@ -2028,24 +1790,17 @@ class Booster:
                     self._bag, self._pred_train, fmask, self._hyper,
                     round_key, goss_k, float(p.top_rate),
                     float(p.other_rate), p.seed * 1_000_003 + i,
-                    p.num_leaves, self._num_bins, hist_impl, hist_dtype,
-                    wave_width, renew_alpha, renew_scale)
+                    spec, renew_alpha, renew_scale)
             else:
                 tree, new_pred = stream_plain_round(
                     store, self._obj_key, ds.y, self._w_eff,
                     self._bag, self._pred_train, fmask, self._hyper,
-                    p.num_leaves, self._num_bins, hist_impl, hist_dtype,
-                    wave_width, p.boosting == "rf", renew_alpha,
-                    renew_scale)
+                    spec, p.boosting == "rf", renew_alpha, renew_scale)
         elif getattr(self, "_fp_mesh", None) is not None:
             from ..parallel.feature_parallel import make_fp_train_step
 
-            fn = make_fp_train_step(
-                self._fp_mesh, self._obj_key, p.num_leaves, self._num_bins,
-                p.extra.get("hist_impl", "auto"),
-                int(p.extra.get("row_chunk", 131072)), p.boosting == "rf",
-                resolve_hist_dtype(p, eff_rows), self._num_class,
-                self._cat_key, resolve_wave_width(p, eff_rows))
+            fn = make_fp_train_step(self._fp_mesh, self._obj_key, spec,
+                                    p.boosting == "rf", self._num_class)
             from .feature_mask import pad_feature_mask
 
             fmask_p = pad_feature_mask(fmask, self._fp_width)
@@ -2058,12 +1813,8 @@ class Booster:
             # columns — see parallel.feature_parallel.make_dp_fp_train_step
             from ..parallel.feature_parallel import make_dp_fp_train_step
 
-            fn = make_dp_fp_train_step(
-                self._dp_mesh, self._obj_key, p.num_leaves, self._num_bins,
-                p.extra.get("hist_impl", "auto"),
-                int(p.extra.get("row_chunk", 131072)), p.boosting == "rf",
-                resolve_hist_dtype(p, eff_rows),
-                resolve_wave_width(p, eff_rows))
+            fn = make_dp_fp_train_step(self._dp_mesh, self._obj_key, spec,
+                                       p.boosting == "rf")
             from .feature_mask import pad_feature_mask
 
             fmask_p = pad_feature_mask(fmask, self._dp2_width)
@@ -2082,13 +1833,8 @@ class Booster:
             stats = shard_rows(self._dp_mesh, stats)
             merge_mode, voting_k = self._dp_merge_mode()
             wire_dtype, merge_chunks = self._dp_wire(merge_mode, eff_rows)
-            fn = make_dp_grow_step(
-                self._dp_mesh, p.num_leaves, self._num_bins,
-                p.extra.get("hist_impl", "auto"),
-                int(p.extra.get("row_chunk", 131072)),
-                resolve_wave_width(p, eff_rows),
-                resolve_hist_dtype(p, eff_rows),
-                merge_mode, voting_k, wire_dtype, merge_chunks)
+            fn = make_dp_grow_step(self._dp_mesh, spec, merge_mode,
+                                   voting_k, wire_dtype, merge_chunks)
             dp_bins = (self._dp_bins if active_ids is None
                        else self._screen_view(self._dp_bins, active_ids))
             tree, row_leaf = fn(dp_bins, stats, fmask, self._hyper,
@@ -2102,11 +1848,7 @@ class Booster:
             merge_mode, voting_k = self._dp_merge_mode()
             wire_dtype, merge_chunks = self._dp_wire(merge_mode, eff_rows)
             fn = make_dp_linear_train_step(
-                self._dp_mesh, self._obj_key, p.num_leaves, self._num_bins,
-                p.extra.get("hist_impl", "auto"),
-                int(p.extra.get("row_chunk", 131072)),
-                resolve_hist_dtype(p, eff_rows),
-                resolve_wave_width(p, eff_rows), self._linear_k,
+                self._dp_mesh, self._obj_key, spec, self._linear_k,
                 merge_mode, voting_k, wire_dtype, merge_chunks)
             tree, new_pred = fn(self._dp_bins, self._dp_y, self._dp_w,
                                 self._bag, self._pred_train, self._dp_xraw,
@@ -2122,18 +1864,14 @@ class Booster:
                 n_dev = self._dp_mesh.devices.size
                 goss_k_shard = (max(goss_k[0] // n_dev, 1),
                                 max(goss_k[1] // n_dev, 1))
-                if self._num_class == 1:
+                if self._num_class == 1:   # each shard grows on its sample
                     eff_rows = sum(goss_k_shard)
+                    spec = self._grow_spec(eff_rows)
             merge_mode, voting_k = self._dp_merge_mode()
             wire_dtype, merge_chunks = self._dp_wire(merge_mode, eff_rows)
             fn = make_dp_train_step(
-                self._dp_mesh, self._obj_key, p.num_leaves, self._num_bins,
-                p.extra.get("hist_impl", "auto"),
-                int(p.extra.get("row_chunk", 131072)), p.boosting == "rf",
-                resolve_wave_width(p, eff_rows),
-                resolve_hist_dtype(p, eff_rows), goss_k_shard,
-                self._mono_key, p.extra_trees, self._nbins_key,
-                self._num_class, self._ic_key, self._cat_key,
+                self._dp_mesh, self._obj_key, spec,
+                p.boosting == "rf", goss_k_shard, self._num_class,
                 merge_mode, voting_k, wire_dtype, merge_chunks)
             dp_bins = (self._dp_bins if active_ids is None
                        else self._screen_view(self._dp_bins, active_ids))
@@ -2141,15 +1879,8 @@ class Booster:
                                 self._bag, self._pred_train, fmask,
                                 self._hyper, round_key)
         else:
-            fn = _round_fn(self._obj_key, p.num_leaves, self._num_bins,
-                           p.extra.get("hist_impl", "auto"),
-                           int(p.extra.get("row_chunk", 131072)),
-                           p.boosting == "rf", self._num_class,
-                           resolve_hist_dtype(p, eff_rows),
-                           resolve_wave_width(p, eff_rows), goss_k,
-                           self._cat_key, self._mono_key, p.extra_trees,
-                           self._nbins_key, self._linear_k, self._ic_key,
-                           bynode_off=p.feature_fraction_bynode >= 1.0)
+            fn = _round_fn(self._obj_key, spec, p.boosting == "rf",
+                           self._num_class, goss_k, self._linear_k)
             if self._linear_k is not None:
                 tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
@@ -2282,13 +2013,12 @@ class Booster:
             goss_k = (int(p.top_rate * ds.num_data_),
                       int(p.other_rate * ds.num_data_))
             eff_rows = goss_k[0] + goss_k[1]
-        hist_dtype = resolve_hist_dtype(p, eff_rows)
-        wave_width = resolve_wave_width(p, eff_rows)
+        spec = self._grow_spec(eff_rows)
+        wave, hist_dtype = spec.wave, spec.hist_dtype
         # what the pass that runs was decided to be: the shapes a roofline
         # counts its work from (the benchmark's named metrics read them);
         # overgrow_leaves is the exact tail's cap, not what each tree grows to
-        width, tail, overgrow = decode_wave_width(wave_width)
-        segments = min(width, (overgrow or p.num_leaves) - 1)
+        segments = wave_extent(wave, p.num_leaves)[1]
         features = int(ds.X_binned.shape[1])
         # ... and what the kernels' VMEM blocking made of a wave pass at
         # this width: feature blocks, the feature rows they cover (the
@@ -2299,7 +2029,8 @@ class Booster:
                                                  3 * segments)
         for fact, value in (
                 ("wave_width", segments),
-                ("wave_tail", tail), ("overgrow_leaves", overgrow),
+                ("wave_tail", wave.tail),
+                ("overgrow_leaves", wave.cap_leaves),
                 ("hist_dtype", hist_dtype), ("rows_padded", eff_rows),
                 ("num_bins", self._num_bins),
                 ("features", features),
@@ -2309,18 +2040,12 @@ class Booster:
                 ("hist_calls_per_pass",
                  2 if hist_dtype == "f32" or (
                      hist_dtype == "f32x"
-                     and p.extra.get("hist_impl") == "pallas") else 1)):
+                     and spec.hist_impl == "pallas") else 1)):
             profiling.note("train." + fact, value)
         fn = _multi_round_fn(
-            self._obj_key, p.num_leaves, self._num_bins,
-            p.extra.get("hist_impl", "auto"),
-            int(p.extra.get("row_chunk", 131072)), p.boosting == "rf",
-            hist_dtype, wave_width, n_rounds,
+            self._obj_key, spec, p.boosting == "rf", n_rounds,
             p.bagging_freq if use_bagging else 0,
-            p.feature_fraction < 1.0,
-            self._cat_key, goss_k, self._mono_key, p.extra_trees,
-            self._nbins_key, self._ic_key,
-            bynode_off=p.feature_fraction_bynode >= 1.0)
+            p.feature_fraction < 1.0, goss_k)
         return fn, (
             ds.X_binned, ds.y, self._w_eff, self._bag, self._pred_train,
             self._hyper, self._key,
@@ -2388,14 +2113,8 @@ class Booster:
             pred = pred - lr * drop_sum
 
         eff_rows = int(ds.row_mask.shape[0])
-        fn = _round_fn(self._obj_key, p.num_leaves, self._num_bins,
-                       p.extra.get("hist_impl", "auto"),
-                       int(p.extra.get("row_chunk", 131072)), False, nc,
-                       resolve_hist_dtype(p, eff_rows),
-                       resolve_wave_width(p, eff_rows), None, self._cat_key,
-                       self._mono_key, p.extra_trees, self._nbins_key,
-                       None, self._ic_key,
-                       bynode_off=p.feature_fraction_bynode >= 1.0)
+        fn = _round_fn(self._obj_key, self._grow_spec(eff_rows), False, nc,
+                       None, None)
         round_key = jax.random.fold_in(self._key, i)
         tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff, self._bag, pred,
                             fmask, self._hyper, round_key)
@@ -2837,6 +2556,7 @@ class Booster:
                     "trained booster (it changes the compiled program)")
         self.params = newp
         self._hyper = HyperScalars.from_params(newp)
+        self._grow_specs = {}    # hist_dtype, bynode, ... take effect next round
         return self
 
     def rollback_one_iter(self) -> "Booster":

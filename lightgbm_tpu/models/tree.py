@@ -34,11 +34,13 @@ from ..ops.histogram import compute_histograms, histogram_merge, histogram_psum
 from ..ops.lookup import lookup_rows, lookup_values
 from ..ops.split import (
     BestSplit,
+    CatInfo,
     SplitContext,
     constrained_leaf_output,
     find_best_split,
     leaf_output,
 )
+from .spec import STRICT, GrowSpec, WaveSchedule
 
 # tools/hlo_counts.py flips this to compile the fused strict grower with
 # the split-iteration kernel replaced by an optimization barrier, so the
@@ -140,22 +142,6 @@ class _GrowState(NamedTuple):
     cand_catmask: Optional[jnp.ndarray] = None  # bool[M, B]
     # interaction constraints: surviving group set per node (None = off)
     ic_sets: Optional[jnp.ndarray] = None       # bool[M, NG]
-
-
-def decode_wave_width(wave_width: int):
-    """Decode the static wave-width int into (width, tail, overgrow_leaves).
-
-    SINGLE SOURCE for the encoding produced by ``gbdt.resolve_wave_width``
-    (negative = greedy tail; >= 1024 = exact tail, ``overgrow_leaves *
-    1024 + width``; else half) — the grower, the facts
-    ``Booster._fused_segment`` notes for the trace's metrics, and the
-    bench FLOP model all decode through here.
-    """
-    if wave_width < 0:
-        return -wave_width, "greedy", None
-    if wave_width >= 1024:
-        return wave_width % 1024, "exact", wave_width // 1024
-    return wave_width, "half", None
 
 
 def _write(arr, idx, val, active):
@@ -554,6 +540,54 @@ def pad_tree(tree: Tree, capacity: int) -> Tree:
                      else p_node2(tree.linear_coef, 0.0)))
 
 
+def build_cat_info(cat_key, num_features: int):
+    """Static ``GrowSpec.cat_key`` -> traced CatInfo (None passthrough).
+
+    The key is static so the compiled program specializes on WHICH
+    columns take subset splits.
+    """
+    if cat_key is None:
+        return None
+    idx, smooth, l2, mct = cat_key
+    is_cat = jnp.zeros(num_features, bool).at[jnp.asarray(idx)].set(True)
+    return CatInfo(is_cat=is_cat, cat_smooth=jnp.float32(smooth),
+                   cat_l2=jnp.float32(l2), max_cat_threshold=int(mct))
+
+
+def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
+    """The ONE place a :class:`GrowSpec` is mapped onto :func:`grow_tree`.
+
+    Returns ``grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode,
+    key) -> (Tree, row_leaf)``.  ``placement`` is what the learner, not the
+    spec, decides and goes to ``grow_tree`` as it is: ``axis_name``,
+    ``fp_axis``, ``fuse_partition`` and the merge settings.  Call it where
+    the round program is BUILT: the per-column constraint arrays become
+    constants the traced bodies close over.  ``cat_info_for(num_features)``
+    replaces the CatInfo built from ``spec.cat_key`` (the feature-sharded
+    learner slices its own).
+    """
+    mono = (None if spec.mono_key is None
+            else jnp.asarray(spec.mono_key, jnp.int32))
+    col_bins = (None if spec.nbins_key is None
+                else jnp.asarray(spec.nbins_key, jnp.int32))
+    ic_member = (None if spec.ic_key is None
+                 else jnp.asarray(spec.ic_key, bool))
+    if cat_info_for is None:
+        cat_info_for = functools.partial(build_cat_info, spec.cat_key)
+
+    def grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode, key):
+        return grow_tree(
+            bins, stats, feature_mask, ctx, spec.num_leaves, spec.num_bins,
+            max_depth, ff_bynode=None if spec.bynode_off else ff_bynode,
+            key=key, hist_impl=spec.hist_impl, row_chunk=spec.row_chunk,
+            hist_dtype=spec.hist_dtype, wave=spec.wave,
+            cat_info=cat_info_for(bins.shape[1]), mono=mono,
+            extra_trees=spec.extra_trees, col_bins=col_bins,
+            ic_member=ic_member, **placement)
+
+    return grow
+
+
 def grow_tree(
     bins: jnp.ndarray,
     stats: jnp.ndarray,
@@ -568,14 +602,13 @@ def grow_tree(
     hist_impl: str = "auto",
     row_chunk: int = 131072,
     hist_dtype: str = "f32",
-    wave_width: int = 1,
+    wave: WaveSchedule = STRICT,
     cat_info=None,
     fp_axis: Optional[str] = None,
     mono=None,
     extra_trees: bool = False,
     col_bins=None,
     ic_member=None,
-    wave_tail: str = "half",
     fuse_partition: bool = False,
     fuse_split: bool = True,
     hist_merge: str = "psum",
@@ -643,51 +676,20 @@ def grow_tree(
       (Tree, row_leaf) — row_leaf gives each training row's final leaf node id
       so the boosting loop can update train predictions with one gather.
 
-    ``|wave_width| > 1`` dispatches to :func:`grow_tree_frontier` (multiple
-    splits per histogram pass via the subtraction trick — the large-data
-    fast path).  ``wave_width`` carries the wave TAIL policy in its
-    encoding so the policy rides every existing static plumbing path
-    (compile-cache keys, mesh learners) untouched:
-
-      * NEGATIVE — "greedy" tail (spend the whole remaining leaf budget
-        per wave, fewest histogram passes);
-      * ``>= 1024`` — "exact" mode, encoded ``overgrow_leaves * 1024 +
-        width``: overgrow until the replay is certified, at most to
-        ``overgrow_leaves``, then replay strict best-first selection over
-        the realized gains and prune back to ``num_leaves``
-        (LightGBM-exact split ORDER at near-greedy pass counts — see
-        :func:`_exact_prune`, :func:`_replay_certified`);
-      * otherwise — "half" tail (near-strict tail ordering).
+    A ``wave`` of width > 1 dispatches to :func:`grow_tree_frontier`
+    (multiple splits per histogram pass via the subtraction trick — the
+    large-data fast path); its tail policy is the schedule's
+    (:func:`~lightgbm_tpu.models.spec.resolve_wave`).
     """
-    raw_wave_width = wave_width
-    wave_width, decoded_tail, overgrow_leaves = decode_wave_width(wave_width)
-    if decoded_tail == "exact" and (
-            wave_width > 512 or overgrow_leaves <= num_leaves):
-        # ints >= 1024 are RESERVED for resolve_wave_width's exact-tail
-        # encoding (overgrow_leaves * 1024 + width, width <= 512, overgrow
-        # strictly past num_leaves).  A direct caller passing a genuine
-        # width (e.g. 2000) would otherwise be silently misrouted into
-        # exact mode with a nonsense overgrow target (ADVICE r5) — reject
-        # it instead; widths beyond 512 are past the MXU tile sweet spot
-        # and are clamped by the encoder anyway.
-        raise ValueError(
-            f"wave_width={raw_wave_width} decodes to exact-tail "
-            f"(width={wave_width}, overgrow_leaves={overgrow_leaves}) but "
-            f"is not a valid resolve_wave_width encoding for "
-            f"num_leaves={num_leaves}; raw widths must be < 1024 — use "
-            "gbdt.resolve_wave_width to encode the exact tail")
-    if decoded_tail != "half" or wave_tail == "half":
-        wave_tail = decoded_tail
-    if wave_width > 1 and not (fp_axis is not None and cat_info is not None):
+    if wave.width > 1 and not (fp_axis is not None and cat_info is not None):
         # (frontier + feature-parallel since r5; categorical k-vs-rest
         # splits under fp keep the strict grower's psum-broadcast path)
         return grow_tree_frontier(
             bins, stats, feature_mask, ctx, num_leaves, num_bins, max_depth,
-            wave_width, ff_bynode=ff_bynode, key=key, axis_name=axis_name,
+            wave, ff_bynode=ff_bynode, key=key, axis_name=axis_name,
             hist_impl=hist_impl, row_chunk=row_chunk, hist_dtype=hist_dtype,
             cat_info=cat_info, mono=mono, extra_trees=extra_trees,
-            col_bins=col_bins, ic_member=ic_member, wave_tail=wave_tail,
-            overgrow_leaves=overgrow_leaves, fp_axis=fp_axis,
+            col_bins=col_bins, ic_member=ic_member, fp_axis=fp_axis,
             fuse_partition=fuse_partition, hist_merge=hist_merge,
             n_shards=n_shards, voting_k=voting_k, hist_wire=hist_wire,
             merge_chunks=merge_chunks)
@@ -1190,6 +1192,21 @@ def _replay_certified(P, num_leaves: int):
     return jnp.sum(above.astype(jnp.int32)) >= num_leaves - 1
 
 
+def wave_extent(wave: WaveSchedule, num_leaves: int) -> Tuple[int, int]:
+    """``(grow_leaves, w_width)`` of a wave-grown tree: the leaves it may
+    reach (the exact tail's cap, else the budget) and the splits one pass
+    holds.  The one check of a schedule that needs ``num_leaves``."""
+    grow_leaves = num_leaves
+    if wave.tail == "exact":
+        if wave.cap_leaves <= num_leaves:
+            raise ValueError(
+                f"the exact tail overgrows past num_leaves={num_leaves} "
+                f"and prunes back: its cap must exceed it, got "
+                f"{wave.cap_leaves}")
+        grow_leaves = wave.cap_leaves
+    return grow_leaves, min(wave.width, grow_leaves - 1)
+
+
 class _WaveState(NamedTuple):
     nodes: jnp.ndarray          # f32[M, _PK.NC] packed per-node table
     # frontier extras
@@ -1214,7 +1231,7 @@ def grow_tree_frontier(
     num_leaves: int,
     num_bins: int,
     max_depth,
-    wave_width: int,
+    wave: WaveSchedule,
     ff_bynode=None,
     key: Optional[jnp.ndarray] = None,
     axis_name: Optional[str] = None,
@@ -1226,8 +1243,6 @@ def grow_tree_frontier(
     extra_trees: bool = False,
     col_bins=None,
     ic_member=None,
-    wave_tail: str = "half",
-    overgrow_leaves: Optional[int] = None,
     fp_axis: Optional[str] = None,
     fuse_partition: bool = False,
     hist_merge: str = "psum",
@@ -1236,7 +1251,7 @@ def grow_tree_frontier(
     hist_wire: str = "f32",
     merge_chunks: int = 4,
 ) -> Tuple[Tree, jnp.ndarray]:
-    """Best-first growth in WAVES: up to ``wave_width`` splits per data pass.
+    """Best-first growth in WAVES: up to ``wave.width`` splits per data pass.
 
     The strict grower (:func:`grow_tree`) re-scans all rows once per split —
     ``num_leaves - 1`` full-data histogram passes per tree, which caps
@@ -1258,15 +1273,15 @@ def grow_tree_frontier(
         histograms with no extra data pass.
 
     A balanced 127-leaf tree takes ~8 passes instead of 126.  Semantics:
-    with ``wave_width=1`` the split order equals strict best-first; with
+    with width 1 the split order equals strict best-first; with
     larger widths the wave's split set is chosen before the wave's children
     are scored, so when the leaf budget binds mid-wave the tree can spend
     budget on wave-start leaves that strict growth would have skipped in
     favor of higher-gain fresh children.  Predictive quality is equivalent
     in practice (tests compare both modes); LightGBM-exact split order
-    needs either the strict grower or ``wave_tail="exact"`` — overgrow
+    needs either the strict grower or the "exact" tail — overgrow
     in pathmin order until :func:`_replay_certified` proves the replay
-    (at most to ``overgrow_leaves``, the cap), then :func:`_exact_prune`
+    (at most to ``wave.cap_leaves``), then :func:`_exact_prune`
     replays strict best-first selection over the realized gains and
     prunes back to ``num_leaves`` (the budget-binding tail is the ONLY
     place wave and strict order diverge, so recovering it recovers strict
@@ -1275,11 +1290,9 @@ def grow_tree_frontier(
     decomposition).
     """
     n, num_features = bins.shape
-    exact = wave_tail == "exact"
-    grow_leaves = (max(num_leaves + 1, int(overgrow_leaves or 0))
-                   if exact else num_leaves)
+    exact = wave.tail == "exact"
+    grow_leaves, w_width = wave_extent(wave, num_leaves)
     capacity = 2 * grow_leaves - 1
-    w_width = min(int(wave_width), grow_leaves - 1)
 
     # partition-fused wave kernel (histogram + row routing in one pallas
     # call — r5 trace: ~22 ms/wave of XLA-side partition work at 11M rows
@@ -1493,7 +1506,7 @@ def grow_tree_frontier(
     iota_w = lax.iota(jnp.int32, w_width)
 
     # The exact tail's overgrowth cap is wave-aligned
-    # (gbdt._exact_overgrow_target): full waves land on it.  A tree whose
+    # (spec._exact_overgrow_target): full waves land on it.  A tree whose
     # replay is certified earlier (_replay_certified) stops there; one
     # that is not runs on to the cap.  A leaf of the
     # doubling waves with no split to offer leaves the count ONE short,
@@ -1538,10 +1551,10 @@ def grow_tree_frontier(
             # allocates the tail splits near-strict-best-first at ~5 extra
             # passes.  The tail refinement is what preserves strict-growth
             # quality when the leaf budget nearly saturates the data (small-n /
-            # large-num_leaves); ``wave_tail`` picks the tradeoff.  "exact"
+            # large-num_leaves); ``wave.tail`` picks the tradeoff.  "exact"
             # overgrows with the greedy schedule (the post-hoc replay, not the
             # wave order, is what restores strict allocation).
-            if wave_tail == "half":
+            if wave.tail == "half":
                 alloc = jnp.maximum(jnp.int32(1), budget // 2)
             else:  # "greedy" / "exact"
                 alloc = budget

@@ -31,8 +31,9 @@ previously all aliased the same full ``psum`` — see README and
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,8 +41,9 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.gbdt import HyperScalars, _rebuild_objective
+from ..models.spec import GrowSpec
+from ..models.tree import grower_from_spec
 from ..ops.lookup import lookup_values
-from ..models.tree import Tree, grow_tree
 
 DATA_AXIS = "data"
 
@@ -80,14 +82,23 @@ def shard_rows(mesh: Mesh, *arrays):
     return out if len(out) > 1 else out[0]
 
 
+def _mesh_grower(spec: GrowSpec, mesh: Mesh, merge_mode: str,
+                 voting_k: int, wire_dtype: str, merge_chunks: int, **kw):
+    """``grower_from_spec`` placed on the row mesh: histograms merge over
+    ``DATA_AXIS`` as ``merge_mode`` says.  The row-mesh learners have never
+    taken the static bynode skip (every shard draws the same per-node mask
+    from the shared key), so their programs keep the draw."""
+    return grower_from_spec(
+        dataclasses.replace(spec, bynode_off=False), axis_name=DATA_AXIS,
+        hist_merge=merge_mode, n_shards=mesh.shape[DATA_AXIS],
+        voting_k=voting_k, hist_wire=wire_dtype, merge_chunks=merge_chunks,
+        **kw)
+
+
 @functools.lru_cache(maxsize=None)
-def make_dp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
-                       num_bins: int, hist_impl: str = "auto",
-                       row_chunk: int = 131072, is_rf: bool = False,
-                       wave_width: int = 1, hist_dtype: str = "f32",
-                       goss_k_shard=None, mono_key=None,
-                       extra_trees: bool = False, nbins_key=None,
-                       num_class: int = 1, ic_key=None, cat_key=None,
+def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
+                       is_rf: bool = False, goss_k_shard=None,
+                       num_class: int = 1,
                        merge_mode: str = "psum", voting_k: int = 0,
                        wire_dtype: str = "f32", merge_chunks: int = 4):
     """Build the jitted data-parallel round step for a mesh.
@@ -113,19 +124,13 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
     compression and the sub-chunk count whose hops overlap the per-chunk
     split scans); both are inert outside the ring modes.
     """
-    from ..models.gbdt import _build_cat_info
-
     obj = _rebuild_objective(obj_key)
-    n_shards = mesh.shape[DATA_AXIS]
-    mono_arr = (None if mono_key is None
-                else jnp.asarray(mono_key, jnp.int32))
-    colb = (None if nbins_key is None
-            else jnp.asarray(nbins_key, jnp.int32))
-    ic_member = (None if ic_key is None else jnp.asarray(ic_key, bool))
     # categorical k-vs-rest splits work unchanged under the mesh: the scan
     # runs on psum-MERGED histograms (replicated, so every shard picks the
-    # same subset mask) and the partition gathers per-shard rows
-    make_cat = lambda nf: _build_cat_info(cat_key, nf)  # noqa: E731
+    # same subset mask) and the partition gathers per-shard rows.
+    # The class axis vmaps over the grower: no in-kernel partition there
+    grow = _mesh_grower(spec, mesh, merge_mode, voting_k, wire_dtype,
+                        merge_chunks, fuse_partition=num_class == 1)
 
     def step_mc(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
                 key):
@@ -149,18 +154,8 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
         def grow_one(gc, hc, kc):
             stats = jnp.stack([gc * bag, hc * bag,
                                (bag > 0).astype(jnp.float32)], axis=-1)
-            return grow_tree(
-                bins, stats, feature_mask, hyper.ctx(), num_leaves,
-                num_bins, hyper.max_depth,
-                ff_bynode=hyper.feature_fraction_bynode, key=kc,
-                axis_name=DATA_AXIS, hist_impl=hist_impl,
-                row_chunk=row_chunk, hist_dtype=hist_dtype,
-                wave_width=wave_width, mono=mono_arr,
-                extra_trees=extra_trees, col_bins=colb,
-                ic_member=ic_member, cat_info=make_cat(bins.shape[1]),
-                hist_merge=merge_mode, n_shards=n_shards,
-                voting_k=voting_k, hist_wire=wire_dtype,
-                merge_chunks=merge_chunks)
+            return grow(bins, stats, feature_mask, hyper.ctx(),
+                        hyper.max_depth, hyper.feature_fraction_bynode, kc)
 
         from ..models.gbdt import mc_round_update
         return mc_round_update(grow_one, g, h,
@@ -180,27 +175,13 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
             sample_key = jax.random.fold_in(
                 key, lax.axis_index(DATA_AXIS))
             tree, new_pred = _goss_compact_round(
-                bins, y, w, bag, pred, feature_mask, hyper, key,
-                g, h, goss_k_shard, num_leaves, num_bins, hist_impl,
-                row_chunk, hist_dtype, wave_width,
-                make_cat(bins.shape[1]), None,
-                axis_name=DATA_AXIS, sample_key=sample_key,
-                mono=mono_arr, extra_trees=extra_trees, col_bins=colb,
-                ic_member=ic_member, hist_merge=merge_mode,
-                n_shards=n_shards, voting_k=voting_k,
-                hist_wire=wire_dtype, merge_chunks=merge_chunks)
+                grow, bins, y, w, bag, pred, feature_mask, hyper, key,
+                g, h, goss_k_shard, None, sample_key=sample_key)
             return tree, new_pred
         stats = jnp.stack([g * bag, h * bag, bag], axis=-1)
-        tree, row_leaf = grow_tree(
-            bins, stats, feature_mask, hyper.ctx(), num_leaves, num_bins,
-            hyper.max_depth, ff_bynode=hyper.feature_fraction_bynode,
-            key=key, axis_name=DATA_AXIS, hist_impl=hist_impl,
-            row_chunk=row_chunk, hist_dtype=hist_dtype,
-            wave_width=wave_width, mono=mono_arr, extra_trees=extra_trees,
-            col_bins=colb, ic_member=ic_member,
-            cat_info=make_cat(bins.shape[1]), fuse_partition=True,
-            hist_merge=merge_mode, n_shards=n_shards, voting_k=voting_k,
-            hist_wire=wire_dtype, merge_chunks=merge_chunks)
+        tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
+                              hyper.max_depth,
+                              hyper.feature_fraction_bynode, key)
         shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
         new_pred = pred + shrink * lookup_values(row_leaf, tree.leaf_value)
         return tree, new_pred
@@ -216,19 +197,9 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
     return jax.jit(sharded)
 
 
-def dp_full_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
-                       num_bins: int, wave_width: int = 1):
-    """One full training step (grad->tree->update) for dry-run validation."""
-    return make_dp_train_step(mesh, obj_key, num_leaves, num_bins,
-                              wave_width=wave_width)
-
-
 @functools.lru_cache(maxsize=None)
-def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
-                              num_bins: int, hist_impl: str = "auto",
-                              row_chunk: int = 131072,
-                              hist_dtype: str = "f32",
-                              wave_width: int = 1, linear_k: int = 8,
+def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
+                              linear_k: int = 8,
                               merge_mode: str = "psum", voting_k: int = 0,
                               wire_dtype: str = "f32",
                               merge_chunks: int = 4):
@@ -242,27 +213,22 @@ def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
     step(bins_sh, y_sh, w_sh, bag_sh, pred_sh, xraw_sh, fmask, hyper,
     key) -> (tree [replicated], new_pred [row-sharded]).
     """
-    from ..models.gbdt import _rebuild_objective
-    from ..models.tree import fit_linear_leaves, grow_tree
+    from ..models.tree import fit_linear_leaves
 
     obj = _rebuild_objective(obj_key)
+    grow = _mesh_grower(spec, mesh, merge_mode, voting_k, wire_dtype,
+                        merge_chunks, fuse_partition=True)
 
     def step(bins, y, w, bag, pred, xraw, feature_mask,
              hyper: HyperScalars, key):
         g, h = obj.grad_hess(pred, y, w)
         stats = jnp.stack([g * bag, h * bag, bag], axis=-1)
-        tree, row_leaf = grow_tree(
-            bins, stats, feature_mask, hyper.ctx(), num_leaves, num_bins,
-            hyper.max_depth, ff_bynode=hyper.feature_fraction_bynode,
-            key=key, axis_name=DATA_AXIS, hist_impl=hist_impl,
-            row_chunk=row_chunk, hist_dtype=hist_dtype,
-            wave_width=wave_width, fuse_partition=True,
-            hist_merge=merge_mode, n_shards=mesh.shape[DATA_AXIS],
-            voting_k=voting_k, hist_wire=wire_dtype,
-            merge_chunks=merge_chunks)
+        tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
+                              hyper.max_depth,
+                              hyper.feature_fraction_bynode, key)
         tree, delta = fit_linear_leaves(
             tree, row_leaf, xraw, g, h, bag, hyper.linear_lambda,
-            linear_k, row_chunk, axis_name=DATA_AXIS)
+            linear_k, spec.row_chunk, axis_name=DATA_AXIS)
         new_pred = pred + hyper.learning_rate * delta
         return tree, new_pred
 
@@ -278,9 +244,7 @@ def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
 
 
 @functools.lru_cache(maxsize=None)
-def make_dp_grow_step(mesh: Mesh, num_leaves: int, num_bins: int,
-                      hist_impl: str = "auto", row_chunk: int = 131072,
-                      wave_width: int = 1, hist_dtype: str = "f32",
+def make_dp_grow_step(mesh: Mesh, spec: GrowSpec,
                       merge_mode: str = "psum", voting_k: int = 0,
                       wire_dtype: str = "f32", merge_chunks: int = 4):
     """Data-parallel growth from PRECOMPUTED per-row stats.
@@ -296,18 +260,12 @@ def make_dp_grow_step(mesh: Mesh, num_leaves: int, num_bins: int,
     predictions with one ``leaf_value[row_leaf]`` gather instead of
     re-traversing the tree (code-review r2).
     """
+    grow = _mesh_grower(spec, mesh, merge_mode, voting_k, wire_dtype,
+                        merge_chunks, fuse_partition=True)
 
     def step(bins, stats, feature_mask, hyper: HyperScalars, key):
-        tree, row_leaf = grow_tree(
-            bins, stats, feature_mask, hyper.ctx(), num_leaves, num_bins,
-            hyper.max_depth, ff_bynode=hyper.feature_fraction_bynode,
-            key=key, axis_name=DATA_AXIS, hist_impl=hist_impl,
-            row_chunk=row_chunk, hist_dtype=hist_dtype,
-            wave_width=wave_width, fuse_partition=True,
-            hist_merge=merge_mode, n_shards=mesh.shape[DATA_AXIS],
-            voting_k=voting_k, hist_wire=wire_dtype,
-            merge_chunks=merge_chunks)
-        return tree, row_leaf
+        return grow(bins, stats, feature_mask, hyper.ctx(), hyper.max_depth,
+                    hyper.feature_fraction_bynode, key)
 
     sharded = shard_map(
         step,

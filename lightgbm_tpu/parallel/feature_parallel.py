@@ -34,7 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.gbdt import HyperScalars, _rebuild_objective
 from ..ops.lookup import lookup_values
-from ..models.tree import grow_tree
+from ..models.spec import GrowSpec
+from ..models.tree import build_cat_info, grower_from_spec
 
 FEATURE_AXIS = "feature"
 
@@ -121,11 +122,8 @@ def shard_features(mesh: Mesh, bins, fmask):
 
 
 @functools.lru_cache(maxsize=None)
-def make_fp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
-                       num_bins: int, hist_impl: str = "auto",
-                       row_chunk: int = 131072, is_rf: bool = False,
-                       hist_dtype: str = "f32", num_class: int = 1,
-                       cat_key=None, wave_width: int = 1):
+def make_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
+                       is_rf: bool = False, num_class: int = 1):
     """Build the jitted feature-parallel round step for a mesh.
 
     step(bins_fsharded, y, w, bag, pred, fmask_fsharded, hyper, key) ->
@@ -134,46 +132,39 @@ def make_fp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
     ``num_class > 1`` vmaps the class axis over the grower INSIDE the
     shard_map (one tree per class per round, exactly like the dp
     learner's step_mc — the per-class split-exchange all_gathers batch
-    into one collective).  ``cat_key`` enables categorical k-vs-rest
+    into one collective).  ``spec.cat_key`` enables categorical k-vs-rest
     splits: the static global is_cat mask is sliced to each shard's
     column range (cat_key indices are GLOBAL training columns), the
     winning subset mask rides the split exchange like any other
     BestSplit field, and the partition's category-membership test runs
     on the psum-broadcast global column.
     """
-    from ..models.gbdt import _build_cat_info
-
     obj = _rebuild_objective(obj_key)
     n_shards = mesh.shape[FEATURE_AXIS]
 
     def local_cat_info(f_local):
-        if cat_key is None:
+        if spec.cat_key is None:
             return None
-        full = _build_cat_info(cat_key, f_local * n_shards)
+        full = build_cat_info(spec.cat_key, f_local * n_shards)
         shard = jax.lax.axis_index(FEATURE_AXIS)
         return full._replace(is_cat=jax.lax.dynamic_slice(
             full.is_cat, (shard * f_local,), (f_local,)))
 
+    # wave growth composes with the split exchange since r5 (categorical
+    # datasets drop to the strict fp path inside grow_tree)
+    grow = grower_from_spec(spec, cat_info_for=local_cat_info,
+                            fp_axis=FEATURE_AXIS)
+
     def step(bins_l, y, w, bag, pred, fmask_l, hyper: HyperScalars, key):
-        cat_l = local_cat_info(bins_l.shape[1])
         g, h = obj.grad_hess(pred, y, w)          # [n] or [n, K]
 
         def grow_one(gc, hc, kc):
             stats = jnp.stack([gc * bag, hc * bag,
                                (bag > 0).astype(jnp.float32)], axis=-1)
-            return grow_tree(
-                bins_l, stats, fmask_l, hyper.ctx(), num_leaves, num_bins,
-                # the Booster gate guarantees bynode == 1.0 on the fp path;
-                # None engages the static bynode skip (no per-node
-                # threefry draw, ~20 dead kernels/split — ADVICE r4)
-                hyper.max_depth, ff_bynode=None,
-                key=kc, hist_impl=hist_impl, row_chunk=row_chunk,
-                hist_dtype=hist_dtype,
-                # wave growth composes with the split exchange since r5
-                # (categorical datasets drop to the strict fp path inside
-                # grow_tree)
-                wave_width=wave_width, fp_axis=FEATURE_AXIS,
-                cat_info=cat_l)
+            # the Booster gate guarantees bynode == 1.0 on the fp path (it
+            # would sample per SHARD): no per-node threefry draw
+            return grow(bins_l, stats, fmask_l, hyper.ctx(),
+                        hyper.max_depth, None, kc)
 
         if num_class > 1:
             from ..models.gbdt import mc_round_update
@@ -214,10 +205,8 @@ def make_mesh_2d(n_data: int, n_feature: int, devices=None) -> Mesh:
 
 
 @functools.lru_cache(maxsize=None)
-def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
-                          num_bins: int, hist_impl: str = "auto",
-                          row_chunk: int = 131072, is_rf: bool = False,
-                          hist_dtype: str = "f32", wave_width: int = 1):
+def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
+                          is_rf: bool = False):
     """2-D composed round step: each device holds an [n/dr, F/dc] block;
     per-block histograms psum-merge over the DATA axis (the dp allreduce),
     per-column-slice best splits exchange over the FEATURE axis (the fp
@@ -229,23 +218,22 @@ def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, num_leaves: int,
     new_pred [row-sharded]).
 
     r10 promotes this topology to the data learner's default at D>=8,
-    F>=64 (Booster._dp2_shape); ``wave_width`` rides through so wave
-    growth composes with both collectives.
+    F>=64 (Booster._dp2_shape); wave growth composes with both
+    collectives.
     """
     from .data_parallel import DATA_AXIS
 
     obj = _rebuild_objective(obj_key)
+    grow = grower_from_spec(spec, axis_name=DATA_AXIS, fp_axis=FEATURE_AXIS)
 
     def step(bins_b, y_l, w_l, bag_l, pred_l, fmask_l, hyper: HyperScalars,
              key):
         g, h = obj.grad_hess(pred_l, y_l, w_l)
         stats = jnp.stack([g * bag_l, h * bag_l,
                            (bag_l > 0).astype(jnp.float32)], axis=-1)
-        tree, row_leaf = grow_tree(
-            bins_b, stats, fmask_l, hyper.ctx(), num_leaves, num_bins,
-            hyper.max_depth, key=key, axis_name=DATA_AXIS,
-            fp_axis=FEATURE_AXIS, hist_impl=hist_impl, row_chunk=row_chunk,
-            hist_dtype=hist_dtype, wave_width=wave_width)
+        # (Booster._dp2_shape admits only bynode == 1.0: no per-node draw)
+        tree, row_leaf = grow(bins_b, stats, fmask_l, hyper.ctx(),
+                              hyper.max_depth, None, key)
         shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
         new_pred = pred_l + shrink * lookup_values(row_leaf, tree.leaf_value)
         return tree, new_pred

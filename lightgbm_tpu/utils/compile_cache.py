@@ -22,8 +22,7 @@ made of, is decided here and nowhere else:
   and profiles name ops by their name stack only, not by file and line.
 
 ``import lightgbm_tpu`` puts the rule in force, so ``lgb.train``,
-``lgb.cv``, the serving stack, ``bench.py`` and ``chip_smoke.py`` share
-one cache.  ``serving.enable_persistent_cache``, ``ModelBank(cache_dir=)``
+``lgb.cv``, the serving stack and ``chip_smoke.py`` share one cache.  ``serving.enable_persistent_cache``, ``ModelBank(cache_dir=)``
 and the serve CLI's ``compile_cache_dir`` key only report the directory
 in force.
 """

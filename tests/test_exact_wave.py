@@ -13,6 +13,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from lightgbm_tpu.models.spec import STRICT, WaveSchedule
 from lightgbm_tpu.models.tree import grow_tree
 from lightgbm_tpu.ops.lookup import lookup_values
 from lightgbm_tpu.ops.split import SplitContext
@@ -52,10 +53,10 @@ def test_exact_replay_matches_strict_grower(seed):
     bins, stats = _make(seed)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     t_s, rl_s = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                          wave_width=1, hist_impl="jnp")
-    enc = (4 * nl) * 1024 + 16          # overgrow_leaves=124, width=16
+                          wave=STRICT, hist_impl="jnp")
     t_e, rl_e = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                          wave_width=enc, hist_impl="jnp")
+                          wave=WaveSchedule(16, "exact", 4 * nl),
+                          hist_impl="jnp")
     assert int(t_s.num_leaves) == int(t_e.num_leaves) == nl
     assert _splits(t_s) == _splits(t_e)
     v_s = np.asarray(lookup_values(rl_s, t_s.leaf_value))
@@ -68,16 +69,17 @@ def test_exact_default_overgrow_near_strict():
     split multiset differs from strict in at most a few tail splits.
     (The production default is 2.0x — gap-converged on-chip,
     PERF_HISTORY.md r5.)"""
-    from lightgbm_tpu.models.gbdt import _exact_overgrow_target
+    from lightgbm_tpu.models.spec import _exact_overgrow_target
 
     nl, B = 31, 64
     bins, stats = _make(0)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     t_s, _ = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                       wave_width=1, hist_impl="jnp")
+                       wave=STRICT, hist_impl="jnp")
     l_over = _exact_overgrow_target(nl, 16, 1.5)
     t_e, _ = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                       wave_width=l_over * 1024 + 16, hist_impl="jnp")
+                       wave=WaveSchedule(16, "exact", l_over),
+                       hist_impl="jnp")
     from collections import Counter
 
     s_s, s_e = _splits(t_s), _splits(t_e)
@@ -93,9 +95,8 @@ def test_exact_row_leaf_consistent():
     nl, B = 31, 64
     bins, stats = _make(3)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
-    enc = 47 * 1024 + 16
     t, rl = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                      wave_width=enc, hist_impl="jnp")
+                      wave=WaveSchedule(16, "exact", 47), hist_impl="jnp")
     via_rl = np.asarray(lookup_values(rl, t.leaf_value))
     # traverse the tree directly for every row
     sf = np.asarray(t.split_feature)
@@ -121,40 +122,79 @@ def test_exact_respects_num_leaves_budget():
     bins, stats = _make(5, n=5000, F=6, B=32)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     t, rl = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                      wave_width=40 * 1024 + 8, hist_impl="jnp")
+                      wave=WaveSchedule(8, "exact", 40), hist_impl="jnp")
     assert t.capacity == 2 * nl - 1
     assert int(t.num_leaves) <= nl
     assert int(np.asarray(rl).max()) < t.capacity
 
 
-def test_resolve_wave_width_exact_encoding():
-    """Default tails: exact for large/rank/small-saturating shapes, greedy
-    only for mid-size pointwise; encoding decodes to a wave-aligned
-    overgrowth target."""
-    from lightgbm_tpu.config import parse_params
-    from lightgbm_tpu.models.gbdt import resolve_wave_width
+_WIDE_CAP = 526      # the wave boundary nearest 2 x 255 leaves at width 42
 
-    p = parse_params({"objective": "binary", "num_leaves": 127})
-    ww = resolve_wave_width(p, 1 << 20)          # large data -> exact
-    assert ww >= 1024
-    l_over, width = ww // 1024, ww % 1024
-    assert 127 < l_over <= 2 * 127 + 64
-    assert width == 42
-    p2 = parse_params({"objective": "regression", "num_leaves": 31})
-    assert resolve_wave_width(p2, 46000) < 0     # mid-size pointwise greedy
+# (params, rows the tree grows on) -> the schedule the round is built with;
+# each row names the round or PR that fixed it
+_WAVE_TABLE = {
+    "r5_large_data_exact":
+        ({"objective": "binary", "num_leaves": 127}, 1 << 20,
+         WaveSchedule(42, "exact", 274)),
+    "r4_mid_size_pointwise_greedy":
+        ({"objective": "regression", "num_leaves": 31}, 46_000,
+         WaveSchedule(30, "greedy")),
     # ... only while the tree closes before the wave width binds: 255
     # leaves at 1,568 rows a leaf grow with the exact tail (PR 28: greedy
     # read a best-first excess of 0.12 and 0.37 there, limit 0.04)
-    wide = parse_params({"objective": "binary", "num_leaves": 255})
-    assert resolve_wave_width(wide, 400_128) // 1024 > 255
-    assert resolve_wave_width(wide, 400_128) % 1024 == 42
-    p43 = parse_params({"objective": "binary", "num_leaves": 43})
-    assert resolve_wave_width(p43, 400_128) == -42
-    p3 = parse_params({"objective": "lambdarank", "num_leaves": 63})
-    assert resolve_wave_width(p3, 100000) >= 1024   # ranking -> exact
-    p4 = parse_params({"objective": "binary", "num_leaves": 127,
-                       "wave_tail": "greedy"})
-    assert resolve_wave_width(p4, 1 << 20) < 0   # explicit override wins
+    "pr28_width_binds_exact":
+        ({"objective": "binary", "num_leaves": 255}, 400_128,
+         WaveSchedule(42, "exact", _WIDE_CAP)),
+    "pr28_tree_closes_in_width_greedy":
+        ({"objective": "binary", "num_leaves": 43}, 400_128,
+         WaveSchedule(42, "greedy")),
+    "r5_ranking_exact":
+        ({"objective": "lambdarank", "num_leaves": 63}, 100_000,
+         WaveSchedule(42, "exact", 148)),
+    "r5_explicit_tail_wins":
+        ({"objective": "binary", "num_leaves": 127, "wave_tail": "greedy"},
+         1 << 20, WaveSchedule(42, "greedy")),
+    "r5_explicit_half":
+        ({"objective": "binary", "num_leaves": 127, "wave_tail": "half",
+          "wave_width": 16}, 1 << 20, WaveSchedule(16, "half")),
+    "r1_leafwise_is_strict":
+        ({"objective": "binary", "num_leaves": 127,
+          "grow_policy": "leafwise"}, 1 << 20, STRICT),
+    # r4's auto tail regimes (test_round4_fixes)
+    "r4_diamonds_greedy":
+        ({"objective": "regression", "num_leaves": 31}, 46_080,
+         WaveSchedule(30, "greedy")),
+    "r4_budget_saturating_small_exact":
+        ({"objective": "regression", "num_leaves": 31}, 8_192,
+         WaveSchedule(30, "exact", 62)),
+    "r4_ranking_mid_exact":
+        ({"objective": "lambdarank", "num_leaves": 63}, 100_096,
+         WaveSchedule(42, "exact", 148)),
+    "r4_ranking_any_size_exact":
+        ({"objective": "lambdarank", "num_leaves": 63}, 1 << 22,
+         WaveSchedule(42, "exact", 148)),
+    "r4_large_binary_exact":
+        ({"objective": "binary", "num_leaves": 127}, 1 << 20,
+         WaveSchedule(42, "exact", 274)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WAVE_TABLE))
+def test_resolve_wave(case):
+    """Default tails: exact for large/rank/small-saturating shapes, greedy
+    only for mid-size pointwise tasks far from leaf-budget saturation
+    (measured quality-neutral at the diamonds shape; greedy costs ~6e-2
+    NDCG@10 on the MSLR bench); the exact tail's cap is wave-aligned and
+    past ``num_leaves``."""
+    from lightgbm_tpu.config import parse_params
+    from lightgbm_tpu.models.spec import resolve_wave
+
+    params, rows, want = _WAVE_TABLE[case]
+    got = resolve_wave(parse_params(params), rows)
+    assert got == want and hash(got) == hash(want)
+    if got.tail == "exact":
+        nl = params["num_leaves"]
+        assert nl < got.cap_leaves <= 2 * nl + 64
 
 
 def test_exact_stalled_growth_no_ghost_leaves():
@@ -170,7 +210,7 @@ def test_exact_stalled_growth_no_ghost_leaves():
     stats = jnp.stack([jnp.asarray(g), jnp.ones(n), jnp.ones(n)], axis=-1)
     fmask = jnp.ones(3, jnp.float32)
     t, rl = grow_tree(jnp.asarray(X), stats, fmask, _ctx(min_data=1),
-                      31, 4, -1, wave_width=62 * 1024 + 16,
+                      31, 4, -1, wave=WaveSchedule(16, "exact", 62),
                       hist_impl="jnp")
     n_leaves = int(t.num_leaves)
     isl = np.asarray(t.is_leaf)
@@ -195,16 +235,17 @@ def test_partition_fused_kernel_matches_unfused():
     nl, B = 31, 64
     bins, stats = _make(4, n=12000, F=8)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
-    for enc in (16, -16, 48 * 1024 + 16):        # half, greedy, exact
+    for wave in (WaveSchedule(16, "half"), WaveSchedule(16, "greedy"),
+                 WaveSchedule(16, "exact", 48)):
         t_u, rl_u = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                              wave_width=enc, hist_impl="pallas",
+                              wave=wave, hist_impl="pallas",
                               hist_dtype="bf16", fuse_partition=False)
         t_f, rl_f = grow_tree(bins, stats, fmask, _ctx(), nl, B, -1,
-                              wave_width=enc, hist_impl="pallas",
+                              wave=wave, hist_impl="pallas",
                               hist_dtype="bf16", fuse_partition=True)
-        assert _splits(t_u) == _splits(t_f), enc
+        assert _splits(t_u) == _splits(t_f), wave
         np.testing.assert_array_equal(np.asarray(rl_u), np.asarray(rl_f),
-                                      err_msg=str(enc))
+                                      err_msg=str(wave))
         np.testing.assert_allclose(np.asarray(t_u.leaf_value),
                                    np.asarray(t_f.leaf_value),
                                    rtol=1e-5, atol=1e-6)
@@ -288,7 +329,7 @@ _IDENTITY_CASES = {
 def _grow_exact(case, monkeypatch, never_certified=False):
     """Exact-tail tree of a case, and the overgrown table its replay saw."""
     from lightgbm_tpu.models import tree as T
-    from lightgbm_tpu.models.gbdt import _exact_overgrow_target
+    from lightgbm_tpu.models.spec import _exact_overgrow_target
 
     bins, stats, ctx, nl, width = _IDENTITY_CASES[case]()
     cap = _exact_overgrow_target(nl, width, 2.0)
@@ -305,7 +346,8 @@ def _grow_exact(case, monkeypatch, never_certified=False):
             m.setattr(T, "_replay_certified",
                       lambda P, num_leaves: jnp.bool_(False))
         t, rl = grow_tree(bins, stats, jnp.ones(bins.shape[1], jnp.float32),
-                          ctx, nl, 64, -1, wave_width=cap * 1024 + width,
+                          ctx, nl, 64, -1,
+                          wave=WaveSchedule(width, "exact", cap),
                           hist_impl="jnp")
     return t, np.asarray(rl), seen["P"], (bins, stats, ctx, nl, width, cap)
 
@@ -336,7 +378,7 @@ def test_certified_stop_grows_the_same_tree(case, monkeypatch):
     if bool(_replay_certified(jnp.asarray(P_c), nl)):
         t_s, rl_s = grow_tree(bins, stats,
                               jnp.ones(bins.shape[1], jnp.float32), ctx, nl,
-                              64, -1, wave_width=1, hist_impl="jnp")
+                              64, -1, wave=STRICT, hist_impl="jnp")
         assert int(t_s.num_leaves) == int(t_c.num_leaves)
         assert _splits(t_s) == _splits(t_c)
         np.testing.assert_allclose(

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu.models.spec import WaveSchedule
 from lightgbm_tpu.models.tree import grow_tree, grow_tree_frontier
 from lightgbm_tpu.ops.predict import predict_tree_binned
 from lightgbm_tpu.ops.split import SplitContext
@@ -50,7 +51,7 @@ def test_wave1_matches_strict_structure():
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 15, 32, -1)
     t_wave, rl_wave = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 15, 32, -1,
-        wave_width=1)
+        wave=WaveSchedule(1, "half"))
     assert int(t_wave.num_leaves) == int(t_strict.num_leaves)
     np.testing.assert_array_equal(np.asarray(t_wave.split_feature),
                                   np.asarray(t_strict.split_feature))
@@ -70,7 +71,7 @@ def test_wide_wave_predictive_parity(width):
         31, 32, -1)
     t_wave, rl_w = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(min_data=20.0),
-        31, 32, -1, wave_width=width)
+        31, 32, -1, wave=WaveSchedule(width, "half"))
     assert int(t_wave.num_leaves) <= 31
     mse_s = float(np.mean((np.asarray(t_strict.leaf_value)[rl_s] - y) ** 2))
     mse_w = float(np.mean((np.asarray(t_wave.leaf_value)[rl_w] - y) ** 2))
@@ -83,7 +84,7 @@ def test_wave_traversal_matches_row_leaf():
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     tree, row_leaf = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 31, 32, -1,
-        wave_width=8)
+        wave=WaveSchedule(8, "half"))
     vals_train = np.asarray(tree.leaf_value)[np.asarray(row_leaf)]
     vals_traverse = np.asarray(
         predict_tree_binned(tree, jnp.asarray(bins), max_depth_cap=31))
@@ -95,7 +96,7 @@ def test_wave_min_data_and_budget():
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     tree, row_leaf = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(min_data=100.0),
-        16, 32, -1, wave_width=8)
+        16, 32, -1, wave=WaveSchedule(8, "half"))
     leaves = np.asarray(row_leaf)
     is_leaf = np.asarray(tree.is_leaf)
     assert int(tree.num_leaves) <= 16
@@ -109,7 +110,7 @@ def test_wave_max_depth():
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     tree, _ = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 31, 32,
-        max_depth=2, wave_width=8)
+        max_depth=2, wave=WaveSchedule(8, "half"))
     assert int(tree.num_leaves) <= 4
 
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.config import Params
 from lightgbm_tpu.models.gbdt import HyperScalars
+from lightgbm_tpu.models.spec import STRICT, GrowSpec, WaveSchedule
 from lightgbm_tpu.models.tree import grow_tree
 from lightgbm_tpu.ops.split import SplitContext
 from lightgbm_tpu.parallel.data_parallel import (
@@ -67,15 +68,16 @@ def _grow_pair(f, merge, voting_k=0, wave_width=1, num_leaves=15,
     fmask = jnp.ones(f, jnp.float32)
     mesh = Mesh(np.array(jax.devices()[:N_DEV]), ("data",))
     ctx = _ctx()
+    wave = STRICT if wave_width == 1 else WaveSchedule(wave_width, "half")
 
     tree_s, rows_s = jax.jit(lambda: grow_tree(
         jnp.asarray(bins), jnp.asarray(stats), fmask, ctx, num_leaves,
-        num_bins, jnp.int32(-1), wave_width=wave_width))()
+        num_bins, jnp.int32(-1), wave=wave))()
 
     def step(b, s):
         return grow_tree(b, s, fmask, ctx, num_leaves, num_bins,
                          jnp.int32(-1), axis_name="data",
-                         wave_width=wave_width, hist_merge=merge,
+                         wave=wave, hist_merge=merge,
                          n_shards=N_DEV, voting_k=voting_k)
 
     tree_d, rows_d = jax.jit(shard_map(
@@ -159,8 +161,8 @@ def test_pipelined_multiclass_matches_psum():
     mesh = make_mesh(N_DEV)
 
     def run(merge_mode):
-        step = make_dp_train_step(mesh, obj_mc, 7, 16, num_class=k,
-                                  merge_mode=merge_mode)
+        step = make_dp_train_step(mesh, obj_mc, GrowSpec(7, 16),
+                                  num_class=k, merge_mode=merge_mode)
         bins, y, w, bag = shard_rows(
             mesh, jnp.asarray(bins_np), jnp.asarray(y_mc),
             jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32))
@@ -183,7 +185,7 @@ def test_pipelined_ranking_stats():
     merge vs serial."""
     bins_np, _y, stats_np = _make_problem(13, n=1024)
     mesh = make_mesh(N_DEV)
-    grow = make_dp_grow_step(mesh, 15, 16,
+    grow = make_dp_grow_step(mesh, GrowSpec(15, 16),
                              merge_mode="reduce_scatter_pipelined")
     bins, stats = shard_rows(mesh, jnp.asarray(bins_np),
                              jnp.asarray(stats_np))
@@ -374,7 +376,7 @@ def test_dp_train_step_merge_modes_match_psum():
     mesh = make_mesh(N_DEV)
 
     def run(merge_mode, voting_k=0):
-        step = make_dp_train_step(mesh, OBJ_KEY, 15, 16,
+        step = make_dp_train_step(mesh, OBJ_KEY, GrowSpec(15, 16),
                                   merge_mode=merge_mode,
                                   voting_k=voting_k)
         bins, y, w, bag, pred = shard_rows(
@@ -402,7 +404,8 @@ def test_dp_grow_step_reduce_scatter_ranking_stats():
     bins_np, _y, stats_np = _make_problem(13, n=1024)
     n = stats_np.shape[0]
     mesh = make_mesh(N_DEV)
-    grow = make_dp_grow_step(mesh, 15, 16, merge_mode="reduce_scatter")
+    grow = make_dp_grow_step(mesh, GrowSpec(15, 16),
+                             merge_mode="reduce_scatter")
     bins, stats = shard_rows(mesh, jnp.asarray(bins_np),
                              jnp.asarray(stats_np))
     fmask = jnp.ones(bins_np.shape[1], jnp.float32)
@@ -428,8 +431,8 @@ def test_dp_multiclass_reduce_scatter_matches_psum():
     mesh = make_mesh(N_DEV)
 
     def run(merge_mode):
-        step = make_dp_train_step(mesh, obj_mc, 7, 16, num_class=k,
-                                  merge_mode=merge_mode)
+        step = make_dp_train_step(mesh, obj_mc, GrowSpec(7, 16),
+                                  num_class=k, merge_mode=merge_mode)
         bins, y, w, bag = shard_rows(
             mesh, jnp.asarray(bins_np), jnp.asarray(y_mc),
             jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32))
@@ -548,7 +551,7 @@ def test_int8_overflow_guards():
     """The int8 accumulation cliff (2^31/127 rows per (segment, bin)
     cell) must raise at every layer instead of silently wrapping."""
     from lightgbm_tpu.config import parse_params
-    from lightgbm_tpu.models.gbdt import check_int8_row_limit
+    from lightgbm_tpu.models.spec import check_int8_row_limit
     from lightgbm_tpu.ops.histogram_pallas import (
         INT8_ACC_ROW_LIMIT, hist_from_segstats_pallas)
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.config import Params
 from lightgbm_tpu.models.gbdt import HyperScalars
+from lightgbm_tpu.models.spec import GrowSpec
 from lightgbm_tpu.models.tree import grow_tree
 from lightgbm_tpu.ops.split import SplitContext
 from lightgbm_tpu.parallel.data_parallel import (
@@ -39,7 +40,8 @@ def _run_dp(problem, n_devices, num_leaves=15):
     bins_np, y_np, num_bins = problem
     n = len(y_np)
     mesh = make_mesh(n_devices)
-    step = make_dp_train_step(mesh, OBJ_KEY, num_leaves, num_bins)
+    step = make_dp_train_step(mesh, OBJ_KEY,
+                              GrowSpec(num_leaves, num_bins))
     bins, y, w, bag, pred = shard_rows(
         mesh, jnp.asarray(bins_np), jnp.asarray(y_np),
         jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
@@ -387,7 +389,8 @@ def test_2d_mesh_dp_fp_composition_matches_serial():
     fmask[:f] = 1.0
 
     step = make_dp_fp_train_step(
-        mesh, _objective_static_key(obj, p), p.num_leaves, ds.num_bins)
+        mesh, _objective_static_key(obj, p),
+        GrowSpec(p.num_leaves, ds.num_bins))
     bins_b = jax.device_put(jnp.asarray(codes),
                             NamedSharding(mesh, P("data", "feature")))
     fmask_d = jax.device_put(jnp.asarray(fmask),

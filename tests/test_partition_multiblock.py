@@ -12,6 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from lightgbm_tpu.models.spec import WaveSchedule
 from lightgbm_tpu.models.tree import grow_tree
 from lightgbm_tpu.ops.histogram_pallas import (_vmem_blocking,
                                                hist_partition_fused_pallas,
@@ -122,9 +123,9 @@ def test_tree_parity_f136():
                        jnp.float32(1e-3), jnp.float32(0.0))
 
     def grow(fp):
-        return grow_tree(bins, stats, fmask, ctx, 15, B, -1, wave_width=8,
-                         hist_impl="pallas", hist_dtype="bf16",
-                         fuse_partition=fp)
+        return grow_tree(bins, stats, fmask, ctx, 15, B, -1,
+                         wave=WaveSchedule(8, "half"), hist_impl="pallas",
+                         hist_dtype="bf16", fuse_partition=fp)
 
     tu, ru = jax.jit(lambda: grow(False))()
     tf, rf = jax.jit(lambda: grow(True))()

@@ -178,24 +178,3 @@ def test_tree_store_mutation_paths(small_reg):
     np.testing.assert_allclose(b.predict(X), before, rtol=0, atol=0)
     leaves = b.predict(X[:8], pred_leaf=True)
     assert leaves.shape == (8, 10)
-
-
-def test_auto_wave_tail_regimes():
-    """The auto tail rule (r5): greedy only for mid-size pointwise tasks
-    far from leaf-budget saturation (measured quality-neutral at the
-    diamonds shape); EXACT — strict order via overgrow + replay — for
-    large data (the AUC-parity north star), budget-saturating small
-    data, and ranking objectives at any size (greedy costs ~6e-2 NDCG@10
-    on the MSLR bench)."""
-    from lightgbm_tpu.config import parse_params
-    from lightgbm_tpu.models.gbdt import resolve_wave_width
-
-    diamonds = parse_params({"objective": "regression", "num_leaves": 31})
-    assert resolve_wave_width(diamonds, 46_080) < 0          # greedy
-    tiny = parse_params({"objective": "regression", "num_leaves": 31})
-    assert resolve_wave_width(tiny, 8_192) >= 1024           # exact
-    rank = parse_params({"objective": "lambdarank", "num_leaves": 63})
-    assert resolve_wave_width(rank, 100_096) >= 1024         # exact
-    assert resolve_wave_width(rank, 1 << 22) >= 1024         # exact, any n
-    big = parse_params({"objective": "binary", "num_leaves": 127})
-    assert resolve_wave_width(big, 1 << 20) >= 1024          # exact

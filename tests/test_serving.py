@@ -577,19 +577,35 @@ def test_convergence_loop_bounded_on_malformed_tree():
     assert np.asarray(vals).shape == (4,)    # returned: bounded by capacity
 
 
-def test_grow_tree_rejects_raw_wave_width_ge_1024():
-    """Raw widths >= 1024 collide with resolve_wave_width's exact-tail
-    encoding and must be rejected, not silently misrouted."""
-    from lightgbm_tpu.models.tree import grow_tree
+def test_wave_schedule_refuses_what_no_grower_runs():
+    """What the encoded width's collision check caught lives in the value:
+    a width past 512, an exact tail without its cap (or a cap without the
+    exact tail), an unknown tail; and where ``num_leaves`` is known, a cap
+    that does not exceed it."""
+    import jax.numpy as jnp
 
-    with pytest.raises(ValueError, match="resolve_wave_width"):
-        grow_tree(None, None, None, None, num_leaves=31, num_bins=256,
-                  max_depth=-1, wave_width=2000)
-    # a "valid-looking" exact encoding whose overgrow target does not
-    # exceed num_leaves is equally meaningless
-    with pytest.raises(ValueError, match="resolve_wave_width"):
-        grow_tree(None, None, None, None, num_leaves=31, num_bins=256,
-                  max_depth=-1, wave_width=31 * 1024 + 42)
+    from lightgbm_tpu.models.spec import STRICT, WaveSchedule
+    from lightgbm_tpu.models.tree import grow_tree, wave_extent
+
+    with pytest.raises(ValueError, match="width"):
+        WaveSchedule(2000, "greedy")
+    with pytest.raises(ValueError, match="width"):
+        WaveSchedule(0, "half")
+    with pytest.raises(ValueError, match="cap_leaves"):
+        WaveSchedule(42, "exact")
+    with pytest.raises(ValueError, match="cap_leaves"):
+        WaveSchedule(42, "greedy", 526)
+    with pytest.raises(ValueError, match="tail"):
+        WaveSchedule(42, "eager")
+    with pytest.raises(ValueError, match="strict"):
+        WaveSchedule(42, "strict")
+    assert STRICT == WaveSchedule(1, "strict")
+    assert wave_extent(WaveSchedule(42, "exact", 62), 31) == (62, 42)
+    assert wave_extent(WaveSchedule(42, "half"), 31) == (31, 30)
+    with pytest.raises(ValueError, match="num_leaves=31"):
+        grow_tree(jnp.zeros((4, 2), jnp.int32), None, None, None,
+                  num_leaves=31, num_bins=256, max_depth=-1,
+                  wave=WaveSchedule(42, "exact", 31))
 
 
 def test_fused_part_kernel_has_no_hist_dtype_param():

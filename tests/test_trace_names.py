@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.models import gbdt
+from lightgbm_tpu.models.spec import resolve_grow_spec
 from lightgbm_tpu.models.tree import HIST_ROOT, HIST_WAVE
 
 LEAVES, ROWS, FEATURES = 127, 6144, 10
@@ -49,9 +50,8 @@ def _round(policy):
     p = lgb.config.parse_params(_params(policy))
     obj = gbdt.create_objective(p)
     fn = gbdt._multi_round_fn.__wrapped__(
-        gbdt._objective_static_key(obj, p), p.num_leaves, 255, "auto",
-        131072, False, gbdt.resolve_hist_dtype(p, ROWS),
-        gbdt.resolve_wave_width(p, ROWS), 1, 0, False, bynode_off=True)
+        gbdt._objective_static_key(obj, p), resolve_grow_spec(p, ROWS, 255),
+        False, 1, 0, False)
     S = jax.ShapeDtypeStruct
     rows, key = S((ROWS,), jnp.float32), S((2,), jnp.uint32)
     hyper = jax.tree.map(lambda x: S(jnp.shape(x), jnp.asarray(x).dtype),

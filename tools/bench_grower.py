@@ -80,6 +80,7 @@ def mslr_round_ms(n=60_000, num_features=136, num_bins=256, num_leaves=31,
     """ms/round of the frontier grower at the MSLR shape (F=136) — the
     class the r5 single-block partition kernel gated off."""
     import jax.numpy as jnp
+    from lightgbm_tpu.models.spec import WaveSchedule
     from lightgbm_tpu.models.tree import grow_tree
     from lightgbm_tpu.ops.split import SplitContext
 
@@ -93,8 +94,8 @@ def mslr_round_ms(n=60_000, num_features=136, num_bins=256, num_leaves=31,
     ctx = SplitContext(jnp.float32(0.0), jnp.float32(1.0), jnp.float32(20.0),
                        jnp.float32(1e-3), jnp.float32(0.0))
     dt = _time_grow(lambda: grow_tree(
-        bins, stats, fmask, ctx, num_leaves, num_bins, -1, wave_width=8,
-        hist_impl="pallas", hist_dtype="bf16",
+        bins, stats, fmask, ctx, num_leaves, num_bins, -1,
+        wave=WaveSchedule(8, "half"), hist_impl="pallas", hist_dtype="bf16",
         fuse_partition=fuse_partition), reps=2)
     return dt * 1e3
 

@@ -52,6 +52,7 @@ import sys
 sys.path.insert(0, {repo!r})
 from lightgbm_tpu.config import Params
 from lightgbm_tpu.models.gbdt import HyperScalars
+from lightgbm_tpu.models.spec import GrowSpec
 from lightgbm_tpu.parallel.data_parallel import (
     make_dp_train_step, make_mesh, shard_rows)
 
@@ -77,7 +78,7 @@ for label, mode, vk, wire in (
         ("reduce_scatter_pipelined_int8", "reduce_scatter_pipelined", 0,
          "int8"),
         ("voting", "voting", 20, "f32")):
-    step = make_dp_train_step(mesh, obj_key, num_leaves, num_bins,
+    step = make_dp_train_step(mesh, obj_key, GrowSpec(num_leaves, num_bins),
                               merge_mode=mode, voting_k=vk,
                               wire_dtype=wire)
     key = jax.random.PRNGKey(0)
