@@ -2029,6 +2029,11 @@ class Booster:
                                                  3 * segments)
         for fact, value in (
                 ("wave_width", segments),
+                # the schedule's narrow phase (0 = none); it runs where the
+                # grower's partition-fused kernel does, and the trace's
+                # lgbtpu_hist_narrow events say how often
+                ("wave_narrow_width",
+                 wave.narrow_width if wave.narrow_width < segments else 0),
                 ("wave_tail", wave.tail),
                 ("overgrow_leaves", wave.cap_leaves),
                 ("hist_dtype", hist_dtype), ("rows_padded", eff_rows),

@@ -30,14 +30,23 @@ class WaveSchedule:
     ``width`` 1 is strict best-first, one split a pass, whatever the tail.
     ``cap_leaves`` is the exact tail's overgrowth cap and is given exactly
     when the tail is exact; that it exceeds ``num_leaves`` is checked by
-    the growers, which know both.
+    the growers, which know both.  ``narrow_width`` (0 = none, else a
+    power of two below ``width``) is the width of the passes a tree runs
+    while it has at most that many leaves (:func:`narrow_width_for`); a
+    grower without the partition-fused kernel ignores it.
     """
 
     width: int = 1
     tail: str = "strict"
     cap_leaves: Optional[int] = None
+    narrow_width: int = 0
 
     def __post_init__(self):
+        nw = self.narrow_width
+        if nw and not (0 < nw < self.width and nw & (nw - 1) == 0):
+            raise ValueError(
+                f"narrow_width must be 0 or a power of two below the wave "
+                f"width {self.width}, got {nw}")
         if self.tail not in WAVE_TAILS:
             raise ValueError(
                 f"wave tail must be one of {WAVE_TAILS}, got {self.tail!r}")
@@ -144,8 +153,8 @@ def _exact_overgrow_target(num_leaves: int, width: int, over: float) -> int:
     tree grows to when its replay is never certified earlier:
     ``tree._replay_certified``).
 
-    Every histogram pass costs the same whether it retires 2 or ``width``
-    splits, so a cap that lands mid-wave buys its last few
+    Every full-width histogram pass costs the same whether it retires 2 or
+    ``width`` splits, so a cap that lands mid-wave buys its last few
     candidate nodes at the price of a full pass.  Walk the greedy wave
     schedule (same recurrence as the grower: wave size = min(frontier
     doubling, width)) and pick the wave boundary closest to
@@ -165,6 +174,27 @@ def _exact_overgrow_target(num_leaves: int, width: int, over: float) -> int:
     return best or int(math.ceil(target))
 
 
+def narrow_width_for(width: int) -> int:
+    """The narrow phase's width for a tree whose waves are ``width`` wide:
+    the largest power of two of segments whose partition-fused pass the
+    kernel still turns (``ops.histogram_pallas.TURNED_MAX_K`` statistics
+    columns, 3 a segment), if that is below ``width``; else 0, no narrow
+    phase (every pass of so narrow a tree is turned already).
+
+    The rule behind the constant: the largest power of two whose turned
+    pass costs at most 0.75 of the full-width pass at both benchmark
+    shapes.  Measured (v5e, PR 32; ms a pass at 10,500,096 x 28 and at
+    400,128 x 2,000, full width 42: 113.1 and 645.2): width 16 turned
+    62.6 and 360.2 (0.55, 0.56), width 32 turned 88.7 and 509.9 (0.78,
+    0.79): 16.  A 255-leaf tree's first five passes (1, 2, 4, 8, 16
+    leaves) run at it; never a user parameter.
+    """
+    from ..ops.histogram_pallas import TURNED_MAX_K
+
+    narrow = 1 << ((TURNED_MAX_K // 3).bit_length() - 1)
+    return narrow if narrow < width else 0
+
+
 def resolve_wave(p: Params, n_rows: int) -> WaveSchedule:
     """Pick the grower's splits-per-histogram-pass and its tail (static).
 
@@ -178,8 +208,14 @@ def resolve_wave(p: Params, n_rows: int) -> WaveSchedule:
     wave retires up to ``width`` splits per pass instead of one (the strict
     grower's ``num_leaves - 1`` passes are the round-time ceiling — VERDICT
     r1 item 3).  Default width 42 keeps the segment-folded one-hot matmul
-    at 3*42=126 lanes — inside one 128-lane MXU tile, so a wave costs about
-    the same as a single strict trip.
+    at 3*42=126 lanes, inside one 128-lane MXU tile: in the orientation
+    ``onehot [B, chunk] x operand [3W, chunk]^T`` the MXU streams the
+    one-hot's 255 rows per weight tile whatever ``3W <= 128`` is, so a
+    pass of 42 splits costs what a pass of one does (113.1 ms for 109.1 at
+    10.5M x 28, v5e, PR 32).  That is NOT the least a narrow pass can
+    cost: with the dot turned a pass of up to 16 splits takes 0.55 of it,
+    which is what the schedule's ``narrow_width`` is for
+    (:func:`narrow_width_for`).
     """
     if p.grow_policy == "leafwise":
         return STRICT
@@ -229,8 +265,9 @@ def resolve_wave(p: Params, n_rows: int) -> WaveSchedule:
                     and n_rows < (1 << 19) and p.num_leaves - 1 <= width
                     else "exact")
     tail = str(p.extra.get("wave_tail", default_tail))
+    narrow = narrow_width_for(width)
     if tail != "exact":
-        return WaveSchedule(width, tail)
+        return WaveSchedule(width, tail, narrow_width=narrow)
     # wave_overgrow is the CAP of the overgrowth, for trees whose replay
     # is not certified earlier.  Default 2.0: history sized it, when every
     # tree ran to it (the r5 on-chip gap-vs-overgrow sweep converged at
@@ -238,7 +275,8 @@ def resolve_wave(p: Params, n_rows: int) -> WaveSchedule:
     # oracle draws, 2.5x no better; PERF_HISTORY.md r5)
     over = float(p.extra.get("wave_overgrow", 2.0))
     return WaveSchedule(width, "exact",
-                        _exact_overgrow_target(p.num_leaves, width, over))
+                        _exact_overgrow_target(p.num_leaves, width, over),
+                        narrow)
 
 
 def resolve_grow_spec(p: Params, n_rows: int, num_bins: int, *,

@@ -57,6 +57,9 @@ _SPLIT_ITER_OPCOUNT_STUB = False
 # lgbtpu.grad, lgbtpu.pred_update in models/gbdt.py).
 HIST_ROOT = "lgbtpu_hist_root"
 HIST_WAVE = "lgbtpu_hist_wave"
+# a wave pass of the narrow phase (grow_tree_frontier): its own role, and
+# no prefix of the two above, whose metrics count a full-width pass's work
+HIST_NARROW = "lgbtpu_hist_narrow"
 
 
 class Tree(NamedTuple):
@@ -1262,9 +1265,12 @@ def grow_tree_frontier(
 
       * per wave, the top-``W`` active leaves by cached candidate gain are
         split TOGETHER; one histogram pass computes each split's *smaller*
-        child directly (W segments folded into one one-hot matmul — MXU
-        lanes below 128 are padded anyway, so batching W splits into one
-        pass costs roughly the same as one strict trip);
+        child directly (W segments folded into one one-hot matmul: in
+        the full-width pass's orientation the MXU streams the one-hot's
+        rows per 128-lane weight tile whatever 3W <= 128 is, so W splits
+        cost what one does; while the tree has at most
+        ``wave.narrow_width`` leaves the pass runs that narrow, through
+        the turned dot, at 0.55 of the price: the narrow phase below);
       * the sibling histogram is ``parent − child`` from a per-leaf
         histogram cache (f32 ``[num_leaves, 3*F*B]``: each leaf's three
         planes ``[3, F, B]``, flat, because both uses of the cache are
@@ -1503,7 +1509,10 @@ def grow_tree_frontier(
     )
 
     bins_i32 = bins.astype(jnp.int32)
-    iota_w = lax.iota(jnp.int32, w_width)
+
+    def wave_body(width: int, role: str):
+        return functools.partial(body, width=width, role=role,
+                                 iota_w=lax.iota(jnp.int32, width))
 
     # The exact tail's overgrowth cap is wave-aligned
     # (spec._exact_overgrow_target): full waves land on it.  A tree whose
@@ -1525,7 +1534,11 @@ def grow_tree_frontier(
               & (budget > 0) & jnp.any(jnp.isfinite(gains)))
         return go & ~_replay_certified(P, num_leaves) if exact else go
 
-    def body(st: _WaveState) -> _WaveState:
+    def body(st: _WaveState, width: int, role: str,
+             iota_w: jnp.ndarray) -> _WaveState:
+        """One wave pass of at most ``width`` splits (static), its kernel
+        named ``role``; ``iota_w`` = ``iota(width)``, made outside the
+        loop."""
         m = capacity
         P = st.nodes
         with jax.named_scope("lgbtpu.wave.rank"):
@@ -1544,10 +1557,12 @@ def grow_tree_frontier(
                 lax.iota(jnp.int32, m))
             budget = grow_leaves - st.n_leaves
             n_cand = jnp.sum(jnp.isfinite(gains)).astype(jnp.int32)
-            # Wave size: every histogram pass costs the same (the one-hot
-            # matmul pads the segment lanes to a full MXU tile), so wave count
-            # IS tree cost.  Greedy (s = min(budget, W)) closes a 127-leaf tree
-            # in 8 passes; spending at most HALF the remaining budget per wave
+            # Wave size: a pass of a given static width costs the same
+            # however many of its segments carry a leaf (113 ms at 10.5M x
+            # 28 at width 42, 63 ms at the narrow phase's 16: v5e, PR 32),
+            # so the count of passes at each width IS tree cost.  Greedy (s =
+            # min(budget, W)) closes a 127-leaf tree in 8 passes;
+            # spending at most HALF the remaining budget per wave
             # allocates the tail splits near-strict-best-first at ~5 extra
             # passes.  The tail refinement is what preserves strict-growth
             # quality when the leaf budget nearly saturates the data (small-n /
@@ -1559,7 +1574,7 @@ def grow_tree_frontier(
             else:  # "greedy" / "exact"
                 alloc = budget
             s = jnp.minimum(jnp.minimum(n_cand, alloc),
-                            jnp.int32(w_width))               # splits this wave
+                            jnp.int32(width))       # splits this wave
             sel = jnp.isfinite(gains) & (rank < s)            # [M]
 
         with jax.named_scope("lgbtpu.wave.hist"):
@@ -1568,7 +1583,7 @@ def grow_tree_frontier(
             # native [n]-from-[capacity] gathers cost ~7 ms each at 1M rows on
             # TPU, and this block needs six of them — more than the histogram
             # kernel itself.
-            parent_r = order[:w_width]                        # [W] node ids
+            parent_r = order[:width]                          # [W] node ids
             active_r = iota_w < s
             prow = P[parent_r]            # [W, NC] — ONE gather for all the
             direct_left = prow[:, K.CAND_LC] <= prow[:, K.CAND_RC]  # per-parent
@@ -1589,7 +1604,7 @@ def grow_tree_frontier(
                 # the full-table compare was ~6 ms/wave at 11M rows.  Table
                 # values (sel/feat/thr/rank2/dl) are all <= 256 under the
                 # single-f-block gate, so the dot stays bf16-exact.
-                zw = jnp.zeros(w_width)
+                zw = jnp.zeros(width)
                 tbl_w = jnp.stack([active_r.astype(f32),
                                    (zw if multi_block
                                     else prow[:, K.CAND_FEAT]),
@@ -1606,12 +1621,12 @@ def grow_tree_frontier(
                 if n_pad_rows != n:
                     pv_t = jnp.pad(pv_t, ((0, 0), (0, n_pad_rows - n)))
                 direct_hist, enc = hist_partition_fused_pallas(
-                    bins_t_prep, stats_t_prep, pv_t, w_width, num_bins,
+                    bins_t_prep, stats_t_prep, pv_t, width, num_bins,
                     part_chunk, hist_dtype=kernel_dtype,
                     # multi-f-block routing gathers the wave split features'
                     # code rows; ignored on single-block shapes
                     wfeat=prow[:, K.CAND_FEAT].astype(jnp.int32),
-                    num_features=num_features, name=HIST_WAVE)
+                    num_features=num_features, name=role, f_blk=wave_f_blk)
                 # the kernel's direct_hist is the LOCAL pre-merge partial,
                 # planes [W, 3, F, B]: every merge topology applies after it
                 # unchanged (voting keeps it unmerged for the scorer's
@@ -1640,7 +1655,7 @@ def grow_tree_frontier(
                 # GLOBAL feature ids whose range this shard cannot bound
                 # statically — always exact there.
                 exact_in_bf16 = (fp_axis is None
-                                 and max(num_features, 2 * w_width,
+                                 and max(num_features, 2 * width,
                                          num_bins) <= 256)
                 pv = lookup_rows(p, jnp.stack(cols, axis=1),
                                  precision=(lax.Precision.DEFAULT
@@ -1688,9 +1703,8 @@ def grow_tree_frontier(
                 # it went to the direct (smaller) side; its segment is the
                 # leaf's wave rank.
                 to_direct = psel & (go_left == (pv[:, 4] > 0))
-                seg_id = jnp.where(to_direct, rank2_r >> 1, w_width)
-                direct_hist = hist_fn(seg_id, w_width,
-                                      HIST_WAVE)      # [W, 3, F, B]
+                seg_id = jnp.where(to_direct, rank2_r >> 1, width)
+                direct_hist = hist_fn(seg_id, width, role)  # [W, 3, F, B]
 
         with jax.named_scope("lgbtpu.wave.sibling"):
             # 4. sibling = parent - child (the subtraction trick).  The cache
@@ -1710,7 +1724,7 @@ def grow_tree_frontier(
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=lax.Precision.HIGHEST,
-            ).reshape(w_width, 3, f_hist, num_bins)
+            ).reshape(width, 3, f_hist, num_bins)
             other_hist = parent_hist - direct_hist
             dl = direct_left[:, None, None, None]
             left_hist = jnp.where(dl, direct_hist, other_hist)
@@ -1730,7 +1744,7 @@ def grow_tree_frontier(
             keep = 1.0 - jnp.any(q, axis=1).astype(f32)       # [L]
             newvals = jnp.concatenate([left_hist, right_hist])
             cache = st.hist_cache * keep[:, None] + lax.dot_general(
-                q.astype(f32), newvals.reshape(2 * w_width, fb3),
+                q.astype(f32), newvals.reshape(2 * width, fb3),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
                 precision=lax.Precision.HIGHEST)
@@ -1802,17 +1816,17 @@ def grow_tree_frontier(
                 K.SPLIT_GAIN])].set(jnp.stack([
                     prow[:, K.CAND_FEAT], prow[:, K.CAND_BIN],
                     nl_r.astype(jnp.float32), nr_r.astype(jnp.float32),
-                    jnp.zeros(w_width), gains[parent_r]], axis=-1))
+                    jnp.zeros(width), gains[parent_r]], axis=-1))
             child_rows = jnp.stack([
-                jnp.full((2 * w_width,), -1.0),              # SPLIT_FEAT
-                jnp.zeros((2 * w_width,)),                   # SPLIT_BIN
-                jnp.full((2 * w_width,), -1.0),              # LEFT
-                jnp.full((2 * w_width,), -1.0),              # RIGHT
+                jnp.full((2 * width,), -1.0),                # SPLIT_FEAT
+                jnp.zeros((2 * width,)),                     # SPLIT_BIN
+                jnp.full((2 * width,), -1.0),                # LEFT
+                jnp.full((2 * width,), -1.0),                # RIGHT
                 child_vals,                                  # LEAF_VALUE
-                jnp.ones((2 * w_width,)),                    # IS_LEAF
+                jnp.ones((2 * width,)),                      # IS_LEAF
                 jnp.concatenate([prow[:, K.CAND_LC],
                                  prow[:, K.CAND_RC]]),       # COUNT
-                jnp.zeros((2 * w_width,)),                   # SPLIT_GAIN
+                jnp.zeros((2 * width,)),                     # SPLIT_GAIN
                 child_depth,                                 # DEPTH
                 bs.gain,                                     # CAND_GAIN
                 bs.feature.astype(jnp.float32),              # CAND_FEAT
@@ -1824,7 +1838,7 @@ def grow_tree_frontier(
                 child_lo,                                    # BOUND_LO
                 child_hi,                                    # BOUND_HI
                 (bs.cat.astype(jnp.float32) if cat_info is not None
-                 else jnp.zeros((2 * w_width,))),            # CAND_CAT
+                 else jnp.zeros((2 * width,))),              # CAND_CAT
                 jnp.minimum(jnp.concatenate([prow[:, K.PM], prow[:, K.PM]]),
                             bs.gain),                        # PM
             ], axis=-1)                                      # [2W, NC]
@@ -1850,7 +1864,22 @@ def grow_tree_frontier(
                          mode="drop")),
         )
 
-    st = lax.while_loop(cond, body, st)
+    # The narrow phase.  While the tree has at most ``narrow_width`` leaves
+    # every leaf it has fits a wave of that width (n_cand <= n_leaves), so
+    # a pass of ``narrow_width`` selects the leaves, node ids, cache slots
+    # and order that a pass of ``w_width`` would: the same splits by
+    # construction, through a kernel whose turned dot does not pay for the
+    # columns that carry no leaf (_accumulate_wave), and through stages
+    # (the per-row lookup, the cache gather and update, the 2W children's
+    # scan) that are ``narrow_width`` wide.  ``n_leaves`` only grows, so
+    # the two loops in sequence are the one loop.  Taken exactly where the
+    # partition-fused kernel is: every other path keeps its one loop.
+    narrow_width = wave.narrow_width if fuse_part else 0
+    if 0 < narrow_width < w_width:
+        st = lax.while_loop(
+            lambda st: cond(st) & (st.n_leaves <= narrow_width),
+            wave_body(narrow_width, HIST_NARROW), st)
+    st = lax.while_loop(cond, wave_body(w_width, HIST_WAVE), st)
     if exact:
         with jax.named_scope("lgbtpu.replay"):
             newP, new_cat, row_leaf_new, n_leaves_f = _exact_prune(

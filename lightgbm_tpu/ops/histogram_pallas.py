@@ -42,6 +42,20 @@ DEFAULT_CHUNK = 2048
 # old conservative 16M figure).
 INT8_ACC_ROW_LIMIT = (1 << 31) // 127          # 16,909,320
 
+# The partition-fused pass turns its dot (_accumulate_wave) up to this many
+# statistics columns (3 a segment).  Measured on a v5e, a pass over
+# 10,500,096 x 28 (bfloat16) and over 400,128 x 2,000 (hi/lo, two calls), ms:
+#   K          3      6      12     24     48     96     126
+#   unturned   109.1  109.2  109.4  109.3  110.1  111.8  113.1
+#   turned     60.0   60.0   60.4   61.0   62.6   88.7   113.6
+#   unturned   612.7  612.8  615.4  617.1  622.7  636.3  645.2
+#   turned     339.9  339.8  343.5  348.5  360.2  509.9  648.2
+# Turned, something other than the MXU (the one-hot's construction) sets a
+# floor of 0.53 of the full-width pass up to K = 48; past it the streamed
+# rows do, and at K = 126 the orientations cost the same
+# (tools/sweep_narrow_pass.py; PERF.md section 6, PR 32).
+TURNED_MAX_K = 48
+
 
 def _hist_kernel(bins_ref, segstats_ref, out_ref, *, num_features: int,
                  num_bins: int, hist_dtype: str = "f32"):
@@ -750,8 +764,51 @@ def split_iter_pallas(hist2_t: jnp.ndarray, table: jnp.ndarray,
     )(hist2_t, table, fmask, aux, scal)
 
 
+def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_bins: int,
+                     num_segments: int, bins_minor: bool):
+    """Phase 2 of the partition-fused kernels: the segment-folded one-hot
+    dots of :func:`_fused_kernel` over this block's features, with ``seg``
+    ``[1, chunk]`` produced in-register by the routing phase.
+
+    ``bins_minor`` turns the dot as ``_fused_kernel`` does for the root:
+    ``operand [K, chunk] x onehot [B, chunk]^T`` into an ``[F_blk, K, B]``
+    accumulator.  Unturned, the MXU streams the one-hot's 255 rows through
+    every 128-column weight tile whatever ``K <= 128`` is, 3 useful columns
+    or 126; turned it streams ``K`` rows, and a narrow pass (the doubling
+    passes of a tree) stops paying for the full width: the table at
+    ``TURNED_MAX_K``."""
+    chunk = bins_ref.shape[1]
+    s = stats_ref.shape[0]
+    w = num_segments
+    stats = stats_ref[:]
+    iota_r = lax.broadcasted_iota(jnp.int32, (w * s, chunk), 0)
+    seg_match = seg == iota_r // s
+    proj_t = (lax.broadcasted_iota(jnp.int32, (w * s, s), 0) % s
+              == lax.broadcasted_iota(jnp.int32, (w * s, s), 1))
+    spread = lax.dot_general(
+        proj_t.astype(jnp.float32), stats.astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    operand = jnp.where(seg_match, spread, 0.0).astype(jnp.bfloat16)
+    iota_bt = lax.broadcasted_iota(jnp.int32, (num_bins, chunk), 0)
+
+    def body(f, _):
+        codes_t = bins_ref[pl.dslice(f, 1), :]
+        onehot_t = (iota_bt == codes_t).astype(jnp.bfloat16)
+        lhs, rhs = (operand, onehot_t) if bins_minor else (onehot_t, operand)
+        tile = lax.dot_general(
+            lhs, rhs,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        out_ref[pl.dslice(f, 1), :, :] += tile[None]
+        return _
+
+    lax.fori_loop(0, bins_ref.shape[0], body, 0)
+
+
 def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
-                       num_features: int, num_bins: int, num_segments: int):
+                       num_features: int, num_bins: int, num_segments: int,
+                       bins_minor: bool = False):
     """Wave histogram + ROW PARTITION in one kernel (single f-block).
 
     Accumulation is ALWAYS bf16-dot into f32 here; f32-exact callers get
@@ -782,7 +839,6 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     chunk = bins_ref.shape[1]
-    s = stats_ref.shape[0]
     w = num_segments
 
     sel = pv_ref[0, :]
@@ -809,33 +865,13 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
         0).reshape(1, chunk)
 
     # phase 2: standard segment-folded accumulation (see _fused_kernel)
-    stats = stats_ref[:]
-    iota_r = lax.broadcasted_iota(jnp.int32, (w * s, chunk), 0)
-    seg_match = seg == iota_r // s
-    proj_t = (lax.broadcasted_iota(jnp.int32, (w * s, s), 0) % s
-              == lax.broadcasted_iota(jnp.int32, (w * s, s), 1))
-    spread = lax.dot_general(
-        proj_t.astype(jnp.float32), stats.astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    operand = jnp.where(seg_match, spread, 0.0).astype(jnp.bfloat16)
-    iota_bt = lax.broadcasted_iota(jnp.int32, (num_bins, chunk), 0)
-
-    def body(f, _):
-        codes_t = bins_ref[pl.dslice(f, 1), :]
-        onehot_t = (iota_bt == codes_t).astype(jnp.bfloat16)
-        tile = lax.dot_general(
-            onehot_t, operand,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        out_ref[pl.dslice(f, 1), :, :] += tile[None]
-        return _
-
-    lax.fori_loop(0, bins_ref.shape[0], body, 0)
+    _accumulate_wave(bins_ref, stats_ref, seg, out_ref, num_bins=num_bins,
+                     num_segments=w, bins_minor=bins_minor)
 
 
 def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
-                          enc_ref, *, num_bins: int, num_segments: int):
+                          enc_ref, *, num_bins: int, num_segments: int,
+                          bins_minor: bool = False):
     """Multi-feature-block variant of :func:`_fused_part_kernel`.
 
     When the feature axis needs more than one VMEM block (MSLR's 136
@@ -855,7 +891,6 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     chunk = bins_ref.shape[1]
-    s = stats_ref.shape[0]
     w = num_segments
 
     sel = pv_ref[0, :]
@@ -883,29 +918,8 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
 
     # phase 2: standard segment-folded accumulation over THIS block's
     # features (see _fused_part_kernel)
-    stats = stats_ref[:]
-    iota_r = lax.broadcasted_iota(jnp.int32, (w * s, chunk), 0)
-    seg_match = seg == iota_r // s
-    proj_t = (lax.broadcasted_iota(jnp.int32, (w * s, s), 0) % s
-              == lax.broadcasted_iota(jnp.int32, (w * s, s), 1))
-    spread = lax.dot_general(
-        proj_t.astype(jnp.float32), stats.astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    operand = jnp.where(seg_match, spread, 0.0).astype(jnp.bfloat16)
-    iota_bt = lax.broadcasted_iota(jnp.int32, (num_bins, chunk), 0)
-
-    def body(f, _):
-        codes_t = bins_ref[pl.dslice(f, 1), :]
-        onehot_t = (iota_bt == codes_t).astype(jnp.bfloat16)
-        tile = lax.dot_general(
-            onehot_t, operand,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        out_ref[pl.dslice(f, 1), :, :] += tile[None]
-        return _
-
-    lax.fori_loop(0, bins_ref.shape[0], body, 0)
+    _accumulate_wave(bins_ref, stats_ref, seg, out_ref, num_bins=num_bins,
+                     num_segments=w, bins_minor=bins_minor)
 
 
 def prepare_wave_operands(bins: jnp.ndarray, stats: jnp.ndarray,
@@ -944,13 +958,26 @@ def hist_partition_fused_pallas(
     wfeat: jnp.ndarray | None = None,   # [W] i32 wave split features
     num_features: int | None = None,    # nominal F (bins_t may be f-padded)
     name: str = "lgbtpu_hist_partition_fused",
+    f_blk: int | None = None,           # feature block bins_t is padded for
+    bins_minor: bool | None = None,     # None: from the width (TURNED_MAX_K)
 ):
     """Fused wave pass: histogram over the direct children PLUS the row
     partition (see _fused_part_kernel).  Returns
     (hist f32 [num_segments, S, F, num_bins], enc i32 [n_pad]).
 
+    ``f_blk`` is the feature block ``bins_t`` was padded for, where that
+    was another width's (a tree's narrow passes read the operands
+    :func:`prepare_wave_operands` prepared at its full width, so the tree
+    keeps one transposed copy of the codes); by default this width's own.
+    The dot's orientation follows the width (:func:`_accumulate_wave`):
+    turned up to ``TURNED_MAX_K`` statistics columns.  Either way the
+    routing, the precision (bfloat16 operands, float32 accumulation in
+    chunk order) and the returned planes are the same; only the float32
+    summation order inside one MXU contraction may differ.
+
     The histograms leave as PLANES, bins minor: the kernel's ``[F, B,
-    W*S]`` accumulator is turned once, as a 2-D transpose, and nothing
+    W*S]`` accumulator is turned once, as a 2-D transpose (the turned
+    dot's ``[F, W*S, B]`` needs none), and nothing
     after it has the S-wide statistics axis as its minor one.  In HBM the
     chip tiles the two minor axes by (8, 128), so ``[W, F, B, 3]`` is
     stored as ``[W, F, B, 128]``: 42.7x its size, 22 GB for the 2W children
@@ -977,11 +1004,16 @@ def hist_partition_fused_pallas(
     s = stats_t.shape[0]
     k = num_segments * s
     n_chunks = n_pad // chunk
-    f_blk, n_fblk, _, _ = _vmem_blocking(num_features, num_bins, k,
-                                         chunk_align=512)
+    if f_blk is None:
+        f_blk = _vmem_blocking(num_features, num_bins, k,
+                               chunk_align=512)[0]
+    n_fblk = f_rows // f_blk
     assert f_rows == n_fblk * f_blk, (f_rows, n_fblk, f_blk)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
+    if bins_minor is None:
+        bins_minor = k <= TURNED_MAX_K
+    acc_dims = (k, num_bins) if bins_minor else (num_bins, k)
 
     if n_fblk == 1:
         def one_pass(stats_arr):
@@ -989,7 +1021,8 @@ def hist_partition_fused_pallas(
                 functools.partial(_fused_part_kernel,
                                   num_features=num_features,
                                   num_bins=num_bins,
-                                  num_segments=num_segments),
+                                  num_segments=num_segments,
+                                  bins_minor=bins_minor),
                 grid=(n_chunks,),
                 in_specs=[
                     pl.BlockSpec((num_features, chunk), lambda c: (0, c),
@@ -1000,14 +1033,14 @@ def hist_partition_fused_pallas(
                                  memory_space=pltpu.VMEM),
                 ],
                 out_specs=[
-                    pl.BlockSpec((num_features, num_bins, k),
+                    pl.BlockSpec((num_features,) + acc_dims,
                                  lambda c: (0, 0, 0),
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((1, chunk), lambda c: (0, c),
                                  memory_space=pltpu.VMEM),
                 ],
                 out_shape=[
-                    jax.ShapeDtypeStruct((num_features, num_bins, k),
+                    jax.ShapeDtypeStruct((num_features,) + acc_dims,
                                          jnp.float32),
                     jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
                 ],
@@ -1029,7 +1062,8 @@ def hist_partition_fused_pallas(
             return pl.pallas_call(
                 functools.partial(_fused_part_kernel_mb,
                                   num_bins=num_bins,
-                                  num_segments=num_segments),
+                                  num_segments=num_segments,
+                                  bins_minor=bins_minor),
                 grid=(n_fblk, n_chunks),
                 in_specs=[
                     pl.BlockSpec((f_blk, chunk), lambda f, c: (f, c),
@@ -1042,14 +1076,14 @@ def hist_partition_fused_pallas(
                                  memory_space=pltpu.VMEM),
                 ],
                 out_specs=[
-                    pl.BlockSpec((f_blk, num_bins, k),
+                    pl.BlockSpec((f_blk,) + acc_dims,
                                  lambda f, c: (f, 0, 0),
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((1, chunk), lambda f, c: (0, c),
                                  memory_space=pltpu.VMEM),
                 ],
                 out_shape=[
-                    jax.ShapeDtypeStruct((f_rows, num_bins, k),
+                    jax.ShapeDtypeStruct((f_rows,) + acc_dims,
                                          jnp.float32),
                     jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
                 ],
@@ -1064,7 +1098,11 @@ def hist_partition_fused_pallas(
         out = h1 + h2
     else:
         out, enc = one_pass(stats_t)
-    out = out[:num_features].transpose(2, 0, 1)          # [W*S, F, B]
+    out = out[:num_features]
+    if bins_minor:                       # [F, W*S, B]: major axes only
+        out = out.reshape(num_features, num_segments, s, num_bins)
+        return out.transpose(1, 2, 0, 3), enc[0]
+    out = out.transpose(2, 0, 1)                         # [W*S, F, B]
     return out.reshape(num_segments, s, num_features, num_bins), enc[0]
 
 

@@ -186,10 +186,17 @@ def test_resolve_wave(case):
     (measured quality-neutral at the diamonds shape; greedy costs ~6e-2
     NDCG@10 on the MSLR bench); the exact tail's cap is wave-aligned and
     past ``num_leaves``."""
+    import dataclasses
+
     from lightgbm_tpu.config import parse_params
-    from lightgbm_tpu.models.spec import resolve_wave
+    from lightgbm_tpu.models.spec import narrow_width_for, resolve_wave
 
     params, rows, want = _WAVE_TABLE[case]
+    # the narrow phase's width is resolved with the width (PR 32): 16 under
+    # every wave wider than that, none otherwise
+    want = dataclasses.replace(want,
+                               narrow_width=narrow_width_for(want.width))
+    assert want.narrow_width == (16 if want.width > 16 else 0)
     got = resolve_wave(parse_params(params), rows)
     assert got == want and hash(got) == hash(want)
     if got.tail == "exact":

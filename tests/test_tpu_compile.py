@@ -235,7 +235,9 @@ def _named_cases():
     rows = (S((n, f), jnp.uint8), S((n, 3), jnp.float32),
             S((n,), jnp.int32))
 
-    def partition(num_features):
+    def partition(num_features, width=42):
+        # a narrow pass (width 16: the dot turned) reads the operands and
+        # the feature block of the tree's full-width pass
         def build(named):
             def wave(bins, stats, pv, wfeat):
                 bins_t, stats_t, chunk = hp.prepare_wave_operands(
@@ -243,12 +245,14 @@ def _named_cases():
                 pv_t = jnp.pad(pv, ((0, 0),
                                     (0, bins_t.shape[1] - pv.shape[1])))
                 return hp.hist_partition_fused_pallas(
-                    bins_t, stats_t, pv_t, 42, b, chunk, interpret=False,
+                    bins_t, stats_t, pv_t, width, b, chunk, interpret=False,
                     hist_dtype="bf16", wfeat=wfeat,
-                    num_features=num_features, **named)
+                    num_features=num_features,
+                    f_blk=hp._vmem_blocking(num_features, b, 3 * 42)[0],
+                    **named)
             return wave, (S((n, num_features), jnp.uint8),
                           S((n, 3), jnp.float32), S((8, n), jnp.float32),
-                          S((42,), jnp.int32))
+                          S((width,), jnp.int32))
         return build
 
     return {
@@ -263,6 +267,8 @@ def _named_cases():
             (rows[0], S((2, n, 3), jnp.float32), S((2, n), jnp.int32))),
         "hist_partition_fused": partition(28),
         "hist_partition_fused_mb": partition(136),
+        "hist_partition_fused_narrow": partition(28, 16),
+        "hist_partition_fused_mb_narrow": partition(136, 16),
         # two features and a small chunk: this kernel unrolls one matmul
         # per feature and is not on the main path (28 compile for minutes)
         "hist_segstats": lambda named: (
@@ -297,6 +303,10 @@ def _named_cases():
     ("hist_fused", "lgbtpu_hist_wave", "%lgbtpu_hist_wave"),
     ("hist_partition_fused", "lgbtpu_hist_wave", "%lgbtpu_hist_wave"),
     ("hist_partition_fused_mb", "lgbtpu_hist_wave", "%lgbtpu_hist_wave"),
+    ("hist_partition_fused_narrow", "lgbtpu_hist_narrow",
+     "%lgbtpu_hist_narrow"),
+    ("hist_partition_fused_mb_narrow", "lgbtpu_hist_narrow",
+     "%lgbtpu_hist_narrow"),
 ])
 def test_kernel_instruction_names(one_chip, case, name, instruction):
     """The compiled Mosaic call's HLO instruction is named by the program:
@@ -427,6 +437,8 @@ _ROUNDS = {
 
 @pytest.mark.parametrize("case", list(_ROUNDS))
 def test_whole_round(one_chip, monkeypatch, case):
+    from lightgbm_tpu.models.spec import narrow_width_for
+
     rows_padded, num_features, params, resolved, most_temp = _ROUNDS[case]
     compiled, facts = _round_compiled(one_chip, monkeypatch, rows_padded,
                                       num_features, **params)
@@ -436,6 +448,9 @@ def test_whole_round(one_chip, monkeypatch, case):
     assert facts["train.features"] == num_features
     text = compiled.as_text()
     assert "%lgbtpu_hist_root" in text and "%lgbtpu_hist_wave" in text
+    # the doubling passes' own role, at the width the schedule resolved
+    assert "%lgbtpu_hist_narrow" in text
+    assert facts["train.wave_narrow_width"] == narrow_width_for(42) > 0
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= most_temp
     assert _narrow_minor_f32(text, 64 << 20) == []

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu.models import gbdt
 from lightgbm_tpu.models.spec import resolve_grow_spec
-from lightgbm_tpu.models.tree import HIST_ROOT, HIST_WAVE
+from lightgbm_tpu.models.tree import HIST_NARROW, HIST_ROOT, HIST_WAVE
 
 LEAVES, ROWS, FEATURES = 127, 6144, 10
 ROUND = ["lgbtpu.grad", "lgbtpu.root", "lgbtpu.wave.hist",
@@ -86,8 +86,8 @@ def test_round_kernels_carry_role_names(policy, monkeypatch):
     assert all(n and n.startswith("lgbtpu_") for n in names), names
     if policy == "leafwise":        # the split-iteration kernel by its own
         assert "lgbtpu_split_iter" in names
-    else:
-        assert set(names) == {HIST_ROOT, HIST_WAVE}
+    else:       # ... and the doubling passes under theirs (PR 32)
+        assert set(names) == {HIST_ROOT, HIST_NARROW, HIST_WAVE}
 
 
 @pytest.mark.parametrize("policy", list(GROWERS))
