@@ -31,9 +31,11 @@ class WaveSchedule:
     ``cap_leaves`` is the exact tail's overgrowth cap and is given exactly
     when the tail is exact; that it exceeds ``num_leaves`` is checked by
     the growers, which know both.  ``narrow_width`` (0 = none, else a
-    power of two below ``width``) is the width of the passes a tree runs
-    while it has at most that many leaves (:func:`narrow_width_for`); a
-    grower without the partition-fused kernel ignores it.
+    power of two below ``width``) is the width of the passes that have at
+    most that many leaves to expand (:func:`narrow_width_for`): those of a
+    tree with at most that many leaves and, under the exact tail, those
+    whose replay still needs at most that many (``tree._replay_needed``);
+    a grower without the partition-fused kernel ignores it.
     """
 
     width: int = 1
@@ -187,7 +189,8 @@ def narrow_width_for(width: int) -> int:
     400,128 x 2,000, full width 42: 113.1 and 645.2): width 16 turned
     62.6 and 360.2 (0.55, 0.56), width 32 turned 88.7 and 509.9 (0.78,
     0.79): 16.  A 255-leaf tree's first five passes (1, 2, 4, 8, 16
-    leaves) run at it; never a user parameter.
+    leaves) run at it and, under the exact tail, the certification passes
+    that need at most 16 leaves expanded (PR 35); never a user parameter.
     """
     from ..ops.histogram_pallas import TURNED_MAX_K
 

@@ -1168,6 +1168,11 @@ def _exact_prune(P, cand_catmask, row_leaf, num_leaves: int,
     return newP, new_cat, row_leaf_new, n_kept + 1
 
 
+def _has_candidate(P):
+    """bool[M]: the leaves of the packed node table that can still split."""
+    return (P[:, _PK.IS_LEAF] > 0.5) & jnp.isfinite(P[:, _PK.CAND_GAIN])
+
+
 def _replay_certified(P, num_leaves: int):
     """True once :func:`_exact_prune`'s replay over the packed node table
     ``P`` is provably the strict best-first tree, whatever further
@@ -1189,10 +1194,39 @@ def _replay_certified(P, num_leaves: int):
     """
     K = _PK
     pm = P[:, K.PM]
-    cand = (P[:, K.IS_LEAF] > 0.5) & jnp.isfinite(P[:, K.CAND_GAIN])
-    t = jnp.max(jnp.where(cand, pm, -jnp.inf))
+    t = jnp.max(jnp.where(_has_candidate(P), pm, -jnp.inf))
     above = (P[:, K.LEFT] >= 0) & (pm > t)
     return jnp.sum(above.astype(jnp.int32)) >= num_leaves - 1
+
+
+def _replay_needed(P, num_leaves: int):
+    """How many leaves of the packed node table ``P`` the replay still
+    NEEDS expanded before :func:`_replay_certified` can fire.
+
+    ``theta`` is the ``(num_leaves - 1)``-th largest pathmin among the
+    EXPANDED nodes, minus infinity while there are fewer.  A leaf whose
+    pathmin is under ``theta`` cannot enter the replay's ``num_leaves -
+    1`` extractions, nor can anything that grows under it (pathmin only
+    falls along a path, ``theta`` only rises as nodes are added):
+    expanding it changes neither the kept splits nor the certificate.  The
+    others, the leaves with a candidate split and a pathmin of at least
+    ``theta``, are needed; a tie with ``theta`` counts (the certificate
+    does not count a tie either: both err towards the wider pass).  They
+    are the first ``needed`` leaves of the exact tail's pathmin ranking, so
+    a pass at least that wide expands them all.  While the tree is short
+    of ``num_leaves - 1`` expanded nodes every candidate is needed; none is
+    exactly when the table is certified or no leaf has a candidate left.
+    Node-table-sized reductions only.
+    """
+    K = _PK
+    pm = P[:, K.PM]
+    # pathmin >= theta, without the sort: fewer than num_leaves - 1
+    # expanded nodes lie strictly above it (one [M, M] compare; a sort of
+    # the table costs the chip more than the rest of the loop's condition)
+    above = jnp.sum(((P[:, K.LEFT] >= 0)[None, :]
+                     & (pm[None, :] > pm[:, None])).astype(jnp.int32), axis=1)
+    return jnp.sum((_has_candidate(P)
+                    & (above < num_leaves - 1)).astype(jnp.int32))
 
 
 def wave_extent(wave: WaveSchedule, num_leaves: int) -> Tuple[int, int]:
@@ -1268,9 +1302,9 @@ def grow_tree_frontier(
         child directly (W segments folded into one one-hot matmul: in
         the full-width pass's orientation the MXU streams the one-hot's
         rows per 128-lane weight tile whatever 3W <= 128 is, so W splits
-        cost what one does; while the tree has at most
-        ``wave.narrow_width`` leaves the pass runs that narrow, through
-        the turned dot, at 0.55 of the price: the narrow phase below);
+        cost what one does; a pass with at most ``wave.narrow_width``
+        leaves to expand runs that narrow, through the turned dot, at 0.55
+        of the price: the narrow passes below);
       * the sibling histogram is ``parent − child`` from a per-leaf
         histogram cache (f32 ``[num_leaves, 3*F*B]``: each leaf's three
         planes ``[3, F, B]``, flat, because both uses of the cache are
@@ -1526,11 +1560,14 @@ def grow_tree_frontier(
     # have room for an eighth of a wave.
     min_budget = max(1, w_width // 8) if exact else 1
 
-    def cond(st: _WaveState):
+    def cond(st: _WaveState, leaves=None):
+        """Whether the tree runs another pass; ``leaves`` = the leaf count
+        held against the cap, the tree's own unless given."""
         P = st.nodes
         gains = jnp.where(P[:, K.IS_LEAF] > 0.5, P[:, K.CAND_GAIN], neg_inf)
-        budget = grow_leaves - st.n_leaves
-        go = (((budget >= min_budget) | (st.n_leaves <= num_leaves))
+        leaves = st.n_leaves if leaves is None else leaves
+        budget = grow_leaves - leaves
+        go = (((budget >= min_budget) | (leaves <= num_leaves))
               & (budget > 0) & jnp.any(jnp.isfinite(gains)))
         return go & ~_replay_certified(P, num_leaves) if exact else go
 
@@ -1864,7 +1901,7 @@ def grow_tree_frontier(
                          mode="drop")),
         )
 
-    # The narrow phase.  While the tree has at most ``narrow_width`` leaves
+    # The narrow passes.  While the tree has at most ``narrow_width`` leaves
     # every leaf it has fits a wave of that width (n_cand <= n_leaves), so
     # a pass of ``narrow_width`` selects the leaves, node ids, cache slots
     # and order that a pass of ``w_width`` would: the same splits by
@@ -1875,11 +1912,62 @@ def grow_tree_frontier(
     # the two loops in sequence are the one loop.  Taken exactly where the
     # partition-fused kernel is: every other path keeps its one loop.
     narrow_width = wave.narrow_width if fuse_part else 0
-    if 0 < narrow_width < w_width:
+    if 0 < narrow_width < w_width and exact:
+        # The exact tail knows more than the leaf count: a pass has to
+        # expand only the leaves its replay still NEEDS (_replay_needed),
+        # and they head the pathmin ranking, so while they fit the narrow
+        # width the narrow pass expands them all (its other slots go to the
+        # next leaves of the ranking, as the wide pass fills its own).
+        # What the wide pass would have expanded besides lies under
+        # ``theta`` for good: the kept splits, the certificate's verdict
+        # after every pass and _exact_prune's extractions are the
+        # full-width schedule's.  While the tree is short of ``num_leaves
+        # - 1`` splits every candidate is needed: the rule CONTAINS the
+        # narrow phase above and runs the growth passes at full width.
+        # Past that, a certification pass (2-4 a tree at 10.5M x 28, most
+        # on a chain that needs one to five leaves) runs narrow.
+        # ``needed`` is not monotone (both children of a needed leaf can
+        # be needed), so the width is chosen before every pass: an outer
+        # loop over the two inner loops, every carry aliased in place.
+        # The cap bounds the passes as it did: it is held against
+        # ``sched``, the leaves the FULL-WIDTH schedule would have by now
+        # (a narrow pass adds 16 leaves at most: held against the tree's
+        # own count, a tree that never certifies would run 16 more
+        # passes); the two counts part only once a certification pass
+        # has run narrow.
+        def plan(st, sched):
+            """After a pass: whether the tree runs another, and whether
+            narrow.  Carried, so the loops' conditions read two flags and
+            the table's reductions run once a pass."""
+            needed = _replay_needed(st.nodes, num_leaves)
+            return (cond(st, sched),
+                    (needed > 0) & (needed <= narrow_width))
+
+        def passes(width: int, role: str, narrow: bool):
+            one_pass = wave_body(width, role)
+
+            def step(carry):
+                st, sched = carry[:2]
+                n_cand = jnp.sum(_has_candidate(st.nodes).astype(jnp.int32))
+                sched = sched + jnp.minimum(
+                    jnp.minimum(n_cand, grow_leaves - sched), w_width)
+                st = one_pass(st)
+                return (st, sched) + plan(st, sched)
+
+            return lambda carry: lax.while_loop(
+                lambda c: c[2] & (c[3] == narrow), step, carry)
+
+        narrow_passes = passes(narrow_width, HIST_NARROW, True)
+        wide_passes = passes(w_width, HIST_WAVE, False)
         st = lax.while_loop(
-            lambda st: cond(st) & (st.n_leaves <= narrow_width),
-            wave_body(narrow_width, HIST_NARROW), st)
-    st = lax.while_loop(cond, wave_body(w_width, HIST_WAVE), st)
+            lambda c: c[2], lambda c: wide_passes(narrow_passes(c)),
+            (st, st.n_leaves) + plan(st, st.n_leaves))[0]
+    else:
+        if 0 < narrow_width < w_width:
+            st = lax.while_loop(
+                lambda st: cond(st) & (st.n_leaves <= narrow_width),
+                wave_body(narrow_width, HIST_NARROW), st)
+        st = lax.while_loop(cond, wave_body(w_width, HIST_WAVE), st)
     if exact:
         with jax.named_scope("lgbtpu.replay"):
             newP, new_cat, row_leaf_new, n_leaves_f = _exact_prune(

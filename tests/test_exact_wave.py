@@ -302,6 +302,63 @@ def test_replay_certified_on_hand_made_tables(name, expanded_pm, leaves,
     assert bool(_replay_certified(_table(expanded_pm, leaves), 5)) is fires
 
 
+# PR 35: how many leaves the replay still needs expanded (the width of
+# the next pass on the partition-fused path: tests/test_narrow_pass.py)
+
+
+@pytest.mark.parametrize("name,expanded_pm,leaves,needed", [
+    # num_leaves = 5: theta = the 4th largest pathmin among the expanded
+    ("certified_needs_none", [9., 7., 5., 3., 1.], [(2., 4.), (.5, .5)], 0),
+    ("one_fewer_needs_the_best_leaf", [9., 7., 5., 1.5, 1.],
+     [(2., 4.), (.5, .5)], 1),
+    ("tie_with_theta_is_needed", [9., 7., 5., 2., 1.],
+     [(2., 4.), (.5, .5)], 1),
+    ("all_above_theta", [9., 7., 5., 1., 1.], [(2., 4.), (1.5, 3.), (1., 1.)],
+     3),
+    ("dead_leaf_is_not_needed", [9., 7., 5., 3.], [(8., _NEG), (2., 4.)], 0),
+    ("short_of_splits_every_candidate", [9., 7., 5.],
+     [(8., _NEG), (2., 4.), (.5, .5)], 2),
+    ("all_stump", [], [(6., 6.)], 1),
+    ("all_stump_dead_root", [], [(_NEG, _NEG)], 0),
+])
+def test_replay_needed_on_hand_made_tables(name, expanded_pm, leaves, needed):
+    from lightgbm_tpu.models.tree import _replay_needed
+
+    assert int(_replay_needed(_table(expanded_pm, leaves), 5)) == needed
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_replay_needed_against_brute_force(seed):
+    """Random node tables, pathmins from a handful of values so that ties
+    with theta are common: the count equals a numpy sort's, and it is 0
+    exactly when the table is certified or no leaf has a candidate (the
+    loop stops on either), so the loop never asks for a pass with nothing
+    to expand."""
+    from lightgbm_tpu.models.tree import _replay_certified, _replay_needed
+
+    rng = np.random.default_rng(seed)
+    zeros = 0
+    for _ in range(25):
+        num_leaves = int(rng.integers(2, 9))
+        expanded_pm = rng.integers(0, 7, rng.integers(0, 13)).astype(float)
+        n_leaves = int(rng.integers(max(1, num_leaves // 2), 11))
+        leaves = [(float(rng.integers(0, 7)),
+                   float(rng.integers(0, 9)) if rng.random() < 0.7 else _NEG)
+                  for _ in range(n_leaves)]
+        P = _table(expanded_pm.tolist(), leaves)
+        top = np.sort(expanded_pm)[::-1]
+        theta = top[num_leaves - 2] if len(top) >= num_leaves - 1 else _NEG
+        cands = [pm for pm, gain in leaves if np.isfinite(gain)]
+        want = sum(pm >= theta for pm in cands)
+        got = int(_replay_needed(P, num_leaves))
+        assert got == want, (num_leaves, expanded_pm, leaves)
+        certified = bool(_replay_certified(P, num_leaves))
+        assert (got == 0) == (certified or not cands)
+        assert not (certified and got)
+        zeros += got == 0
+    assert 0 < zeros < 25
+
+
 def _rank_stats(seed, n_queries=300, docs=16, F=8, B=64):
     """Bin codes and lambdarank (grad, hess, 1) of a zero score."""
     from lightgbm_tpu.config import parse_params

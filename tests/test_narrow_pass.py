@@ -6,6 +6,11 @@ accumulator ``[F_blk, K, B]``) equals the unturned one on operands prepared
 at the TREE's width, single and multi feature block, bfloat16 and hi/lo
 float32.  Grower: a tree grown with ``narrow_width`` 16 is the tree grown
 with 0, and the narrow loop runs the doubling passes and no more.
+
+Since PR 35 the exact tail chooses the width before every pass: narrow
+while the leaves its replay still needs (``tree._replay_needed``) fit the
+narrow width, which contains the doubling passes and adds the
+certification passes of a tree that holds its splits already.
 """
 
 import numpy as np
@@ -175,12 +180,14 @@ def _table(n=4096, f=12, seed=5):
     return jnp.asarray(bins), stats
 
 
-def _grow(bins, stats, num_leaves, narrow, hist_dtype):
-    """One tree through the partition-fused path, exact tail."""
+def _grow(bins, stats, num_leaves, narrow, hist_dtype, cap=None):
+    """One tree through the partition-fused path, exact tail (``cap``:
+    the overgrowth's, the schedule's own 2x unless given)."""
     from lightgbm_tpu.models.spec import _exact_overgrow_target
 
     wave = WaveSchedule(TREE_W, "exact",
-                        _exact_overgrow_target(num_leaves, TREE_W, 2.0),
+                        cap or _exact_overgrow_target(num_leaves, TREE_W,
+                                                      2.0),
                         narrow_width=narrow)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     return jax.jit(lambda: grow_tree(
@@ -214,11 +221,208 @@ def test_narrow_phase_grows_the_same_tree(num_leaves, hist_dtype,
 
     monkeypatch.setattr(histogram_pallas, "hist_partition_fused_pallas", spy)
     narrow = _grow(bins, stats, num_leaves, 16, hist_dtype)
+    # what the program HOLDS: one narrow and one full-width loop body (a
+    # loop's body is traced once, however its passes alternate at run time:
+    # test_needed_width_passes_grow_the_same_tree reads that order)
     assert calls == [(HIST_NARROW, 16), (HIST_WAVE, TREE_W)]
     del calls[:]
     wide = _grow(bins, stats, num_leaves, 0, hist_dtype)
     assert calls == [(HIST_WAVE, TREE_W)]
     assert int(narrow[0].num_leaves) == num_leaves
+    _assert_same_tree(narrow, wide)
+
+
+# ---------------------------------------------------------------------------
+# PR 35: the width of an exact-tail pass follows the leaves the replay needs
+# ---------------------------------------------------------------------------
+
+
+def _chain_table(n=4096, f=12, seed=13):
+    """Heavy-tailed targets (Pareto, shape 2): best-first growth follows a
+    few outliers down a deep chain, so a tree that holds its splits still
+    runs many certification passes, each needing a handful of leaves (the
+    shape of a boosted Higgs tree from round 7 on: PERF.md, PR 35)."""
+    rng = np.random.default_rng(seed)
+    bins = rng.integers(0, B, (n, f)).astype(np.uint8)
+    y = rng.pareto(2.0, n) * (1 + bins[:, 0] / 64.0) * (1 + bins[:, 1] / 128.0)
+    g = (y - y.mean()).astype(np.float32)
+    h = rng.uniform(0.5, 1.0, n).astype(np.float32)
+    stats = jnp.stack([jnp.asarray(-g), jnp.asarray(h),
+                       jnp.ones(n, jnp.float32)], axis=-1)
+    return jnp.asarray(bins), stats
+
+
+# name -> (table, the overgrowth cap by num_leaves; None = the schedule's).
+# The chain's caps are far enough out that its replay is certified.
+_TABLES = {
+    "plain": (_table, None),
+    "plain_other_rows": (lambda: _table(n=6000, f=8, seed=11), None),
+    "deep_chain": (_chain_table, {63: 610, 255: 1030}),
+}
+
+
+@pytest.fixture
+def passes(monkeypatch):
+    """``(role, width)`` of every partition-fused pass a tree RUNS, in
+    order (a callback in the traced call; the trace itself holds each
+    loop body once)."""
+    ran = []
+    real = histogram_pallas.hist_partition_fused_pallas
+
+    def spy(bins_t, stats_t, pv_t, num_segments, *args, **kwargs):
+        role = kwargs["name"]
+        jax.debug.callback(lambda _: ran.append((role, num_segments)),
+                           pv_t[0, 0])
+        return real(bins_t, stats_t, pv_t, num_segments, *args, **kwargs)
+
+    monkeypatch.setattr(histogram_pallas, "hist_partition_fused_pallas", spy)
+
+    def take():
+        jax.effects_barrier()
+        out = list(ran)
+        del ran[:]
+        return out
+
+    return take
+
+
+def _runs(ran):
+    """Consecutive passes of one (role, width) merged: [(role, width, n)]."""
+    out = []
+    for call in ran:
+        if out and out[-1][:2] == call:
+            out[-1] = call + (out[-1][2] + 1,)
+        else:
+            out.append(call + (1,))
+    return out
+
+
+def _certified_spy(monkeypatch, num_leaves):
+    """Whether the table handed to the replay was certified."""
+    seen = []
+    prune = tree_mod._exact_prune
+
+    def spy(P, *args):
+        jax.debug.callback(lambda c: seen.append(bool(c)),
+                           tree_mod._replay_certified(P, num_leaves))
+        return prune(P, *args)
+
+    monkeypatch.setattr(tree_mod, "_exact_prune", spy)
+    return seen
+
+
+def _assert_strict_tree(got, bins, stats, num_leaves, hist_dtype):
+    """The strict (one split a pass) grower's tree over the same kernels'
+    histograms: the same partition of the rows into as many leaves, the
+    same value on every row.  (Not split for split: in a leaf of a few
+    rows two thresholds with no row between them tie exactly, and which
+    one wins is the summation order of the two growers' histograms.)"""
+    from lightgbm_tpu.models.spec import STRICT
+    from lightgbm_tpu.ops.lookup import lookup_values
+
+    def partition(row_leaf):
+        _, first, inverse = np.unique(np.asarray(row_leaf),
+                                      return_index=True, return_inverse=True)
+        return first[inverse]
+
+    fmask = jnp.ones(bins.shape[1], jnp.float32)
+    t_s, rl_s = jax.jit(lambda: grow_tree(
+        bins, stats, fmask, _ctx(), num_leaves, B, -1, wave=STRICT,
+        hist_dtype=hist_dtype, hist_impl="pallas"))()
+    t, rl = got
+    assert int(t_s.num_leaves) == int(t.num_leaves)
+    np.testing.assert_array_equal(partition(rl_s), partition(rl))
+    np.testing.assert_allclose(
+        np.asarray(lookup_values(rl_s, t_s.leaf_value)),
+        np.asarray(lookup_values(rl, t.leaf_value)), rtol=2e-4, atol=2e-6)
+
+
+@pytest.mark.parametrize("hist_dtype", ["bf16", "f32"])
+@pytest.mark.parametrize("num_leaves", [63, 255])
+@pytest.mark.parametrize("table", sorted(_TABLES))
+def test_needed_width_passes_grow_the_same_tree(table, num_leaves,
+                                                hist_dtype, passes,
+                                                monkeypatch):
+    """The tree of the full-width-only schedule, array for array, in as
+    many passes; where the replay was certified, the strict grower's.  On
+    the chain the passes run narrow, wide, narrow: the doubling passes, the
+    growth passes (every candidate is needed) and first certification
+    passes, then at least three certification passes of few needed
+    leaves."""
+    make, caps = _TABLES[table]
+    bins, stats = make()
+    cap = caps and caps[num_leaves]
+    certified = _certified_spy(monkeypatch, num_leaves)
+    narrow = _grow(bins, stats, num_leaves, 16, hist_dtype, cap)
+    ran = passes()
+    wide = _grow(bins, stats, num_leaves, 0, hist_dtype, cap)
+    ran_wide = passes()
+    assert set(ran_wide) == {(HIST_WAVE, TREE_W)}
+    assert len(ran) == len(ran_wide)
+    assert int(narrow[0].num_leaves) == num_leaves
+    _assert_same_tree(narrow, wide)
+    assert certified[0] == certified[1]
+    roles = [r[:2] for r in _runs(ran)]
+    assert roles[:2] == [(HIST_NARROW, 16), (HIST_WAVE, TREE_W)]
+    if table == "deep_chain":
+        assert certified[0]
+        assert roles == [(HIST_NARROW, 16), (HIST_WAVE, TREE_W),
+                         (HIST_NARROW, 16)]
+        assert _runs(ran)[-1][2] >= 3
+    if certified[0]:
+        _assert_strict_tree(narrow, bins, stats, num_leaves, hist_dtype)
+
+
+def test_needed_rises_past_the_narrow_width_mid_tail(passes, monkeypatch):
+    """``needed`` is not monotone: the chain at 63 leaves needs 27, 13, 6,
+    9, 9, 3, 2, 1 leaves in its certification passes, so at narrow width 8
+    the full-width body takes over again mid-tail, and the tree is still
+    the full-width schedule's and the strict grower's."""
+    bins, stats = _chain_table()
+    certified = _certified_spy(monkeypatch, 63)
+    wave = WaveSchedule(TREE_W, "exact", 610, narrow_width=8)
+    fmask = jnp.ones(bins.shape[1], jnp.float32)
+    got = jax.jit(lambda: grow_tree(
+        bins, stats, fmask, _ctx(), 63, B, -1, wave=wave, hist_dtype="bf16",
+        hist_impl="pallas", fuse_partition=True))()
+    ran = _runs(passes())
+    assert [r[:2] for r in ran] == [
+        (HIST_NARROW, 8), (HIST_WAVE, TREE_W), (HIST_NARROW, 8),
+        (HIST_WAVE, TREE_W), (HIST_NARROW, 8)]
+    # 1, 2, 4, 8 leaves; 16, 32 and the needed 27, 13; 6; 9, 9; 3, 2, 1
+    assert [r[2] for r in ran] == [4, 5, 1, 2, 3]
+    _assert_same_tree(got, _grow(bins, stats, 63, 0, "bf16", 610))
+    assert certified == [True, True]
+    _assert_strict_tree(got, bins, stats, 63, "bf16")
+
+
+@pytest.mark.parametrize("table", ["full_waves", "deep_chain"])
+def test_a_tree_never_certified_stops_at_the_full_width_pass_count(
+        table, passes, monkeypatch):
+    """The cap bounds the passes as it bounded them: narrow passes that add
+    16 leaves do not buy an uncertified tree more passes than the
+    full-width schedule's 42 a pass reach the cap in, because the cap is
+    held against the leaves THAT schedule would have.  Exact where every
+    pass is full (16,384 rows, the certificate off: the doubling passes
+    and ``ceil((526 - 32) / 42)`` = 12 more); where passes run short of
+    candidates (the chain at the schedule's own cap, never certified) the
+    schedule's count follows this tree's candidates, not the other's: to
+    within a pass."""
+    if table == "full_waves":
+        bins, stats = _table(n=16384, f=8, seed=8)
+        monkeypatch.setattr(tree_mod, "_replay_certified",
+                            lambda P, num_leaves: jnp.bool_(False))
+    else:
+        bins, stats = _chain_table()
+    narrow = _grow(bins, stats, 255, 16, "bf16")
+    ran = passes()
+    wide = _grow(bins, stats, 255, 0, "bf16")
+    ran_wide = passes()
+    if table == "full_waves":
+        assert len(ran) == len(ran_wide) == 5 + 12
+    else:
+        assert abs(len(ran) - len(ran_wide)) <= 1
+    assert (HIST_NARROW, 16) in ran[6:]
     _assert_same_tree(narrow, wide)
 
 
