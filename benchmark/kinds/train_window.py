@@ -1,6 +1,8 @@
 """Traffic kind ``train_window``: boosting rounds for ``--seconds``.
 
-Set-up makes the rows from the seed, bins them through ``lgb.Dataset``,
+Set-up makes the rows (the configuration's own table where it names a
+``table_seed``, its columns in the seed's order: every seed the same
+trees; else a table drawn from the seed), bins them through ``lgb.Dataset``,
 builds ONE ``Booster`` and drives it through its first ``checked_rounds``
 by the window's own call (which also compiles the round program); the
 window then goes on with that same object.  Every call is
@@ -63,25 +65,32 @@ class Cell:
         self.build()
 
     def make_inputs(self) -> None:
-        """Rows and labels from the seed, binned through ``lgb.Dataset``."""
+        """Rows and labels, binned through ``lgb.Dataset``: the
+        configuration's table in the seed's column order, or, where the
+        configuration names no ``table_seed``, a table of the seed's own."""
         import jax
 
         import lightgbm_tpu as lgb
 
         t0 = time.perf_counter()
+        table_seed = self.config.get("table_seed")
         self.X, self.y = datagen.higgs_like(
-            self.rows, self.features, self.seed)
+            self.rows, self.features,
+            self.seed if table_seed is None else int(table_seed))
+        t_made = time.perf_counter()
+        if table_seed is not None:
+            datagen.reorder_columns(self.X, self.seed)
         t1 = time.perf_counter()
         self.dataset = lgb.Dataset(self.X, label=self.y, free_raw_data=True)
         self.dataset.construct()
         jax.block_until_ready(self.dataset.X_binned)
-        self.counters.update(datagen_s=t1 - t0,
+        self.counters.update(datagen_s=t1 - t0, reorder_s=t1 - t_made,
                              binning_s=time.perf_counter() - t1)
 
     def share_inputs(self, other: "Cell") -> None:
         """Another run on the same rows (``benchmark/readings.py``)."""
         self.X, self.y, self.dataset = other.X, other.y, other.dataset
-        self.counters.update(datagen_s=0.0, binning_s=0.0)
+        self.counters.update(datagen_s=0.0, reorder_s=0.0, binning_s=0.0)
 
     def build(self) -> None:
         """ONE booster, driven through its first rounds by the window's
@@ -135,19 +144,25 @@ class Cell:
     def window(self, seconds: float) -> dict:
         import jax
 
-        calls = 0
+        call_s = []
         with self.meter.measure(), \
                 jax.profiler.TraceAnnotation("bench.window"):
             t0 = time.perf_counter()
             while True:
                 self._call()
-                calls += 1
                 elapsed = time.perf_counter() - t0
+                call_s.append(elapsed)
                 if elapsed >= seconds:
                     break
+        calls = len(call_s)
         rounds = calls * self.rounds_per_call
+        # window_call_s: the window's clock after every call, so that
+        # ``benchmark/spread.py`` can read what a shorter window of the
+        # same run would have measured; its last entry is window_s
         self.counters.update(window_rounds=rounds, window_calls=calls,
-                             window_compiles=self.meter.programs)
+                             window_compiles=self.meter.programs,
+                             window_call_s=call_s,
+                             rounds_per_call=self.rounds_per_call)
         return {
             "window_s": elapsed,
             "attempted": calls,

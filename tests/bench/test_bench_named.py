@@ -194,13 +194,89 @@ def test_named_roofline_by_hand(snapshot):
 
 
 def test_new_metrics_are_listed_for_the_training_cell():
+    """PR 26's nine metrics, found by name: each lists the cell it was
+    made for (later cells and later metrics come and go beside them)."""
     doc = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     listed = {m["name"]: m for m in doc["per_layer"]}
     for name in NEW_METRICS:
-        assert listed[name]["workloads"] == ["higgs-10m5.train"]
+        assert "higgs-10m5.train" in listed[name]["workloads"]
         assert _spec(name)["what"]
-    assert [m["name"] for m in doc["per_layer"]][-len(NEW_METRICS):] == \
-        NEW_METRICS                                    # appended, in order
+
+
+NARROW = ('%lgbtpu_hist_narrow.8 = (f32[28,48,255]{2,1,0:T(8,128)S(1)}, '
+          's32[1,1024]{1,0}) custom-call(s32[28,1024]{1,0} %a), '
+          'custom_call_target="tpu_custom_call", frontend_attributes={}')
+
+
+def narrow_trace():
+    """``named_trace`` with two passes of the narrow role, one in the
+    loop's gap and one after it: busy 8.2 + 0.5 = 8.7 of 10."""
+    ops = [("%while.71 = (f32[8]) while(%tuple), body=%b", 1.0, 9.0),
+           (WAVE, 1.0, 3.0), (NARROW, 3.0, 3.5), (WAVE, 3.5, 5.5),
+           (WAVE, 5.5, 7.5),
+           ("%fusion.245 = f32[8,1024]{1,0} fusion(%c), kind=kLoop",
+            7.5, 8.0),
+           (NARROW, 9.0, 9.5), (ROOT, 9.5, 9.7)]
+    return tr.Trace({"/device:TPU:0": ops}, [("bench.window", 0.0, 10.0)],
+                    chips=1)
+
+
+def test_grower_xla_pct_leaves_out_the_narrow_role():
+    """The XLA stages' share is the busy time outside ALL three kernel
+    roles: a narrow pass moves it only through the busy time."""
+    spec = _spec("grower_xla_pct")
+    ctx = _ctx(narrow_trace(), window_rounds=2)
+    # the while's own time: 8.0 - 6.0 (waves) - 0.5 (the narrow pass it
+    # holds) - 0.5 (fusion) = 1.0, plus the fusion 0.5: 1.5 of 8.7 busy
+    assert trace_named.read(ctx, spec) == pytest.approx(100 * 1.5 / 8.7)
+    old = dict(spec, patterns=spec["patterns"][:2])
+    assert trace_named.read(ctx, old) == pytest.approx(100 * 2.5 / 8.7)
+    assert trace_named.read(ctx, _spec("hist_narrow_busy_pct")) == \
+        pytest.approx(100 * 1.0 / 8.7)
+    assert trace_named.read(ctx, _spec("narrow_calls_per_round")) == 1.0
+    assert trace_named.read(ctx, _spec("wave_passes_per_round")) == 1.5
+    for name in ("wave_passes_per_round", "wave_calls_per_round"):
+        assert "FULL-WIDTH" in _spec(name)["what"]
+
+
+@pytest.mark.parametrize("name, facts", [
+    ("hist_narrow_roofline", {}),
+    # hi/lo: the program's precision is f32, each event one bf16 pass
+    ("hist_narrow_hilo_roofline", {"train.hist_dtype": "f32"}),
+])
+def test_narrow_rooflines_by_hand(name, facts, snapshot):
+    snapshot(dict(SNAPSHOT, facts=dict(
+        FACTS, **{"train.wave_narrow_width": 16}, **facts)))
+    ctx = _ctx(narrow_trace())
+    least = work.least_seconds(work.hist_onehot_call(
+        {"rows": 1024, "features": 28, "bins": 255, "code_bytes": 4,
+         "segments": 16}), PEAKS)[0]
+    # two events of 0.5 s, each credited with 16 segments' work
+    assert named_roofline.read(ctx, _spec(name)) == \
+        pytest.approx(100 * 2 * least / 1.0)
+    # the wave's roofline does not see them, nor they the wave's events
+    if not facts:
+        assert named_roofline.read(ctx, _spec("hist_wave_roofline")) == \
+            named_roofline.read(_ctx(named_trace()),
+                                _spec("hist_wave_roofline"))
+    # no narrow event (a program before PR 32), no such fact, no peaks
+    assert named_roofline.read(_ctx(named_trace()), _spec(name)) is None
+    assert named_roofline.read(_ctx(narrow_trace(), peaks=None),
+                               _spec(name)) is None
+    snapshot(SNAPSHOT)                     # no train.wave_narrow_width
+    assert named_roofline.read(ctx, _spec(name)) is None
+
+
+def test_narrow_rooflines_are_listed_once_each():
+    doc = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    listed = {m["name"]: m for m in doc["per_layer"]}
+    assert listed["hist_narrow_roofline"]["workloads"] == \
+        ["higgs-10m5.train"]
+    assert listed["hist_narrow_hilo_roofline"]["workloads"] == \
+        ["epsilon-400k.train"]
+    for name in ("hist_narrow_roofline", "hist_narrow_hilo_roofline"):
+        assert listed[name]["layer"] == _spec(name)["layer"] == "kernels"
+        assert listed[name]["moves"] == "train_rows_rounds_per_s"
 
 
 def test_tiny_cell_prints_the_host_side_metrics(bench_copy, capsys):

@@ -68,6 +68,50 @@ def test_same_seed_same_inputs():
     assert a[0].dtype == np.float32 and 0.3 < a[1].mean() < 0.7
 
 
+def test_table_is_one_table_in_the_seeds_column_order():
+    """``datagen.table``: the rows and labels of ``table_seed``, the columns
+    in an order drawn from the seed; nothing else of the seed's."""
+    import numpy as np
+
+    from benchmark import datagen
+
+    X0, y0 = datagen.higgs_like(3000, 28, 11)
+    seed = 2**31 + 77
+    X, y = datagen.table(3000, 28, 11, seed)
+    order = np.random.default_rng(seed).permutation(28)
+    assert np.array_equal(X, X0[:, order]) and np.array_equal(y, y0)
+    again = datagen.table(3000, 28, 11, seed)
+    assert np.array_equal(again[0], X) and np.array_equal(again[1], y)
+    other, y_other = datagen.table(3000, 28, 11, seed + 1)
+    assert not np.array_equal(other, X) and np.array_equal(y_other, y0)
+    assert np.array_equal(np.sort(other, axis=1), np.sort(X, axis=1))
+    # more rows than one block of the in-place permutation holds
+    wide, _ = datagen.table(700, 2000, 5, seed)
+    wide0, _ = datagen.higgs_like(700, 2000, 5)
+    assert np.array_equal(
+        wide, wide0[:, np.random.default_rng(seed).permutation(2000)])
+
+
+def test_every_seed_grows_the_tables_trees(bench_copy, capsys):
+    """A configuration that names a ``table_seed`` gives every seed the same
+    work: the same trees on other column numbers, so the reference reads
+    the same losses and the same leaf statistics; without it the seed draws
+    a table of its own."""
+    fixed = bench_copy.add_tiny_cell(
+        "tiny-table", dict(copy.deepcopy(TINY_CONFIG), table_seed=2136000008))
+    free = bench_copy.add_tiny_cell("tiny-free")
+
+    def losses(cell, seed):
+        res, _ = bench_copy.run(capsys, cell, seed=seed)
+        assert res["correct"] is True
+        return res["counters"]["reference_loss"]
+
+    a, b = losses(fixed, 2**31 + 1), losses(fixed, 2**31 + 2)
+    assert a == pytest.approx(b, rel=1e-9)
+    assert losses(free, 2**31 + 1) != pytest.approx(
+        losses(free, 2**31 + 2), rel=1e-4)
+
+
 def test_extension_is_data_only(bench_copy, capsys):
     """A new cell, a new configuration and a new per-layer metric (with a
     reader of its own) are new files plus entries in BENCHMARK.json."""
