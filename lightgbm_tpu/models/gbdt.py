@@ -91,15 +91,13 @@ def _objective_static_key(obj: Objective, p: Params) -> tuple:
     identity), so user fobj objectives get their own cached program instead
     of crashing the rebuild path.
 
-    Group-based objectives (lambdarank) carry per-training packed group
-    tensors that cannot be rebuilt from scalars, so the prepared instance
-    itself IS the key (hashes by identity — one compiled program per
-    training, which is inevitable anyway since the [Q, G] layout is shape-
-    defining).
+    Group-based objectives (lambdarank) add the STATIC part of their
+    packed layout (``obj.layout``: the shapes of the query blocks); the
+    layout's tensors are operands of the round program (``obj.groups``,
+    handed in by every caller), so two trainings on equal shapes share one
+    compiled program and nothing is keyed by identity.
     """
-    if getattr(obj, "needs_group", False):
-        return ("__group_objective__", obj)
-    return (
+    key = (
         obj.name,
         p.sigmoid,
         getattr(obj, "pos_weight", 1.0),
@@ -112,11 +110,12 @@ def _objective_static_key(obj: Objective, p: Params) -> tuple:
         p.extra.get("fobj"),
         p.tweedie_variance_power,
     )
+    if getattr(obj, "needs_group", False):
+        key += (obj.layout,)
+    return key
 
 
 def _rebuild_objective(key: tuple) -> Objective:
-    if key and key[0] == "__group_objective__":
-        return key[1]
     (name, sigmoid, pos_weight, alpha, fair_c, pmd, trunc, norm, num_class,
      fobj, tvp) = (key + (None, 1.5))[:11]
     p = Params(
@@ -131,7 +130,24 @@ def _rebuild_objective(key: tuple) -> Objective:
     obj = create_objective(p)
     if hasattr(obj, "pos_weight"):
         obj.pos_weight = pos_weight
+    if getattr(obj, "needs_group", False):
+        obj.layout = key[11]
     return obj
+
+
+def _grad_hess(obj: Objective, pred, y, w, groups):
+    """The objective's gradients; a group objective also takes its packed
+    layout's tensors (``groups``, an operand of the caller's program)."""
+    if groups is None:
+        return obj.grad_hess(pred, y, w)
+    return obj.grad_hess(pred, y, w, groups)
+
+
+@functools.lru_cache(maxsize=None)
+def _group_grad_fn(obj_key: tuple):
+    """The jitted lambda pass alone, ``(pred, y, w, groups) -> (g, h)``:
+    the replicated pass of the data-parallel learner."""
+    return jax.jit(_rebuild_objective(obj_key).grad_hess)
 
 
 def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
@@ -243,8 +259,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         # is a vmapped batch over the grower (SURVEY.md §7 batching design)
         @jax.jit
         def round_fn_mc(bins, y, w, bag, pred, feature_mask,
-                        hyper: HyperScalars, key):
-            g, h = obj.grad_hess(pred, y, w)          # [n, K]
+                        hyper: HyperScalars, key, groups=None):
+            g, h = _grad_hess(obj, pred, y, w, groups)    # [n, K]
             if is_goss:
                 bag = goss_bag(jax.random.fold_in(key, 0x7FFFFFFF), g, bag, hyper)
 
@@ -265,8 +281,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
         @jax.jit
         def round_fn_goss(bins, y, w, bag, pred, feature_mask,
-                          hyper: HyperScalars, key):
-            g, h = obj.grad_hess(pred, y, w)
+                          hyper: HyperScalars, key, groups=None):
+            g, h = _grad_hess(obj, pred, y, w, groups)
             return _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
                 goss_k, renew_alpha, renew_scale=renew_scale)
@@ -278,12 +294,12 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
         @jax.jit
         def round_fn_linear(bins, y, w, bag, pred, feature_mask,
-                            hyper: HyperScalars, key, xraw):
+                            hyper: HyperScalars, key, xraw, groups=None):
             """linear_tree round: constant-leaf growth on binned codes,
             then every leaf refits a ridge model over its path features on
             the RAW values (tree.fit_linear_leaves) — the Newton constant
             remains the fallback for degenerate leaves."""
-            g, h = obj.grad_hess(pred, y, w)
+            g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
             tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
@@ -299,9 +315,9 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
     @jax.jit
     def round_fn(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
-                 key):
+                 key, groups=None):
         with jax.named_scope("lgbtpu.grad"):
-            g, h = obj.grad_hess(pred, y, w)
+            g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
         tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
@@ -342,7 +358,8 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
 
     @jax.jit
     def multi(bins, y, w, bag0, pred0, hyper: HyperScalars, round_key,
-              bag_key, ff_key, row_mask, num_data, start_iter, bag_frac, ff):
+              bag_key, ff_key, row_mask, num_data, start_iter, bag_frac, ff,
+              groups=None):
         num_features = bins.shape[1]
 
         def body(carry, i):
@@ -365,7 +382,7 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
                 fmask = jnp.ones(num_features, jnp.float32)
             rkey = jax.random.fold_in(round_key, i)
             with jax.named_scope("lgbtpu.grad"):
-                g, h = obj.grad_hess(pred, y, w)
+                g, h = _grad_hess(obj, pred, y, w, groups)
             if goss_k is not None:
                 tree, new_pred = _goss_compact_round(
                     grow, bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
@@ -663,6 +680,9 @@ class Booster:
                     f"objective '{self.obj.name}' requires query group "
                     "information: Dataset(X, label=y, group=sizes)")
             self.obj.set_group(gs, y_host, int(ds.row_mask.shape[0]))
+        # a group objective's packed layout: operands of every program
+        # that takes its gradients (None for the pointwise objectives)
+        self._groups = getattr(self.obj, "groups", None)
         k = self._num_class
         if k > 1:  # every boosting mode (gbdt/goss/rf/dart) supports K>1
             self.init_score_ = np.asarray(
@@ -1197,12 +1217,11 @@ class Booster:
         self._dp_mesh = make_mesh(n_dev)
         ds = self.train_set
         if ranking:
-            # LambdaRank lambdas need whole queries: the [Q, G] pairwise
+            # LambdaRank lambdas need whole queries: the packed pairwise
             # pass runs REPLICATED (cheap next to histogram work) and only
             # the grower is sharded — see make_dp_grow_step.
             self._dp_stats_only = True
             self._dp_bins = shard_rows(self._dp_mesh, ds.X_binned)
-            self._dp_grad_jit = jax.jit(self.obj.grad_hess)
             return
         (self._dp_bins, self._dp_y, self._dp_w, self._pred_train,
          self._bag) = shard_rows(
@@ -1826,7 +1845,8 @@ class Booster:
             from ..parallel.data_parallel import (make_dp_grow_step,
                                                   shard_rows)
 
-            g, h = self._dp_grad_jit(self._pred_train, ds.y, self._w_eff)
+            grad_fn, grad_args = self._group_grad_call()
+            g, h = grad_fn(*grad_args)
             bag = self._bag
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
@@ -1884,13 +1904,14 @@ class Booster:
             if self._linear_k is not None:
                 tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
-                                    self._hyper, round_key, self._xraw)
+                                    self._hyper, round_key, self._xraw,
+                                    self._groups)
             else:
                 bins = (ds.X_binned if active_ids is None
                         else self._screen_view(ds.X_binned, active_ids))
                 tree, new_pred = fn(bins, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
-                                    self._hyper, round_key)
+                                    self._hyper, round_key, self._groups)
         if active_ids is not None:
             # the tree grew in compacted space — gather the winner ids
             # back to GLOBAL features before anything downstream
@@ -1999,6 +2020,16 @@ class Booster:
                     self._forest_cache = None
                 k -= n_rounds
 
+    def _group_grad_call(self):
+        """``(fn, args)``: a group objective's jitted lambda pass ALONE and
+        its operands at the booster's current scores (the replicated pass
+        of the data-parallel learner; the ranking cell's probe times the
+        same pair); ``None`` for an objective without groups."""
+        if self._groups is None:
+            return None
+        return _group_grad_fn(self._obj_key), (
+            self._pred_train, self.train_set.y, self._w_eff, self._groups)
+
     def _fused_segment(self, n_rounds: int):
         """``(fn, args)``: the jitted ``n_rounds``-round program and its
         operands at the booster's current state — what ``update_many``
@@ -2047,6 +2078,10 @@ class Booster:
                      hist_dtype == "f32x"
                      and spec.hist_impl == "pallas") else 1)):
             profiling.note("train." + fact, value)
+        # a group objective's layout: queries, block shapes, document and
+        # pair slots against what LightGBM's loops visit (rank_* facts)
+        for fact, value in getattr(self.obj, "facts", {}).items():
+            profiling.note("train." + fact, value)
         fn = _multi_round_fn(
             self._obj_key, spec, p.boosting == "rf", n_rounds,
             p.bagging_freq if use_bagging else 0,
@@ -2058,7 +2093,7 @@ class Booster:
             jax.random.PRNGKey(p.feature_fraction_seed + p.seed),
             ds.row_mask, jnp.float32(ds.num_data_), jnp.int32(self._iter),
             jnp.float32(p.bagging_fraction),
-            jnp.float32(p.feature_fraction))
+            jnp.float32(p.feature_fraction), self._groups)
 
     def _dart_round(self) -> bool:
         """One DART boosting round (upstream dart.hpp semantics).
@@ -2122,7 +2157,7 @@ class Booster:
                        None, None)
         round_key = jax.random.fold_in(self._key, i)
         tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff, self._bag, pred,
-                            fmask, self._hyper, round_key)
+                            fmask, self._hyper, round_key, self._groups)
 
         if k > 0:
             # upstream Normalize(): on drop rounds the new tree's weight is
@@ -2637,7 +2672,7 @@ class Booster:
         lam = jnp.float32(p.lambda_l2)
         decay = jnp.float32(decay_rate)
         lr = jnp.float32(getattr(self, "_base_lr", p.learning_rate))
-        obj = self.obj
+        obj, groups = self.obj, None
         if getattr(obj, "needs_group", False):
             if group is None:
                 raise ValueError(
@@ -2648,6 +2683,8 @@ class Booster:
             obj.set_group(np.asarray(group, np.int64).reshape(-1),
                           np.asarray(label, np.float32),
                           int(np.asarray(label).reshape(-1).shape[0]))
+            groups = obj.groups
+            obj = _rebuild_objective(_objective_static_key(obj, p))
         elif group is not None:
             raise TypeError("refit got group= for a non-ranking objective")
         depth_cap = self._depth_cap
@@ -2684,13 +2721,13 @@ class Booster:
             return tree._replace(leaf_value=vals), vals[leafs]
 
         @jax.jit
-        def one_tree(tree, pred):
-            g, h = obj.grad_hess(pred, y, w)
+        def one_tree(tree, pred, groups):
+            g, h = _grad_hess(obj, pred, y, w, groups)
             new_tree, delta = renew(tree, leaf_of(tree), g, h)
             return new_tree, pred + lr * delta
 
         @jax.jit
-        def one_round_mc(tree, pred):   # tree fields [K, M]; pred [n, K]
+        def one_round_mc(tree, pred, _groups):   # tree [K, M]; pred [n, K]
             g, h = obj.grad_hess(pred, y, w)            # [n, K]
             leafs = jax.vmap(leaf_of)(tree)             # [K, n]
             new_tree, delta = jax.vmap(renew)(tree, leafs, g.T, h.T)
@@ -2707,7 +2744,7 @@ class Booster:
             step_fn = one_tree
         new_trees = []
         for t in self.trees:
-            nt, pred = step_fn(t, pred)
+            nt, pred = step_fn(t, pred, groups)
             new_trees.append(nt)
         out = _copy.copy(self)
         out.trees = new_trees

@@ -249,7 +249,7 @@ def make_dp_grow_step(mesh: Mesh, spec: GrowSpec,
                       wire_dtype: str = "f32", merge_chunks: int = 4):
     """Data-parallel growth from PRECOMPUTED per-row stats.
 
-    The ranking path: LambdaRank gradients need whole queries (the [Q, G]
+    The ranking path: LambdaRank gradients need whole queries (the packed
     pairwise pass), so they are computed replicated — cheap next to the
     histogram work — and only the grower runs sharded with psum-merged
     histograms (upstream's data-parallel ranking keeps whole queries per
