@@ -1,7 +1,7 @@
 """One-pass mergeable streaming quantile sketch for out-of-core binning.
 
-The in-memory :meth:`BinMapper.fit` needs the whole column resident to run
-``np.unique`` / ``np.quantile``; under out-of-core training (ISSUE 7) the
+The in-memory :meth:`BinMapper.fit` needs the whole column resident to sort
+its sample; under out-of-core training (ISSUE 7) the
 dataset arrives as row blocks and is never materialized.  This module
 builds the SAME BinMapper from a single pass over the blocks via a
 per-feature adaptive sketch with three regimes:

@@ -21,7 +21,9 @@ two give the same bytes.
 from __future__ import annotations
 
 import functools
+import os
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -38,6 +40,14 @@ ROW_PAD_MULTIPLE = 256  # lane-friendly and shard-friendly (divides by 2,4,8 dev
 # the host loop, which costs it under a second (0.1 us a value) where the
 # device's program would first have to be built for its shape.
 CODE_BLOCK_VALUES = 1 << 23
+# Columns of one block of :meth:`BinMapper.fit`'s sample (at most 51 MB of
+# float32, the sample being at most ``sample_cnt`` rows), the rows one task
+# gathers into it (a task's rows by the block's columns stay in cache until
+# they are written out turned), and the threads that gather and sort.  None
+# of the three changes a result.
+EDGE_BLOCK_COLUMNS = 64
+EDGE_GATHER_ROWS = 4096
+EDGE_THREADS = min(8, os.cpu_count() or 1)
 
 
 class FeatureBundler:
@@ -188,55 +198,81 @@ class FeatureBundler:
         return FeatureBundler(groups, n_bins, default_bins)
 
 
-def _weighted_quantile(distinct: np.ndarray, counts: np.ndarray,
-                       qs: np.ndarray) -> np.ndarray:
-    """``np.quantile(expanded, qs, method="linear")`` on weighted distinct
-    values WITHOUT expanding them.
+def _linear_quantile(n: int, qs: np.ndarray, value_at) -> np.ndarray:
+    """``np.quantile(v, qs, method="linear")`` of ``n`` values, given
+    ``value_at(positions)`` of their ascending order.
 
     Replicates numpy's linear interpolation bit-for-bit (virtual index
     ``h = q*(n-1)``, and numpy's ``_lerp`` computes ``b - (b-a)*(1-t)``
     when ``t >= 0.5`` instead of ``a + (b-a)*t`` — the branch matters for
-    bitwise parity), so the streaming sketch's bounded-distinct path
-    yields the SAME bounds the in-memory fit would have produced from the
-    expanded sample (tests/test_sketch.py pins this against np.quantile).
+    bitwise parity).
     """
-    n = int(counts.sum())
-    cum = np.cumsum(counts)                 # value i ends at position cum[i]-1
     h = np.asarray(qs, np.float64) * (n - 1)
     lo = np.floor(h).astype(np.int64)
     gamma = h - lo
     hi = np.minimum(lo + 1, n - 1)
-    v_lo = distinct[np.searchsorted(cum, lo, side="right")]
-    v_hi = distinct[np.searchsorted(cum, hi, side="right")]
+    v_lo = value_at(lo)
+    v_hi = value_at(hi)
     d = v_hi - v_lo
     return np.where(gamma >= 0.5, v_hi - d * (1.0 - gamma),
                     v_lo + d * gamma)
 
 
+def _weighted_quantile(distinct: np.ndarray, counts: np.ndarray,
+                       qs: np.ndarray) -> np.ndarray:
+    """:func:`_linear_quantile` on weighted distinct values WITHOUT
+    expanding them, so the streaming sketch's bounded-distinct path yields
+    the SAME bounds the in-memory fit would have produced from the expanded
+    sample (tests/test_sketch.py pins this against np.quantile).
+    """
+    cum = np.cumsum(counts)                 # value i ends at position cum[i]-1
+    return _linear_quantile(
+        int(counts.sum()), qs,
+        lambda at: distinct[np.searchsorted(cum, at, side="right")])
+
+
 def numeric_bin_bounds(budget: int, min_data_in_bin: int,
                        vals: Optional[np.ndarray] = None,
                        distinct: Optional[np.ndarray] = None,
-                       counts: Optional[np.ndarray] = None) -> np.ndarray:
+                       counts: Optional[np.ndarray] = None,
+                       sorted_vals: Optional[np.ndarray] = None
+                       ) -> np.ndarray:
     """Numeric-feature bound finder shared by :meth:`BinMapper.fit` and the
     streaming sketch builder (``data.sketch``).
 
-    Given either the raw finite sample ``vals`` or its ``(distinct,
+    Given the finite sample in ascending order (``sorted_vals``, float32 or
+    float64), in any order (``vals``: sorted here) or as its ``(distinct,
     counts)`` summary, honors ``min_data_in_bin`` (budget cap + greedy
-    sparse-bin merge) exactly as the historical in-memory fit did; the
-    quantile path uses ``np.quantile`` when ``vals`` is available and the
-    bit-equivalent :func:`_weighted_quantile` otherwise, so the streaming
-    builder is bit-compatible with the in-memory fit whenever both see the
-    same sample.
+    sparse-bin merge) exactly as the historical in-memory fit did.  The
+    distinct values are where neighbours of the sorted sample differ, and
+    the quantiles are read off it by :func:`_linear_quantile` (off the
+    summary by :func:`_weighted_quantile`), which is ``np.quantile``'s
+    arithmetic: the streaming builder is bit-compatible with the in-memory
+    fit whenever both see the same sample.
     """
-    if distinct is None:
-        distinct, counts = np.unique(vals, return_counts=True)
-    n_vals = int(counts.sum())
+    if vals is not None:
+        sorted_vals = np.sort(vals)
+    if sorted_vals is not None:
+        n_vals = len(sorted_vals)
+        # a distinct value starts where a neighbour differs: what
+        # np.unique(..., return_counts=True) does after its sort
+        starts = np.empty(n_vals, bool)
+        starts[:1] = True
+        np.not_equal(sorted_vals[1:], sorted_vals[:-1], out=starts[1:])
+        n_distinct = np.count_nonzero(starts)
+    else:
+        n_vals = int(counts.sum())
+        n_distinct = len(distinct)
     if n_vals == 0:
         return np.zeros(0)
     budget_eff = budget
     if min_data_in_bin > 1:
         budget_eff = max(1, min(budget, n_vals // min_data_in_bin))
-    if len(distinct) <= budget_eff:
+    if n_distinct <= budget_eff:
+        if sorted_vals is not None:
+            starts = np.flatnonzero(starts)
+            distinct = sorted_vals[starts].astype(np.float64)   # exact
+            counts = np.diff(starts, append=n_vals)
         mids = (distinct[:-1] + distinct[1:]) / 2.0
         if min_data_in_bin > 1 and len(distinct) > 1:
             # greedily merge adjacent sparse distinct values until each
@@ -253,10 +289,12 @@ def numeric_bin_bounds(budget: int, min_data_in_bin: int,
             ub = mids
     else:
         qs = np.linspace(0.0, 1.0, budget_eff + 1)[1:-1]
-        if vals is not None:
-            ub = np.unique(np.quantile(vals, qs, method="linear"))
+        if sorted_vals is not None:
+            ub = _linear_quantile(
+                n_vals, qs, lambda at: sorted_vals[at].astype(np.float64))
         else:
-            ub = np.unique(_weighted_quantile(distinct, counts, qs))
+            ub = _weighted_quantile(distinct, counts, qs)
+        ub = np.unique(ub)
         # drop near-duplicate bounds
         if len(ub) > 1:
             ub = ub[np.concatenate(([True], np.diff(ub) > 0))]
@@ -291,6 +329,8 @@ class BinMapper:
             else np.zeros(self.num_features, dtype=bool)
         )
         self.bundler: Optional[FeatureBundler] = None  # EFB (attach post-fit)
+        # what :meth:`fit` did (the fields of the span ``lgbtpu.dataset.edges``)
+        self.fit_counts: Dict[str, int] = {}
 
     @property
     def max_num_bins(self) -> int:
@@ -312,49 +352,85 @@ class BinMapper:
         Mirrors LightGBM's GreedyFindBin behavior loosely: distinct values get
         their own bins when few; otherwise equal-frequency quantile bins;
         a dedicated NaN bin is appended when the feature has missing values.
+
+        The sample is taken ``EDGE_BLOCK_COLUMNS`` columns at a time, laid
+        out so that each column's values are contiguous, and each column is
+        sorted ONCE, in the table's own dtype; its NaN count, its distinct
+        values and its quantiles are all read off the sorted array.  The
+        gather and the sorts release the GIL and run on a small pool; what
+        comes out depends on neither the pool nor the block.
         """
         n, num_features = X.shape
         rng = np.random.default_rng(seed)
         if n > sample_cnt:
-            idx = rng.choice(n, size=sample_cnt, replace=False)
+            # a column is sorted, so its sample may be read in the table's
+            # row order, which walks the memory forward
+            idx = np.sort(rng.choice(n, size=sample_cnt, replace=False))
         else:
-            idx = slice(None)
-        cat = set(int(c) for c in categorical)
+            idx = np.arange(n)
+        dtype = X.dtype if X.dtype in (np.float32, np.float64) else np.float64
+        is_cat = np.isin(np.arange(num_features),
+                         [int(c) for c in categorical])
         bounds: List[np.ndarray] = []
         nan_bin = np.full(num_features, -1, dtype=np.int32)
         n_bins = np.ones(num_features, dtype=np.int32)
-        is_cat = np.zeros(num_features, dtype=bool)
-        for f in range(num_features):
-            col = np.asarray(X[idx, f], dtype=np.float64)
-            has_nan = bool(np.isnan(col).any())
-            vals = col[~np.isnan(col)]
-            budget = max_bin - (1 if has_nan else 0)
-            if f in cat:
-                # categorical: one bin per kept category value (exact match
-                # at transform time; unseen/rare values share the overflow
-                # bin).  The grower finds gradient-ordered k-vs-rest SUBSET
-                # splits over these bins (ops.split CatInfo path).
-                is_cat[f] = True
-                cats = np.unique(vals)
-                if len(cats) > budget - 1:
-                    uniq, cnts = np.unique(vals, return_counts=True)
-                    cats = np.sort(uniq[np.argsort(-cnts)[: budget - 1]])
-                ub = cats  # stores category VALUES for categorical features
-            elif len(vals) == 0:
-                ub = np.zeros(0)
-            else:
-                # honor min_data_in_bin (LightGBM GreedyFindBin) — shared
-                # with the streaming sketch builder (data.sketch), which
-                # must stay bit-compatible with this in-memory path
-                ub = numeric_bin_bounds(budget, min_data_in_bin, vals=vals)
-            ub = np.asarray(ub, dtype=np.float64)
-            nb = len(ub) + 1
-            if has_nan:
-                nan_bin[f] = nb
-                nb += 1
-            bounds.append(ub)
-            n_bins[f] = nb
-        return BinMapper(bounds, nan_bin, n_bins, is_cat)
+        columns_sorted = 0
+        sample = np.empty((min(EDGE_BLOCK_COLUMNS, num_features), len(idx)),
+                          dtype)
+
+        def gather(block, f0, r0):
+            rows = idx[r0:r0 + EDGE_GATHER_ROWS]
+            block[:, r0:r0 + len(rows)] = X[rows, f0:f0 + len(block)].T
+
+        with ThreadPoolExecutor(EDGE_THREADS) as pool:
+            for f0 in range(0, num_features, EDGE_BLOCK_COLUMNS):
+                block = sample[:min(EDGE_BLOCK_COLUMNS, num_features - f0)]
+                # reading the results raises what a task raised
+                list(pool.map(functools.partial(gather, block, f0),
+                              range(0, len(idx), EDGE_GATHER_ROWS)))
+                list(pool.map(np.ndarray.sort, block))          # NaNs last
+                for f, col in enumerate(block, start=f0):
+                    vals = col[:np.searchsorted(col, np.nan)]
+                    has_nan = len(vals) < len(col)
+                    ub = BinMapper._sorted_column_bounds(
+                        vals, max_bin - (1 if has_nan else 0),
+                        min_data_in_bin, is_cat[f])
+                    n_bins[f] = len(ub) + 1
+                    if has_nan:
+                        nan_bin[f] = n_bins[f]
+                        n_bins[f] += 1
+                    bounds.append(ub)
+                    columns_sorted += len(vals) > 0 and not is_cat[f]
+        mapper = BinMapper(bounds, nan_bin, n_bins, is_cat)
+        mapper.fit_counts = dict(
+            columns_sorted=columns_sorted,
+            columns_other=num_features - columns_sorted,
+            blocks=-(-num_features // EDGE_BLOCK_COLUMNS),
+            sample_rows=len(idx))
+        return mapper
+
+    @staticmethod
+    def _sorted_column_bounds(vals: np.ndarray, budget: int,
+                              min_data_in_bin: int,
+                              categorical: bool) -> np.ndarray:
+        """One column's bounds from its finite sample in ascending order."""
+        if categorical:
+            # categorical: one bin per kept category value (exact match
+            # at transform time; unseen/rare values share the overflow
+            # bin).  The grower finds gradient-ordered k-vs-rest SUBSET
+            # splits over these bins (ops.split CatInfo path).
+            cats = np.unique(vals)
+            if len(cats) > budget - 1:
+                uniq, cnts = np.unique(vals, return_counts=True)
+                cats = np.sort(uniq[np.argsort(-cnts)[: budget - 1]])
+            ub = cats  # stores category VALUES for categorical features
+        else:
+            # honor min_data_in_bin (LightGBM GreedyFindBin) — shared
+            # with the streaming sketch builder (data.sketch), which
+            # must stay bit-compatible with this in-memory path
+            ub = numeric_bin_bounds(budget, min_data_in_bin,
+                                    sorted_vals=vals)
+        return np.asarray(ub, dtype=np.float64)
 
     def transform(self, X: np.ndarray) -> np.ndarray:
         """Map raw features to bin codes uint8[n, F] (bundled columns when
@@ -712,10 +788,12 @@ class Dataset:
             self._reference.construct()
             self.bin_mapper = self._reference.bin_mapper
         if self.bin_mapper is None:
-            with span("lgbtpu.dataset.edges"):
+            profiling.note("dataset.edges_path", "one_sort")
+            with span("lgbtpu.dataset.edges") as fields:
                 self.bin_mapper = BinMapper.fit(
                     X, max_bin=p.max_bin, min_data_in_bin=p.min_data_in_bin,
                     categorical=cat_idx, seed=p.data_random_seed)
+                fields.update(self.bin_mapper.fit_counts)
             if p.enable_bundle:
                 with span("lgbtpu.dataset.bundle"):
                     # whether a bundle forms is read from the leading rows

@@ -287,3 +287,223 @@ def test_code_block_is_sized_in_values():
     assert code_block_rows(28) == 299_520
     assert code_block_rows(700) == 11_776
     assert code_block_rows(1 << 30) == ROW_PAD_MULTIPLE
+
+
+# ------------------------------------------------ edges: one sort a column
+#
+# The ORACLE: BinMapper.fit as it stood until PR 38 (a float64 copy of each
+# column's sample, np.unique for the distinct values, np.quantile for the
+# bounds), kept here as the plain reference.  It shares nothing with the code
+# under test; the fit has to give its bytes.
+
+def _oracle_numeric_bounds(budget, min_data_in_bin, vals):
+    distinct, counts = np.unique(vals, return_counts=True)
+    n_vals = int(counts.sum())
+    budget_eff = budget
+    if min_data_in_bin > 1:
+        budget_eff = max(1, min(budget, n_vals // min_data_in_bin))
+    if len(distinct) <= budget_eff:
+        mids = (distinct[:-1] + distinct[1:]) / 2.0
+        if min_data_in_bin > 1 and len(distinct) > 1:
+            keep, acc = [], 0
+            for i in range(len(distinct) - 1):
+                acc += counts[i]
+                if acc >= min_data_in_bin and \
+                        counts[i + 1:].sum() >= min_data_in_bin:
+                    keep.append(mids[i])
+                    acc = 0
+            ub = np.asarray(keep)
+        else:
+            ub = mids
+    else:
+        qs = np.linspace(0.0, 1.0, budget_eff + 1)[1:-1]
+        ub = np.unique(np.quantile(vals, qs, method="linear"))
+        if len(ub) > 1:
+            ub = ub[np.concatenate(([True], np.diff(ub) > 0))]
+    return np.asarray(ub, dtype=np.float64)
+
+
+def _oracle_fit(X, max_bin=255, min_data_in_bin=3, categorical=(),
+                sample_cnt=200_000, seed=1):
+    n, num_features = X.shape
+    rng = np.random.default_rng(seed)
+    if n > sample_cnt:
+        idx = rng.choice(n, size=sample_cnt, replace=False)
+    else:
+        idx = slice(None)
+    cat = set(int(c) for c in categorical)
+    bounds = []
+    nan_bin = np.full(num_features, -1, dtype=np.int32)
+    n_bins = np.ones(num_features, dtype=np.int32)
+    is_cat = np.zeros(num_features, dtype=bool)
+    for f in range(num_features):
+        col = np.asarray(X[idx, f], dtype=np.float64)
+        has_nan = bool(np.isnan(col).any())
+        vals = col[~np.isnan(col)]
+        budget = max_bin - (1 if has_nan else 0)
+        if f in cat:
+            is_cat[f] = True
+            cats = np.unique(vals)
+            if len(cats) > budget - 1:
+                uniq, cnts = np.unique(vals, return_counts=True)
+                cats = np.sort(uniq[np.argsort(-cnts)[: budget - 1]])
+            ub = cats
+        elif len(vals) == 0:
+            ub = np.zeros(0)
+        else:
+            ub = _oracle_numeric_bounds(budget, min_data_in_bin, vals)
+        ub = np.asarray(ub, dtype=np.float64)
+        nb = len(ub) + 1
+        if has_nan:
+            nan_bin[f] = nb
+            nb += 1
+        bounds.append(ub)
+        n_bins[f] = nb
+    return BinMapper(bounds, nan_bin, n_bins, is_cat)
+
+
+def _with_nan(share):
+    def column(rng, n):
+        col = rng.normal(0, 1, n)
+        col[rng.random(n) < share] = np.nan
+        return col
+    return column
+
+
+def _with_infs(each):
+    def column(rng, n):
+        col = rng.normal(0, 1, n)
+        col[:each], col[each:2 * each] = np.inf, -np.inf
+        return rng.permutation(col)
+    return column
+
+
+def _both_zeros(rng, n):
+    col = rng.integers(-3, 4, n).astype(np.float64)
+    col[(col == 0) & (rng.random(n) < 0.5)] = -0.0
+    assert np.signbit(col[col == 0]).any() and not np.signbit(col[col == 0]).all()
+    return col
+
+
+def _ties_at_a_quantile(rng, n):
+    col = rng.normal(0, 1, n)
+    col[rng.random(n) < 0.4] = 0.25          # 40 % of the rows on one value
+    return col
+
+
+EDGE_COLUMNS = {
+    "normal": lambda rng, n: rng.normal(0, 1, n),
+    "lognormal": lambda rng, n: rng.lognormal(0, 2, n),
+    "integers_12": lambda rng, n: rng.integers(0, 12, n).astype(np.float64),
+    "integers_250": lambda rng, n: rng.integers(0, 250, n).astype(np.float64),
+    "constant": lambda rng, n: np.full(n, 3.5),
+    "all_nan": lambda rng, n: np.full(n, np.nan),
+    "nan_1pct": _with_nan(0.01),
+    "nan_60pct": _with_nan(0.6),
+    "infs_few": _with_infs(3),
+    "infs_many": _with_infs(150),
+    "both_zeros": _both_zeros,
+    "ties_at_a_quantile": _ties_at_a_quantile,
+}
+EDGE_ROWS = 5_000         # two gather tasks: EDGE_GATHER_ROWS and a part
+
+
+def _edge_table(kinds, n=EDGE_ROWS, dtype=F32, seed=0):
+    rng = np.random.default_rng(seed)
+    return np.stack([EDGE_COLUMNS[k](rng, n) for k in kinds],
+                    axis=1).astype(dtype)
+
+
+def _mixed(num_features, **kwargs):
+    kinds = [k for k in EDGE_COLUMNS if k != "both_zeros"]
+    return _edge_table([kinds[f % len(kinds)] for f in range(num_features)],
+                       **kwargs)
+
+
+def _strided(dtype):
+    wide = np.zeros((2 * EDGE_ROWS, 3 * 11), dtype)
+    wide[::2, ::3] = _mixed(11, dtype=dtype)
+    return wide[::2, ::3]
+
+
+def _edge_cases():
+    for kind in EDGE_COLUMNS:
+        yield f"column_{kind}", lambda kind=kind: _edge_table([kind]), {}
+    for min_data in (1, 3, 50):
+        for max_bin in (16, 63, 255):
+            yield (f"min_data_{min_data}_max_bin_{max_bin}",
+                   lambda: _mixed(11),
+                   {"min_data_in_bin": min_data, "max_bin": max_bin})
+    for dtype in (F32, np.float64):
+        name = np.dtype(dtype).name
+        yield f"{name}_c_order", lambda dtype=dtype: _mixed(11, dtype=dtype), {}
+        yield (f"{name}_f_order", lambda dtype=dtype: np.asfortranarray(
+            _mixed(11, dtype=dtype)), {})
+        yield f"{name}_strided_view", lambda dtype=dtype: _strided(dtype), {}
+    yield "int64_table", lambda: np.random.default_rng(0).integers(
+        -40, 400, (EDGE_ROWS, 3)), {}
+    yield "fewer_rows_than_sample_cnt", lambda: _mixed(11), {}
+    yield "more_rows_than_sample_cnt", lambda: _mixed(11), {
+        "sample_cnt": 3_000}
+    yield "no_rows", lambda: np.zeros((0, 3), F32), {}
+    yield ("categorical_beside_numeric",
+           lambda: _edge_table(["normal", "integers_12", "integers_250",
+                                "nan_1pct"]), {"categorical": [1, 2],
+                                               "max_bin": 63})
+    for num_features in (1, 63, 64, 65, 129):   # a block is 64 columns
+        yield (f"columns_{num_features}",
+               lambda num_features=num_features: _mixed(num_features, n=600),
+               {})
+    for threads in (1, 4):
+        yield f"threads_{threads}", lambda: _mixed(70), {"threads": threads}
+
+
+@pytest.mark.parametrize("case,make,kwargs", [
+    pytest.param(*c, id=c[0]) for c in _edge_cases()])
+def test_fit_gives_the_oracles_edges(case, make, kwargs, monkeypatch):
+    """One sort a column gives the edges that np.unique + np.quantile over a
+    float64 copy gave, bit for bit, and so the same codes."""
+    kwargs = dict(kwargs)
+    if "threads" in kwargs:
+        monkeypatch.setattr(dataset_mod, "EDGE_THREADS", kwargs.pop("threads"))
+    X = make()
+    before = X.tobytes()
+    got = BinMapper.fit(X, **kwargs)
+    want = _oracle_fit(X, **kwargs)
+    assert X.tobytes() == before               # sorted in a copy, not in place
+    assert got.num_features == want.num_features == X.shape[1]
+    assert got.nan_bin.tobytes() == want.nan_bin.tobytes()
+    assert got.n_bins.tobytes() == want.n_bins.tobytes()
+    assert got.is_categorical.tolist() == want.is_categorical.tolist()
+    for f, (ub, ref) in enumerate(zip(got.upper_bounds, want.upper_bounds)):
+        assert ub.dtype == ref.dtype == np.float64
+        if case == "column_both_zeros":
+            # which zero a sort puts first is the sort's own, then as now:
+            # equal values, not equal bytes
+            assert np.array_equal(ub, ref), f
+        else:
+            assert ub.tobytes() == ref.tobytes(), (f, ub, ref)
+    assert got._transform_unbundled(X).tobytes() == \
+        want._transform_unbundled(X).tobytes()
+    assert dataset_mod.EDGE_BLOCK_COLUMNS == 64
+    blocks = -(-X.shape[1] // 64)
+    sample_rows = min(len(X), kwargs.get("sample_cnt", 200_000))
+    assert got.fit_counts["blocks"] == blocks
+    assert got.fit_counts["sample_rows"] == sample_rows
+    assert got.fit_counts["columns_sorted"] + \
+        got.fit_counts["columns_other"] == X.shape[1]
+
+
+def test_edges_span_says_what_the_fit_did():
+    n = 900
+    X = _edge_table(["normal", "integers_12", "all_nan", "lognormal",
+                     "nan_60pct", "integers_250"], n=n)
+    profiling.reset()
+    Dataset(X, label=np.zeros(n), categorical_feature=[1]).construct()
+    snap = profiling.snapshot()
+    assert snap["facts"]["dataset.edges_path"] == "one_sort"
+    (edges_span,) = [r for r in snap["ring"]
+                     if r["name"] == "lgbtpu.dataset.edges"]
+    assert edges_span["fields"] == {
+        "columns_sorted": 4, "columns_other": 2, "blocks": 1,
+        "sample_rows": n}

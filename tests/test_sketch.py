@@ -91,6 +91,32 @@ def test_weighted_quantile_matches_numpy_linear():
     assert np.array_equal(got, want)          # bitwise, incl. _lerp branch
 
 
+@pytest.mark.parametrize("case,draw,budget,min_data_in_bin", [
+    ("quantiles", lambda rng: rng.normal(0, 3, 5_000), 63, 3),
+    ("quantiles_under_a_floor", lambda rng: rng.lognormal(0, 2, 5_000),
+     255, 50),
+    ("own_bins", lambda rng: rng.integers(0, 40, 5_000), 63, 1),
+    ("own_bins_merged", lambda rng: rng.integers(0, 250, 5_000), 255, 50),
+    ("float32", lambda rng: rng.normal(0, 3, 5_000).astype(np.float32),
+     63, 3),
+])
+def test_sorted_sample_and_summary_give_the_same_bounds(case, draw, budget,
+                                                        min_data_in_bin):
+    """The three ways into ``numeric_bin_bounds`` are one result: the sorted
+    sample BinMapper.fit hands over, the sample in any order, and the
+    (distinct, counts) summary the streaming sketch keeps."""
+    vals = draw(np.random.default_rng(11))
+    distinct, counts = np.unique(vals.astype(np.float64), return_counts=True)
+    from_sorted = numeric_bin_bounds(budget, min_data_in_bin,
+                                     sorted_vals=np.sort(vals))
+    from_summary = numeric_bin_bounds(budget, min_data_in_bin,
+                                      distinct=distinct, counts=counts)
+    from_vals = numeric_bin_bounds(budget, min_data_in_bin, vals=vals)
+    assert len(from_sorted) > 1
+    assert from_sorted.tobytes() == from_summary.tobytes()
+    assert from_sorted.tobytes() == from_vals.tobytes()
+
+
 # ------------------------------------------------------------------- GK path
 
 def _gk_rank_errors(summary, vals, qs):
