@@ -189,7 +189,7 @@ def _goss_grow_fn(spec: GrowSpec):
 
     @jax.jit
     def fn(bins_c, stats, fmask, ctx, max_depth, key):
-        return grow(bins_c, stats, fmask, ctx, max_depth, None, key)
+        return grow(bins_c, stats, fmask, ctx, max_depth, None, key)[:2]
 
     return fn
 

@@ -106,7 +106,7 @@ def _fused_cv_fn(obj_key: tuple, spec: GrowSpec,
         def grow_one(gc, hc, kc):
             stats = jnp.stack([gc * bag, hc * bag, bag], axis=-1)
             return grow(bins, stats, fmask, hyper.ctx(), hyper.max_depth,
-                        hyper.feature_fraction_bynode, kc)
+                        hyper.feature_fraction_bynode, kc)[:2]
 
         if num_class > 1:
             from .gbdt import mc_round_update

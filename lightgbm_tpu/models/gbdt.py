@@ -200,8 +200,9 @@ def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
     wt = wt * live
     bins_c = jnp.take(bins, idx, axis=0)
     stats = jnp.stack([g[idx] * wt, h[idx] * wt, live], axis=-1)
-    tree, rl_c = grow(bins_c, stats, fmask, hyper.ctx(), hyper.max_depth,
-                      hyper.feature_fraction_bynode, key)
+    tree, rl_c, passes = grow(bins_c, stats, fmask, hyper.ctx(),
+                              hyper.max_depth, hyper.feature_fraction_bynode,
+                              key)
     if renew_alpha is not None:
         rw = w[idx] * wt
         if renew_scale is not None:
@@ -214,7 +215,7 @@ def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
     # optimistic static bound is unsound under stalled waves
     new_pred = pred + hyper.learning_rate * predict_tree_binned(
         tree, bins, None)
-    return tree, new_pred
+    return tree, new_pred, passes
 
 
 
@@ -269,7 +270,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
                                    (bag > 0).astype(jnp.float32)], axis=-1)
                 return grow(bins, stats, feature_mask, hyper.ctx(),
                             hyper.max_depth, hyper.feature_fraction_bynode,
-                            kc)
+                            kc)[:2]
 
             return mc_round_update(grow_one, g, h,
                                    jax.random.split(key, num_class), pred,
@@ -285,7 +286,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
             g, h = _grad_hess(obj, pred, y, w, groups)
             return _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
-                goss_k, renew_alpha, renew_scale=renew_scale)
+                goss_k, renew_alpha, renew_scale=renew_scale)[:2]
 
         return round_fn_goss
 
@@ -302,9 +303,9 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
             g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
-            tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
-                                  hyper.max_depth,
-                                  hyper.feature_fraction_bynode, key)
+            tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
+                                     hyper.max_depth,
+                                     hyper.feature_fraction_bynode, key)
             tree, delta = fit_linear_leaves(
                 tree, row_leaf, xraw, g, h, bag, hyper.linear_lambda,
                 linear_k, spec.row_chunk)
@@ -320,9 +321,9 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
             g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
-        tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
-                              hyper.max_depth, hyper.feature_fraction_bynode,
-                              key)
+        tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
+                                 hyper.max_depth,
+                                 hyper.feature_fraction_bynode, key)
         if renew_alpha is not None:
             rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
             tree = renew_leaf_values(tree, row_leaf, y - pred, rw,
@@ -347,7 +348,9 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
     bench spends 30 strict histogram trips of microseconds each per
     round).  Scanning rounds on device
     removes that entirely; trees come back stacked with a leading
-    [n_rounds] axis.  RNG streams match the host loop exactly (same
+    [n_rounds] axis, and so do the rounds' pass logs (``passes``: f32
+    ``[n_rounds, 5, grow_leaves - 1]``, models.tree._PASS), which are not
+    part of the model.  RNG streams match the host loop exactly (same
     fold_in(key, round_index) chain), so fused and host training produce
     identical models.
     """
@@ -384,17 +387,17 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
             with jax.named_scope("lgbtpu.grad"):
                 g, h = _grad_hess(obj, pred, y, w, groups)
             if goss_k is not None:
-                tree, new_pred = _goss_compact_round(
+                tree, new_pred, passes = _goss_compact_round(
                     grow, bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
                     goss_k, renew_alpha, renew_scale=renew_scale)
-                return (new_pred, bag), tree
+                return (new_pred, bag), (tree, passes)
             with jax.named_scope("lgbtpu.grad"):
                 stats = jnp.stack(
                     [g * bag, h * bag, (bag > 0).astype(jnp.float32)],
                     axis=-1)
-            tree, row_leaf = grow(bins, stats, fmask, hyper.ctx(),
-                                  hyper.max_depth,
-                                  hyper.feature_fraction_bynode, rkey)
+            tree, row_leaf, passes = grow(bins, stats, fmask, hyper.ctx(),
+                                          hyper.max_depth,
+                                          hyper.feature_fraction_bynode, rkey)
             if renew_alpha is not None:
                 rw = (w * bag if renew_scale is None
                       else w * bag * renew_scale(y))
@@ -406,11 +409,11 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
                 with jax.named_scope("lgbtpu.pred_update"):
                     new_pred = pred + hyper.learning_rate * \
                         lookup_values(row_leaf, tree.leaf_value)
-            return (new_pred, bag), tree
+            return (new_pred, bag), (tree, passes)
 
-        (pred, bag), trees = lax.scan(
+        (pred, bag), (trees, passes) = lax.scan(
             body, (pred0, bag0), start_iter + jnp.arange(n_rounds))
-        return pred, bag, trees
+        return pred, bag, trees, passes
 
     return multi
 
@@ -2008,8 +2011,10 @@ class Booster:
                 with profiling.span("lgbtpu.train.segment"):
                     fn, args = self._fused_segment(n_rounds)
                 with profiling.span("lgbtpu.train.dispatch"):
-                    pred, bag, trees = fn(*args)
+                    pred, bag, trees, passes = fn(*args)
                 with profiling.span("lgbtpu.train.commit"):
+                    # kept unread: the pass log is fetched when asked for
+                    profiling.defer("train.passes", passes)
                     self._pred_train = pred
                     self._bag = bag
                     if not isinstance(self.trees, _TreeStore):

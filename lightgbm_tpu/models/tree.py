@@ -135,6 +135,29 @@ class _PK:
     NC = 24           # (set at creation; drives exact-tail selection)
 
 
+class _PASS:
+    """Columns of the wave grower's pass log, ``f32[NC, grow_leaves - 1]``:
+    one column per wave pass (passes on the minor axis: a 5-wide minor
+    axis is stored 128 lanes wide on the chip), the root pass not logged;
+    columns of passes that did not run stay zero, so ``SPLITS > 0`` marks
+    those that did.  Counts are in-bag rows, from the count channel of
+    each split parent's chosen candidate.  The loops carry it flat, pass
+    after pass, and write a pass as one 5-element slice: the compiled
+    round's temporaries grow least that way (v5e, 10.5M x 28: +1.0 MB, for
+    +1.5 MB with the ``[5, passes]`` carry and a column write)."""
+
+    ROLE = 0          # 0: narrow (HIST_NARROW), 1: full width (HIST_WAVE)
+    SPLITS = 1        # splits the pass made
+    STREAMED = 2      # rows the pass's kernel reads
+    PARENTS = 3       # rows of the leaves it split: what the partition routes
+    DIRECT = 4        # rows of their smaller children: what it histograms
+    NC = 5
+
+
+def _empty_pass_log(grow_leaves: int) -> jnp.ndarray:
+    return jnp.zeros((_PASS.NC, grow_leaves - 1), jnp.float32)
+
+
 class _GrowState(NamedTuple):
     nodes: jnp.ndarray          # f32[M, _PK.NC] packed per-node table
     row_leaf: jnp.ndarray       # i32[n]
@@ -561,13 +584,15 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
     """The ONE place a :class:`GrowSpec` is mapped onto :func:`grow_tree`.
 
     Returns ``grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode,
-    key) -> (Tree, row_leaf)``.  ``placement`` is what the learner, not the
-    spec, decides and goes to ``grow_tree`` as it is: ``axis_name``,
-    ``fp_axis``, ``fuse_partition`` and the merge settings.  Call it where
-    the round program is BUILT: the per-column constraint arrays become
-    constants the traced bodies close over.  ``cat_info_for(num_features)``
-    replaces the CatInfo built from ``spec.cat_key`` (the feature-sharded
-    learner slices its own).
+    key) -> (Tree, row_leaf, passes)``, ``passes`` the wave grower's pass
+    log (:class:`_PASS`; all zero from the strict grower): the fused round
+    program keeps it, every other caller drops it.  ``placement`` is what
+    the learner, not the spec, decides and goes to ``grow_tree`` as it is:
+    ``axis_name``, ``fp_axis``, ``fuse_partition`` and the merge settings.
+    Call it where the round program is BUILT: the per-column constraint
+    arrays become constants the traced bodies close over.
+    ``cat_info_for(num_features)`` replaces the CatInfo built from
+    ``spec.cat_key`` (the feature-sharded learner slices its own).
     """
     mono = (None if spec.mono_key is None
             else jnp.asarray(spec.mono_key, jnp.int32))
@@ -579,7 +604,7 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
         cat_info_for = functools.partial(build_cat_info, spec.cat_key)
 
     def grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode, key):
-        return grow_tree(
+        return grow_tree_logged(
             bins, stats, feature_mask, ctx, spec.num_leaves, spec.num_bins,
             max_depth, ff_bynode=None if spec.bynode_off else ff_bynode,
             key=key, hist_impl=spec.hist_impl, row_chunk=spec.row_chunk,
@@ -591,7 +616,12 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
     return grow
 
 
-def grow_tree(
+def grow_tree(*args, **kwargs) -> Tuple[Tree, jnp.ndarray]:
+    """:func:`grow_tree_logged` without the pass log: ``(Tree, row_leaf)``."""
+    return grow_tree_logged(*args, **kwargs)[:2]
+
+
+def grow_tree_logged(
     bins: jnp.ndarray,
     stats: jnp.ndarray,
     feature_mask: jnp.ndarray,
@@ -619,7 +649,7 @@ def grow_tree(
     voting_k: int = 0,
     hist_wire: str = "f32",
     merge_chunks: int = 4,
-) -> Tuple[Tree, jnp.ndarray]:
+) -> Tuple[Tree, jnp.ndarray, jnp.ndarray]:
     """Grow one best-first tree.
 
     Args:
@@ -676,8 +706,10 @@ def grow_tree(
         f32 keeps the exactness bar, bf16/int8 are quality-gated.
 
     Returns:
-      (Tree, row_leaf) — row_leaf gives each training row's final leaf node id
-      so the boosting loop can update train predictions with one gather.
+      (Tree, row_leaf, passes) — row_leaf gives each training row's final
+      leaf node id so the boosting loop can update train predictions with
+      one gather; ``passes`` is the wave grower's pass log (:class:`_PASS`),
+      all zero from the strict grower.
 
     A ``wave`` of width > 1 dispatches to :func:`grow_tree_frontier`
     (multiple splits per histogram pass via the subtraction trick — the
@@ -895,7 +927,8 @@ def grow_tree(
         P_f, row_leaf_f, _, n_leaves_f, _ = lax.fori_loop(
             0, num_leaves - 1, body_f,
             (st.nodes, st.row_leaf, st.n_nodes, st.n_leaves, aux0))
-        return (_tree_from_packed(P_f, n_leaves_f, None, None), row_leaf_f)
+        return (_tree_from_packed(P_f, n_leaves_f, None, None), row_leaf_f,
+                _empty_pass_log(num_leaves))
 
     def body(_, st: _GrowState) -> _GrowState:
         P = st.nodes
@@ -1034,7 +1067,7 @@ def grow_tree(
     st = lax.fori_loop(0, num_leaves - 1, body, st)
     tree = _tree_from_packed(st.nodes, st.n_leaves, cat_info,
                              st.cand_catmask)
-    return tree, st.row_leaf
+    return tree, st.row_leaf, _empty_pass_log(num_leaves)
 
 
 def _scatter(arr, idx, val, active):
@@ -1254,6 +1287,9 @@ class _WaveState(NamedTuple):
     row_leaf: jnp.ndarray
     n_nodes: jnp.ndarray
     n_leaves: jnp.ndarray
+    passes: jnp.ndarray         # f32[(grow_leaves - 1) * _PASS.NC] pass
+                                #   log, flat, pass after pass
+    n_passes: jnp.ndarray       # i32[] passes logged
     # categorical candidate split masks (None when the dataset has none)
     cand_catmask: Optional[jnp.ndarray] = None  # bool[M, B]
     # interaction constraints: surviving group set per node (None = off)
@@ -1287,7 +1323,7 @@ def grow_tree_frontier(
     voting_k: int = 0,
     hist_wire: str = "f32",
     merge_chunks: int = 4,
-) -> Tuple[Tree, jnp.ndarray]:
+) -> Tuple[Tree, jnp.ndarray, jnp.ndarray]:
     """Best-first growth in WAVES: up to ``wave.width`` splits per data pass.
 
     The strict grower (:func:`grow_tree`) re-scans all rows once per split —
@@ -1328,6 +1364,10 @@ def grow_tree_frontier(
     order; a certified tree costs the larger of greedy's pass count and
     the strict tree's depth — PERF.md PR 29; PERF_HISTORY.md r4 gap
     decomposition).
+
+    Returns ``(Tree, row_leaf, passes)``: ``passes`` logs every wave pass
+    after the root's (:class:`_PASS`), so that the rows a pass streams can
+    be set against the rows its splits needed.
     """
     n, num_features = bins.shape
     exact = wave.tail == "exact"
@@ -1534,6 +1574,8 @@ def grow_tree_frontier(
         row_leaf=jnp.zeros(n, jnp.int32),
         n_nodes=jnp.int32(1),
         n_leaves=jnp.int32(1),
+        passes=_empty_pass_log(grow_leaves).reshape(-1),
+        n_passes=jnp.int32(0),
         cand_catmask=(None if cat_info is None else
                       jnp.zeros((capacity, num_bins), jnp.bool_)
                       .at[0].set(root_best.cat_mask)),
@@ -1543,6 +1585,8 @@ def grow_tree_frontier(
     )
 
     bins_i32 = bins.astype(jnp.int32)
+    # the rows a pass's kernel reads: every row, padding included
+    streamed_rows = n_pad_rows if fuse_part else n
 
     def wave_body(width: int, role: str):
         return functools.partial(body, width=width, role=role,
@@ -1885,6 +1929,17 @@ def grow_tree_frontier(
             kid_idx = jnp.where(active_2, child_nodes, oob)
             P2 = P2.at[kid_idx].set(child_rows, mode="drop")
 
+            # 8. the pass log: this pass's column (_PASS)
+            lc_w, rc_w = prow[:, K.CAND_LC], prow[:, K.CAND_RC]
+            logged = jnp.stack([
+                jnp.float32(role != HIST_NARROW), s.astype(f32),
+                jnp.float32(streamed_rows),
+                jnp.sum(jnp.where(active_r, lc_w + rc_w, 0.0)),
+                jnp.sum(jnp.where(active_r,
+                                  jnp.where(direct_left, lc_w, rc_w), 0.0))])
+            passes = lax.dynamic_update_slice(
+                st.passes, logged, (_PASS.NC * st.n_passes,))
+
         return st._replace(
             nodes=P2,
             hist_cache=cache,
@@ -1892,6 +1947,8 @@ def grow_tree_frontier(
             row_leaf=row_leaf,
             n_nodes=st.n_nodes + 2 * s,
             n_leaves=st.n_leaves + s,
+            passes=passes,
+            n_passes=st.n_passes + 1,
             cand_catmask=(None if cat_info is None else
                           st.cand_catmask.at[kid_idx].set(
                               bs.cat_mask, mode="drop")),
@@ -1973,10 +2030,10 @@ def grow_tree_frontier(
             newP, new_cat, row_leaf_new, n_leaves_f = _exact_prune(
                 st.nodes, st.cand_catmask, st.row_leaf, num_leaves, cat_info)
         return (_tree_from_packed(newP, n_leaves_f, cat_info, new_cat),
-                row_leaf_new)
+                row_leaf_new, st.passes.reshape(-1, _PASS.NC).T)
     tree = _tree_from_packed(st.nodes, st.n_leaves, cat_info,
                              st.cand_catmask)
-    return tree, st.row_leaf
+    return tree, st.row_leaf, st.passes.reshape(-1, _PASS.NC).T
 
 
 # ---------------------------------------------------------------------------

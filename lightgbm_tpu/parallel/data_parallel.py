@@ -155,7 +155,8 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
             stats = jnp.stack([gc * bag, hc * bag,
                                (bag > 0).astype(jnp.float32)], axis=-1)
             return grow(bins, stats, feature_mask, hyper.ctx(),
-                        hyper.max_depth, hyper.feature_fraction_bynode, kc)
+                        hyper.max_depth, hyper.feature_fraction_bynode,
+                        kc)[:2]
 
         from ..models.gbdt import mc_round_update
         return mc_round_update(grow_one, g, h,
@@ -174,14 +175,14 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
             # shard and the "replicated" tree would silently diverge
             sample_key = jax.random.fold_in(
                 key, lax.axis_index(DATA_AXIS))
-            tree, new_pred = _goss_compact_round(
+            tree, new_pred, _ = _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key,
                 g, h, goss_k_shard, None, sample_key=sample_key)
             return tree, new_pred
         stats = jnp.stack([g * bag, h * bag, bag], axis=-1)
-        tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
-                              hyper.max_depth,
-                              hyper.feature_fraction_bynode, key)
+        tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
+                                 hyper.max_depth,
+                                 hyper.feature_fraction_bynode, key)
         shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
         new_pred = pred + shrink * lookup_values(row_leaf, tree.leaf_value)
         return tree, new_pred
@@ -223,9 +224,9 @@ def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
              hyper: HyperScalars, key):
         g, h = obj.grad_hess(pred, y, w)
         stats = jnp.stack([g * bag, h * bag, bag], axis=-1)
-        tree, row_leaf = grow(bins, stats, feature_mask, hyper.ctx(),
-                              hyper.max_depth,
-                              hyper.feature_fraction_bynode, key)
+        tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
+                                 hyper.max_depth,
+                                 hyper.feature_fraction_bynode, key)
         tree, delta = fit_linear_leaves(
             tree, row_leaf, xraw, g, h, bag, hyper.linear_lambda,
             linear_k, spec.row_chunk, axis_name=DATA_AXIS)
@@ -265,7 +266,7 @@ def make_dp_grow_step(mesh: Mesh, spec: GrowSpec,
 
     def step(bins, stats, feature_mask, hyper: HyperScalars, key):
         return grow(bins, stats, feature_mask, hyper.ctx(), hyper.max_depth,
-                    hyper.feature_fraction_bynode, key)
+                    hyper.feature_fraction_bynode, key)[:2]
 
     sharded = shard_map(
         step,

@@ -164,7 +164,7 @@ def make_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
             # the Booster gate guarantees bynode == 1.0 on the fp path (it
             # would sample per SHARD): no per-node threefry draw
             return grow(bins_l, stats, fmask_l, hyper.ctx(),
-                        hyper.max_depth, None, kc)
+                        hyper.max_depth, None, kc)[:2]
 
         if num_class > 1:
             from ..models.gbdt import mc_round_update
@@ -232,8 +232,8 @@ def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
         stats = jnp.stack([g * bag_l, h * bag_l,
                            (bag_l > 0).astype(jnp.float32)], axis=-1)
         # (Booster._dp2_shape admits only bynode == 1.0: no per-node draw)
-        tree, row_leaf = grow(bins_b, stats, fmask_l, hyper.ctx(),
-                              hyper.max_depth, None, key)
+        tree, row_leaf, _ = grow(bins_b, stats, fmask_l, hyper.ctx(),
+                                 hyper.max_depth, None, key)
         shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
         new_pred = pred_l + shrink * lookup_values(row_leaf, tree.leaf_value)
         return tree, new_pred

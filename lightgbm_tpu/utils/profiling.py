@@ -10,8 +10,14 @@ and ``builds``: the seconds JAX spent tracing, lowering and compiling (or
 reading the persistent cache) while the span was the thread's innermost,
 which says which step recompiled.  ``@span(name)`` on a function opens one
 per call.  ``note(name, value)`` keeps a fact the
-program decided, ``add(name, n)`` a count; ``snapshot()`` is all of it as
-a plain dict, ``reset()`` forgets it.
+program decided, ``add(name, n)`` a count; ``defer(name, array)`` keeps a
+device array the program computed, unread: a counter the device keeps
+(the round program's pass log, ``train.passes``), read only when an
+operator or the benchmark asks, never on the dispatch path.  Per name the
+newest arrays are kept until they hold ``DEFER_ROWS`` rows of their leading
+axis (rounds), so that the ring pins a few MB of device memory at most.
+``snapshot()`` is all of it as a plain dict, the deferred arrays fetched
+there in one ``jax.device_get``; ``reset()`` forgets it.
 
 Always on: it has to see set-up, which no profiler session covers.  With
 no session a span costs two clock reads, one locked dict update and an
@@ -31,6 +37,7 @@ import time
 import jax
 
 RING_SPANS = 4096
+DEFER_ROWS = 128
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _BUILD_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -52,6 +59,7 @@ class Recorder:
             self._ring = collections.deque(maxlen=self._ring_size)
             self._spans, self._facts = {}, {}
             self._counts = collections.Counter()
+            self._deferred = {}
 
     def _stack(self) -> list:
         stack = getattr(self._local, "stack", None)
@@ -124,16 +132,34 @@ class Recorder:
         with self._lock:
             self._counts[name] += n
 
+    def defer(self, name: str, array) -> None:
+        """Keep ``array`` without reading it: no sync, no copy.  The oldest
+        arrays of ``name`` go once the newer ones hold ``DEFER_ROWS`` rows
+        of the leading axis; the newest is always kept."""
+        rows = array.shape[0] if array.ndim else 1
+        with self._lock:
+            ring = self._deferred.setdefault(name, collections.deque())
+            ring.append((rows, array))
+            held = sum(r for r, _ in ring)
+            while len(ring) > 1 and held - ring[0][0] >= DEFER_ROWS:
+                held -= ring.popleft()[0]
+
     def snapshot(self) -> dict:
         with self._lock:
-            return {
+            snap = {
                 "spans": {k: dict(v) for k, v in self._spans.items()},
                 "facts": dict(self._facts),
                 "counts": dict(self._counts),
                 "ring": [dict(zip(("id", "parent", "name", "start", "end",
                                    "fields"), r)) for r in self._ring]}
+            deferred = {k: [a for _, a in ring]
+                        for k, ring in self._deferred.items()}
+        # outside the lock: the fetch waits for the device
+        snap["arrays"] = jax.device_get(deferred)
+        return snap
 
 
 _process = Recorder()
 span, note, add = _process.span, _process.note, _process.add
+defer = _process.defer
 snapshot, reset = _process.snapshot, _process.reset

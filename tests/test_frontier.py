@@ -49,7 +49,7 @@ def test_wave1_matches_strict_structure():
     fmask = jnp.ones(bins.shape[1], jnp.float32)
     t_strict, rl_strict = grow_tree(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 15, 32, -1)
-    t_wave, rl_wave = grow_tree_frontier(
+    t_wave, rl_wave, _ = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 15, 32, -1,
         wave=WaveSchedule(1, "half"))
     assert int(t_wave.num_leaves) == int(t_strict.num_leaves)
@@ -69,7 +69,7 @@ def test_wide_wave_predictive_parity(width):
     t_strict, rl_s = grow_tree(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(min_data=20.0),
         31, 32, -1)
-    t_wave, rl_w = grow_tree_frontier(
+    t_wave, rl_w, _ = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(min_data=20.0),
         31, 32, -1, wave=WaveSchedule(width, "half"))
     assert int(t_wave.num_leaves) <= 31
@@ -82,7 +82,7 @@ def test_wide_wave_predictive_parity(width):
 def test_wave_traversal_matches_row_leaf():
     bins, y = _problem(seed=2)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
-    tree, row_leaf = grow_tree_frontier(
+    tree, row_leaf, _ = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 31, 32, -1,
         wave=WaveSchedule(8, "half"))
     vals_train = np.asarray(tree.leaf_value)[np.asarray(row_leaf)]
@@ -94,7 +94,7 @@ def test_wave_traversal_matches_row_leaf():
 def test_wave_min_data_and_budget():
     bins, y = _problem(seed=3)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
-    tree, row_leaf = grow_tree_frontier(
+    tree, row_leaf, _ = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(min_data=100.0),
         16, 32, -1, wave=WaveSchedule(8, "half"))
     leaves = np.asarray(row_leaf)
@@ -108,7 +108,7 @@ def test_wave_min_data_and_budget():
 def test_wave_max_depth():
     bins, y = _problem(seed=4)
     fmask = jnp.ones(bins.shape[1], jnp.float32)
-    tree, _ = grow_tree_frontier(
+    tree, _, _ = grow_tree_frontier(
         jnp.asarray(bins), _stats(y), fmask, make_ctx(), 31, 32,
         max_depth=2, wave=WaveSchedule(8, "half"))
     assert int(tree.num_leaves) <= 4

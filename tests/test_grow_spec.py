@@ -139,7 +139,7 @@ def test_grower_from_spec_is_the_explicit_call(wave):
     mono = (1, -1, 0, 0, 0)
     spec = GrowSpec(12, bins_n, hist_impl="jnp", wave=wave, mono_key=mono,
                     bynode_off=True)
-    t_spec, rl_spec = grower_from_spec(spec)(
+    t_spec, rl_spec, _ = grower_from_spec(spec)(
         bins, stats, fmask, ctx, -1, jnp.float32(0.5), None)
     t_call, rl_call = grow_tree(
         bins, stats, fmask, ctx, 12, bins_n, -1, ff_bynode=None,

@@ -96,7 +96,7 @@ def test_facts_counts_and_reset():
     assert rec.snapshot()["spans"]["s"]["count"] == 1
     rec.reset()
     assert rec.snapshot() == {"spans": {}, "facts": {}, "counts": {},
-                              "ring": []}
+                              "ring": [], "arrays": {}}
 
 
 def test_build_seconds_land_on_the_span_that_was_open():
