@@ -2058,11 +2058,14 @@ class Booster:
         features = int(ds.X_binned.shape[1])
         # ... and what the kernels' VMEM blocking made of a wave pass at
         # this width: feature blocks, the feature rows they cover (the
-        # last block is padded), rows per grid step, and kernel calls per
-        # pass (two under the hi/lo split that serves "f32")
-        from ..ops.histogram_pallas import _vmem_blocking
+        # last block is padded) and the rows their feature loops run over
+        # (the padding skipped: histogram_pallas._feature_loop), rows per
+        # grid step, and kernel calls per pass (two under the hi/lo split
+        # that serves "f32")
+        from ..ops.histogram_pallas import _vmem_blocking, feature_loop_trips
         f_blk, n_fblk, _, chunk = _vmem_blocking(features, self._num_bins,
                                                  3 * segments)
+        tail = feature_loop_trips(features, f_blk)[1]
         for fact, value in (
                 ("wave_width", segments),
                 # the schedule's narrow phase (0 = none); it runs where the
@@ -2077,6 +2080,7 @@ class Booster:
                 ("features", features),
                 ("feature_blocks", n_fblk),
                 ("features_padded", n_fblk * f_blk),
+                ("feature_rows_looped", (n_fblk - 1) * f_blk + tail),
                 ("chunk_rows", chunk),
                 ("hist_calls_per_pass",
                  2 if hist_dtype == "f32" or (

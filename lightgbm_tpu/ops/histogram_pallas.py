@@ -297,7 +297,42 @@ def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *,
         out_ref[pl.dslice(f, 1), :, :] += tile[None]
         return _
 
-    lax.fori_loop(0, bins_ref.shape[0], body, 0)
+    _feature_loop(body, bins_ref.shape[0], num_features, chunk_dim - 1)
+
+
+def feature_loop_trips(num_features: int, f_blk: int) -> tuple[int, int]:
+    """``(blocks, tail)``: the feature blocks of ``f_blk`` rows that hold
+    ``num_features``, and the trip count of the last block's feature loop
+    (:func:`_feature_loop`); every other block loops ``f_blk`` times, so
+    a pass loops over ``(blocks - 1) * f_blk + tail`` feature rows."""
+    n_fblk = -(-num_features // f_blk)
+    return n_fblk, num_features - (n_fblk - 1) * f_blk
+
+
+def _feature_loop(body, f_blk: int, num_features: int, fblock_axis):
+    """``lax.fori_loop`` of a kernel's per-feature ``body`` over its
+    feature block's rows that hold one of the table's ``num_features``.
+
+    A full block loops over all ``f_blk`` rows.  Where the blocks pad the
+    feature axis (``_vmem_blocking``: MSLR's 136 features in 5 blocks of
+    32 = 160 rows), the last block loops over its ``tail`` real rows only:
+    a padded row's one-hot and dot would land in accumulator rows that
+    keep the zeros of ``_init`` and that the wrapper trims.  The block is
+    ``pl.program_id(fblock_axis)``; both trip counts are static, and a
+    blocking that pads nothing keeps the single loop over the block."""
+    n_fblk, tail = feature_loop_trips(num_features, f_blk)
+    if tail == f_blk:
+        lax.fori_loop(0, f_blk, body, 0)
+        return
+    fb = pl.program_id(fblock_axis)
+
+    @pl.when(fb < n_fblk - 1)
+    def _full():
+        lax.fori_loop(0, f_blk, body, 0)
+
+    @pl.when(fb == n_fblk - 1)
+    def _last():
+        lax.fori_loop(0, tail, body, 0)
 
 
 def _vmem_blocking(num_features: int, num_bins: int, k: int,
@@ -764,11 +799,13 @@ def split_iter_pallas(hist2_t: jnp.ndarray, table: jnp.ndarray,
     )(hist2_t, table, fmask, aux, scal)
 
 
-def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_bins: int,
-                     num_segments: int, bins_minor: bool):
+def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_features: int,
+                     num_bins: int, num_segments: int, bins_minor: bool,
+                     fblock_axis=None):
     """Phase 2 of the partition-fused kernels: the segment-folded one-hot
-    dots of :func:`_fused_kernel` over this block's features, with ``seg``
-    ``[1, chunk]`` produced in-register by the routing phase.
+    dots of :func:`_fused_kernel` over this block's features (the table's
+    own: :func:`_feature_loop`, the block at grid axis ``fblock_axis``),
+    with ``seg`` ``[1, chunk]`` produced in-register by the routing phase.
 
     ``bins_minor`` turns the dot as ``_fused_kernel`` does for the root:
     ``operand [K, chunk] x onehot [B, chunk]^T`` into an ``[F_blk, K, B]``
@@ -803,7 +840,7 @@ def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_bins: int,
         out_ref[pl.dslice(f, 1), :, :] += tile[None]
         return _
 
-    lax.fori_loop(0, bins_ref.shape[0], body, 0)
+    _feature_loop(body, bins_ref.shape[0], num_features, fblock_axis)
 
 
 def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
@@ -865,13 +902,14 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
         0).reshape(1, chunk)
 
     # phase 2: standard segment-folded accumulation (see _fused_kernel)
-    _accumulate_wave(bins_ref, stats_ref, seg, out_ref, num_bins=num_bins,
+    _accumulate_wave(bins_ref, stats_ref, seg, out_ref,
+                     num_features=num_features, num_bins=num_bins,
                      num_segments=w, bins_minor=bins_minor)
 
 
 def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
-                          enc_ref, *, num_bins: int, num_segments: int,
-                          bins_minor: bool = False):
+                          enc_ref, *, num_features: int, num_bins: int,
+                          num_segments: int, bins_minor: bool = False):
     """Multi-feature-block variant of :func:`_fused_part_kernel`.
 
     When the feature axis needs more than one VMEM block (MSLR's 136
@@ -918,8 +956,9 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
 
     # phase 2: standard segment-folded accumulation over THIS block's
     # features (see _fused_part_kernel)
-    _accumulate_wave(bins_ref, stats_ref, seg, out_ref, num_bins=num_bins,
-                     num_segments=w, bins_minor=bins_minor)
+    _accumulate_wave(bins_ref, stats_ref, seg, out_ref,
+                     num_features=num_features, num_bins=num_bins,
+                     num_segments=w, bins_minor=bins_minor, fblock_axis=0)
 
 
 def prepare_wave_operands(bins: jnp.ndarray, stats: jnp.ndarray,
@@ -929,8 +968,9 @@ def prepare_wave_operands(bins: jnp.ndarray, stats: jnp.ndarray,
     while_loop (the in-call pad/convert re-ran per wave — ~2.7 ms each at
     11M rows, r5 trace).  When the feature axis needs multiple VMEM
     blocks (F > ~45; MSLR), the feature axis is zero-padded to a whole
-    number of blocks here — the r7 multi-block kernel trims the padded
-    histogram rows on the way out."""
+    number of blocks here — the kernels' feature loops skip the padded
+    rows (:func:`_feature_loop`) and the wrapper trims their histogram
+    rows on the way out."""
     n, num_features = bins.shape
     s = stats.shape[1]
     k = num_segments * s
@@ -1061,6 +1101,7 @@ def hist_partition_fused_pallas(
         def one_pass(stats_arr):
             return pl.pallas_call(
                 functools.partial(_fused_part_kernel_mb,
+                                  num_features=num_features,
                                   num_bins=num_bins,
                                   num_segments=num_segments,
                                   bins_minor=bins_minor),

@@ -383,7 +383,16 @@ def _narrow_minor_f32(text, least_bytes):
 def _round_compiled(one_chip, monkeypatch, rows_padded, num_features,
                     **params):
     """``Booster._fused_segment(1)`` lowered for the described chip with
-    the row axis set to ``rows_padded``: a booster on a small table of the
+    the row axis set to ``rows_padded`` (:func:`_round_segment`)."""
+    fn, shapes, facts = _round_segment(monkeypatch, rows_padded,
+                                       num_features, one_chip, **params)
+    return fn.lower(*shapes).compile(), facts
+
+
+def _round_segment(monkeypatch, rows_padded, num_features, sharding=None,
+                   **params):
+    """``(fn, shapes, facts)`` of ``Booster._fused_segment(1)`` with the
+    row axis set to ``rows_padded``: a booster on a small table of the
     real width (255 bins, 255 leaves), its dataset's row count replaced
     by a shape so the program resolves its precision, wave tail and
     blocking as at the real size, ``jax.default_backend`` patched so the
@@ -408,9 +417,8 @@ def _round_compiled(one_chip, monkeypatch, rows_padded, num_features,
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(
             tuple(rows_padded if d == small else d for d in a.shape),
-            a.dtype, sharding=one_chip), args)
-    compiled = fn.lower(*shapes).compile()
-    return compiled, dict(profiling.snapshot()["facts"])
+            a.dtype, sharding=sharding), args)
+    return fn, shapes, dict(profiling.snapshot()["facts"])
 
 
 # (rows padded, features, parameters, facts the program resolves to, the most
@@ -455,11 +463,32 @@ def test_whole_round(one_chip, monkeypatch, case):
     assert memory.temp_size_in_bytes <= most_temp
     assert _narrow_minor_f32(text, 64 << 20) == []
     if num_features == 2000:
-        # the blocking of _vmem_blocking as the facts state it
+        # the blocking of _vmem_blocking as the facts state it: the last
+        # block's loop runs over its 16 real features of 32
         assert (facts["train.feature_blocks"], facts["train.features_padded"],
-                facts["train.chunk_rows"]) == (63, 2016, 3072)
+                facts["train.feature_rows_looped"],
+                facts["train.chunk_rows"]) == (63, 2016, 2000, 3072)
         assert facts["train.hist_calls_per_pass"] == (
             2 if resolved["hist_dtype"] == "f32" else 1)
+    else:
+        assert (facts["train.feature_blocks"], facts["train.features_padded"],
+                facts["train.feature_rows_looped"]) == (1, 28, 28)
+
+
+@pytest.mark.parametrize("rows_padded,num_features,blocking", [
+    (10_500_096, 28, (1, 28, 28)),
+    (400_128, 2000, (63, 2016, 2000)),
+    (2_270_208, 136, (5, 160, 136))], ids=["higgs", "epsilon", "mslr"])
+def test_round_feature_rows(monkeypatch, rows_padded, num_features,
+                            blocking):
+    """The facts of the benchmark cells' widths (nothing is compiled):
+    feature blocks, the feature rows they cover and the rows a pass's
+    feature loops run over, which skip the last block's padding."""
+    facts = _round_segment(monkeypatch, rows_padded, num_features)[2]
+    assert facts["train.features"] == num_features
+    assert facts["train.wave_width"] == 42
+    assert (facts["train.feature_blocks"], facts["train.features_padded"],
+            facts["train.feature_rows_looped"]) == blocking
 
 
 def test_narrow_minor_reader_sees_the_parents_buffer():
