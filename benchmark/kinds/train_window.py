@@ -18,6 +18,12 @@ they chose against the reference's own search of the same nodes, their
 split order against leaf-wise growth, the leaf count of every tree of
 the run, and the final scores of a sample of rows against a walk over
 every tree of the run.  Nothing may compile inside the window.
+
+Another kind is a subclass that brings its inputs (``make_inputs``), the
+reference's rounds (``reference_rounds``), the score the reference starts
+from (``init_score``), its own compared numbers and read counters
+(``own_checks``) and faults of its own (``PARAM_FAULTS``); ``check`` is
+this module's alone.
 """
 
 from __future__ import annotations
@@ -29,31 +35,46 @@ import numpy as np
 from .. import datagen
 from ..reference import gbdt_check
 
-# Faults the CPU tests plant to see ``correct`` come out false; nothing on
-# the command line or in the environment sets this.
-FAULT = None
-
-# Faults that are a path of the program's own, switched on by a parameter:
-# a grower that stops at half the leaves, a split scan over half of the
-# features, wave growth without the replay of strict best-first order.
-PARAM_FAULTS = {
-    "fewer_leaves": lambda p: {"num_leaves": (int(p["num_leaves"]) + 1) // 2},
-    "restricted_features": lambda p: {"feature_fraction": 0.5},
-    "greedy_tail": lambda p: {"wave_tail": "greedy"},
-}
-
 
 class Cell:
-    def __init__(self, config: dict, traffic: dict, seed: int, devices):
+    # Faults that break the timed path from outside: a call that leaves the
+    # booster as it was, half of the rows' statistics left out, one leaf of
+    # round 2 altered where it is produced.
+    TIMED_PATH_FAULTS = ("state_unchanged", "half_batch", "altered_answer")
+    # Faults that are a path of the program's own, switched on by a
+    # parameter: ``(params, config) -> params to merge``.  A grower that
+    # stops at half the leaves, a split scan over half of the features, wave
+    # growth without the replay of strict best-first order.
+    PARAM_FAULTS = {
+        "fewer_leaves": lambda p, cfg: {
+            "num_leaves": (int(p["num_leaves"]) + 1) // 2},
+        "restricted_features": lambda p, cfg: {"feature_fraction": 0.5},
+        "greedy_tail": lambda p, cfg: {"wave_tail": "greedy"},
+    }
+
+    def __init__(self, config: dict, traffic: dict, seed: int, devices,
+                 fault: str = None):
+        """``fault``: one of ``TIMED_PATH_FAULTS`` or ``PARAM_FAULTS`` for
+        this run alone (the CPU tests and ``benchmark/readings.py`` plant
+        them to see ``correct`` come out false); nothing on the command line
+        or in the environment sets it."""
+        if fault is not None and fault not in self.TIMED_PATH_FAULTS \
+                and fault not in self.PARAM_FAULTS:
+            raise ValueError(f"benchmark: no fault {fault!r} in this kind")
         self.config, self.traffic, self.seed = config, traffic, int(seed)
-        self.devices = devices
+        self.devices, self.fault = devices, fault
         self.rows = int(config["rows"])
         self.features = int(config["features"])
         self.rounds_per_call = int(traffic.get("rounds_per_call", 1))
         self.checked_rounds = int(traffic.get("checked_rounds", 3))
         self.sample_rows = int(traffic.get("sample_rows", 100_000))
-        self.split_nodes = int(traffic.get("split_nodes", 0))
-        self.order_leaves = int(traffic.get("order_leaves", 0))
+        # the nodes the reference searches and the leaves it replays in each
+        # checked tree: none where no limit reads them
+        limits = config["limits"]
+        searched = "split_gain_short" in limits or "order_excess" in limits
+        self.split_nodes = int(traffic.get("split_nodes", 0)) if searched else 0
+        self.order_leaves = \
+            int(traffic.get("order_leaves", 0)) if searched else 0
         self.counters = {}
         from ..device import CompileMeter
 
@@ -102,10 +123,10 @@ class Cell:
         ds = self.dataset
         t2 = time.perf_counter()
         params = dict(self.config["params"])
-        if FAULT in PARAM_FAULTS:
-            params.update(PARAM_FAULTS[FAULT](params))
+        if self.fault in self.PARAM_FAULTS:
+            params.update(self.PARAM_FAULTS[self.fault](params, self.config))
         self.booster = lgb.Booster(params, ds)
-        if FAULT == "half_batch":
+        if self.fault == "half_batch":
             # half of the batch left out, the statistics taken over the rest
             half = np.ones(int(ds.row_mask.shape[0]), np.float32)
             half[1::2] = 0.0
@@ -128,12 +149,12 @@ class Cell:
         import jax
 
         before = self.booster.current_iteration()
-        if FAULT != "state_unchanged":
+        if self.fault != "state_unchanged":
             with jax.profiler.TraceAnnotation("bench.update_many"):
                 self.booster.update_many(self.rounds_per_call)
         with jax.profiler.TraceAnnotation("bench.block_until_ready"):
             jax.block_until_ready(self.booster._pred_train)
-        if FAULT == "altered_answer" and before == 1:
+        if self.fault == "altered_answer" and before == 1:
             # one answer altered where it is produced: a leaf of round 2
             tree = self.booster.trees[1]
             i = int(np.flatnonzero(np.asarray(tree.is_leaf))[0])
@@ -202,11 +223,38 @@ class Cell:
         except Exception as e:       # the reading is a counter, not a check
             return {"round_memory_error": 1, "round_memory_why": repr(e)}
 
+    def leaf_counts(self) -> list:
+        """The leaves of every tree of the run."""
+        return [int((t["feature"] < 0).sum()) for t in self.trees]
+
+    def init_score(self) -> float:
+        """The score the reference starts every row from."""
+        return gbdt_check.init_score(self.y)
+
+    def reference_rounds(self, k: int) -> list:
+        """The reference's numbers of each of the first ``k`` rounds, one
+        dict a round (``gbdt_check.check_rounds``)."""
+        return gbdt_check.check_rounds(
+            self.X, self.y, self.trees[:k], self.scores_after,
+            self.program_init, self.config["reference"], seed=self.seed,
+            split_nodes=self.split_nodes,
+            order_leaves=self.order_leaves)["rounds"]
+
+    def own_checks(self, rounds) -> list:
+        """The kind's own compared numbers ``[(name, value, limit)]``, and
+        what it reads without comparing, into ``counters``; ``rounds`` is
+        ``reference_rounds``' answer, ``None`` where the run holds fewer
+        trees than it checks."""
+        if rounds is not None:
+            self.counters["reference_loss"] = [rd["loss"] for rd in rounds]
+        return []
+
     def check(self) -> list:
         """``[(name, value, limit)]``: a value over its limit is a fault."""
         limits = self.config["limits"]
         hyper = self.config["reference"]
         k = self.checked_rounds
+        rounds = self.reference_rounds(k) if len(self.trees) >= k else None
         out = []
         expected = k + self.counters.get("window_rounds", 0)
         out.append(("trees_missing",
@@ -214,36 +262,27 @@ class Cell:
         out.append(("compiles_in_window",
                     float(self.counters.get("window_compiles", 0)), 0.0))
         if self.trees and "leaves_off" in limits:
-            leaves = [int((t["feature"] < 0).sum()) for t in self.trees]
             out.append(("leaves_off", float(max(
-                abs(n - int(hyper["num_leaves"])) for n in leaves)),
+                abs(n - int(hyper["num_leaves"])) for n in self.leaf_counts())),
                 limits["leaves_off"]))
-        if len(self.trees) >= k:
-            search = "split_gain_short" in limits or "order_excess" in limits
-            r = gbdt_check.check_rounds(
-                self.X, self.y, self.trees[:k], self.scores_after,
-                self.program_init, hyper, seed=self.seed,
-                split_nodes=self.split_nodes if search else 0,
-                order_leaves=self.order_leaves if search else 0)
+        out += self.own_checks(rounds)
+        if rounds is not None:
             for name in ("leaf_value_worst", "leaf_count_off", "score_abs",
                          "split_gain_short", "order_excess"):
                 if name in limits:
-                    worst = max(rd[name] for rd in r["rounds"])
+                    worst = max(rd[name] for rd in rounds)
                     out.append((name, float(worst), limits[name]))
-            if search:
+            if self.split_nodes or self.order_leaves:
                 self.counters["split_checks"] = [
                     [rd["nodes_checked"], rd["leaves_checked"]]
-                    for rd in r["rounds"]]
+                    for rd in rounds]
             # read, not compared: no control or fault reads far enough
             # above the sound runs (PERF.md, section 2)
             self.counters["leaf_value_rms"] = max(
-                rd["leaf_value_rms"] for rd in r["rounds"])
-            self.counters["reference_loss"] = [
-                rd["loss"] for rd in r["rounds"]]
+                rd["leaf_value_rms"] for rd in rounds)
         if self.trees and "final_score_abs" in limits:
             gap = gbdt_check.check_sample(
-                self.X[self.sample], self.trees,
-                gbdt_check.init_score(self.y),
+                self.X[self.sample], self.trees, self.init_score(),
                 float(hyper["learning_rate"]), self.final_sample_scores)
             out.append(("final_score_abs", gap, limits["final_score_abs"]))
         return out

@@ -1,10 +1,9 @@
 """Traffic kind ``train_window_rank``: ``train_window`` for a learning-to-
 rank table, boosting rounds of ``objective=lambdarank`` for ``--seconds``.
 
-``train_window``'s ``Cell`` (imported, not copied: one booster driven
-through its checked rounds by the window's own call, the window, the
-release, the compiled round's memory account) with three things of its
-own:
+``train_window``'s ``Cell`` (one booster driven through its checked rounds
+by the window's own call, the window, the release, the compiled round's
+memory account and ``check``) with what is its own:
 
 * **inputs**: ``datagen_rank.mslr_like``: the configuration's ``queries``
   query groups of ``query_docs`` documents (rows group-contiguous), its
@@ -21,13 +20,15 @@ own:
   that notes none of them packs its queries some other way, and its
   counters could not be filled: the run ends there, before a round is
   trained, with no result line;
-* **the reference**: ``benchmark/reference/rank_check.py``: LightGBM's
-  lambdarank gradients in float64, query by query, from the scores the
-  program stored, then what ``gbdt_check`` does with them; initial score 0.
-  ``reference_ndcg10`` (NDCG@10 of the training scores after each checked
-  round) is read, not compared, and so are ``own_rank_flips``,
-  ``own_grad_rows`` and ``own_grad_gap`` (what ranking by the program's
-  stored scores hides: ``rank_check.own_walk_gap``).
+* **the reference** (``reference_rounds``, ``init_score``):
+  ``benchmark/reference/rank_check.py``: LightGBM's lambdarank gradients in
+  float64, query by query, from the scores the program stored, then what
+  ``gbdt_check`` does with them; initial score 0.  ``own_checks`` compares
+  ``init_abs`` (the program's initial score against 0) and reads, without
+  comparing, ``reference_ndcg10`` (NDCG@10 of the training scores after
+  each checked round), ``reference_leaves``, ``reference_hessian_sum``,
+  ``own_rank_flips``, ``own_grad_rows`` and ``own_grad_gap`` (what ranking
+  by the program's stored scores hides: ``rank_check.own_walk_gap``).
   ``rank_grad_probe_ms``: after the window, the program's jitted lambda
   pass ALONE on the booster's real scores, median of 5 (neither set-up's
   time nor the window's).
@@ -46,19 +47,9 @@ import time
 import numpy as np
 
 from .. import datagen, datagen_rank
-from ..reference import gbdt_check, rank_check
+from ..reference import rank_check
 from . import train_window as tw
 
-# Faults the CPU tests plant to see ``correct`` come out false; nothing on
-# the command line or in the environment sets this.
-FAULT = None
-
-RANK_FAULTS = {
-    "pointwise": lambda p, cfg: {"objective": "regression"},
-    "no_truncation": lambda p, cfg: {
-        "lambdarank_truncation_level": docs_range(cfg)[1]},
-}
-PARAM_FAULTS = dict(tw.PARAM_FAULTS, **RANK_FAULTS)
 RANK_FACTS = ("rank_queries", "rank_doc_slots", "rank_pair_slots",
               "rank_pairs_visited", "rank_truncation", "rank_blocks")
 
@@ -70,8 +61,15 @@ def docs_range(config: dict):
 
 
 class Cell(tw.Cell):
-    def __init__(self, config: dict, traffic: dict, seed: int, devices):
-        super().__init__(config, traffic, seed, devices)
+    PARAM_FAULTS = dict(
+        tw.Cell.PARAM_FAULTS,
+        pointwise=lambda p, cfg: {"objective": "regression"},
+        no_truncation=lambda p, cfg: {
+            "lambdarank_truncation_level": docs_range(cfg)[1]})
+
+    def __init__(self, config: dict, traffic: dict, seed: int, devices,
+                 fault: str = None):
+        super().__init__(config, traffic, seed, devices, fault)
         self.queries = int(config["queries"])
         self._facts_read = False
 
@@ -102,30 +100,10 @@ class Cell(tw.Cell):
         super().share_inputs(other)
         self.sizes = other.sizes
 
-    def build(self) -> None:
-        self._sync_fault()
-        if FAULT in RANK_FAULTS:
-            params = dict(self.config["params"])
-            params.update(RANK_FAULTS[FAULT](params, self.config))
-            self.config = dict(self.config, params=params)
-        try:
-            super().build()
-        finally:
-            tw.FAULT = None
-
-    def _sync_fault(self) -> None:
-        """``train_window``'s methods read ITS module's ``FAULT``: hand it
-        the faults that are its own."""
-        tw.FAULT = None if FAULT in RANK_FAULTS else FAULT
-
     def _call(self) -> None:
         if not self._facts_read:
             self.read_layout_facts()
-        self._sync_fault()
-        try:
-            super()._call()
-        finally:
-            tw.FAULT = None
+        super()._call()
 
     def read_layout_facts(self) -> None:
         """Before the first round: the facts the program notes for its
@@ -133,7 +111,7 @@ class Cell(tw.Cell):
         from ..readers.program_span import snapshot
 
         self._facts_read = True
-        if FAULT == "pointwise":        # no ranking objective, no layout
+        if self.fault == "pointwise":   # no ranking objective, no layout
             return
         self.booster._fused_segment(self.rounds_per_call)
         facts = snapshot().get("facts", {})
@@ -177,58 +155,28 @@ class Cell(tw.Cell):
         self.counters["rank_grad_probe_ms"] = 1000.0 * float(np.median(took))
         self.counters["rank_grad_probe_s"] = time.perf_counter() - t_start
 
-    def check(self) -> list:
-        """``[(name, value, limit)]``: a value over its limit is a fault."""
-        limits = self.config["limits"]
-        hyper = self.config["reference"]
-        k = self.checked_rounds
-        out = []
-        expected = k + self.counters.get("window_rounds", 0)
-        out.append(("trees_missing",
-                    float(abs(expected - len(self.trees))), 0.0))
-        out.append(("compiles_in_window",
-                    float(self.counters.get("window_compiles", 0)), 0.0))
+    # -- the reference -----------------------------------------------------
+    def init_score(self) -> float:
+        return 0.0
+
+    def reference_rounds(self, k: int) -> list:
+        return rank_check.check_rounds(
+            self.X, self.y, self.sizes, self.trees[:k], self.scores_after,
+            self.program_init, self.config["reference"], seed=self.seed,
+            split_nodes=self.split_nodes,
+            order_leaves=self.order_leaves)["rounds"]
+
+    def own_checks(self, rounds) -> list:
         if self.trees:
-            leaves = [int((t["feature"] < 0).sum()) for t in self.trees]
+            leaves = self.leaf_counts()
             self.counters["leaves_least"] = min(leaves)
             self.counters["leaves_most"] = max(leaves)
-            if "leaves_off" in limits:
-                out.append(("leaves_off", float(max(
-                    abs(n - int(hyper["num_leaves"])) for n in leaves)),
-                    limits["leaves_off"]))
-        out.append(("init_abs", abs(float(self.program_init)),
-                    limits.get("init_abs", 0.0)))
-        if len(self.trees) >= k:
-            search = "split_gain_short" in limits or "order_excess" in limits
-            r = rank_check.check_rounds(
-                self.X, self.y, self.sizes, self.trees[:k],
-                self.scores_after, self.program_init, hyper, seed=self.seed,
-                split_nodes=self.split_nodes if search else 0,
-                order_leaves=self.order_leaves if search else 0)
-            for name in ("leaf_value_worst", "leaf_count_off", "score_abs",
-                         "split_gain_short", "order_excess"):
-                if name in limits:
-                    worst = max(rd[name] for rd in r["rounds"])
-                    out.append((name, float(worst), limits[name]))
-            if search:
-                self.counters["split_checks"] = [
-                    [rd["nodes_checked"], rd["leaves_checked"]]
-                    for rd in r["rounds"]]
-            # read, not compared
-            self.counters["leaf_value_rms"] = max(
-                rd["leaf_value_rms"] for rd in r["rounds"])
-            self.counters["reference_ndcg10"] = [
-                rd["ndcg10"] for rd in r["rounds"]]
-            self.counters["reference_leaves"] = [
-                rd["leaves"] for rd in r["rounds"]]
-            self.counters["reference_hessian_sum"] = [
-                rd["hessian_sum"] for rd in r["rounds"]]
-            # what ranking by the program's stored scores hides
+        if rounds is not None:
+            # read, not compared; the own_* are what ranking by the
+            # program's stored scores hides
+            for key in ("ndcg10", "leaves", "hessian_sum"):
+                self.counters["reference_" + key] = [rd[key] for rd in rounds]
             for name in ("own_rank_flips", "own_grad_rows", "own_grad_gap"):
-                self.counters[name] = [rd[name] for rd in r["rounds"]]
-        if self.trees and "final_score_abs" in limits:
-            gap = gbdt_check.check_sample(
-                self.X[self.sample], self.trees, 0.0,
-                float(hyper["learning_rate"]), self.final_sample_scores)
-            out.append(("final_score_abs", gap, limits["final_score_abs"]))
-        return out
+                self.counters[name] = [rd[name] for rd in rounds]
+        return [("init_abs", abs(float(self.program_init)),
+                 self.config["limits"].get("init_abs", 0.0))]

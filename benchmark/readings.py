@@ -8,10 +8,10 @@ For each seed the inputs are made once and every mode builds its own run
 on them through the kind's own set-up, window and check: ``program`` is
 the configuration as stated; ``control`` is the configuration with its
 ``control.params`` switched on (the nearest precision below the stated
-one); the others plant a fault in the timed path (see the kind's
-``FAULT``).  One JSON line per (seed, mode) with every number compared,
-``correct`` by the limits as committed; ``chiprun_out/readings-<name>.jsonl``
-keeps them.  Not part of a benchmark run: nothing here is timed.
+one); the others plant a fault in the timed path, given to that run's
+``Cell`` alone (the kind's ``TIMED_PATH_FAULTS`` and ``PARAM_FAULTS``).
+One JSON line per (seed, mode) with every number compared, ``correct`` by
+the limits as committed; ``chiprun_out/readings-<name>.jsonl`` keeps them.  Not part of a benchmark run: nothing here is timed.
 """
 
 from __future__ import annotations
@@ -28,7 +28,7 @@ def variant(config: dict, mode: str):
     """``(config, fault)`` of one mode: ``program`` is the configuration as
     stated, ``control`` has every group of ``config["control"]`` that is a
     dict merged over the group of the same name (``control.params`` over
-    ``params``), any other mode names a fault of the kind's ``FAULT``."""
+    ``params``), any other mode names a fault of the kind's ``Cell``."""
     cfg = copy.deepcopy(config)
     if mode == "program":
         return cfg, None
@@ -63,15 +63,14 @@ def main(argv=None, *, require_tpu: bool = True) -> int:
         base = kind.Cell(config, traffic, seed, devices)
         base.make_inputs()
         for mode in args.modes:
-            cfg, kind.FAULT = variant(config, mode)
+            cfg, fault = variant(config, mode)
             t0 = time.perf_counter()
-            run = kind.Cell(cfg, traffic, seed, devices)
+            run = kind.Cell(cfg, traffic, seed, devices, fault=fault)
             run.share_inputs(base)
             run.build()
             run.window(args.window)
             run.release()
             checks = run.check()
-            kind.FAULT = None
             line = {"workload": cell["name"], "seed": seed, "mode": mode,
                     "kind": devices[0].device_kind,
                     "correct": all(v <= lim for _, v, lim in checks),

@@ -54,7 +54,11 @@ def _say(*parts) -> None:
     print(*parts, file=sys.stderr, flush=True)
 
 
-def main(argv=None, *, require_tpu: bool = True, root: str = None) -> int:
+def main(argv=None, *, require_tpu: bool = True, root: str = None,
+         fault: str = None) -> int:
+    """``require_tpu``, ``root`` and ``fault`` (a fault of the kind's
+    ``Cell`` planted in this run) are for the CPU tests: no flag or
+    environment variable reaches them."""
     ap = argparse.ArgumentParser(prog="benchmark.run", description=__doc__)
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -78,7 +82,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = None) -> int:
          f"count={len(devices)}")
 
     run = mf.load_kind(traffic["kind"]).Cell(
-        config, traffic, args.seed, devices)
+        config, traffic, args.seed, devices, fault=fault)
     # where set-up goes before the kind's own phases: the interpreter and
     # this module, then JAX's start and its first contact with the chip
     run.counters.update(start_s=_IMPORTED_AT - started_at,

@@ -28,6 +28,49 @@ TINY_CONFIG = {
 }
 
 
+# 6,000 documents x 28 columns in 80 queries of 1 to 400; lambdarank's
+# hessians are small (about 2 a query), so a leaf is asked for 1 of them
+TINY_RANK = {
+    "source": "a tiny stand-in for the CPU tests of the ranking kind",
+    "rows": 6000, "features": 28, "queries": 80, "query_docs": "1-400",
+    "params": {"objective": "lambdarank", "num_leaves": 15,
+               "learning_rate": 0.1, "max_bin": 255, "min_data_in_leaf": 0,
+               "min_sum_hessian_in_leaf": 1.0, "lambda_l2": 0.0,
+               "lambdarank_truncation_level": 30, "lambdarank_norm": True,
+               "sigmoid": 1.0, "hist_dtype": "f32", "verbosity": -1},
+    "precision": "float32 histograms (hi/lo split), so the control is bf16",
+    "reference": {"learning_rate": 0.1, "lambda_l2": 0.0, "num_leaves": 15,
+                  "max_bin": 255, "min_sum_hessian_in_leaf": 1.0,
+                  "sigmoid": 1.0, "lambdarank_truncation_level": 30,
+                  "lambdarank_norm": True},
+    "control": {"params": {"hist_dtype": "bf16"}},
+    "limits": {"leaves_off": 0, "split_gain_short": 0.05,
+               "order_excess": 0.1,
+               "leaf_value_worst": 1e-4, "leaf_count_off": 0,
+               "score_abs": 1e-5, "final_score_abs": 1e-5, "init_abs": 0.0},
+    "reduced": [], "assumed": [],
+}
+
+
+def order_faults(doc: dict) -> list:
+    """What breaks the order of ``BENCHMARK.json``'s metric lists: a name
+    that is no cell, a cell listed twice, a list out of the order of the
+    top-level ``workloads`` (a cell is appended after the cells before it,
+    and what was listed before still is, in its order)."""
+    place = {w["name"]: i for i, w in enumerate(doc["workloads"])}
+    faults = []
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        listed = m.get("workloads", [])
+        unknown = [c for c in listed if c not in place]
+        if unknown:
+            faults.append((m["name"], "no such cell", unknown))
+        elif len(set(listed)) < len(listed):
+            faults.append((m["name"], "listed twice", listed))
+        elif [place[c] for c in listed] != sorted(place[c] for c in listed):
+            faults.append((m["name"], "out of order", listed))
+    return faults
+
+
 class BenchCopy:
     def __init__(self, root):
         self.root = root
@@ -45,38 +88,51 @@ class BenchCopy:
             doc[key].extend(new)
         (self.root / "BENCHMARK.json").write_text(json.dumps(doc))
 
+    def add_cell(self, name, config, traffic="train-window", like=None):
+        """A configuration and its cell on one chip, added as data: new
+        files and appended entries.  The cell is appended to the metric
+        lists that name the cell ``like`` (``"every"``: to every list;
+        ``None``: to ``train_rows_rounds_per_s``'s alone)."""
+        cell = f"{name}.train"
+        self.add(
+            files={f"benchmark/configs/{name}.json": config},
+            configs=[{"name": name, "source": config["source"],
+                      "reduced": config["reduced"],
+                      "file": f"benchmark/configs/{name}.json",
+                      "why": "tiny"}],
+            workloads=[{"name": cell, "config": name, "traffic": traffic,
+                        "chips": 1, "why": "tiny"}])
+        doc = json.loads((self.root / "BENCHMARK.json").read_text())
+        for m in doc["end_to_end"] + doc["per_layer"]:
+            listed = m.get("workloads")
+            if listed is not None and (
+                    like == "every" or like in listed or (
+                        like is None
+                        and m["name"] == "train_rows_rounds_per_s")):
+                listed.append(cell)
+        (self.root / "BENCHMARK.json").write_text(json.dumps(doc))
+        return cell
+
     def add_tiny_cell(self, name="tiny", config=None):
         """A tiny configuration and its training cell, which reports the
         end-to-end metrics of the training cells and per-layer metrics of
         its own choosing (none by default)."""
-        self.add(
-            files={f"benchmark/configs/{name}.json": config or TINY_CONFIG},
-            configs=[{"name": name, "source": "tests", "reduced": [],
-                      "file": f"benchmark/configs/{name}.json",
-                      "why": "tiny"}],
-            workloads=[{"name": f"{name}.train", "config": name,
-                        "traffic": "train-window", "chips": 1,
-                        "why": "tiny"}])
-        doc = json.loads((self.root / "BENCHMARK.json").read_text())
-        for m in doc["end_to_end"]:
-            if m["name"] == "train_rows_rounds_per_s":
-                m["workloads"].append(f"{name}.train")
-        (self.root / "BENCHMARK.json").write_text(json.dumps(doc))
-        return f"{name}.train"
+        return self.add_cell(name, config or TINY_CONFIG)
 
-    def run(self, capsys, workload, seed=7, seconds=0.3, trace=0):
-        """``(result, stderr)`` of one run in this process."""
+    def run(self, capsys, workload, seed=7, seconds=0.3, trace=0,
+            fault=None):
+        """``(result, stderr)`` of one run in this process; ``fault``: a
+        fault of the kind's ``Cell``, planted in this run alone."""
         from benchmark import run
 
         capsys.readouterr()
         rc = run.main(["--workload", workload, "--seed", str(seed),
                        "--seconds", str(seconds), "--trace", str(trace)],
-                      require_tpu=False, root=str(self.root))
+                      require_tpu=False, root=str(self.root), fault=fault)
         assert rc == 0
         captured = capsys.readouterr()
         lines = [ln for ln in captured.out.splitlines() if ln.strip()]
         return json.loads(lines[-1]), captured.err
-
 
 
 def grow_plain(X, g, h, edges, num_leaves, lam=0.0, min_hess=0.0,

@@ -9,7 +9,7 @@ import shutil
 
 import pytest
 
-from bench_helpers import REPO, BenchCopy
+from bench_helpers import REPO, TINY_RANK, BenchCopy
 
 
 @pytest.fixture
@@ -20,3 +20,20 @@ def bench_copy(tmp_path):
     shutil.copytree(os.path.join(REPO, "benchmark"), root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
     return BenchCopy(root)
+
+
+@pytest.fixture(params=["committed", "fourth_cell"])
+def manifest(request):
+    """The ``Manifest`` a manifest-level test of the cells reads: the
+    committed ``BENCHMARK.json``, then a copy with a tiny fourth cell
+    appended to ``configs``, ``workloads`` and every metric's list, as the
+    next cell will be.  A ranking cell, so that it may stand in every list:
+    a test that finds a cell by its place in a list, or holds a list to
+    the cells it has today, fails on the copy."""
+    from benchmark.manifest import Manifest
+
+    if request.param == "committed":
+        return Manifest(REPO)
+    copy = request.getfixturevalue("bench_copy")
+    copy.add_cell("tiny-fourth", TINY_RANK, "train-window-rank", like="every")
+    return Manifest(str(copy.root))

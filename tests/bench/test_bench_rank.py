@@ -3,58 +3,23 @@ reference, its kind end to end at a tiny size on the CPU with the control
 and every planted fault, and its entries in the real ``BENCHMARK.json``."""
 
 import copy
-import json
 import os
 
 import numpy as np
 import pytest
 
-from bench_helpers import REPO
+from bench_helpers import REPO, TINY_RANK
 
 CELL = "mslr-web30k.train"
 RANK_METRICS = ("rank_grad_ms", "rank_pair_slot_ratio", "rank_pad_ratio",
                 "rank_pack_s")
 
-# 6,000 documents x 28 columns in 80 queries of 1 to 400; lambdarank's
-# hessians are small (about 2 a query), so a leaf is asked for 1 of them
-TINY_RANK = {
-    "source": "a tiny stand-in for the CPU tests of the ranking kind",
-    "rows": 6000, "features": 28, "queries": 80, "query_docs": "1-400",
-    "params": {"objective": "lambdarank", "num_leaves": 15,
-               "learning_rate": 0.1, "max_bin": 255, "min_data_in_leaf": 0,
-               "min_sum_hessian_in_leaf": 1.0, "lambda_l2": 0.0,
-               "lambdarank_truncation_level": 30, "lambdarank_norm": True,
-               "sigmoid": 1.0, "hist_dtype": "f32", "verbosity": -1},
-    "precision": "float32 histograms (hi/lo split), so the control is bf16",
-    "reference": {"learning_rate": 0.1, "lambda_l2": 0.0, "num_leaves": 15,
-                  "max_bin": 255, "min_sum_hessian_in_leaf": 1.0,
-                  "sigmoid": 1.0, "lambdarank_truncation_level": 30,
-                  "lambdarank_norm": True},
-    "control": {"params": {"hist_dtype": "bf16"}},
-    "limits": {"leaves_off": 0, "split_gain_short": 0.05,
-               "order_excess": 0.1,
-               "leaf_value_worst": 1e-4, "leaf_count_off": 0,
-               "score_abs": 1e-5, "final_score_abs": 1e-5, "init_abs": 0.0},
-    "reduced": [], "assumed": [],
-}
-
 
 def add_rank_cell(bench_copy, name="tiny-rank", config=None):
     """A tiny ranking configuration and its cell, built as data: files and
     entries, the metrics of the real cell listed for it too."""
-    bench_copy.add(
-        files={f"benchmark/configs/{name}.json": config or TINY_RANK},
-        configs=[{"name": name, "source": "tests", "reduced": [],
-                  "file": f"benchmark/configs/{name}.json", "why": "tiny"}],
-        workloads=[{"name": f"{name}.train", "config": name,
-                    "traffic": "train-window-rank", "chips": 1,
-                    "why": "tiny"}])
-    doc = json.loads((bench_copy.root / "BENCHMARK.json").read_text())
-    for m in doc["end_to_end"] + doc["per_layer"]:
-        if CELL in m.get("workloads", []):
-            m["workloads"].append(f"{name}.train")
-    (bench_copy.root / "BENCHMARK.json").write_text(json.dumps(doc))
-    return f"{name}.train"
+    return bench_copy.add_cell(name, config or TINY_RANK,
+                               "train-window-rank", like=CELL)
 
 
 def over(res):
@@ -268,16 +233,24 @@ def test_rank_control_comes_out_not_correct(bench_copy, capsys):
     ("pointwise", "leaf_value_worst"),
     ("no_truncation", "leaf_value_worst"),
 ])
-def test_planted_rank_fault_is_not_correct(bench_copy, capsys, monkeypatch,
-                                           fault, number):
+def test_planted_rank_fault_is_not_correct(bench_copy, capsys, fault,
+                                           number):
     from benchmark.kinds import train_window, train_window_rank
+    from benchmark.manifest import Manifest
 
-    monkeypatch.setattr(train_window_rank, "FAULT", fault)
     cell = add_rank_cell(bench_copy)
-    res, _ = bench_copy.run(capsys, cell, seed=5)
+    res, _ = bench_copy.run(capsys, cell, seed=5, fault=fault)
     assert res["correct"] is False
     assert number in over(res), res["checks"]
-    assert train_window.FAULT is None        # handed over, and taken back
+    # the fault was that run's: the next Cell built in this process has
+    # none, and no module of the kinds holds one
+    man = Manifest(str(bench_copy.root))
+    entry = man.cell(cell)
+    nxt = train_window_rank.Cell(man.config(entry), man.traffic(entry), 5,
+                                 None)
+    assert nxt.fault is None
+    assert not hasattr(train_window, "FAULT")
+    assert not hasattr(train_window_rank, "FAULT")
 
 
 def test_a_program_without_the_rank_facts_is_refused(bench_copy, capsys,
@@ -329,10 +302,10 @@ def test_real_cell_limits_lie_between_their_readings():
 
 # -- the real cell's entries -------------------------------------------------
 
-def test_real_cell_resolves_to_its_three_files():
-    from benchmark.manifest import Manifest, load_kind
+def test_real_cell_resolves_to_its_three_files(manifest):
+    from benchmark.manifest import load_kind
 
-    man = Manifest()
+    man = manifest
     cell = man.cell(CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "train-window-rank"
     config, traffic = man.config(cell), man.traffic(cell)
@@ -353,33 +326,30 @@ def test_real_cell_resolves_to_its_three_files():
             "split_nodes": 16, "order_leaves": 16, "trace_seconds": 10}
     kind = load_kind(traffic["kind"])
     assert {"pointwise", "no_truncation", "fewer_leaves",
-            "restricted_features", "greedy_tail"} <= set(kind.PARAM_FAULTS)
+            "restricted_features", "greedy_tail"} <= set(
+                kind.Cell.PARAM_FAULTS)
     names = {m["name"] for m in man.metrics_of(CELL, "end_to_end")}
     assert names == {"train_rows_rounds_per_s", "setup_s"}
 
 
-def test_rank_metrics_are_listed_for_the_rank_cell_alone():
-    from benchmark.manifest import Manifest
-
-    man = Manifest()
+def test_rank_metrics_are_listed_for_the_rank_cell_alone(manifest):
+    man = manifest
     listed = {m["name"]: m for m in man.doc["per_layer"]}
     for name in RANK_METRICS:
-        assert listed[name]["workloads"] == [CELL]
+        assert CELL in listed[name]["workloads"]
+        for cell in listed[name]["workloads"]:
+            kind = man.traffic(man.cell(cell))["kind"]
+            assert kind == "train_window_rank", (name, cell)
         assert listed[name]["layer"] == "objective"
         spec = man.metric_spec(name)
         assert spec["what"] and spec["unit"] == listed[name]["unit"]
         assert not os.path.exists(os.path.join(
-            REPO, "benchmark", "metrics", name + ".py"))
+            man.root, "benchmark", "metrics", name + ".py"))
     mine = {m["name"] for m in man.metrics_of(CELL, "per_layer")}
     assert set(RANK_METRICS) <= mine
     assert {"hist_wave_roofline", "hist_root_roofline",
             "hist_narrow_roofline", "grower_xla_pct", "train_floor_mfu_pct",
             "device_idle_pct.train", "wave_passes_per_round"} <= mine
-    assert "hist_roofline" not in mine
     for cell in ("higgs-10m5.train", "epsilon-400k.train"):
         assert not set(RANK_METRICS) & {
             m["name"] for m in man.metrics_of(cell, "per_layer")}
-    # appended: what was listed before still is, in its order
-    for m in man.doc["end_to_end"] + man.doc["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL

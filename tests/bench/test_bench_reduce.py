@@ -1,19 +1,30 @@
 """The trace reduction and the work functions against hand-worked numbers."""
 
-import json
-import os
-
 import pytest
 
 from benchmark.reduce import trace as tr
 from benchmark.reduce import work
-from bench_helpers import REPO
 
 HIST = ('%body.10 = (f32[28,255,126]{2,1,0:T(8,128)S(1)}, s32[1,1024]{1,0}) '
         'custom-call(s32[28,1024]{1,0} %a, f32[3,1024]{1,0} %b), '
         'custom_call_target="tpu_custom_call", frontend_attributes={}')
 ROOT = ('%closed_call.2 = f32[28,255,3]{2,1,0:T(8,128)S(1)} custom-call('
         's32[28,1024]{1,0} %pad.74), custom_call_target="tpu_custom_call"')
+# a roofline of both histogram kernels found by their shapes alone: f32
+# [F, B, 126] for a wave pass of 42 segments, f32 [F, B, 3] for the root
+HIST_SPEC = {"layer": "kernels", "calls": [
+    {"pattern": '^%[^ ]+ = \\(f32\\[\\d+,\\d+,126\\].*'
+                'custom_call_target="tpu_custom_call"',
+     "work": "hist_onehot_call",
+     "shapes": {"rows": "counter:rows_padded", "features": "config:features",
+                "bins": 255, "segments": 42, "code_bytes": 4,
+                "dtype": "bf16"}},
+    {"pattern": '^%[^ ]+ = f32\\[\\d+,\\d+,3\\].*'
+                'custom_call_target="tpu_custom_call"',
+     "work": "hist_onehot_call",
+     "shapes": {"rows": "counter:rows_padded", "features": "config:features",
+                "bins": 255, "segments": 1, "code_bytes": 4,
+                "dtype": "bf16"}}]}
 CONCAT = ('%custom-call.68 = f32[1024]{0} custom-call(f32[512]{0} %x, '
           'f32[512]{0} %y), custom_call_target="ConcatBitcast"')
 
@@ -69,9 +80,7 @@ def test_events_are_clipped_to_the_window():
 
 def test_pattern_sums():
     t = hand_trace()
-    spec = json.load(open(os.path.join(
-        REPO, "benchmark", "metrics", "hist_roofline.json")))
-    wave, root = (c["pattern"] for c in spec["calls"])
+    wave, root = (c["pattern"] for c in HIST_SPEC["calls"])
     assert t.matching(wave) == (6.0, 2.0)
     assert t.matching(root) == (pytest.approx(0.2), 1.0)
     assert t.matching("no such kernel") == (0.0, 0.0)
@@ -193,8 +202,7 @@ def test_readers():
                             spec) is None              # no peaks: no share
     assert floor_share.read(_ctx(peaks=PEAKS, code_bytes=1), spec) is None
 
-    hist = json.load(open(os.path.join(
-        REPO, "benchmark", "metrics", "hist_roofline.json")))
+    hist = HIST_SPEC
     ctx = _ctx(hand_trace(), PEAKS, rows_padded=1024)
     got = trace_roofline.read(ctx, hist)
     wave = work.least_seconds(work.hist_onehot_call(

@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from bench_helpers import REPO, TINY_CONFIG
+from bench_helpers import REPO, TINY_CONFIG, order_faults
 
 E2E_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
@@ -35,17 +35,13 @@ def test_result_line_trace0(bench_copy, capsys):
 
 
 def test_result_line_trace1(bench_copy, capsys):
-    cell = bench_copy.add_tiny_cell()
-    doc = json.loads((bench_copy.root / "BENCHMARK.json").read_text())
-    for m in doc["per_layer"]:
-        m["workloads"].append(cell)
-    (bench_copy.root / "BENCHMARK.json").write_text(json.dumps(doc))
+    cell = bench_copy.add_cell("tiny", TINY_CONFIG, like="every")
     res, _ = bench_copy.run(capsys, cell, trace=1)
     assert res["correct"] is True
     assert res["device"]["busy_s"] > 0
     assert res["device"]["window_s"] >= res["device"]["busy_s"] * 0.5
     # no table of peaks for a CPU: shares of a peak are left out, never 0
-    assert "hist_roofline" not in res["metrics"]
+    assert "hist_wave_roofline" not in res["metrics"]
     assert "train_floor_mfu_pct" not in res["metrics"]
     assert res["metrics"]["binning_s"]["value"] > 0
     assert 0 <= res["metrics"]["device_idle_pct.train"]["value"] <= 100
@@ -167,26 +163,31 @@ def test_control_comes_out_not_correct(bench_copy, capsys):
     ("fewer_leaves", "leaves_off"),
     ("restricted_features", "split_gain_short"),
 ])
-def test_planted_fault_is_not_correct(bench_copy, capsys, monkeypatch,
-                                      fault, number):
+def test_planted_fault_is_not_correct(bench_copy, capsys, fault, number):
     """The rest of a run, with the timed path broken underneath."""
-    from benchmark.kinds import train_window
-
-    monkeypatch.setattr(train_window, "FAULT", fault)
     cell = bench_copy.add_tiny_cell()
-    res, _ = bench_copy.run(capsys, cell)
+    res, _ = bench_copy.run(capsys, cell, fault=fault)
     assert res["correct"] is False
     assert res["checks"][number]["value"] > res["checks"][number]["limit"]
 
 
-def test_growth_without_the_replay_is_not_correct(bench_copy, capsys,
-                                                  monkeypatch):
+def test_a_fault_the_kind_does_not_have_is_refused():
+    """``readings.py`` takes its modes from the command line: a name that
+    is no fault of the kind would read as a sound run."""
+    from benchmark.kinds import train_window, train_window_rank
+
+    with pytest.raises(ValueError, match="no fault 'pointwise'"):
+        train_window.Cell(TINY_CONFIG, {}, 1, None, fault="pointwise")
+    assert train_window_rank.Cell(
+        dict(TINY_CONFIG, queries=1), {}, 1, None,
+        fault="pointwise").fault == "pointwise"
+
+
+def test_growth_without_the_replay_is_not_correct(bench_copy, capsys):
     """Wave growth with the exact tail passes; the same run with the
     replay of strict best-first order left out (``wave_tail=greedy``, a
     path of the program's own that saves histogram passes) keeps exact
     leaf statistics and best splits, and fails ``order_excess`` alone."""
-    from benchmark.kinds import train_window
-
     cfg = copy.deepcopy(TINY_CONFIG)
     cfg.update(rows=20_000)
     cfg["params"].update(num_leaves=31, wave_tail="exact")
@@ -194,8 +195,7 @@ def test_growth_without_the_replay_is_not_correct(bench_copy, capsys,
     cell = bench_copy.add_tiny_cell("tiny-exact", cfg)
     res, _ = bench_copy.run(capsys, cell, seed=1)
     assert res["correct"] is True
-    monkeypatch.setattr(train_window, "FAULT", "greedy_tail")
-    res, _ = bench_copy.run(capsys, cell, seed=1)
+    res, _ = bench_copy.run(capsys, cell, seed=1, fault="greedy_tail")
     over = {n for n, c in res["checks"].items() if c["value"] > c["limit"]}
     assert res["correct"] is False and over == {"order_excess"}
 
@@ -223,10 +223,8 @@ def test_bare_directory_fails(bench_copy):
     assert p.returncode != 0 and p.stdout.strip() == ""
 
 
-def test_manifest_names_files_that_exist():
-    from benchmark.manifest import Manifest
-
-    man = Manifest()
+def test_manifest_names_files_that_exist(manifest):
+    man = manifest
     for cell in man.doc["workloads"]:
         assert man.config(cell)["rows"] > 0
         assert man.traffic(cell)["kind"]
@@ -238,6 +236,27 @@ def test_manifest_names_files_that_exist():
         assert spec["moves"] == m["moves"]
         assert callable(man.metric_reader(m["name"], spec))
     for c in man.doc["configs"]:
-        cfg = json.load(open(os.path.join(REPO, c["file"])))
+        cfg = json.load(open(os.path.join(man.root, c["file"])))
         assert cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
+
+
+def test_metric_lists_follow_the_cells_order(manifest):
+    """Every name a metric lists is a cell, listed once, and each list
+    keeps the order of the top-level ``workloads``."""
+    assert order_faults(manifest.doc) == []
+
+
+@pytest.mark.parametrize("fault", ["no such cell", "listed twice",
+                                   "out of order"])
+def test_order_faults_are_found(fault):
+    doc = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    rate = next(m for m in doc["end_to_end"]
+                if m["name"] == "train_rows_rounds_per_s")
+    rate["workloads"] = {
+        "no such cell": rate["workloads"] + ["nowhere.train"],
+        "listed twice": rate["workloads"] + rate["workloads"][:1],
+        "out of order": rate["workloads"][::-1],
+    }[fault]
+    assert [f[:2] for f in order_faults(doc)] == [
+        ("train_rows_rounds_per_s", fault)]

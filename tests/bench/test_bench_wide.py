@@ -27,19 +27,7 @@ WIDE_TINY = dict(
 
 def _add_wide_cell(bench_copy, name, config):
     """A cell of the new traffic mix on a small wide table."""
-    bench_copy.add(
-        files={f"benchmark/configs/{name}.json": config},
-        configs=[{"name": name, "source": "tests", "reduced": [],
-                  "file": f"benchmark/configs/{name}.json", "why": "tiny"}],
-        workloads=[{"name": f"{name}.train", "config": name,
-                    "traffic": "train-window-wide", "chips": 1,
-                    "why": "tiny"}])
-    doc = json.loads((bench_copy.root / "BENCHMARK.json").read_text())
-    for m in doc["end_to_end"] + doc["per_layer"]:
-        if CELL in m.get("workloads", []):
-            m["workloads"].append(f"{name}.train")
-    (bench_copy.root / "BENCHMARK.json").write_text(json.dumps(doc))
-    return f"{name}.train"
+    return bench_copy.add_cell(name, config, "train-window-wide", like=CELL)
 
 
 @pytest.mark.parametrize("hist_dtype,tail", [
@@ -89,13 +77,13 @@ def test_multi_block_route_holds_against_the_reference(
         2 + res["counters"]["window_rounds"]
 
 
-def test_wide_cell_is_data_only():
+def test_wide_cell_is_data_only(manifest):
     """The cell is an entry and three kinds of data file: a configuration
     that selects no path of the program, the kind that is there, and
     metric files that the general readers read."""
-    from benchmark.manifest import Manifest, load_kind
+    from benchmark.manifest import load_kind
 
-    man = Manifest()
+    man = manifest
     cell = man.cell(CELL)
     assert cell["chips"] == 1
     config, traffic = man.config(cell), man.traffic(cell)
@@ -110,12 +98,23 @@ def test_wide_cell_is_data_only():
         "order_leaves", "trace_seconds")} == {
             "rounds_per_call": 1, "checked_rounds": 2, "sample_rows": 100000,
             "split_nodes": 4, "order_leaves": 4, "trace_seconds": 20}
+    reported = {}
     for group in ("end_to_end", "per_layer"):
         names = {m["name"] for m in man.metrics_of(CELL, group)}
         assert names, group
         for m in man.doc[group]:
             if m["name"] in names and "workloads" in m:
-                assert m["workloads"][-1] == CELL    # appended, at the end
+                assert CELL in m["workloads"]
+        reported[group] = names
+    assert reported["end_to_end"] == {"train_rows_rounds_per_s", "setup_s"}
+    # the program resolves f32 here: each pass is two bf16 kernel events,
+    # which only the hi/lo rooflines and the calls a round count as such
+    assert {"hist_wave_hilo_roofline", "hist_root_hilo_roofline",
+            "hist_narrow_hilo_roofline", "wave_calls_per_round"} \
+        <= reported["per_layer"]
+    assert not {"hist_wave_roofline", "hist_root_roofline",
+                "hist_narrow_roofline", "wave_passes_per_round"} \
+        & reported["per_layer"]
 
 
 def test_every_metric_of_the_wide_cell_resolves_its_shapes(monkeypatch):
