@@ -355,8 +355,8 @@ def schema_digest(mapper: BinMapper) -> str:
     mapper: resume recomputes it from the offered Dataset and a mismatch
     is an *incompatible schema*, not corruption.  Covers the per-feature
     bound arrays bit-for-bit, the nan-bin layout, categorical flags, and
-    the EFB bundling (which remaps the training column space without
-    touching ``upper_bounds``).
+    the EFB bundling (a training-time layout the resumed rounds' codes
+    must share; the trees' splits are in the original features).
     """
     import hashlib
 

@@ -21,6 +21,7 @@ two give the same bytes.
 from __future__ import annotations
 
 import functools
+import hashlib
 import os
 import warnings
 from concurrent.futures import ThreadPoolExecutor
@@ -59,12 +60,14 @@ class FeatureBundler:
     number of BUNDLES, not features (upstream ``FindGroups``/``EFB`` in
     dataset construction; SURVEY.md §2C EFB row, BASELINE.md Criteo config).
 
-    TPU-native formulation: bundling is a pure host-side recoding at bin
-    time (uint8 in, uint8 out), so the device pipeline is unchanged — the
-    binned matrix just has fewer columns.  Splits are found on the merged
-    bin axis directly; a threshold inside member f's range separates f's
-    values (plus all earlier members on the left / later on the right),
-    a strict superset of the per-member thresholds upstream scans.
+    TPU-native formulation: bundling is a recoding at bin time (uint8 in,
+    uint8 out), made on the device with the codes where the table allows
+    it (:func:`device_bin_codes`), so the kernels histogram fewer columns.
+    A tree still splits on ONE original feature, as upstream's do: the
+    split scan reads each member's histogram out of its column's
+    (``ops.members``), and rows route by the range of merged codes that
+    the member's split sends left.  Trees, dumps and predictions are in
+    the original feature space; the bundles are a training-time layout.
 
     ``groups`` covers every original feature exactly once; singleton groups
     pass through unchanged.  Merged code layout per multi-feature group:
@@ -103,6 +106,11 @@ class FeatureBundler:
     def max_col_bins(self) -> int:
         return max(self.col_bins)
 
+    @property
+    def num_bundled(self) -> int:
+        """Original features that share their column with another."""
+        return sum(len(g) for g in self.groups if len(g) > 1)
+
     def merge(self, codes: np.ndarray) -> np.ndarray:
         """Original per-feature codes [n, F] -> bundled codes [n, B]."""
         out = np.zeros((codes.shape[0], len(self.groups)), np.uint8)
@@ -120,26 +128,37 @@ class FeatureBundler:
             out[:, c] = col.astype(np.uint8)
         return out
 
-    def split_to_original(self, cols: np.ndarray,
-                          bins: np.ndarray) -> np.ndarray:
-        """Map (bundled column, threshold bin) of tree splits back to the
-        original feature index (for feature_importance / model dumps).
-        A threshold inside member j's range is attributed to member j;
-        bin 0 (the all-default slot) attributes to the first member."""
-        cols = np.asarray(cols, np.int64)
-        bins = np.asarray(bins, np.int64)
-        out = np.empty_like(cols)
+    def conflict_rows(self, codes: np.ndarray) -> int:
+        """Rows of original codes [n, F] on which two members of one
+        bundle are off their default bins."""
+        hit = np.zeros(codes.shape[0], bool)
+        for g in self.groups:
+            if len(g) > 1:
+                nd = codes[:, g] != self.default_bins[g][None, :]
+                hit |= np.count_nonzero(nd, axis=1) > 1
+        return int(np.count_nonzero(hit))
+
+    def device_tables(self) -> dict:
+        """Slot tables of :func:`device_bin_codes`' merge, ``[B, J]`` for J
+        the largest group: slot j of column c is its j-th member (``feat``),
+        whose non-default codes land at ``off`` + code - (code > ``dflt``)
+        and whose default gives way to the slots before it; a column of one
+        feature is its feature's codes (``dflt`` past every code), the
+        unused slots have ``valid`` 0.  Members merge in group order, so a
+        conflicting row keeps the last one's code, as :meth:`merge`."""
+        width = max(len(g) for g in self.groups)
+        shape = (len(self.groups), width)
+        feat = np.zeros(shape, np.int32)
+        off = np.zeros(shape, np.int32)
+        dflt = np.full(shape, 1 << 16, np.int32)
+        valid = np.zeros(shape, bool)
         for c, g in enumerate(self.groups):
-            m = cols == c
-            if not m.any():
-                continue
-            if len(g) == 1:
-                out[m] = g[0]
-            else:
-                j = np.searchsorted(self.offsets[c], bins[m],
-                                    side="right") - 1
-                out[m] = np.asarray(g)[np.clip(j, 0, len(g) - 1)]
-        return out
+            feat[c, :len(g)] = g
+            valid[c, :len(g)] = True
+            if len(g) > 1:
+                off[c, :len(g)] = self.offsets[c]
+                dflt[c, :len(g)] = self.default_bins[g]
+        return dict(feat=feat, off=off, dflt=dflt, valid=valid)
 
     @staticmethod
     def fit(codes: np.ndarray, n_bins: np.ndarray,
@@ -153,6 +172,14 @@ class FeatureBundler:
         ``sparse_threshold``, LightGBM's kSparseThreshold) are candidates;
         returns None when no multi-feature bundle forms (bundling dense
         data would only distort histograms for zero gain).
+
+        Candidates are placed most non-default first, ties broken by a
+        digest of the column's sample codes and then its bin count, never
+        by its position: any order of a table's columns gives the same
+        bundles, in that order (a column identical to another may trade
+        places with it).  Each is placed into the first bundle it fits,
+        its conflicts with every bundle counted at once over bit-packed
+        rows.
         """
         n, num_features = codes.shape
         if num_features < 3:
@@ -169,32 +196,41 @@ class FeatureBundler:
             eligible &= ~np.asarray(exclude, bool)
         budget = max_conflict_rate * ns
 
-        order = np.argsort(-nd_count)
-        bundles: List[dict] = []
-        for f in order:
-            f = int(f)
-            if not eligible[f]:
-                continue
-            placed = False
-            for b in bundles:
-                extra = int(np.count_nonzero(b["occ"] & nondef[:, f]))
-                if (b["conflicts"] + extra <= budget
-                        and b["bins"] + int(n_bins[f]) - 1 <= max_merged_bins):
-                    b["members"].append(f)
-                    b["occ"] |= nondef[:, f]
-                    b["conflicts"] += extra
-                    b["bins"] += int(n_bins[f]) - 1
-                    placed = True
-                    break
-            if not placed:
-                bundles.append({"members": [f], "occ": nondef[:, f].copy(),
-                                "conflicts": 0, "bins": 1 + int(n_bins[f]) - 1})
-        multi = [b for b in bundles if len(b["members"]) > 1]
+        cand = np.flatnonzero(eligible)
+        digest = np.array([int.from_bytes(hashlib.blake2b(
+            np.ascontiguousarray(samp[:, f]).tobytes(),
+            digest_size=8).digest(), "little") for f in cand], np.uint64)
+        order = cand[np.lexsort((np.asarray(n_bins)[cand], digest,
+                                 -nd_count[cand]))]
+        words = np.packbits(nondef[:, order], axis=0, bitorder="little")
+        words = np.ascontiguousarray(words.T)     # [candidates, ceil(ns/8)]
+        occ = np.zeros((len(order), words.shape[1]), np.uint8)
+        conflicts = np.zeros(len(order), np.int64)
+        bins = np.zeros(len(order), np.int64)
+        members: List[List[int]] = []
+        for i, f in enumerate(order):
+            f, nb = int(f), int(n_bins[f])
+            k = len(members)
+            extra = np.bitwise_count(occ[:k] & words[i]).sum(
+                axis=1, dtype=np.int64)
+            fits = np.flatnonzero((conflicts[:k] + extra <= budget)
+                                  & (bins[:k] + nb - 1 <= max_merged_bins))
+            if len(fits):
+                b = int(fits[0])
+                members[b].append(f)
+                conflicts[b] += extra[b]
+            else:
+                b = k
+                members.append([f])
+                bins[b] = 1
+            occ[b] |= words[i]
+            bins[b] += nb - 1
+        multi = [m for m in members if len(m) > 1]
         if not multi:
             return None
-        bundled_feats = {f for b in multi for f in b["members"]}
+        bundled_feats = {f for m in multi for f in m}
         groups = [[f] for f in range(num_features) if f not in bundled_feats]
-        groups += [sorted(b["members"]) for b in multi]
+        groups += [sorted(m) for m in multi]
         return FeatureBundler(groups, n_bins, default_bins)
 
 
@@ -311,11 +347,13 @@ class BinMapper:
 
     Two implementations assign codes, and give the same bytes.  The host loop
     (:meth:`_transform_unbundled`: one float64 ``searchsorted`` a feature)
-    takes any table, and is what ``transform`` (predict, serving,
-    ``from_blocks``) runs.  ``Dataset.construct`` hands a float32 table of at
-    least one row block (``CODE_BLOCK_VALUES``) whose columns are all numeric
-    and form no EFB bundle to :func:`device_bin_codes`, which counts on the
-    device the bounds below each value, from :meth:`device_tables`.
+    takes any table, and is what prediction, serving and ``from_blocks``
+    run: codes in the original feature space, the space trees split in.
+    ``Dataset.construct`` hands a float32 table of at least one row block
+    (``CODE_BLOCK_VALUES``) whose columns are all numeric to
+    :func:`device_bin_codes`, which counts on the device the bounds below
+    each value, from :meth:`device_tables`, and merges an EFB table's
+    bundles in the same program.
     """
 
     def __init__(self, upper_bounds: List[np.ndarray], nan_bin: np.ndarray,
@@ -433,8 +471,9 @@ class BinMapper:
         return np.asarray(ub, dtype=np.float64)
 
     def transform(self, X: np.ndarray) -> np.ndarray:
-        """Map raw features to bin codes uint8[n, F] (bundled columns when
-        EFB is active — the training and predict paths must agree)."""
+        """Map raw features to the training layout's codes uint8[n, C]:
+        bundled columns when EFB is active (prediction walks the original
+        features' codes, :meth:`_transform_unbundled`)."""
         codes = self._transform_unbundled(X)
         if self.bundler is not None:
             return self.bundler.merge(codes)
@@ -538,46 +577,96 @@ def code_block_rows(num_features: int) -> int:
     return max(rows // ROW_PAD_MULTIPLE, 1) * ROW_PAD_MULTIPLE
 
 
-@functools.partial(jax.jit, donate_argnums=0)
-def _write_code_block(codes, bits, at, edge_keys, nan_code):
-    """Codes of one row block (``bits[B, F]``: the bit patterns of its
-    float32 values) written into rows ``at...`` of ``codes``.  Integer
-    compares only; rows lie on the minor axis while the edges are counted."""
+def _block_codes(bits, edge_keys, nan_code):
+    """``int32[F, B]``: the codes of one row block (``bits[B, F]``: the bit
+    patterns of its float32 values).  Integer compares only; rows lie on
+    the minor axis while the edges are counted."""
     key = _order_keys(bits).T                                   # [F, B]
     above = key[:, None, :] > edge_keys[:, :, None]
     code = jnp.sum(above, axis=1, dtype=jnp.int32)
     is_nan = (key > _F32_INF) | (key < -_F32_INF)
-    code = jnp.where(is_nan, nan_code[:, None], code)
+    return jnp.where(is_nan, nan_code[:, None], code)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_code_block(codes, bits, at, edge_keys, nan_code):
+    """One row block's codes written into rows ``at...`` of ``codes``."""
+    code = _block_codes(bits, edge_keys, nan_code)
     codes = jax.lax.dynamic_update_slice(
         codes, code.T.astype(jnp.uint8), (at, 0))
     return codes, at
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_bundled_block(codes, bits, at, skip, edge_keys, nan_code, slots):
+    """:func:`_write_code_block` for a bundled table: the block's codes of
+    every original feature merged into its columns in the same program,
+    slot by slot of ``FeatureBundler.device_tables`` (``slots``), and the
+    block's rows from ``skip`` on on which two members of one column are
+    off their defaults counted (the last block overlaps the one before)."""
+    code = _block_codes(bits, edge_keys, nan_code)
+
+    def slot(j, carry):
+        merged, nondefault = carry
+        member = code[slots["feat"][:, j]]                      # [C, B]
+        d = slots["dflt"][:, j, None]
+        on = (member != d) & slots["valid"][:, j, None]
+        merged = jnp.where(on, slots["off"][:, j, None] + member
+                           - (member > d), merged)
+        return merged, nondefault + on
+
+    zero = jnp.zeros((slots["feat"].shape[0], code.shape[1]), jnp.int32)
+    merged, nondefault = jax.lax.fori_loop(
+        0, slots["feat"].shape[1], slot, (zero, zero))
+    rows_new = jnp.arange(code.shape[1]) >= skip
+    conflicts = jnp.sum(jnp.any(nondefault > 1, axis=0) & rows_new,
+                        dtype=jnp.int32)
+    codes = jax.lax.dynamic_update_slice(
+        codes, merged.T.astype(jnp.uint8), (at, 0))
+    return codes, conflicts
+
+
 def device_bin_codes(X: np.ndarray, mapper: BinMapper, n_pad: int):
-    """``uint8[n_pad, F]`` on the device: the bytes of
-    ``mapper._transform_unbundled(X)`` above ``n_pad - len(X)`` rows of
-    zeros, for a float32 ``X`` of at least one block's rows.
+    """``uint8[n_pad, C]`` on the device: the bytes of ``mapper.transform
+    (X)`` above ``n_pad - len(X)`` rows of zeros, for a float32 ``X`` of at
+    least one block's rows; a bundled table's columns are merged in the
+    block's own program.
 
     One program for one block shape; the last block ends at the last row
     and so overlaps the one before it.  A block's transfer is in flight
     while the block before it is coded, and a third is not sent before the
     first is done, so the table is never whole on the device.  Returns the
-    array (not waited for) and the number of blocks."""
+    array (not waited for), the number of blocks and, for a bundled table,
+    the device's count of rows with a conflict in some bundle (not waited
+    for; ``None`` without bundles)."""
     n, num_features = X.shape
     rows = code_block_rows(num_features)
     assert X.dtype == np.float32 and X.flags.c_contiguous and n >= rows
     edge_keys, nan_code = map(jnp.asarray, mapper.device_tables())
-    codes = jnp.zeros((n_pad, num_features), jnp.uint8)
+    bundler = mapper.bundler
+    slots = (None if bundler is None else
+             {k: jnp.asarray(v) for k, v in bundler.device_tables().items()})
+    width = num_features if bundler is None else bundler.num_columns
+    codes = jnp.zeros((n_pad, width), jnp.uint8)
     starts = list(range(0, n - rows, rows)) + [n - rows]
     done = []        # a token of each block in flight, two at most
-    for at in starts:
+    conflicts = None if slots is None else []
+    for i, at in enumerate(starts):
         if len(done) == 2:
             done.pop(0).block_until_ready()
-        bits = X[at:at + rows].view(np.int32)
-        codes, token = _write_code_block(codes, jax.device_put(bits), at,
-                                         edge_keys, nan_code)
+        bits = jax.device_put(X[at:at + rows].view(np.int32))
+        if slots is None:
+            codes, token = _write_code_block(codes, bits, at, edge_keys,
+                                             nan_code)
+        else:
+            skip = starts[i - 1] + rows - at if i else 0
+            codes, token = _write_bundled_block(
+                codes, bits, at, max(skip, 0), edge_keys, nan_code, slots)
+            conflicts.append(token)
         done.append(token)
-    return codes, len(starts)
+    if conflicts is not None:
+        conflicts = jnp.sum(jnp.stack(conflicts))
+    return codes, len(starts), conflicts
 
 
 def _to_2d_float_array(data: Any, keep_float32: bool = False) -> np.ndarray:
@@ -795,37 +884,54 @@ class Dataset:
                     categorical=cat_idx, seed=p.data_random_seed)
                 fields.update(self.bin_mapper.fit_counts)
             if p.enable_bundle:
-                with span("lgbtpu.dataset.bundle"):
+                with span("lgbtpu.dataset.bundle") as fields:
                     # whether a bundle forms is read from the leading rows
                     head = X[:FeatureBundler.SAMPLE_ROWS]
-                    self.bin_mapper.bundler = FeatureBundler.fit(
+                    bundler = self.bin_mapper.bundler = FeatureBundler.fit(
                         self.bin_mapper._transform_unbundled(head),
                         self.bin_mapper.n_bins,
                         max_conflict_rate=p.max_conflict_rate,
                         exclude=self.bin_mapper.is_categorical)
+                    fields.update(
+                        columns=num_features if bundler is None
+                        else bundler.num_columns,
+                        bundles=0 if bundler is None else sum(
+                            len(g) > 1 for g in bundler.groups),
+                        members=0 if bundler is None
+                        else bundler.num_bundled,
+                        sample_rows=len(head))
         mapper = self.bin_mapper
         self.raw_num_feature_ = num_features
         if mapper.bundler is not None:
             num_features = mapper.bundler.num_columns
             self.num_feature_ = num_features
+            profiling.note("dataset.bundle_columns", num_features)
+            profiling.note("dataset.bundled_features",
+                           mapper.bundler.num_bundled)
 
         n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
         # on a CPU backend the device is the host, and its loop the faster
         # program for it: XLA:CPU counts edges at a tenth of the loop's rate
-        on_device = (X.dtype == np.float32 and mapper.bundler is None
+        on_device = (X.dtype == np.float32
                      and not mapper.is_categorical.any()
-                     and n >= code_block_rows(num_features)
+                     and n >= code_block_rows(self.raw_num_feature_)
                      and jax.default_backend() != "cpu")
         path = "device" if on_device else "host"
         profiling.note("dataset.codes_path", path)
+        conflicts = None
         with span("lgbtpu.dataset.codes", path=path, blocks=0) as fields:
             if on_device:
-                self.X_binned, fields["blocks"] = device_bin_codes(
-                    X, mapper, n_pad)
+                self.X_binned, fields["blocks"], conflicts = \
+                    device_bin_codes(X, mapper, n_pad)
                 jax.block_until_ready(self.X_binned)
                 profiling.add("dataset.codes.device_rows", n)
             else:
-                codes = mapper.transform(X)
+                codes = mapper._transform_unbundled(X)
+                if mapper.bundler is not None:
+                    conflicts = mapper.bundler.conflict_rows(codes)
+                    codes = mapper.bundler.merge(codes)
+        if conflicts is not None:
+            profiling.note("dataset.bundle_conflict_rows", int(conflicts))
         with span("lgbtpu.dataset.put"):
             if not on_device:
                 pad = n_pad - n
@@ -1159,11 +1265,7 @@ class Dataset:
 
     @property
     def col_is_categorical(self) -> np.ndarray:
-        """Categorical flag per TRAINING column (post-EFB: bundled columns
-        are never categorical — categoricals are excluded from bundling)."""
+        """Categorical flag per original feature, the space every split is
+        in (categoricals are never bundled: each is a column of its own)."""
         self.construct()
-        raw = self.bin_mapper.is_categorical
-        b = self.bin_mapper.bundler
-        if b is None:
-            return np.asarray(raw, bool)
-        return np.array([len(g) == 1 and bool(raw[g[0]]) for g in b.groups])
+        return np.asarray(self.bin_mapper.is_categorical, bool)

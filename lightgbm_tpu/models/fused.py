@@ -251,6 +251,11 @@ def fused_cv_eligible(p: Params, feval, callbacks, train_set=None) -> bool:
         # streamed (BlockStore) Dataset has none — densify it first
         # (pipeline/daemon.py does) or take the host loop
         return False
+    if getattr(getattr(train_set, "bin_mapper", None), "bundler",
+               None) is not None:
+        # an EFB table grows through its member tables (ops.members),
+        # which the batch program does not carry: the host loop does
+        return False
     return True
 
 

@@ -29,6 +29,7 @@ from ..dataset import Dataset
 from ..metrics import get_metric
 from ..objectives import Objective, create_objective
 from ..ops.lookup import lookup_values
+from ..ops.members import member_view, members_of
 from ..ops.predict import predict_forest_binned, predict_tree_binned
 from ..ops.split import SplitContext
 from ..utils import profiling
@@ -144,6 +145,21 @@ def _grad_hess(obj: Objective, pred, y, w, groups):
 
 
 @functools.lru_cache(maxsize=None)
+def _member_scan_fn():
+    """The jitted member view + split scan of one node's bundle planes,
+    ``(planes, members, ctx, feature_mask) -> BestSplit``."""
+    from ..ops.split import find_best_split
+
+    @jax.jit
+    def scan(planes, members, ctx, feature_mask):
+        return find_best_split(member_view(planes, members), ctx,
+                               feature_mask, jnp.bool_(True),
+                               bins_minor=True)
+
+    return scan
+
+
+@functools.lru_cache(maxsize=None)
 def _group_grad_fn(obj_key: tuple):
     """The jitted lambda pass alone, ``(pred, y, w, groups) -> (g, h)``:
     the replicated pass of the data-parallel learner."""
@@ -152,7 +168,8 @@ def _group_grad_fn(obj_key: tuple):
 
 def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
                         hyper: HyperScalars, key, g, h, goss_k,
-                        renew_alpha, sample_key=None, renew_scale=None):
+                        renew_alpha, sample_key=None, renew_scale=None,
+                        members=None):
     """One compacted GOSS round (shared by the per-round and scanned paths
     — the two MUST stay in RNG lockstep for fused == host training).
 
@@ -202,7 +219,7 @@ def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
     stats = jnp.stack([g[idx] * wt, h[idx] * wt, live], axis=-1)
     tree, rl_c, passes = grow(bins_c, stats, fmask, hyper.ctx(),
                               hyper.max_depth, hyper.feature_fraction_bynode,
-                              key)
+                              key, members=members)
     if renew_alpha is not None:
         rw = w[idx] * wt
         if renew_scale is not None:
@@ -214,7 +231,7 @@ def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
     # 500k rows (r5 trace), ~10x the whole histogram work, and any
     # optimistic static bound is unsound under stalled waves
     new_pred = pred + hyper.learning_rate * predict_tree_binned(
-        tree, bins, None)
+        tree, bins, None, members)
     return tree, new_pred, passes
 
 
@@ -260,7 +277,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         # is a vmapped batch over the grower (SURVEY.md §7 batching design)
         @jax.jit
         def round_fn_mc(bins, y, w, bag, pred, feature_mask,
-                        hyper: HyperScalars, key, groups=None):
+                        hyper: HyperScalars, key, groups=None, members=None):
             g, h = _grad_hess(obj, pred, y, w, groups)    # [n, K]
             if is_goss:
                 bag = goss_bag(jax.random.fold_in(key, 0x7FFFFFFF), g, bag, hyper)
@@ -270,7 +287,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
                                    (bag > 0).astype(jnp.float32)], axis=-1)
                 return grow(bins, stats, feature_mask, hyper.ctx(),
                             hyper.max_depth, hyper.feature_fraction_bynode,
-                            kc)[:2]
+                            kc, members=members)[:2]
 
             return mc_round_update(grow_one, g, h,
                                    jax.random.split(key, num_class), pred,
@@ -282,11 +299,13 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
         @jax.jit
         def round_fn_goss(bins, y, w, bag, pred, feature_mask,
-                          hyper: HyperScalars, key, groups=None):
+                          hyper: HyperScalars, key, groups=None,
+                          members=None):
             g, h = _grad_hess(obj, pred, y, w, groups)
             return _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
-                goss_k, renew_alpha, renew_scale=renew_scale)[:2]
+                goss_k, renew_alpha, renew_scale=renew_scale,
+                members=members)[:2]
 
         return round_fn_goss
 
@@ -316,14 +335,15 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
     @jax.jit
     def round_fn(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
-                 key, groups=None):
+                 key, groups=None, members=None):
         with jax.named_scope("lgbtpu.grad"):
             g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
         tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
                                  hyper.max_depth,
-                                 hyper.feature_fraction_bynode, key)
+                                 hyper.feature_fraction_bynode, key,
+                                 members=members)
         if renew_alpha is not None:
             rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
             tree = renew_leaf_values(tree, row_leaf, y - pred, rw,
@@ -352,7 +372,9 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
     ``[n_rounds, 5, grow_leaves - 1]``, models.tree._PASS), which are not
     part of the model.  RNG streams match the host loop exactly (same
     fold_in(key, round_index) chain), so fused and host training produce
-    identical models.
+    identical models.  ``members`` (``ops.members.Members``) grows on an
+    EFB table's bundle columns; the feature mask is over the original
+    features.
     """
     obj = _rebuild_objective(obj_key)
     renew_alpha = getattr(obj, "renew_alpha", None)
@@ -362,8 +384,9 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
     @jax.jit
     def multi(bins, y, w, bag0, pred0, hyper: HyperScalars, round_key,
               bag_key, ff_key, row_mask, num_data, start_iter, bag_frac, ff,
-              groups=None):
-        num_features = bins.shape[1]
+              groups=None, members=None):
+        num_features = (bins.shape[1] if members is None
+                        else members.num_features)
 
         def body(carry, i):
             pred, bag = carry
@@ -389,7 +412,8 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
             if goss_k is not None:
                 tree, new_pred, passes = _goss_compact_round(
                     grow, bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
-                    goss_k, renew_alpha, renew_scale=renew_scale)
+                    goss_k, renew_alpha, renew_scale=renew_scale,
+                    members=members)
                 return (new_pred, bag), (tree, passes)
             with jax.named_scope("lgbtpu.grad"):
                 stats = jnp.stack(
@@ -397,7 +421,8 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
                     axis=-1)
             tree, row_leaf, passes = grow(bins, stats, fmask, hyper.ctx(),
                                           hyper.max_depth,
-                                          hyper.feature_fraction_bynode, rkey)
+                                          hyper.feature_fraction_bynode, rkey,
+                                          members=members)
             if renew_alpha is not None:
                 rw = (w * bag if renew_scale is None
                       else w * bag * renew_scale(y))
@@ -420,24 +445,27 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
 
 @functools.lru_cache(maxsize=None)
 def _tree_pred_fn(depth_cap: int, num_class: int = 1):
+    """``pred + shrink * tree(bins)``; ``members`` walks an EFB table's
+    bundle columns (a training or validation set's codes)."""
     if num_class > 1:
         @jax.jit
-        def add_tree_mc(pred, tree, bins, shrink):   # pred [n, K]
-            vals = jax.vmap(
-                lambda t: predict_tree_binned(t, bins, depth_cap))(tree)
+        def add_tree_mc(pred, tree, bins, shrink, members=None):
+            vals = jax.vmap(lambda t: predict_tree_binned(
+                t, bins, depth_cap, members))(tree)      # pred [n, K]
             return pred + shrink * vals.T
 
         return add_tree_mc
 
     @jax.jit
-    def add_tree(pred, tree, bins, shrink):
-        return pred + shrink * predict_tree_binned(tree, bins, depth_cap)
+    def add_tree(pred, tree, bins, shrink, members=None):
+        return pred + shrink * predict_tree_binned(tree, bins, depth_cap,
+                                                   members)
 
     return add_tree
 
 
 def _predict_forest_mc(forest, bins, shrink, inits, n_trees, depth_cap,
-                       start_iteration=0):
+                       start_iteration=0, members=None):
     """Per-class forest replay for multiclass tree stacks ([T, K, M]
     fields) -> raw scores [n, K].  The single shared implementation of the
     class-sliced predict_forest_binned loop (used by predict, the lazy rf
@@ -448,7 +476,7 @@ def _predict_forest_mc(forest, bins, shrink, inits, n_trees, depth_cap,
         jnp.float32(shrink),
         float(inits[c]) if np.ndim(inits) else float(inits),
         jnp.int32(n_trees), depth_cap,
-        start_iteration=jnp.int32(start_iteration))
+        start_iteration=jnp.int32(start_iteration), members=members)
         for c in range(k)]
     return jnp.stack(cols, axis=1)
 
@@ -629,6 +657,8 @@ class Booster:
     (r/gridsearchCV.R:63, bagging_boosting.ipynb:136).
     """
 
+    _members = None     # the training table's EFB bundles (ops.members)
+
     def __init__(self, params: Optional[Union[Dict[str, Any], Params]] = None,
                  train_set: Optional[Dataset] = None,
                  model_file: Optional[str] = None,
@@ -714,6 +744,11 @@ class Booster:
         self._base_lr = float(p.learning_rate)
         self._obj_key = _objective_static_key(self.obj, p)
         self._num_bins = ds.num_bins
+        # an EFB table's bundles as the grower and the walks over its codes
+        # read them (ops.members): operands, so every order of the table's
+        # columns runs one program; None for a table with no bundle.  Every
+        # per-feature key below is over the original features
+        self._members = members_of(ds.bin_mapper, self._num_bins)
         self._w_eff = ds.w  # 0 on padding rows already
         cats = np.flatnonzero(ds.col_is_categorical)
         self._cat_key = (
@@ -726,10 +761,7 @@ class Booster:
         # (code-review r2: a global [0, num_bins) draw starves
         # low-cardinality features of valid thresholds)
         if p.extra_trees:
-            bmm = ds.bin_mapper
-            colb = (bmm.bundler.col_bins if bmm.bundler is not None
-                    else [int(x) for x in bmm.n_bins])
-            self._nbins_key = tuple(int(x) for x in colb)
+            self._nbins_key = tuple(int(x) for x in ds.bin_mapper.n_bins)
         else:
             self._nbins_key = None
         self._grow_specs = {}
@@ -751,7 +783,7 @@ class Booster:
             from .feature_mask import FeatureScreener
 
             self._screener = FeatureScreener(
-                int(ds.num_feature_), p.screen_keep_ratio,
+                self._num_features(), p.screen_keep_ratio,
                 p.screen_ema_decay, p.screen_refresh_rounds)
             stash = getattr(self, "_screen_restore", None)
             if stash is not None:
@@ -774,10 +806,27 @@ class Booster:
                     "streamed (from_blocks) training — only 'data' "
                     "composes with the block loop (r19); falling back to "
                     "serial")
+        elif self._members is not None and p.tree_learner != "serial":
+            import warnings
+
+            warnings.warn(
+                f"tree_learner='{p.tree_learner}' does not take an "
+                "EFB-bundled table (its splits route by bundle ranges the "
+                "mesh learners do not carry); training serially — "
+                "construct the Dataset with params={'enable_bundle': False} "
+                "for the mesh learner", stacklevel=3)
         elif p.tree_learner == "feature":
             self._maybe_setup_fp()
         elif p.tree_learner in ("data", "voting"):
             self._maybe_setup_dp()
+
+    def _num_features(self) -> int:
+        """Original features: the space of every split, feature mask and
+        per-feature key (the training columns are fewer where EFB bundled
+        them)."""
+        if self._members is not None:
+            return self._members.num_features
+        return int(self.train_set.num_feature_)
 
     def _grow_spec(self, eff_rows: int) -> GrowSpec:
         """The static decisions of this booster's trees grown on
@@ -868,10 +917,11 @@ class Booster:
                 f"(unsupported key: {key})", key=key)
 
     def _resolve_monotone_constraints(self) -> Optional[tuple]:
-        """Map user ``monotone_constraints`` (per ORIGINAL feature) onto the
-        TRAINING columns (post-EFB), validating LightGBM's rules: the list
-        must cover every feature and categorical features cannot be
-        constrained (a category set has no order to be monotone in).
+        """User ``monotone_constraints`` (per ORIGINAL feature, the space
+        every split is in), validating LightGBM's rules: the list must
+        cover every feature and categorical features cannot be constrained
+        (a category set has no order to be monotone in).  A feature that
+        shares an EFB bundle takes no constraint.
 
         Returns a static tuple for the jit-compile cache, or None when no
         constraint is active.
@@ -890,21 +940,13 @@ class Booster:
                 raise ValueError(
                     f"monotone constraint on categorical feature {f} is "
                     "not supported (matching lightgbm)")
-        b = bm.bundler
-        if b is None:
-            return tuple(int(c) for c in mc)
-        train_mc = []
-        for g in b.groups:
-            if len(g) == 1:
-                train_mc.append(int(mc[g[0]]))
-            elif any(int(mc[f]) != 0 for f in g):
+        for g in getattr(bm.bundler, "groups", ()):
+            if len(g) > 1 and any(int(mc[f]) != 0 for f in g):
                 raise ValueError(
                     "monotone constraint on an EFB-bundled feature "
                     f"(bundle members {g}); pass enable_bundle=False "
                     "when constraining sparse features")
-            else:
-                train_mc.append(0)
-        return tuple(train_mc)
+        return tuple(int(c) for c in mc)
 
     @staticmethod
     def _raw_to_device(raw, n_pad: int):
@@ -942,13 +984,12 @@ class Booster:
 
     def _resolve_interaction_constraints(self) -> Optional[tuple]:
         """interaction_constraints (original-feature groups) -> static
-        group-membership over TRAINING columns.
+        group-membership over the original features (the space every split
+        is in).
 
         sklearn-HistGBDT convention: features in no listed group become
-        singleton groups (they can still split, alone).  An EFB bundle
-        column belongs to a group only if ALL its members do (a split on
-        the merged axis involves every member's default/non-default
-        structure)."""
+        singleton groups (they can still split, alone).  A listed feature
+        may not share an EFB bundle."""
         p = self.params
         ic = p.interaction_constraints
         if not ic:
@@ -962,30 +1003,17 @@ class Booster:
             raise ValueError(
                 f"interaction_constraints reference feature indices {bad} "
                 f"but the dataset has {f_orig} features")
-        for f in sorted(set(range(f_orig)) - listed):
-            groups.append({f})
-        b = bm.bundler
-        cols = ([tuple(g) for g in getattr(b, "groups", [])] if b is not None
-                else [(f,) for f in range(f_orig)])
-        member = [[1 if all(f in g for f in col_members) else 0
-                   for col_members in cols] for g in groups]
-        # EFB fallout: a multi-member bundle column whose members span
-        # groups belongs to no group and would be silently unsplittable
-        # (code-review r2).  If any member is LISTED the semantics are
-        # genuinely mixed -> reject; if all members are unlisted, the
-        # bundle becomes its own singleton group (its members are
-        # mutually-exclusive sparse features).
-        for c, col_members in enumerate(cols):
-            if any(member[g][c] for g in range(len(member))):
-                continue
-            if any(f in listed for f in col_members):
+        for g in getattr(bm.bundler, "groups", ()):
+            if len(g) > 1 and any(f in listed for f in g):
                 raise ValueError(
                     "interaction_constraints split an EFB bundle "
-                    f"(members {list(col_members)}); pass "
+                    f"(members {list(g)}); pass "
                     "params={'enable_bundle': False} on the Dataset "
                     "when constraining sparse features")
-            member.append([1 if i == c else 0 for i in range(len(cols))])
-        return tuple(tuple(row) for row in member)
+        for f in sorted(set(range(f_orig)) - listed):
+            groups.append({f})
+        return tuple(tuple(1 if f in g else 0 for f in range(f_orig))
+                     for g in groups)
 
     def _dp_merge_mode(self):
         """Resolve the row-sharded learners' histogram merge topology.
@@ -1403,22 +1431,12 @@ class Booster:
 
     @staticmethod
     def _same_binning(cur_m, prev_m) -> bool:
-        """Whether two bin mappers describe the SAME training column
-        space — identical bounds AND identical EFB bundling (bundling
-        remaps training columns without touching ``upper_bounds``)."""
-        same = (len(cur_m.upper_bounds) == len(prev_m.upper_bounds) and all(
+        """Whether two bin mappers give every original feature the SAME
+        bins (trees split on original features and their own bins, so EFB
+        bundling, a training-time layout, may differ)."""
+        return (len(cur_m.upper_bounds) == len(prev_m.upper_bounds) and all(
             len(a) == len(b) and np.allclose(a, b)
             for a, b in zip(cur_m.upper_bounds, prev_m.upper_bounds)))
-        cur_b = getattr(cur_m, "bundler", None)
-        prev_b = getattr(prev_m, "bundler", None)
-        if (cur_b is None) != (prev_b is None):
-            return False
-        if cur_b is not None and (
-                cur_b.groups != prev_b.groups
-                or not np.array_equal(cur_b.default_bins,
-                                      prev_b.default_bins)):
-            return False
-        return same
 
     def _rebase_and_replay(self, init_score) -> None:
         """Rebuild ``_pred_train`` from ``init_score`` and replay the
@@ -1471,7 +1489,7 @@ class Booster:
             add = _tree_pred_fn(self._depth_cap, self._num_class)
             for tree in self.trees:
                 self._pred_train = add(self._pred_train, tree, ds.X_binned,
-                                       shrink)
+                                       shrink, self._members)
 
     def _attach_continuation(self, ds: Dataset) -> None:
         """Attach a training Dataset to a deserialized Booster so
@@ -1491,10 +1509,9 @@ class Booster:
                 ds.bin_mapper, prev_m):
             raise ValueError(
                 "this Booster was saved under a different feature binning "
-                "than the offered Dataset (bin bounds / EFB bundling "
-                "differ); rebuild the Dataset with reference=<original "
-                "training Dataset> (or identical data) before continuing "
-                "training")
+                "than the offered Dataset (bin bounds differ); rebuild the "
+                "Dataset with reference=<original training Dataset> (or "
+                "identical data) before continuing training")
         loaded_init = self.init_score_
         loaded_iter = self._iter
         self.train_set = ds
@@ -1690,8 +1707,7 @@ class Booster:
                 # device, and leaving it there would reshard every round
                 from ..parallel.data_parallel import shard_rows
                 self._bag = shard_rows(self._dp_mesh, self._bag)
-        n_cols = int(ds.num_feature_)  # == X_binned.shape[1]; X_binned is
-        # None under streaming (the codes live in ds.block_store)
+        n_cols = self._num_features()
         base = None
         if screen_ids is not None:
             bm = np.zeros(n_cols, np.float32)
@@ -1734,6 +1750,11 @@ class Booster:
         if screener is not None:
             active_ids, _ = screener.plan()   # None on refresh rounds
         fmask = self._sample_bag_and_fmask(i, screen_ids=active_ids)
+        if self._members is not None:
+            # an EFB table's columns are not the screener's features: its
+            # active set masks the scan (the base mask above), and the
+            # kernels read every column
+            active_ids = None
         if active_ids is not None:
             # screened round: compact the mask to [F_active] — bins and
             # comms compact below per branch; exactly two program shapes
@@ -1914,7 +1935,8 @@ class Booster:
                         else self._screen_view(ds.X_binned, active_ids))
                 tree, new_pred = fn(bins, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
-                                    self._hyper, round_key, self._groups)
+                                    self._hyper, round_key, self._groups,
+                                    self._members)
         if active_ids is not None:
             # the tree grew in compacted space — gather the winner ids
             # back to GLOBAL features before anything downstream
@@ -1952,7 +1974,7 @@ class Booster:
             for idx, (name, vds, vpred) in enumerate(self._valid):
                 self._valid[idx] = (
                     name, vds, add_tree(vpred, tree, vds.X_binned,
-                                        jnp.float32(shrink)))
+                                        jnp.float32(shrink), self._members))
         self._iter += 1
         return False
 
@@ -2025,6 +2047,31 @@ class Booster:
                     self._forest_cache = None
                 k -= n_rounds
 
+    def _member_scan_call(self):
+        """``(fn, args)``: an EFB table's member view and split scan ALONE
+        (``ops.members.member_view`` + ``ops.split.find_best_split``, as the
+        grower runs them on a node) on a real node histogram: the root's,
+        at the booster's current scores, taken once here; the sparse
+        cell's probe times the pair.  ``None`` for a table with no
+        bundle."""
+        if self._members is None:
+            return None
+        from ..ops.histogram import compute_histograms
+
+        ds = self.train_set
+        g, h = _grad_hess(self.obj, self._pred_train, ds.y, self._w_eff,
+                          self._groups)
+        bag = self._bag
+        stats = jnp.stack([g * bag, h * bag, (bag > 0).astype(jnp.float32)],
+                          axis=-1)
+        hist = compute_histograms(ds.X_binned, stats,
+                                  jnp.zeros(stats.shape[0], jnp.int32), 1,
+                                  self._num_bins)[0]             # [C, B, 3]
+        planes = jnp.moveaxis(hist, -1, 0)
+        return _member_scan_fn(), (
+            planes, self._members, self._hyper.ctx(),
+            jnp.ones(self._num_features(), jnp.float32))
+
     def _group_grad_call(self):
         """``(fn, args)``: a group objective's jitted lambda pass ALONE and
         its operands at the booster's current scores (the replicated pass
@@ -2078,6 +2125,7 @@ class Booster:
                 ("hist_dtype", hist_dtype), ("rows_padded", eff_rows),
                 ("num_bins", self._num_bins),
                 ("features", features),
+                ("features_raw", self._num_features()),
                 ("feature_blocks", n_fblk),
                 ("features_padded", n_fblk * f_blk),
                 ("feature_rows_looped", (n_fblk - 1) * f_blk + tail),
@@ -2102,7 +2150,7 @@ class Booster:
             jax.random.PRNGKey(p.feature_fraction_seed + p.seed),
             ds.row_mask, jnp.float32(ds.num_data_), jnp.int32(self._iter),
             jnp.float32(p.bagging_fraction),
-            jnp.float32(p.feature_fraction), self._groups)
+            jnp.float32(p.feature_fraction), self._groups, self._members)
 
     def _dart_round(self) -> bool:
         """One DART boosting round (upstream dart.hpp semantics).
@@ -2151,9 +2199,11 @@ class Booster:
             def dropped_sum(bins):
                 if nc > 1:  # [k, K, M] stacked trees -> [n, K] summed raw
                     return _predict_forest_mc(stack, bins, 1.0, 0.0, k,
-                                              self._depth_cap)
+                                              self._depth_cap,
+                                              members=self._members)
                 return predict_forest_binned(
-                    stack, bins, 1.0, 0.0, jnp.int32(k), self._depth_cap)
+                    stack, bins, 1.0, 0.0, jnp.int32(k), self._depth_cap,
+                    members=self._members)
 
             drop_sum = dropped_sum(ds.X_binned)
 
@@ -2166,7 +2216,8 @@ class Booster:
                        None, None)
         round_key = jax.random.fold_in(self._key, i)
         tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff, self._bag, pred,
-                            fmask, self._hyper, round_key, self._groups)
+                            fmask, self._hyper, round_key, self._groups,
+                            self._members)
 
         if k > 0:
             # upstream Normalize(): on drop rounds the new tree's weight is
@@ -2202,8 +2253,8 @@ class Booster:
         self.trees.append(tree)
         self._forest_cache = None
         for idx, (name, vds, vpred) in enumerate(self._valid):
-            self._valid[idx] = (name, vds,
-                                add(vpred, tree, vds.X_binned, lr))
+            self._valid[idx] = (name, vds, add(vpred, tree, vds.X_binned, lr,
+                                               self._members))
         self._iter += 1
         return False
 
@@ -2281,10 +2332,12 @@ class Booster:
             if self._num_class > 1:
                 return _predict_forest_mc(
                     forest, self.train_set.X_binned, 1.0 / self._iter,
-                    self.init_score_, self._iter, self.params.num_leaves)
+                    self.init_score_, self._iter, self.params.num_leaves,
+                    members=self._members)
             pred = predict_forest_binned(
                 forest, self.train_set.X_binned, 1.0 / self._iter,
-                self.init_score_, jnp.int32(self._iter), self.params.num_leaves)
+                self.init_score_, jnp.int32(self._iter), self.params.num_leaves,
+                members=self._members)
             return pred
         return self._pred_train
 
@@ -2325,7 +2378,7 @@ class Booster:
             add_tree = _tree_pred_fn(self._depth_cap, k)
             for tree in self.trees:
                 vpred = add_tree(vpred, tree, data.X_binned,
-                                 jnp.float32(shrink))
+                                 jnp.float32(shrink), self._members)
         self._valid.append((name, data, vpred))
         return self
 
@@ -2400,7 +2453,8 @@ class Booster:
                 "(matching lightgbm)")
         from ..dataset import _to_2d_float_array
         X = _to_2d_float_array(data)
-        codes = self._bin_mapper_for_predict().transform(X)
+        # trees split on original features: their codes, unbundled
+        codes = self._bin_mapper_for_predict()._transform_unbundled(X)
         bins = jnp.asarray(codes)
         if pred_leaf:
             forest = self._stacked_forest()
@@ -2464,15 +2518,13 @@ class Booster:
     def _pred_contrib(self, bins, start: int, num: int) -> np.ndarray:
         """Exact TreeSHAP contributions over the selected trees.
 
-        Reported per ORIGINAL feature (EFB bundle splits resolved through
-        the bundle map); the bias column carries the per-tree expected
-        values plus the init score, so rows sum to the raw prediction.
+        Reported per ORIGINAL feature (every split is on one); the bias
+        column carries the per-tree expected values plus the init score,
+        so rows sum to the raw prediction.
         """
         from ..ops.shap import forest_pred_contrib
 
-        bm = self._bin_mapper_for_predict()
-        f_orig = bm.num_features
-        bundler = bm.bundler
+        f_orig = self._bin_mapper_for_predict().num_features
         p = self.params
         k = self._num_class
         sel = self.trees[start:start + num]
@@ -2494,8 +2546,7 @@ class Booster:
         outs = []
         for c in range(k):
             tree_dicts = [to_np(t, c if k > 1 else None) for t in sel]
-            phi = forest_pred_contrib(tree_dicts, bins, f_orig, shrink,
-                                      bundler=bundler)
+            phi = forest_pred_contrib(tree_dicts, bins, f_orig, shrink)
             if is_rf and len(sel) > 0:
                 phi /= len(sel)
             init = (float(self.init_score_[c]) if k > 1
@@ -2572,12 +2623,6 @@ class Booster:
         # have a child written (unused slots keep left == -1)
         used = (~np.asarray(forest.is_leaf).ravel()
                 & (np.asarray(forest.left).ravel() >= 0))
-        bundler = getattr(self._bin_mapper_for_predict(), "bundler", None)
-        if bundler is not None:
-            # splits reference EFB bundle columns; attribute each to the
-            # original feature whose bin range holds the threshold
-            bins_thr = np.asarray(forest.split_bin).ravel()
-            feats = bundler.split_to_original(feats, bins_thr)
         vals = (np.ones_like(gains) if importance_type == "split" else gains)
         np.add.at(out, feats[used], vals[used])
         if importance_type == "split":
@@ -2631,10 +2676,12 @@ class Booster:
             add = _tree_pred_fn(self._depth_cap, self._num_class)
             if not is_rf:  # rf keeps _pred_train at init score
                 self._pred_train = add(
-                    self._pred_train, tree, self.train_set.X_binned, -shrink)
+                    self._pred_train, tree, self.train_set.X_binned, -shrink,
+                    self._members)
             for idx, (name, vds, vpred) in enumerate(self._valid):
                 self._valid[idx] = (
-                    name, vds, add(vpred, tree, vds.X_binned, -shrink))
+                    name, vds, add(vpred, tree, vds.X_binned, -shrink,
+                                   self._members))
         return self
 
     # -- persistence (full model dump lands with utils.serialize) --------
@@ -2676,7 +2723,8 @@ class Booster:
         y = jnp.asarray(np.asarray(label, np.float32))
         w = (jnp.ones_like(y) if weight is None
              else jnp.asarray(np.asarray(weight, np.float32)))
-        codes = jnp.asarray(self._bin_mapper_for_predict().transform(X))
+        codes = jnp.asarray(
+            self._bin_mapper_for_predict()._transform_unbundled(X))
         p = self.params
         lam = jnp.float32(p.lambda_l2)
         decay = jnp.float32(decay_rate)

@@ -32,6 +32,8 @@ from jax import lax
 
 from ..ops.histogram import compute_histograms, histogram_merge, histogram_psum
 from ..ops.lookup import lookup_rows, lookup_values
+from ..ops.members import go_left as member_go_left
+from ..ops.members import member_view, split_route
 from ..ops.split import (
     BestSplit,
     CatInfo,
@@ -593,6 +595,9 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
     arrays become constants the traced bodies close over.
     ``cat_info_for(num_features)`` replaces the CatInfo built from
     ``spec.cat_key`` (the feature-sharded learner slices its own).
+    ``members`` (``ops.members.Members``, an operand of the caller's
+    program) grows on an EFB table's bundle columns; every per-feature key
+    of the spec is over the original features.
     """
     mono = (None if spec.mono_key is None
             else jnp.asarray(spec.mono_key, jnp.int32))
@@ -603,15 +608,17 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
     if cat_info_for is None:
         cat_info_for = functools.partial(build_cat_info, spec.cat_key)
 
-    def grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode, key):
+    def grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode, key,
+             members=None):
         return grow_tree_logged(
             bins, stats, feature_mask, ctx, spec.num_leaves, spec.num_bins,
             max_depth, ff_bynode=None if spec.bynode_off else ff_bynode,
             key=key, hist_impl=spec.hist_impl, row_chunk=spec.row_chunk,
             hist_dtype=spec.hist_dtype, wave=spec.wave,
-            cat_info=cat_info_for(bins.shape[1]), mono=mono,
-            extra_trees=spec.extra_trees, col_bins=col_bins,
-            ic_member=ic_member, **placement)
+            cat_info=cat_info_for(bins.shape[1] if members is None
+                                  else members.num_features),
+            mono=mono, extra_trees=spec.extra_trees, col_bins=col_bins,
+            ic_member=ic_member, members=members, **placement)
 
     return grow
 
@@ -649,6 +656,7 @@ def grow_tree_logged(
     voting_k: int = 0,
     hist_wire: str = "f32",
     merge_chunks: int = 4,
+    members=None,
 ) -> Tuple[Tree, jnp.ndarray, jnp.ndarray]:
     """Grow one best-first tree.
 
@@ -704,6 +712,12 @@ def grow_tree_logged(
         per-chunk split scans (r10 comm/compute overlap); ``hist_wire``
         (``"f32"``/``"bf16"``/``"int8"``) compresses ring-hop messages —
         f32 keeps the exactness bar, bf16/int8 are quality-gated.
+      members: ``ops.members.Members`` when ``bins`` holds an EFB table's
+        bundle columns: histograms stay in bundle space, the scan reads the
+        member view (``feature_mask``, ``mono``, ``col_bins``, ``ic_member``
+        and ``cat_info`` are over the original features) and a split is
+        (original feature, its own bin), routed by range.  The mesh
+        learners take no bundled table.
 
     Returns:
       (Tree, row_leaf, passes) — row_leaf gives each training row's final
@@ -716,6 +730,10 @@ def grow_tree_logged(
     large-data fast path); its tail policy is the schedule's
     (:func:`~lightgbm_tpu.models.spec.resolve_wave`).
     """
+    if members is not None and (axis_name is not None or fp_axis is not None):
+        raise ValueError(
+            "the mesh learners do not take an EFB-bundled table: construct "
+            "the Dataset with params={'enable_bundle': False}")
     if wave.width > 1 and not (fp_axis is not None and cat_info is not None):
         # (frontier + feature-parallel since r5; categorical k-vs-rest
         # splits under fp keep the strict grower's psum-broadcast path)
@@ -727,8 +745,10 @@ def grow_tree_logged(
             col_bins=col_bins, ic_member=ic_member, fp_axis=fp_axis,
             fuse_partition=fuse_partition, hist_merge=hist_merge,
             n_shards=n_shards, voting_k=voting_k, hist_wire=hist_wire,
-            merge_chunks=merge_chunks)
+            merge_chunks=merge_chunks, members=members)
     n, num_features = bins.shape
+    if members is not None:
+        num_features = members.num_features
     capacity = 2 * num_leaves - 1
     max_depth = jnp.asarray(max_depth, jnp.int32)
     neg_inf = jnp.float32(-jnp.inf)
@@ -768,7 +788,7 @@ def grow_tree_logged(
     # body — what tests and chip_smoke.py compare the kernel with.
     fuse_si = (fuse_split and cat_info is None and mono is None
                and not extra_trees and ic_member is None and bynode_off
-               and fp_axis is None and not dist_mode)
+               and fp_axis is None and not dist_mode and members is None)
 
     # per-node column subsample: the ONE shared mask-composition layer
     # (models.feature_mask, r20) — bynode draws WITHIN the tree mask,
@@ -799,6 +819,14 @@ def grow_tree_logged(
         return histogram_merge(h, axis_name, mode=hist_merge,
                                n_shards=n_shards, wire_dtype=hist_wire,
                                n_chunks=merge_chunks)
+
+    def scan_view(h):
+        """``[..., F, B, 3]`` -> what the scan reads and its layout flag:
+        the member view's planes for a bundled table, else as it is."""
+        if members is None:
+            return h, False
+        with jax.named_scope("lgbtpu.wave.members"):
+            return member_view(jnp.moveaxis(h, -1, -3), members), True
 
     # ---- root -------------------------------------------------------------
     # under rs the merged root_hist is this shard's [F_pad/D, B, 3] slice;
@@ -834,10 +862,12 @@ def grow_tree_logged(
                 jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
                 None if rb0 is None else rb0[None]))
         else:
-            root_best = find_best_split(root_hist, ctx, root_mask,
+            root_view, minor = scan_view(root_hist)
+            root_best = find_best_split(root_view, ctx, root_mask,
                                         jnp.bool_(True), cat_info, mono=mono,
                                         parent_out=root_out,
-                                        rand_bins=node_rand_bins(0))
+                                        rand_bins=node_rand_bins(0),
+                                        bins_minor=minor)
         if fp_axis is not None:
             root_best = _fp_reduce_best(root_best, fp_axis, num_features)
 
@@ -949,13 +979,19 @@ def grow_tree_logged(
             # 2. partition rows of the split leaf (gather, no pointer chasing).
             if fp_axis is not None:
                 col = _fp_column(bins_i32, feat, fp_axis, num_features)
-            else:
+                below = col <= thr
+            elif members is None:
                 col = jnp.take(bins_i32, feat, axis=1)
+                below = col <= thr
+            else:
+                rcol, rlo, rhi, rinv = split_route(members, feat, thr)
+                col = jnp.take(bins_i32, rcol, axis=1)
+                below = member_go_left(col, rlo, rhi, rinv)
             if cat_info is None:
-                go_left = col <= thr
+                go_left = below
             else:
                 go_left = jnp.where(row[K.CAND_CAT] > 0.5,
-                                    st.cand_catmask[leaf][col], col <= thr)
+                                    st.cand_catmask[leaf][col], below)
             new_rl = jnp.where(
                 st.row_leaf == leaf, jnp.where(go_left, nl, nr), st.row_leaf)
             row_leaf = jnp.where(active, new_rl, st.row_leaf)
@@ -993,20 +1029,22 @@ def grow_tree_logged(
                                 child_lo, child_hi, child_out, child_rand)
             elif extra_trees:
                 child_rand = jnp.stack([node_rand_bins(nl), node_rand_bins(nr)])
+                view2, minor = scan_view(hist2)
 
                 def score(h, m, lo_, hi_, po, rb):
                     return find_best_split(h, ctx, m, depth_ok, cat_info, mono,
-                                           lo_, hi_, po, rb)
+                                           lo_, hi_, po, rb, bins_minor=minor)
 
-                bs: BestSplit = jax.vmap(score)(hist2, child_masks, child_lo,
+                bs: BestSplit = jax.vmap(score)(view2, child_masks, child_lo,
                                                 child_hi, child_out, child_rand)
             else:
+                view2, minor = scan_view(hist2)
 
                 def score(h, m, lo_, hi_, po):
                     return find_best_split(h, ctx, m, depth_ok, cat_info, mono,
-                                           lo_, hi_, po)
+                                           lo_, hi_, po, bins_minor=minor)
 
-                bs = jax.vmap(score)(hist2, child_masks, child_lo, child_hi,
+                bs = jax.vmap(score)(view2, child_masks, child_lo, child_hi,
                                      child_out)
             if fp_axis is not None:
                 bs = jax.vmap(
@@ -1323,6 +1361,7 @@ def grow_tree_frontier(
     voting_k: int = 0,
     hist_wire: str = "f32",
     merge_chunks: int = 4,
+    members=None,
 ) -> Tuple[Tree, jnp.ndarray, jnp.ndarray]:
     """Best-first growth in WAVES: up to ``wave.width`` splits per data pass.
 
@@ -1368,8 +1407,15 @@ def grow_tree_frontier(
     Returns ``(Tree, row_leaf, passes)``: ``passes`` logs every wave pass
     after the root's (:class:`_PASS`), so that the rows a pass streams can
     be set against the rows its splits needed.
+
+    With ``members`` (an EFB table, :func:`grow_tree_logged`) the kernels,
+    the cache and the subtraction work on the ``num_cols`` bundle columns
+    and the scan on the member view of the original features; a wave's
+    splits route by the ranges :func:`~lightgbm_tpu.ops.members.
+    split_route` gives them.
     """
-    n, num_features = bins.shape
+    n, num_cols = bins.shape
+    num_features = num_cols if members is None else members.num_features
     exact = wave.tail == "exact"
     grow_leaves, w_width = wave_extent(wave, num_leaves)
     capacity = 2 * grow_leaves - 1
@@ -1393,7 +1439,7 @@ def grow_tree_frontier(
     # more than one VMEM feature block: the kernel routes rows by wave rank
     # from the gathered code rows of the wave's split features and never
     # reads a feature id from the per-row table
-    wave_f_blk, wave_f_blocks = _vmem_blocking(num_features, num_bins,
+    wave_f_blk, wave_f_blocks = _vmem_blocking(num_cols, num_bins,
                                                3 * w_width)[:2]
     multi_block = wave_f_blocks > 1
     fuse_part = (fuse_partition and fp_axis is None and cat_info is None
@@ -1404,7 +1450,7 @@ def grow_tree_frontier(
                  # offset, and the feature id where one block routes by
                  # it) must be an exact bf16 integer
                  and max(2 * w_width, num_bins) <= 256
-                 and (multi_block or num_features <= 256))
+                 and (multi_block or num_cols <= 256))
     max_depth = jnp.asarray(max_depth, jnp.int32)
     neg_inf = jnp.float32(-jnp.inf)
     if key is None:
@@ -1441,7 +1487,7 @@ def grow_tree_frontier(
         f_hist = merge_slice_width(num_features, n_shards, hist_merge,
                                    merge_chunks)
     else:
-        f_hist = num_features
+        f_hist = num_cols
 
     # shared mask-composition layer (models.feature_mask, r20): same
     # fold_in(key, node_id)-within-tree-mask draw as the strict grower
@@ -1483,6 +1529,13 @@ def grow_tree_frontier(
     def from_planes(h):
         return jnp.moveaxis(h, 1, -1)
 
+    def scan_view(planes):
+        """The planes the scan reads: the member view of a bundled table."""
+        if members is None:
+            return planes
+        with jax.named_scope("lgbtpu.wave.members"):
+            return member_view(planes, members)
+
     if fuse_part:
         # loop-invariant kernel operands prepared ONCE a tree (the in-call
         # pad/convert re-ran per wave, ~2.7 ms each at 11M — r5 trace); the
@@ -1511,7 +1564,7 @@ def grow_tree_frontier(
             root_hist = to_planes(merge(hist_fused_prepared(
                 bins_t_prep, stats_t_prep,
                 jnp.zeros((1, n_pad_rows), jnp.int32), 1, num_bins,
-                part_chunk, wave_f_blk, num_features,
+                part_chunk, wave_f_blk, num_cols,
                 hist_dtype=kernel_dtype, name=HIST_ROOT)))[0]
         else:
             root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1,
@@ -1542,8 +1595,9 @@ def grow_tree_frontier(
                 jnp.full((1,), jnp.inf, jnp.float32), root_out[None],
                 None if rb0 is None else rb0[None]))
         else:
-            root_best = find_best_split(root_hist, ctx, root_mask_f,
-                                        jnp.bool_(True), cat_info, mono=mono,
+            root_best = find_best_split(scan_view(root_hist), ctx,
+                                        root_mask_f, jnp.bool_(True),
+                                        cat_info, mono=mono,
                                         parent_out=root_out,
                                         rand_bins=node_rand_bins(0),
                                         bins_minor=True)
@@ -1685,14 +1739,26 @@ def grow_tree_frontier(
                 # the full-table compare was ~6 ms/wave at 11M rows.  Table
                 # values (sel/feat/thr/rank2/dl) are all <= 256 under the
                 # single-f-block gate, so the dot stays bf16-exact.
+                # a wave split's column and range (ops.members): rows 5-6
+                # carry lo and inv, zero for a plain column's ``v <= thr``
                 zw = jnp.zeros(width)
-                tbl_w = jnp.stack([active_r.astype(f32),
-                                   (zw if multi_block
-                                    else prow[:, K.CAND_FEAT]),
-                                   prow[:, K.CAND_BIN],
-                                   (2 * iota_w).astype(f32),
-                                   direct_left.astype(f32), zw, zw, zw],
-                                  axis=1)                        # [W, 8]
+                if members is None:
+                    tbl_w = jnp.stack([active_r.astype(f32),
+                                       (zw if multi_block
+                                        else prow[:, K.CAND_FEAT]),
+                                       prow[:, K.CAND_BIN],
+                                       (2 * iota_w).astype(f32),
+                                       direct_left.astype(f32), zw, zw, zw],
+                                      axis=1)                    # [W, 8]
+                else:
+                    wcol, wlo, whi, winv = split_route(
+                        members, prow[:, K.CAND_FEAT].astype(jnp.int32),
+                        prow[:, K.CAND_BIN])
+                    tbl_w = jnp.stack([active_r.astype(f32),
+                                       zw if multi_block else wcol.astype(f32),
+                                       whi, (2 * iota_w).astype(f32),
+                                       direct_left.astype(f32), wlo,
+                                       winv.astype(f32), zw], axis=1)
                 oh_w = (parent_r[:, None] == p[None, :])         # [W, n]
                 pv_t = lax.dot_general(
                     tbl_w.astype(f32).T, oh_w.astype(f32),
@@ -1706,8 +1772,9 @@ def grow_tree_frontier(
                     part_chunk, hist_dtype=kernel_dtype,
                     # multi-f-block routing gathers the wave split features'
                     # code rows; ignored on single-block shapes
-                    wfeat=prow[:, K.CAND_FEAT].astype(jnp.int32),
-                    num_features=num_features, name=role, f_blk=wave_f_blk)
+                    wfeat=(prow[:, K.CAND_FEAT].astype(jnp.int32)
+                           if members is None else wcol),
+                    num_features=num_cols, name=role, f_blk=wave_f_blk)
                 # the kernel's direct_hist is the LOCAL pre-merge partial,
                 # planes [W, 3, F, B]: every merge topology applies after it
                 # unchanged (voting keeps it unmerged for the scorer's
@@ -1722,11 +1789,20 @@ def grow_tree_frontier(
                 # whenever the (overgrown) capacity does, which would force
                 # the HIGHEST-precision dot below.  child = n_nodes + offset
                 # reconstructs the absolute id after the lookup.
-                cols = [sel.astype(f32), P[:, K.CAND_FEAT],
-                        P[:, K.CAND_BIN], (2 * rank).astype(f32),
-                        dl_of.astype(f32)]
+                if members is None:
+                    cols = [sel.astype(f32), P[:, K.CAND_FEAT],
+                            P[:, K.CAND_BIN], (2 * rank).astype(f32),
+                            dl_of.astype(f32)]
+                else:
+                    ncol, nlo, nhi, ninv = split_route(
+                        members, P[:, K.CAND_FEAT].astype(jnp.int32),
+                        P[:, K.CAND_BIN])
+                    cols = [sel.astype(f32), ncol.astype(f32), nhi,
+                            (2 * rank).astype(f32), dl_of.astype(f32)]
                 if cat_info is not None:
                     cols.append(P[:, K.CAND_CAT])
+                if members is not None:
+                    cols += [nlo, ninv.astype(f32)]
                 # DEFAULT precision (native-rate bf16 dot) is exact only while
                 # every table value is an integer <= 256 (bf16 has an 8-bit
                 # significand); feature ids beyond 256 need the full-precision
@@ -1736,7 +1812,7 @@ def grow_tree_frontier(
                 # GLOBAL feature ids whose range this shard cannot bound
                 # statically — always exact there.
                 exact_in_bf16 = (fp_axis is None
-                                 and max(num_features, 2 * width,
+                                 and max(num_cols, 2 * width,
                                          num_bins) <= 256)
                 pv = lookup_rows(p, jnp.stack(cols, axis=1),
                                  precision=(lax.Precision.DEFAULT
@@ -1760,10 +1836,13 @@ def grow_tree_frontier(
                         fp_axis)
                 else:
                     fmatch = (feat_r[:, None]
-                              == lax.iota(jnp.int32, num_features)[None, :])
+                              == lax.iota(jnp.int32, num_cols)[None, :])
                     v = jnp.sum(jnp.where(fmatch, bins_i32, 0), axis=1)
+                below = (v.astype(f32) <= thr_r if members is None
+                         else member_go_left(v.astype(f32), pv[:, -2],
+                                             thr_r, pv[:, -1] > 0))
                 if cat_info is None:
-                    go_left = v.astype(f32) <= thr_r
+                    go_left = below
                 else:
                     # category-subset membership: one-hot lookup of the row's
                     # mask row, then select bit v — both stay fused
@@ -1773,8 +1852,7 @@ def grow_tree_frontier(
                         jnp.where(v[:, None]
                                   == lax.iota(jnp.int32, num_bins)[None, :],
                                   mrow, 0.0), axis=1)
-                    go_left = jnp.where(pv[:, 5] > 0, bit > 0,
-                                        v.astype(f32) <= thr_r)
+                    go_left = jnp.where(pv[:, 5] > 0, bit > 0, below)
                 rank2_r = pv[:, 3].astype(jnp.int32)
                 child = st.n_nodes + rank2_r + jnp.where(go_left, 0, 1)
                 row_leaf = jnp.where(psel, child, p)
@@ -1871,16 +1949,16 @@ def grow_tree_frontier(
                                            lo_, hi_, po, rb, bins_minor=True)
 
                 bs: BestSplit = jax.vmap(score)(
-                    child_hists, child_masks, depth_ok, child_lo, child_hi,
-                    child_vals, child_rand)
+                    scan_view(child_hists), child_masks, depth_ok, child_lo,
+                    child_hi, child_vals, child_rand)
             else:
 
                 def score(h, m, d, lo_, hi_, po):
                     return find_best_split(h, ctx, m, d, cat_info, mono,
                                            lo_, hi_, po, bins_minor=True)
 
-                bs = jax.vmap(score)(child_hists, child_masks, depth_ok,
-                                     child_lo, child_hi, child_vals)
+                bs = jax.vmap(score)(scan_view(child_hists), child_masks,
+                                     depth_ok, child_lo, child_hi, child_vals)
             if fp_axis is not None:
                 # globalize all 2W child winners in one batched all_gather
                 bs = jax.vmap(

@@ -843,6 +843,14 @@ def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_features: int,
     _feature_loop(body, bins_ref.shape[0], num_features, fblock_axis)
 
 
+def _route(v, pv_ref, thr):
+    """Whether each row's code ``v`` goes left: inside ``[lo, thr]``
+    (``pv`` row 5), flipped where ``inv`` (row 6) — ``ops.members.go_left``
+    in the kernels' lanes."""
+    inside = (v >= pv_ref[5, :]) & (v <= thr)
+    return inside != (pv_ref[6, :] > 0.0)
+
+
 def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
                        num_features: int, num_bins: int, num_segments: int,
                        bins_minor: bool = False):
@@ -862,10 +870,14 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
     routing moves in here:
 
       pv_ref [8, chunk] f32 — per-row node fields from ONE transposed
-        lookup (rows: sel, feat, thr, rank2, direct-left; 3 zero pads);
+        lookup (rows: sel, feat, thr, rank2, direct-left, lo, inv; 1 zero
+        pad): the split's column, and the range of its codes that goes
+        left, inverted where ``inv`` (``ops.members``: an EFB member's
+        split; a plain column's is lo 0, not inverted);
       phase 1: v = bins[feat] via a fori_loop feature select (VMEM reads,
-        no HBM); go_left = v <= thr; seg = wave rank where the row moves
-        to its split's DIRECT (smaller) child, else num_segments;
+        no HBM); go_left = ((v >= lo) & (v <= thr)) != inv (:func:`_route`);
+        seg = wave rank where the row moves to its split's DIRECT
+        (smaller) child, else num_segments;
       enc_ref [1, chunk] i32 — 1 + rank2 + went-right for moved rows,
         0 otherwise (the caller adds the wave's traced node base);
       phase 2: the standard segment-folded one-hot dots, with seg now
@@ -893,7 +905,7 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
     v = lax.fori_loop(0, num_features, vbody, jnp.zeros((chunk,),
                                                         jnp.float32))
     psel = sel > 0.0
-    go_left = v <= thr
+    go_left = _route(v, pv_ref, thr)
     to_direct = psel & (go_left == (dl > 0.0))
     seg = jnp.where(to_direct, (rank2 * 0.5).astype(jnp.int32),
                     jnp.int32(w)).reshape(1, chunk)
@@ -946,7 +958,7 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
 
     v = lax.fori_loop(0, w, vbody, jnp.zeros((chunk,), jnp.float32))
     psel = sel > 0.0
-    go_left = v <= thr
+    go_left = _route(v, pv_ref, thr)
     to_direct = psel & (go_left == (dl > 0.0))
     seg = jnp.where(to_direct, (rank2 * 0.5).astype(jnp.int32),
                     jnp.int32(w)).reshape(1, chunk)

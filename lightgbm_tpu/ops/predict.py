@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .members import go_left, split_route
+
 DEFAULT_TREE_CHUNK = 32
 
 # --- fused predict mega-kernel (r18) constants ------------------------------
@@ -386,13 +388,40 @@ def predict_forest_pallas(
     return init_score + learning_rate * out[0, :n]
 
 
+def _walk_step(tree, bins, members):
+    """``node -> next node`` of one tree over binned rows: a split on
+    feature f at bin t reads the row's code of f and goes left iff it is
+    ``<= t``; over an EFB table's bundle columns (``members``) it reads
+    f's column and applies the split's range (``ops.members``)."""
+    if members is None:
+        col = tree.split_feature
+    else:
+        col, lo, hi, inv = split_route(members, tree.split_feature,
+                                       tree.split_bin)
+
+    def advance(node):
+        code = jnp.take_along_axis(bins, col[node][:, None], axis=1)[:, 0]
+        if members is None:
+            left = code <= tree.split_bin[node]
+        else:
+            left = go_left(code, lo[node], hi[node], inv[node])
+        if tree.is_cat_split is not None:
+            left = jnp.where(tree.is_cat_split[node],
+                             tree.cat_mask[node, code], left)
+        nxt = jnp.where(left, tree.left[node], tree.right[node])
+        return jnp.where(tree.is_leaf[node], node, nxt)
+
+    return advance
+
+
 def predict_tree_binned(tree, bins: jnp.ndarray,
-                        max_depth_cap=None) -> jnp.ndarray:
+                        max_depth_cap=None, members=None) -> jnp.ndarray:
     """Leaf value per row for one tensorized tree.
 
     Args:
       tree: Tree namedtuple of arrays (see models.tree.Tree).
-      bins: uint8/int32 [n, F] binned features.
+      bins: uint8/int32 [n, F] binned features (an EFB table's bundle
+        columns with ``members``, ``ops.members.Members``).
       max_depth_cap: static traversal depth bound (num_leaves is always
         safe; ``forest_depth_cap`` gives the tight bound).  ``None`` runs
         a convergence-checked ``while_loop`` instead — it iterates
@@ -411,18 +440,7 @@ def predict_tree_binned(tree, bins: jnp.ndarray,
     """
     n = bins.shape[0]
     bins = bins.astype(jnp.int32)
-
-    def advance(node):
-        feat = tree.split_feature[node]            # [n]
-        thr = tree.split_bin[node]                 # [n]
-        code = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]
-        left = code <= thr
-        if tree.is_cat_split is not None:
-            left = jnp.where(tree.is_cat_split[node],
-                             tree.cat_mask[node, code], left)
-        nxt = jnp.where(left, tree.left[node], tree.right[node])
-        return jnp.where(tree.is_leaf[node], node, nxt)
-
+    advance = _walk_step(tree, bins, members)
     node0 = jnp.zeros(n, dtype=jnp.int32)
     if max_depth_cap is None:
         capacity = tree.is_leaf.shape[-1]
@@ -471,11 +489,13 @@ def predict_forest_binned(
     max_depth_cap: int,
     start_iteration: jnp.ndarray = 0,
     tree_chunk: int = DEFAULT_TREE_CHUNK,
+    members=None,
 ) -> jnp.ndarray:
     """Sum of trees [start_iteration, start_iteration + num_iteration) —
     traced truncation, so staged prediction needs no recompilation.
 
-    forest: Tree namedtuple whose arrays have a leading [T] tree axis.
+    forest: Tree namedtuple whose arrays have a leading [T] tree axis;
+    ``members`` as for :func:`predict_tree_binned`.
     """
     n = bins.shape[0]
     num_trees = forest.leaf_value.shape[0]
@@ -495,18 +515,9 @@ def predict_forest_binned(
         lambda a: a.reshape((n_chunks, chunk) + a.shape[1:]), forest)
 
     def traverse_one(tree):
-        def step(node, _):
-            feat = tree.split_feature[node]
-            thr = tree.split_bin[node]
-            code = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]
-            left = code <= thr
-            if tree.is_cat_split is not None:
-                left = jnp.where(tree.is_cat_split[node],
-                                 tree.cat_mask[node, code], left)
-            nxt = jnp.where(left, tree.left[node], tree.right[node])
-            return jnp.where(tree.is_leaf[node], node, nxt), None
-
-        node, _ = lax.scan(step, jnp.zeros(n, jnp.int32), None,
+        advance = _walk_step(tree, bins, members)
+        node, _ = lax.scan(lambda nd, _: (advance(nd), None),
+                           jnp.zeros(n, jnp.int32), None,
                            length=max_depth_cap)
         return tree.leaf_value[node]
 

@@ -25,10 +25,8 @@ with dummy ``a = b = 1`` factors provably leaves every phi unchanged
 leaves share one static slot count and the whole forest is one ``lax.scan``
 over stacked per-tree tables.
 
-EFB note: contributions are reported per ORIGINAL feature — each edge's
-slot feature is resolved through the bundle map (a threshold inside member
-j's range is a test on j), so bundled training columns split their
-attribution exactly as the unbundled model would.
+Contributions are reported per ORIGINAL feature: every split is on one
+(EFB bundles are a training-time layout), and rows are coded unbundled.
 
 The checksum ``sum_i phi_i + phi_bias == raw prediction`` holds exactly
 (the product game telescopes); tests enforce it.
@@ -37,7 +35,7 @@ The checksum ``sum_i phi_i + phi_bias == raw prediction`` holds exactly
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 import jax
@@ -46,7 +44,6 @@ from jax import lax
 
 
 def tree_path_tables(t: Dict[str, np.ndarray], max_depth: int,
-                     node_orig: Optional[np.ndarray] = None,
                      ) -> Dict[str, np.ndarray]:
     """Host-side per-tree path decomposition (one pass over <= M nodes).
 
@@ -54,8 +51,6 @@ def tree_path_tables(t: Dict[str, np.ndarray], max_depth: int,
       t: numpy tree arrays (split_feature, split_bin, left, right,
         leaf_value, is_leaf, count, optionally is_cat_split + cat_mask).
       max_depth: pad target for the slot/edge axes (forest-wide max).
-      node_orig: optional i64 [M] per-node ORIGINAL feature id (EFB bundle
-        resolution, precomputed vectorized) for slot attribution.
 
     Returns arrays (D = E = max_depth):
       leaf_w    f32 [M]     leaf_value where is_leaf else 0
@@ -103,9 +98,8 @@ def tree_path_tables(t: Dict[str, np.ndarray], max_depth: int,
         feat_slot: Dict[int, int] = {}
         p_leaf = 1.0
         for e, (p, went_left, frac) in enumerate(edges):
-            col = int(t["split_feature"][p])
+            fid = col = int(t["split_feature"][p])
             thr = int(t["split_bin"][p])
-            fid = col if node_orig is None else int(node_orig[p])
             if fid not in feat_slot:
                 feat_slot[fid] = len(feat_slot)
                 uniq_feat[l, feat_slot[fid]] = fid
@@ -236,28 +230,21 @@ def _tree_depth(t: Dict[str, np.ndarray]) -> int:
 
 def forest_pred_contrib(trees: List[Dict[str, np.ndarray]],
                         bins: jnp.ndarray, num_features: int,
-                        shrink: np.ndarray,
-                        bundler=None) -> np.ndarray:
+                        shrink: np.ndarray) -> np.ndarray:
     """SHAP contributions for a list of numpy-ified trees.
 
     Args:
       trees: dicts of numpy tree arrays (same capacity M across the list).
-      bins: u8/i32 [n, F_train] binned rows.
+      bins: u8/i32 [n, F] binned rows, the original features' codes.
       num_features: width of the contribution matrix (ORIGINAL features).
       shrink: f32 [T] per-tree multiplier.
-      bundler: optional EFB FeatureBundler — per-node (column, bin) pairs
-        resolve to original feature ids in ONE vectorized call per tree.
 
     Returns f32 [n, num_features + 1]; last column is the expected value.
     """
     if not trees:
         return np.zeros((bins.shape[0], num_features + 1), np.float32)
     depth = max(max(_tree_depth(t) for t in trees), 1)
-    origs = [None] * len(trees)
-    if bundler is not None:
-        origs = [bundler.split_to_original(t["split_feature"],
-                                           t["split_bin"]) for t in trees]
-    tabs = [tree_path_tables(t, depth, o) for t, o in zip(trees, origs)]
+    tabs = [tree_path_tables(t, depth) for t in trees]
     has_cat = any("is_cat_split" in t and t["is_cat_split"] is not None
                   and np.any(t["is_cat_split"]) for t in trees)
     if has_cat:

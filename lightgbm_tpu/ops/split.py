@@ -135,8 +135,9 @@ def split_stats_valid(lc, rc, lh, rh, gain, ctx: SplitContext):
 class CatInfo(NamedTuple):
     """Static-per-dataset categorical split configuration.
 
-    ``is_cat`` marks the TRAINING columns (post-EFB) holding categorical
-    codes; the scalars mirror upstream ``cat_smooth`` / ``cat_l2`` /
+    ``is_cat`` marks the original features holding categorical codes (the
+    scan's features: an EFB table's scan reads its member view); the
+    scalars mirror upstream ``cat_smooth`` / ``cat_l2`` /
     ``max_cat_threshold`` (cat-specific regularization of the k-vs-rest
     subset search).
     """

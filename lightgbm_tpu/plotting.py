@@ -100,10 +100,6 @@ def plot_split_value_histogram(booster, feature, bins=None, ax=None,
     """Histogram of a feature's split THRESHOLD values across the forest
     (lightgbm.plot_split_value_histogram): where the model keeps cutting
     this feature.  ``feature`` is an index or a feature name.
-
-    EFB note: splits on a multi-feature bundle column carry merged-axis
-    bin indices, not raw values (``bundled_bin_threshold`` in dump_model)
-    — those nodes are excluded rather than plotted on a wrong axis.
     """
     b = getattr(booster, "_Booster", booster)
     names = b.feature_name()
@@ -119,8 +115,7 @@ def plot_split_value_histogram(booster, feature, bins=None, ax=None,
         if "leaf_value" in node:
             return
         if names[node["split_feature"]] == fname and \
-                node.get("decision_type", "<=") == "<=" and \
-                not node.get("bundled_bin_threshold"):
+                node.get("decision_type", "<=") == "<=":
             values.append(float(node["threshold"]))
         rec(node["left_child"])
         rec(node["right_child"])

@@ -354,7 +354,7 @@ class ModelBank:
             got = rt.predict(canary_X, raw_score=raw_score)
         except FaultError as e:
             raise SwapRejected("canary", f"device fault: {e}") from e
-        codes = packed.bin_mapper.transform(canary_X)
+        codes = packed.bin_mapper._transform_unbundled(canary_X)
         want = rt.oracle.predict_numpy(codes, raw_score=raw_score)
         if not np.all(np.isfinite(got)):
             raise SwapRejected("canary", "non-finite canary predictions")

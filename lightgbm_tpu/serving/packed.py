@@ -128,9 +128,7 @@ class PackedForest:
         right = self.right.reshape(-1, m)
         is_leaf = self.is_leaf.reshape(-1, m)
         vals = self.leaf_value.reshape(-1, m)
-        n_feat = self.num_feature()
-        bundler = getattr(self.bin_mapper, "bundler", None)
-        n_cols = (bundler.num_columns if bundler is not None else n_feat)
+        n_cols = self.num_feature()
         max_depth = 0
         for t in range(sf.shape[0]):
             visited = np.zeros(m, bool)

@@ -376,7 +376,7 @@ class MicroBatcher:
         self.stats.record_fallback(len(group))
         for r in group:
             try:
-                codes = mapper.transform(r.row[None, :])
+                codes = mapper._transform_unbundled(r.row[None, :])
                 out = packed.predict_numpy(codes, num_iteration=num_it,
                                            raw_score=self.raw_score)
                 r.pending._set(value=out[0])

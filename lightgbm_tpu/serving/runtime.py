@@ -288,7 +288,7 @@ class PredictorRuntime:
         from ..dataset import _to_2d_float_array
 
         X = _to_2d_float_array(data)
-        codes = self.packed.bin_mapper.transform(X)
+        codes = self.packed.bin_mapper._transform_unbundled(X)
         return self.predict_binned(codes, num_iteration=num_iteration,
                                    raw_score=raw_score)
 
@@ -413,10 +413,8 @@ class PredictorRuntime:
         edge transform produces, the f32 row mask, the round count."""
         import jax
 
-        bundler = getattr(self.packed.bin_mapper, "bundler", None)
-        n_cols = (bundler.num_columns if bundler is not None
-                  else self.packed.num_feature())
-        return (jax.ShapeDtypeStruct((bucket, n_cols), np.uint8),
+        return (jax.ShapeDtypeStruct((bucket, self.packed.num_feature()),
+                                     np.uint8),
                 jax.ShapeDtypeStruct((bucket,), np.float32),
                 jax.ShapeDtypeStruct((), np.int32))
 

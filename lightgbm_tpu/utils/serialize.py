@@ -170,18 +170,26 @@ def dump_booster_dict(booster, num_iteration: Optional[int] = None,
 
     Categorical subset splits dump ``decision_type: '=='`` with the LEFT
     category bin set; numeric splits dump ``decision_type: '<='`` with the
-    raw threshold.  When EFB is active, ``split_feature`` is mapped back to
-    the ORIGINAL feature space (matching ``feature_names``); thresholds on
-    multi-feature bundle columns stay in bundled-bin space and are marked
-    with ``"bundled_bin_threshold": true``.
+    raw threshold.  Every split is on one ORIGINAL feature (EFB bundles are
+    a training-time layout), so every threshold is a raw value.  Missing
+    values are told as the codes route them: where fit saw NaN in a feature
+    (``missing_type: "NaN"``) NaN has the last bin and goes right
+    (``default_left: false``); where it saw none (``missing_type:
+    "None"``) NaN is coded as 0.0 and goes where 0.0 goes.
     """
     start = max(int(start_iteration), 0)
     k = (len(booster.trees) if num_iteration is None or num_iteration <= 0
          else min(int(num_iteration), len(booster.trees) - start))
     mapper = booster._bin_mapper_for_predict()
-    bundler = getattr(mapper, "bundler", None)
-    multi_groups = (set() if bundler is None else
-                    {c for c, g in enumerate(bundler.groups) if len(g) > 1})
+
+    def nan_code(feat: int) -> int:
+        """The bin NaN is coded to (the codes' own rule)."""
+        if mapper.nan_bin[feat] >= 0:
+            return int(mapper.nan_bin[feat])
+        if mapper.is_categorical[feat]:
+            return len(mapper.upper_bounds[feat])     # the overflow bin
+        return int(np.searchsorted(mapper.upper_bounds[feat], 0.0,
+                                   side="left"))
 
     def node_dict(tree, i: int, split_index: int):
         sf = np.asarray(tree.split_feature)
@@ -202,31 +210,25 @@ def dump_booster_dict(booster, num_iteration: Optional[int] = None,
                 return {"leaf_index": int(node),
                         "leaf_value": float(vals[node]),
                         "leaf_count": int(counts[node])}
-            col = int(sf[node])
+            feat = int(sf[node])
             thr_bin = int(sb[node])
-            if bundler is not None:
-                feat = int(bundler.split_to_original(
-                    np.array([col]), np.array([thr_bin]))[0])
-            else:
-                feat = col
+            nan_at = nan_code(feat)
+            cat = icb is not None and icb[node]
             out = {
                 "split_index": int(node),
                 "split_feature": feat,
                 "split_gain": float(gains[node]),
                 "internal_count": int(counts[node]),
-                "default_left": True,
+                "missing_type": "NaN" if mapper.nan_bin[feat] >= 0
+                else "None",
+                "default_left": bool(cm[node][nan_at] if cat
+                                     else nan_at <= thr_bin),
                 "left_child": rec(int(left[node])),
                 "right_child": rec(int(right[node])),
             }
-            if icb is not None and icb[node]:
+            if cat:
                 out["decision_type"] = "=="
                 out["threshold"] = [int(b) for b in np.flatnonzero(cm[node])]
-            elif col in multi_groups:
-                # threshold lives on the merged EFB bin axis; raw-value
-                # resolution is not well-defined across members
-                out["decision_type"] = "<="
-                out["threshold"] = thr_bin
-                out["bundled_bin_threshold"] = True
             else:
                 out["decision_type"] = "<="
                 out["threshold"] = float(

@@ -204,7 +204,8 @@ def test_device_codes_are_the_hosts_bytes(case, monkeypatch):
     monkeypatch.setattr(dataset_mod, "CODE_BLOCK_VALUES", BLOCK * len(bounds))
     assert code_block_rows(len(bounds)) == BLOCK
     n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
-    codes, blocks = device_bin_codes(X, mapper, n_pad)
+    codes, blocks, conflicts = device_bin_codes(X, mapper, n_pad)
+    assert conflicts is None             # no bundle, nothing to count
     codes = np.asarray(codes)
     host = mapper._transform_unbundled(X)
     assert codes.dtype == np.uint8 and codes.shape == (n_pad, len(bounds))
@@ -225,7 +226,7 @@ def test_device_codes_of_a_fitted_mapper_with_missing_values(monkeypatch):
     mapper = BinMapper.fit(X, max_bin=255, min_data_in_bin=1)
     assert (mapper.nan_bin[:4] >= 0).all()
     monkeypatch.setattr(dataset_mod, "CODE_BLOCK_VALUES", BLOCK * 5)
-    codes, blocks = device_bin_codes(X, mapper, 4 * BLOCK)
+    codes, blocks, _ = device_bin_codes(X, mapper, 4 * BLOCK)
     assert blocks == 4
     assert np.asarray(codes)[:len(X)].tobytes() == \
         mapper._transform_unbundled(X).tobytes()
@@ -247,7 +248,7 @@ def _bundling(n, num_features, dtype):
     ("float64", _dense, {}, "tpu", True, "host"),
     ("categorical_column", _dense, {"categorical_feature": [1]}, "tpu", True,
      "host"),
-    ("bundling_sparse", _bundling, {}, "tpu", True, "host"),
+    ("bundling_sparse", _bundling, {}, "tpu", True, "device"),
     ("bundling_off_sparse", _bundling, {"params": {"enable_bundle": False}},
      "tpu", True, "device"),
     ("small_like_a_diamonds_fold", _dense, {}, "tpu", False, "host"),

@@ -57,7 +57,7 @@ def test_bundled_predict_consistency_and_importance(onehot_data):
     params = {"objective": "regression", "num_leaves": 31, "verbosity": -1,
               "min_data_in_leaf": 5}
     b = lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=20)
-    # predict on fresh rows goes through transform->merge: same code path
+    # predict on fresh rows codes them unbundled: the trees' own space
     pred_a = b.predict(X[:100])
     pred_b = b.predict(X[:100])
     np.testing.assert_array_equal(pred_a, pred_b)
@@ -97,3 +97,30 @@ def test_conflict_rate_zero_keeps_conflicting_features_apart():
         for g in bundler.groups:
             assert not ({2, 3} <= set(g)), \
                 "conflicting features must not share a bundle at rate 0"
+
+
+def test_every_dumped_split_is_one_feature_at_a_raw_threshold(onehot_data):
+    """Bundles are a training-time layout: a split names one original
+    feature and a raw-value threshold, and the saved model predicts with
+    no bundler."""
+    X, y = onehot_data
+    b = lgb.train({"objective": "regression", "num_leaves": 15,
+                   "verbosity": -1, "min_data_in_leaf": 5},
+                  lgb.Dataset(X, label=y), num_boost_round=5)
+    assert b.train_set.bin_mapper.bundler is not None
+
+    def splits(node):
+        if "leaf_value" in node:
+            return []
+        return ([node] + splits(node["left_child"])
+                + splits(node["right_child"]))
+
+    nodes = [s for t in b.dump_model()["tree_info"]
+             for s in splits(t["tree_structure"])]
+    assert nodes and not any("bundled_bin_threshold" in s for s in nodes)
+    assert all(isinstance(s["threshold"], float) for s in nodes)
+    assert {s["split_feature"] for s in nodes} - {0, 1, 2}  # one-hots split
+    b2 = lgb.Booster(model_str=b.model_to_string())
+    b2._bin_mapper.bundler = None
+    np.testing.assert_allclose(b2.predict(X[:300]), b.predict(X[:300]),
+                               rtol=1e-6, atol=1e-7)

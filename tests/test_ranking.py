@@ -415,7 +415,10 @@ def test_two_boosters_on_equal_shapes_share_one_round_program():
     fn_a, args_a = a._fused_segment(1)
     fn_b, args_b = b._fused_segment(1)
     assert fn_a is fn_b
-    assert args_a[-1] is a._groups and args_b[-1] is b._groups
+    # the operands end with the layout's tensors and the table's bundles
+    # (none: this table has no bundle)
+    assert args_a[-2] is a._groups and args_b[-2] is b._groups
+    assert args_a[-1] is None and args_b[-1] is None
     before = fn_a._cache_size()
     a.update_many(1)
     b.update_many(1)

@@ -188,9 +188,10 @@ def test_screening_composes_with_ff_bynode_and_efb():
     ds = on.train_set
     fb = int(ds.num_feature_)              # post-EFB training width
     assert fb < X.shape[1]                 # bundling really engaged
-    assert on._screener is not None and on._screener.keep < fb
+    # the screener's features, like every split's, are the original ones
+    assert on._screener is not None and on._screener.keep < X.shape[1]
     feats = _split_feature_set(on)
-    assert feats and all(0 <= fid < fb for fid in feats)
+    assert feats and all(0 <= fid < X.shape[1] for fid in feats)
     # no double-masking: the degenerate keeper composes with BOTH
     # fraction draws bit-identically to the unscreened program (the
     # base-mask routing must not perturb either RNG stream)

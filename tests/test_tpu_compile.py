@@ -390,7 +390,7 @@ def _round_compiled(one_chip, monkeypatch, rows_padded, num_features,
 
 
 def _round_segment(monkeypatch, rows_padded, num_features, sharding=None,
-                   **params):
+                   table=None, **params):
     """``(fn, shapes, facts)`` of ``Booster._fused_segment(1)`` with the
     row axis set to ``rows_padded``: a booster on a small table of the
     real width (255 bins, 255 leaves), its dataset's row count replaced
@@ -405,6 +405,8 @@ def _round_segment(monkeypatch, rows_padded, num_features, sharding=None,
     rng = np.random.default_rng(0)
     X = rng.standard_normal((2048, num_features)).astype(np.float32)
     y = (rng.random(2048) < 0.5).astype(np.float32)
+    if table is not None:       # ``(X, y)`` of another shape of table
+        X, y = table
     booster = lgb.Booster(
         dict(objective="binary", num_leaves=255, learning_rate=0.1,
              max_bin=255, min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0,
@@ -473,6 +475,26 @@ def test_whole_round(one_chip, monkeypatch, case):
     else:
         assert (facts["train.feature_blocks"], facts["train.features_padded"],
                 facts["train.feature_rows_looped"]) == (1, 28, 28)
+
+
+def test_whole_round_of_a_bundled_table(one_chip, monkeypatch):
+    """The sparse cell's round (1,000,192 rows of a Bosch-shaped table,
+    968 features bundled into a few hundred columns by EFB) lowers for the
+    described chip: the member view, the range routing and the kernels
+    over the bundle columns, under a bound on the temporaries."""
+    from benchmark.datagen_sparse import bosch_like
+
+    fn, shapes, facts = _round_segment(
+        monkeypatch, 1_000_192, 968, one_chip,
+        table=bosch_like(60_000, 968, 2142000001))
+    compiled = fn.lower(*shapes).compile()
+    assert facts["train.features_raw"] == 968
+    assert facts["train.features"] == facts["dataset.bundle_columns"] < 968
+    assert facts["train.hist_dtype"] == "bf16"
+    text = compiled.as_text()
+    assert "%lgbtpu_hist_root" in text and "%lgbtpu_hist_narrow" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 30
+    assert _narrow_minor_f32(text, 64 << 20) == []
 
 
 @pytest.mark.parametrize("rows_padded,num_features,blocking", [

@@ -103,6 +103,22 @@ def test_round_stages_are_named_scopes(policy):
     assert found == set(GROWERS[policy])
 
 
+def as_the_parent_dumped(node):
+    """A dump as the commits before the missing-value fields wrote it:
+    every split ``default_left: true`` and no ``missing_type``.  The
+    trees and values are compared; the two fields are
+    ``tests/test_efb_members.py``'s."""
+    if isinstance(node, dict):
+        out = {k: as_the_parent_dumped(v) for k, v in node.items()
+               if not ("split_index" in node and k == "missing_type")}
+        if "split_index" in node:
+            out["default_left"] = True
+        return out
+    if isinstance(node, list):
+        return [as_the_parent_dumped(v) for v in node]
+    return node
+
+
 @pytest.mark.parametrize("policy", list(GROWERS))
 def test_names_move_no_tree(policy):
     rounds, digest = PARENT_DIGEST[policy]
@@ -112,5 +128,6 @@ def test_names_move_no_tree(policy):
          > 0).astype(np.float32)
     booster = lgb.Booster(_params(policy), lgb.Dataset(X, label=y))
     booster.update_many(rounds)
-    dump = json.dumps(booster.dump_model(), sort_keys=True)
+    dump = json.dumps(as_the_parent_dumped(booster.dump_model()),
+                      sort_keys=True)
     assert hashlib.sha256(dump.encode()).hexdigest() == digest
