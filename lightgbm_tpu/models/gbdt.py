@@ -169,7 +169,7 @@ def _group_grad_fn(obj_key: tuple):
 def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
                         hyper: HyperScalars, key, g, h, goss_k,
                         renew_alpha, sample_key=None, renew_scale=None,
-                        members=None):
+                        members=None, col_order=None):
     """One compacted GOSS round (shared by the per-round and scanned paths
     — the two MUST stay in RNG lockstep for fused == host training).
 
@@ -219,7 +219,7 @@ def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
     stats = jnp.stack([g[idx] * wt, h[idx] * wt, live], axis=-1)
     tree, rl_c, passes = grow(bins_c, stats, fmask, hyper.ctx(),
                               hyper.max_depth, hyper.feature_fraction_bynode,
-                              key, members=members)
+                              key, members=members, col_order=col_order)
     if renew_alpha is not None:
         rw = w[idx] * wt
         if renew_scale is not None:
@@ -277,7 +277,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         # is a vmapped batch over the grower (SURVEY.md §7 batching design)
         @jax.jit
         def round_fn_mc(bins, y, w, bag, pred, feature_mask,
-                        hyper: HyperScalars, key, groups=None, members=None):
+                        hyper: HyperScalars, key, groups=None, members=None,
+                        col_order=None):
             g, h = _grad_hess(obj, pred, y, w, groups)    # [n, K]
             if is_goss:
                 bag = goss_bag(jax.random.fold_in(key, 0x7FFFFFFF), g, bag, hyper)
@@ -287,7 +288,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
                                    (bag > 0).astype(jnp.float32)], axis=-1)
                 return grow(bins, stats, feature_mask, hyper.ctx(),
                             hyper.max_depth, hyper.feature_fraction_bynode,
-                            kc, members=members)[:2]
+                            kc, members=members, col_order=col_order)[:2]
 
             return mc_round_update(grow_one, g, h,
                                    jax.random.split(key, num_class), pred,
@@ -300,12 +301,12 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         @jax.jit
         def round_fn_goss(bins, y, w, bag, pred, feature_mask,
                           hyper: HyperScalars, key, groups=None,
-                          members=None):
+                          members=None, col_order=None):
             g, h = _grad_hess(obj, pred, y, w, groups)
             return _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
                 goss_k, renew_alpha, renew_scale=renew_scale,
-                members=members)[:2]
+                members=members, col_order=col_order)[:2]
 
         return round_fn_goss
 
@@ -335,7 +336,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
     @jax.jit
     def round_fn(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
-                 key, groups=None, members=None):
+                 key, groups=None, members=None, col_order=None):
         with jax.named_scope("lgbtpu.grad"):
             g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
@@ -343,7 +344,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
                                  hyper.max_depth,
                                  hyper.feature_fraction_bynode, key,
-                                 members=members)
+                                 members=members, col_order=col_order)
         if renew_alpha is not None:
             rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
             tree = renew_leaf_values(tree, row_leaf, y - pred, rw,
@@ -384,7 +385,7 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
     @jax.jit
     def multi(bins, y, w, bag0, pred0, hyper: HyperScalars, round_key,
               bag_key, ff_key, row_mask, num_data, start_iter, bag_frac, ff,
-              groups=None, members=None):
+              groups=None, members=None, col_order=None):
         num_features = (bins.shape[1] if members is None
                         else members.num_features)
 
@@ -413,7 +414,7 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
                 tree, new_pred, passes = _goss_compact_round(
                     grow, bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
                     goss_k, renew_alpha, renew_scale=renew_scale,
-                    members=members)
+                    members=members, col_order=col_order)
                 return (new_pred, bag), (tree, passes)
             with jax.named_scope("lgbtpu.grad"):
                 stats = jnp.stack(
@@ -422,7 +423,7 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
             tree, row_leaf, passes = grow(bins, stats, fmask, hyper.ctx(),
                                           hyper.max_depth,
                                           hyper.feature_fraction_bynode, rkey,
-                                          members=members)
+                                          members=members, col_order=col_order)
             if renew_alpha is not None:
                 rw = (w * bag if renew_scale is None
                       else w * bag * renew_scale(y))
@@ -764,6 +765,21 @@ class Booster:
             self._nbins_key = tuple(int(x) for x in ds.bin_mapper.n_bins)
         else:
             self._nbins_key = None
+        # ... and per TRAINING column (a bundle's merged codes) the bins its
+        # codes lie below, which size the fused kernels' one-hots: their
+        # sorted heights are static (GrowSpec.onehot_rows), the columns in
+        # that order an operand, so every order of the table's columns runs
+        # one program; the streamed grower keeps num_bins
+        self._col_bins = self._col_order = None
+        if not getattr(ds, "is_streamed", False):
+            from ..ops.histogram_pallas import onehot_heights, onehot_order
+
+            bundler = ds.bin_mapper.bundler
+            self._col_bins = tuple(int(b) for b in (
+                ds.bin_mapper.n_bins if bundler is None else bundler.col_bins))
+            heights = onehot_heights(self._col_bins, self._num_bins)
+            if heights is not None:
+                self._col_order = jnp.asarray(onehot_order(heights))
         self._grow_specs = {}
         self._streamed = bool(getattr(ds, "is_streamed", False))
         if self._streamed:
@@ -839,7 +855,8 @@ class Booster:
             spec = self._grow_specs[eff_rows] = resolve_grow_spec(
                 self.params, eff_rows, self._num_bins,
                 cat_key=self._cat_key, mono_key=self._mono_key,
-                nbins_key=self._nbins_key, ic_key=self._ic_key)
+                nbins_key=self._nbins_key, ic_key=self._ic_key,
+                col_bins=self._col_bins)
         return spec
 
     def _check_streamed_scope(self) -> None:
@@ -1936,7 +1953,7 @@ class Booster:
                 tree, new_pred = fn(bins, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
                                     self._hyper, round_key, self._groups,
-                                    self._members)
+                                    self._members, self._col_order)
         if active_ids is not None:
             # the tree grew in compacted space — gather the winner ids
             # back to GLOBAL features before anything downstream
@@ -2109,10 +2126,12 @@ class Booster:
         # (the padding skipped: histogram_pallas._feature_loop), rows per
         # grid step, and kernel calls per pass (two under the hi/lo split
         # that serves "f32")
-        from ..ops.histogram_pallas import _vmem_blocking, feature_loop_trips
+        from ..ops.histogram_pallas import _vmem_blocking, feature_layout
         f_blk, n_fblk, _, chunk = _vmem_blocking(features, self._num_bins,
                                                  3 * segments)
-        tail = feature_loop_trips(features, f_blk)[1]
+        # the grower's FeatureLayout: the looped rows' one-hot heights
+        layout = feature_layout(features, f_blk, self._num_bins,
+                                spec.onehot_rows)
         for fact, value in (
                 ("wave_width", segments),
                 # the schedule's narrow phase (0 = none); it runs where the
@@ -2128,7 +2147,11 @@ class Booster:
                 ("features_raw", self._num_features()),
                 ("feature_blocks", n_fblk),
                 ("features_padded", n_fblk * f_blk),
-                ("feature_rows_looped", (n_fblk - 1) * f_blk + tail),
+                ("feature_rows_looped", layout.rows_looped),
+                # the one-hot rows the fused kernels build over those the
+                # table's num_bins would (1.0: every column that tall)
+                ("onehot_bin_share", layout.onehot_rows
+                 / (layout.rows_looped * self._num_bins)),
                 ("chunk_rows", chunk),
                 ("hist_calls_per_pass",
                  2 if hist_dtype == "f32" or (
@@ -2150,7 +2173,8 @@ class Booster:
             jax.random.PRNGKey(p.feature_fraction_seed + p.seed),
             ds.row_mask, jnp.float32(ds.num_data_), jnp.int32(self._iter),
             jnp.float32(p.bagging_fraction),
-            jnp.float32(p.feature_fraction), self._groups, self._members)
+            jnp.float32(p.feature_fraction), self._groups, self._members,
+            self._col_order)
 
     def _dart_round(self) -> bool:
         """One DART boosting round (upstream dart.hpp semantics).

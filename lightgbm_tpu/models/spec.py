@@ -82,7 +82,13 @@ class GrowSpec:
     ``nbins_key`` per-column used-bin counts (bounds the extra_trees
     draw); ``ic_key`` interaction-group membership rows; ``bynode_off``
     statically true when ``feature_fraction_bynode == 1.0``, so the growers
-    skip the per-node threefry draw.
+    skip the per-node threefry draw.  ``onehot_rows``, ascending, the
+    heights of the partition-fused kernels' one-hots over the TRAINING
+    columns (an EFB table's bundle columns): each column's used bins
+    rounded up to 16 (``ops.histogram_pallas.onehot_heights``), SORTED, so
+    that every order of a table's columns keys one program (the rows'
+    columns, ``onehot_order``, are an operand); ``None`` where every
+    column takes ``num_bins``.
     """
 
     num_leaves: int
@@ -97,6 +103,7 @@ class GrowSpec:
     ic_key: Optional[tuple] = None
     extra_trees: bool = False
     bynode_off: bool = False
+    onehot_rows: Optional[tuple] = None
 
 
 def resolve_hist_dtype(p: Params, n_rows: int) -> str:
@@ -286,10 +293,19 @@ def resolve_grow_spec(p: Params, n_rows: int, num_bins: int, *,
                       cat_key: Optional[tuple] = None,
                       mono_key: Optional[tuple] = None,
                       nbins_key: Optional[tuple] = None,
-                      ic_key: Optional[tuple] = None) -> GrowSpec:
+                      ic_key: Optional[tuple] = None,
+                      col_bins: Optional[tuple] = None) -> GrowSpec:
     """The one place ``Params`` and a row count become a :class:`GrowSpec`
     (``n_rows`` = the rows a tree is grown on: GOSS grows on its
-    ``k_top + k_other`` sample).  The keys come from the dataset."""
+    ``k_top + k_other`` sample).  The keys come from the dataset;
+    ``col_bins`` = the bins each training column uses, its codes below
+    them, which set ``onehot_rows``."""
+    onehot_rows = None
+    if col_bins is not None:
+        from ..ops.histogram_pallas import onehot_heights
+
+        heights = onehot_heights(col_bins, num_bins)
+        onehot_rows = None if heights is None else tuple(sorted(heights))
     return GrowSpec(
         num_leaves=p.num_leaves, num_bins=num_bins,
         hist_impl=p.extra.get("hist_impl", "auto"),
@@ -298,4 +314,4 @@ def resolve_grow_spec(p: Params, n_rows: int, num_bins: int, *,
         wave=resolve_wave(p, n_rows),
         cat_key=cat_key, mono_key=mono_key, nbins_key=nbins_key,
         ic_key=ic_key, extra_trees=p.extra_trees,
-        bynode_off=p.feature_fraction_bynode >= 1.0)
+        bynode_off=p.feature_fraction_bynode >= 1.0, onehot_rows=onehot_rows)

@@ -597,7 +597,10 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
     ``spec.cat_key`` (the feature-sharded learner slices its own).
     ``members`` (``ops.members.Members``, an operand of the caller's
     program) grows on an EFB table's bundle columns; every per-feature key
-    of the spec is over the original features.
+    of the spec is over the original features.  ``col_order`` (an operand
+    too: ``ops.histogram_pallas.onehot_order`` of the table's columns)
+    puts the partition-fused kernels' feature rows in the order of
+    ``spec.onehot_rows``; without it every one-hot is ``num_bins`` tall.
     """
     mono = (None if spec.mono_key is None
             else jnp.asarray(spec.mono_key, jnp.int32))
@@ -609,7 +612,7 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
         cat_info_for = functools.partial(build_cat_info, spec.cat_key)
 
     def grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode, key,
-             members=None):
+             members=None, col_order=None):
         return grow_tree_logged(
             bins, stats, feature_mask, ctx, spec.num_leaves, spec.num_bins,
             max_depth, ff_bynode=None if spec.bynode_off else ff_bynode,
@@ -618,7 +621,8 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
             cat_info=cat_info_for(bins.shape[1] if members is None
                                   else members.num_features),
             mono=mono, extra_trees=spec.extra_trees, col_bins=col_bins,
-            ic_member=ic_member, members=members, **placement)
+            ic_member=ic_member, members=members,
+            onehot_rows=spec.onehot_rows, col_order=col_order, **placement)
 
     return grow
 
@@ -657,6 +661,8 @@ def grow_tree_logged(
     hist_wire: str = "f32",
     merge_chunks: int = 4,
     members=None,
+    onehot_rows: Optional[tuple] = None,
+    col_order: Optional[jnp.ndarray] = None,
 ) -> Tuple[Tree, jnp.ndarray, jnp.ndarray]:
     """Grow one best-first tree.
 
@@ -718,6 +724,13 @@ def grow_tree_logged(
         and ``cat_info`` are over the original features) and a split is
         (original feature, its own bin), routed by range.  The mesh
         learners take no bundled table.
+      onehot_rows, col_order: the partition-fused kernels' feature rows
+        by the height of their one-hot: static, the sorted heights
+        (:func:`~lightgbm_tpu.ops.histogram_pallas.onehot_heights`), and
+        traced, ``i32[C]`` the column of each row
+        (:func:`~lightgbm_tpu.ops.histogram_pallas.onehot_order`).  Either
+        ``None``, or heights of another length (a screened round's
+        compacted view), keeps ``num_bins`` for every column.
 
     Returns:
       (Tree, row_leaf, passes) — row_leaf gives each training row's final
@@ -745,7 +758,8 @@ def grow_tree_logged(
             col_bins=col_bins, ic_member=ic_member, fp_axis=fp_axis,
             fuse_partition=fuse_partition, hist_merge=hist_merge,
             n_shards=n_shards, voting_k=voting_k, hist_wire=hist_wire,
-            merge_chunks=merge_chunks, members=members)
+            merge_chunks=merge_chunks, members=members,
+            onehot_rows=onehot_rows, col_order=col_order)
     n, num_features = bins.shape
     if members is not None:
         num_features = members.num_features
@@ -1362,6 +1376,8 @@ def grow_tree_frontier(
     hist_wire: str = "f32",
     merge_chunks: int = 4,
     members=None,
+    onehot_rows: Optional[tuple] = None,
+    col_order: Optional[jnp.ndarray] = None,
 ) -> Tuple[Tree, jnp.ndarray, jnp.ndarray]:
     """Best-first growth in WAVES: up to ``wave.width`` splits per data pass.
 
@@ -1413,6 +1429,13 @@ def grow_tree_frontier(
     and the scan on the member view of the original features; a wave's
     splits route by the ranges :func:`~lightgbm_tpu.ops.members.
     split_route` gives them.
+
+    ``onehot_rows`` and ``col_order`` (:func:`grow_tree_logged`) order
+    the partition-fused kernels' feature rows by the height of each
+    column's one-hot (:func:`~lightgbm_tpu.ops.histogram_pallas.
+    feature_layout`): the kernels hand the histograms back in the table's
+    column order, and a split's column is mapped to its row for the
+    routing.
     """
     n, num_cols = bins.shape
     num_features = num_cols if members is None else members.num_features
@@ -1434,7 +1457,7 @@ def grow_tree_frontier(
     route_pallas = (hist_impl == "pallas"
                     or (hist_impl == "auto" and not exact_dtype
                         and jax.default_backend() == "tpu"))
-    from ..ops.histogram_pallas import _vmem_blocking
+    from ..ops.histogram_pallas import _vmem_blocking, feature_layout
 
     # more than one VMEM feature block: the kernel routes rows by wave rank
     # from the gathered code rows of the wave's split features and never
@@ -1451,6 +1474,19 @@ def grow_tree_frontier(
                  # it) must be an exact bf16 integer
                  and max(2 * w_width, num_bins) <= 256
                  and (multi_block or num_cols <= 256))
+    # the fused kernels' feature rows, the columns by their one-hot's height
+    by_height = (fuse_part and col_order is not None
+                 and onehot_rows is not None
+                 and len(onehot_rows) == num_cols)
+    layout = feature_layout(num_cols, wave_f_blk, num_bins,
+                            onehot_rows if by_height else None)
+    row_of = (jnp.zeros(num_cols, jnp.int32).at[col_order].set(
+        lax.iota(jnp.int32, num_cols)) if by_height else None)
+
+    def to_row(col):
+        """A split column's row of the fused kernels' codes."""
+        return col if row_of is None else row_of[col]
+
     max_depth = jnp.asarray(max_depth, jnp.int32)
     neg_inf = jnp.float32(-jnp.inf)
     if key is None:
@@ -1553,7 +1589,8 @@ def grow_tree_frontier(
 
             stats_prep_src = sr_round_bf16(stats)
         bins_t_prep, stats_t_prep, part_chunk = prepare_wave_operands(
-            bins, stats_prep_src, num_bins, w_width)
+            bins, stats_prep_src, num_bins, w_width,
+            col_order if by_height else None)
         n_pad_rows = bins_t_prep.shape[1]
         kernel_dtype = "f32" if hist_dtype in ("f32", "f32x") else "bf16"
 
@@ -1565,7 +1602,8 @@ def grow_tree_frontier(
                 bins_t_prep, stats_t_prep,
                 jnp.zeros((1, n_pad_rows), jnp.int32), 1, num_bins,
                 part_chunk, wave_f_blk, num_cols,
-                hist_dtype=kernel_dtype, name=HIST_ROOT)))[0]
+                hist_dtype=kernel_dtype, name=HIST_ROOT, layout=layout,
+                row_of=row_of)))[0]
         else:
             root_hist = hist_fn(jnp.zeros(n, jnp.int32), 1,
                                 HIST_ROOT)[0]           # [3, f_hist, B]
@@ -1745,7 +1783,11 @@ def grow_tree_frontier(
                 if members is None:
                     tbl_w = jnp.stack([active_r.astype(f32),
                                        (zw if multi_block
-                                        else prow[:, K.CAND_FEAT]),
+                                        else prow[:, K.CAND_FEAT]
+                                        if row_of is None
+                                        else to_row(prow[:, K.CAND_FEAT]
+                                                    .astype(jnp.int32))
+                                        .astype(f32)),
                                        prow[:, K.CAND_BIN],
                                        (2 * iota_w).astype(f32),
                                        direct_left.astype(f32), zw, zw, zw],
@@ -1755,7 +1797,8 @@ def grow_tree_frontier(
                         members, prow[:, K.CAND_FEAT].astype(jnp.int32),
                         prow[:, K.CAND_BIN])
                     tbl_w = jnp.stack([active_r.astype(f32),
-                                       zw if multi_block else wcol.astype(f32),
+                                       (zw if multi_block
+                                        else to_row(wcol).astype(f32)),
                                        whi, (2 * iota_w).astype(f32),
                                        direct_left.astype(f32), wlo,
                                        winv.astype(f32), zw], axis=1)
@@ -1772,9 +1815,10 @@ def grow_tree_frontier(
                     part_chunk, hist_dtype=kernel_dtype,
                     # multi-f-block routing gathers the wave split features'
                     # code rows; ignored on single-block shapes
-                    wfeat=(prow[:, K.CAND_FEAT].astype(jnp.int32)
-                           if members is None else wcol),
-                    num_features=num_cols, name=role, f_blk=wave_f_blk)
+                    wfeat=to_row(prow[:, K.CAND_FEAT].astype(jnp.int32)
+                                 if members is None else wcol),
+                    num_features=num_cols, name=role, f_blk=wave_f_blk,
+                    layout=layout, row_of=row_of)
                 # the kernel's direct_hist is the LOCAL pre-merge partial,
                 # planes [W, 3, F, B]: every merge topology applies after it
                 # unchanged (voting keeps it unmerged for the scorer's

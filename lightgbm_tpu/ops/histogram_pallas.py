@@ -25,10 +25,11 @@ program shapes exist per config (full F and the static F_active).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -226,10 +227,9 @@ def split_hi_lo(x: jnp.ndarray):
     return hi, x - hi
 
 
-def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *,
-                  num_features: int, num_bins: int, num_segments: int,
-                  hist_dtype: str, chunk_dim: int = 1,
-                  bins_minor: bool = False):
+def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *, runs: tuple,
+                  num_bins: int, num_segments: int, hist_dtype: str,
+                  chunk_dim: int = 1, bins_minor: bool = False):
     @pl.when(pl.program_id(chunk_dim) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
@@ -276,8 +276,6 @@ def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *,
         operand = fold(stats, jnp.bfloat16)
         oh_t, acc_t = jnp.bfloat16, jnp.float32
 
-    iota_bt = lax.broadcasted_iota(jnp.int32, (num_bins, chunk), 0)
-
     # features iterate via fori_loop (NOT a static unroll: compile time must
     # stay flat in F — MSLR has 136 features); bins arrive TRANSPOSED
     # [F_blk, chunk] so the dynamic per-feature slice is on the major dim
@@ -286,53 +284,171 @@ def _fused_kernel(bins_ref, stats_ref, seg_ref, out_ref, *,
     # <= 8: one tile) with the bins on the lanes.  A narrow pass (the root's
     # K = 3) otherwise leaves the chip an [F, B, 3] array that HBM stores
     # 128 lanes wide: 264 MB at 2,000 features.
-    def body(f, _):
-        codes_t = bins_ref[pl.dslice(f, 1), :]             # [1, chunk] i32
-        onehot_t = (iota_bt == codes_t).astype(oh_t)
-        lhs, rhs = (operand, onehot_t) if bins_minor else (onehot_t, operand)
-        tile = lax.dot_general(
-            lhs, rhs,
-            dimension_numbers=(((1,), (1,)), ((), ())),     # NT: both on
-            preferred_element_type=acc_t)                   # the chunk dim
-        out_ref[pl.dslice(f, 1), :, :] += tile[None]
-        return _
-
-    _feature_loop(body, bins_ref.shape[0], num_features, chunk_dim - 1)
+    _feature_loop(_onehot_dots(bins_ref, out_ref, operand, oh_t, acc_t,
+                               bins_minor, num_bins),
+                  runs, chunk_dim - 1)
 
 
-def feature_loop_trips(num_features: int, f_blk: int) -> tuple[int, int]:
-    """``(blocks, tail)``: the feature blocks of ``f_blk`` rows that hold
-    ``num_features``, and the trip count of the last block's feature loop
-    (:func:`_feature_loop`); every other block loops ``f_blk`` times, so
-    a pass loops over ``(blocks - 1) * f_blk + tail`` feature rows."""
-    n_fblk = -(-num_features // f_blk)
-    return n_fblk, num_features - (n_fblk - 1) * f_blk
+def _onehot_dots(bins_ref, out_ref, operand, oh_t, acc_t, bins_minor: bool,
+                 num_bins: int):
+    """``body_at(height)`` of :func:`_feature_loop` for the fused kernels:
+    row ``f``'s one-hot ``[height, chunk]`` (built transposed, so the dot
+    contracts over the chunk, the minor axis of both operands) against the
+    folded statistics ``operand`` ``[K, chunk]``, added into the bins
+    below ``height`` of the accumulator row ``f``."""
+    chunk = bins_ref.shape[1]
+
+    def body_at(height):
+        iota_bt = lax.broadcasted_iota(jnp.int32, (height, chunk), 0)
+        bins = slice(None) if height == num_bins else slice(0, height)
+        at = ((slice(None), bins) if bins_minor else (bins, slice(None)))
+
+        def body(f, _):
+            codes_t = bins_ref[pl.dslice(f, 1), :]             # [1, chunk]
+            onehot_t = (iota_bt == codes_t).astype(oh_t)
+            lhs, rhs = ((operand, onehot_t) if bins_minor
+                        else (onehot_t, operand))
+            tile = lax.dot_general(
+                lhs, rhs,
+                dimension_numbers=(((1,), (1,)), ((), ())),     # NT: both on
+                preferred_element_type=acc_t)                   # the chunk dim
+            out_ref[(pl.dslice(f, 1),) + at] += tile[None]
+            return _
+
+        return body
+
+    return body_at
 
 
-def _feature_loop(body, f_blk: int, num_features: int, fblock_axis):
-    """``lax.fori_loop`` of a kernel's per-feature ``body`` over its
-    feature block's rows that hold one of the table's ``num_features``.
+# A one-hot's rows are made 16 at a time: a bfloat16 vreg packs 16 sublanes.
+ONEHOT_ROW_ALIGN = 16
 
-    A full block loops over all ``f_blk`` rows.  Where the blocks pad the
-    feature axis (``_vmem_blocking``: MSLR's 136 features in 5 blocks of
-    32 = 160 rows), the last block loops over its ``tail`` real rows only:
-    a padded row's one-hot and dot would land in accumulator rows that
-    keep the zeros of ``_init`` and that the wrapper trims.  The block is
-    ``pl.program_id(fblock_axis)``; both trip counts are static, and a
-    blocking that pads nothing keeps the single loop over the block."""
-    n_fblk, tail = feature_loop_trips(num_features, f_blk)
-    if tail == f_blk:
-        lax.fori_loop(0, f_blk, body, 0)
+
+def onehot_heights(col_bins, num_bins: int) -> Optional[tuple]:
+    """Per column of the table, the height of its one-hot in the fused
+    kernels: the bins the column uses (its codes lie below them) rounded
+    up to ``ONEHOT_ROW_ALIGN``, at most ``num_bins``.  ``None`` where every
+    column is ``num_bins`` tall: the kernels' one height, and program."""
+    heights = tuple(
+        min(num_bins, -(-max(int(b), 1) // ONEHOT_ROW_ALIGN)
+            * ONEHOT_ROW_ALIGN) for b in col_bins)
+    return None if all(h == num_bins for h in heights) else heights
+
+
+def onehot_order(heights) -> np.ndarray:
+    """``i32[C]``: the column each feature row of the fused kernels holds,
+    the columns by their one-hot's height, shortest first (equal heights
+    in the table's order).  Row ``r``'s height is ``sorted(heights)[r]``,
+    whatever the order of the table's columns: the kernels' program reads
+    the sorted heights alone (:func:`feature_layout`) and this order comes
+    as an operand (:func:`prepare_wave_operands`)."""
+    return np.argsort(np.asarray(heights), kind="stable").astype(np.int32)
+
+
+class FeatureLayout(NamedTuple):
+    """The loops of the fused kernels' feature blocks.
+
+    ``runs[b]`` is block ``b``'s loops, ``((rows, height), ...)`` over its
+    rows in turn: each row's one-hot is ``height`` bins tall, and the
+    block's padding rows are in no run (:func:`_feature_loop`)."""
+
+    f_blk: int
+    runs: tuple
+
+    @property
+    def rows_looped(self) -> int:
+        """The feature rows a pass's loops run over: the columns."""
+        return sum(rows for block in self.runs for rows, _ in block)
+
+    @property
+    def onehot_rows(self) -> int:
+        """Σ over the looped rows of their one-hot's height."""
+        return sum(rows * h for block in self.runs for rows, h in block)
+
+
+def feature_layout(num_features: int, f_blk: int, num_bins: int,
+                   row_heights: Optional[tuple] = None) -> FeatureLayout:
+    """The :class:`FeatureLayout` of ``num_features`` feature rows in
+    blocks of ``f_blk`` (``_vmem_blocking``), row ``r``'s one-hot
+    ``row_heights[r]`` tall, ascending (``sorted`` of
+    :func:`onehot_heights`, the rows holding the columns in
+    :func:`onehot_order`); ``None``, or the heights of another table (a
+    screened round's compacted columns), makes every row ``num_bins``
+    tall.  A block's rows of one height are one loop; blocks fill as
+    before, the last padded, so a table of one height loops once a
+    block."""
+    if row_heights is None or len(row_heights) != num_features:
+        row_heights = (num_bins,) * num_features
+    runs = []
+    for start in range(0, num_features, f_blk):
+        block = []
+        for h in row_heights[start:start + f_blk]:
+            if block and block[-1][1] == h:
+                block[-1][0] += 1
+            else:
+                block.append([1, h])
+        runs.append(tuple((rows, h) for rows, h in block))
+    return FeatureLayout(f_blk, tuple(runs))
+
+
+def _in_blocks(fb, first: int, last: int):
+    """``pl.when``'s predicate: grid block ``fb`` lies in ``[first, last]``."""
+    if first == last:
+        return fb == first
+    return fb < last + 1 if first == 0 else (fb >= first) & (fb <= last)
+
+
+def _feature_loop(body_at, runs, fblock_axis):
+    """The per-feature loops of a fused kernel over its block's rows that
+    hold a column: ``body_at(height)`` makes the ``lax.fori_loop`` body of
+    the rows whose one-hot is ``height`` bins tall, ``runs`` is
+    :attr:`FeatureLayout.runs`, the block ``pl.program_id(fblock_axis)``.
+
+    A block loops over its runs in turn, each at its own height: rows of a
+    shorter column compare their codes with fewer bins and their dot
+    writes only the bins below the height, where the one-hot of the table's
+    ``num_bins`` would add zeros (the accumulator's other bins keep the
+    zeros of ``_init``).  Rows that pad the last block (``_vmem_blocking``:
+    MSLR's 136 features in 5 blocks of 32 = 160 rows) are in no run: their
+    accumulator rows keep the zeros and the wrapper drops them.  Every
+    trip count and height is static; the kernel branches once per distinct
+    list of runs, not once per block, and a table of one height makes its
+    body once, outside the branches: with no padding, one loop a block,
+    the program of a kernel with no layout."""
+    heights = {h for block in runs for _, h in block}
+    one_body = (body_at(heights.pop()) if len(heights) == 1 else None)
+
+    def loops(block):
+        start = 0
+        for rows, h in block:
+            lax.fori_loop(start, start + rows,
+                          one_body or body_at(h), 0)
+            start += rows
+
+    # the rows run by height, so the blocks of one list of runs are
+    # contiguous: one branch each, over their range of the grid
+    blocks = {}
+    for b, block in enumerate(runs):
+        blocks.setdefault(block, []).append(b)
+    if len(blocks) == 1:
+        loops(runs[0])
         return
     fb = pl.program_id(fblock_axis)
+    for block, which in blocks.items():
+        assert which == list(range(which[0], which[-1] + 1)), which
+        pl.when(_in_blocks(fb, which[0], which[-1]))(
+            functools.partial(loops, block))
 
-    @pl.when(fb < n_fblk - 1)
-    def _full():
-        lax.fori_loop(0, f_blk, body, 0)
 
-    @pl.when(fb == n_fblk - 1)
-    def _last():
-        lax.fori_loop(0, tail, body, 0)
+def _by_column(out, row_of, num_features: int):
+    """A kernel's accumulator ``[F_rows, ...]``, one row a feature row, as
+    ``[num_features, ...]`` in the table's column order: the padding
+    dropped and, where ``row_of`` (``i32[C]``, the row of each column:
+    the inverse of :func:`onehot_order`) is given, one gather of whole
+    rows."""
+    if row_of is None:
+        return out[:num_features]
+    return jnp.take(out, row_of, axis=0)
 
 
 def _vmem_blocking(num_features: int, num_bins: int, k: int,
@@ -348,6 +464,11 @@ def _vmem_blocking(num_features: int, num_bins: int, k: int,
     occupies 128 lanes per bin — at Criteo's 413 raw features that is a
     54 MB accumulator if sized from the nominal k (the r3 criteo
     efb_off OOM).
+
+    Everything is sized at ``num_bins``, the table's tallest column: a
+    block's rows whose one-hot is shorter (:func:`feature_layout`) build
+    smaller tiles and write fewer bins of the same accumulator, so the
+    blocking of a table of mixed heights is that of one height.
     """
     k_pad = -(-k // 128) * 128
     f_blk = num_features
@@ -486,6 +607,8 @@ def hist_fused_prepared(
     interpret: bool | None = None,
     hist_dtype: str = "bf16",
     name: str = "lgbtpu_hist_fused",
+    layout: Optional[FeatureLayout] = None,
+    row_of: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """:func:`hist_fused_pallas` on operands that are already transposed,
     widened and padded to whole blocks (its own preparation, or
@@ -493,13 +616,20 @@ def hist_fused_prepared(
     the table its wave passes read, so a tree keeps ONE 4-byte transposed
     copy of the codes, 3.2 GB at 400,000 x 2,000, and not one per
     padding).  ``hist_dtype``: "bf16", "f32" (hi/lo) or "int8" (statistics
-    quantized by the caller, who also applies the scales).  Returns f32
-    ``[num_segments, F, num_bins, S]``."""
+    quantized by the caller, who also applies the scales).  ``layout``:
+    the rows' :class:`FeatureLayout` (``None``: every one-hot ``num_bins``
+    tall); ``row_of``: the row of each column where the rows hold the
+    columns in :func:`onehot_order` (``None``: row ``c`` holds column
+    ``c``).  Returns f32 ``[num_segments, F, num_bins, S]``, the columns
+    in the table's order."""
     f_rows, n_pad = bins_t.shape
     s = stats_t.shape[0]
     k = num_segments * s
     n_fblk, n_chunks = f_rows // f_blk, n_pad // chunk
     assert (n_fblk * f_blk, n_chunks * chunk) == (f_rows, n_pad)
+    if layout is None:
+        layout = feature_layout(num_features, f_blk, num_bins)
+    assert layout.f_blk == f_blk, (layout.f_blk, f_blk)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
 
@@ -510,7 +640,7 @@ def hist_fused_prepared(
 
     def one_pass(stats_arr, mode):
         return pl.pallas_call(
-            functools.partial(_fused_kernel, num_features=num_features,
+            functools.partial(_fused_kernel, runs=layout.runs,
                               num_bins=num_bins, num_segments=num_segments,
                               hist_dtype=mode, bins_minor=bins_minor),
             grid=(n_fblk, n_chunks),
@@ -540,7 +670,7 @@ def hist_fused_prepared(
         out = one_pass(hi, "bf16") + one_pass(lo, "bf16")
     else:
         out = one_pass(stats_t, hist_dtype)
-    out = out[:num_features].astype(jnp.float32)
+    out = _by_column(out, row_of, num_features).astype(jnp.float32)
     if bins_minor:
         out = out.reshape(num_features, num_segments, s, num_bins)
         return out.transpose(1, 0, 3, 2)
@@ -799,21 +929,23 @@ def split_iter_pallas(hist2_t: jnp.ndarray, table: jnp.ndarray,
     )(hist2_t, table, fmask, aux, scal)
 
 
-def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_features: int,
+def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, runs: tuple,
                      num_bins: int, num_segments: int, bins_minor: bool,
                      fblock_axis=None):
     """Phase 2 of the partition-fused kernels: the segment-folded one-hot
-    dots of :func:`_fused_kernel` over this block's features (the table's
-    own: :func:`_feature_loop`, the block at grid axis ``fblock_axis``),
-    with ``seg`` ``[1, chunk]`` produced in-register by the routing phase.
+    dots of :func:`_fused_kernel` over this block's rows that hold a
+    column, each row's one-hot as tall as its column's bins
+    (:func:`_feature_loop` over ``runs``, the block at grid axis
+    ``fblock_axis``), with ``seg`` ``[1, chunk]`` produced in-register by
+    the routing phase.
 
     ``bins_minor`` turns the dot as ``_fused_kernel`` does for the root:
-    ``operand [K, chunk] x onehot [B, chunk]^T`` into an ``[F_blk, K, B]``
-    accumulator.  Unturned, the MXU streams the one-hot's 255 rows through
-    every 128-column weight tile whatever ``K <= 128`` is, 3 useful columns
-    or 126; turned it streams ``K`` rows, and a narrow pass (the doubling
-    passes of a tree) stops paying for the full width: the table at
-    ``TURNED_MAX_K``."""
+    ``operand [K, chunk] x onehot [H, chunk]^T`` into an ``[F_blk, K, B]``
+    accumulator.  Unturned, the MXU streams the one-hot's ``H`` rows (255
+    in a column of the table's bins) through every 128-column weight tile
+    whatever ``K <= 128`` is, 3 useful columns or 126; turned it streams
+    ``K`` rows, and a narrow pass (the doubling passes of a tree) stops
+    paying for the full width: the table at ``TURNED_MAX_K``."""
     chunk = bins_ref.shape[1]
     s = stats_ref.shape[0]
     w = num_segments
@@ -827,20 +959,9 @@ def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, num_features: int,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     operand = jnp.where(seg_match, spread, 0.0).astype(jnp.bfloat16)
-    iota_bt = lax.broadcasted_iota(jnp.int32, (num_bins, chunk), 0)
-
-    def body(f, _):
-        codes_t = bins_ref[pl.dslice(f, 1), :]
-        onehot_t = (iota_bt == codes_t).astype(jnp.bfloat16)
-        lhs, rhs = (operand, onehot_t) if bins_minor else (onehot_t, operand)
-        tile = lax.dot_general(
-            lhs, rhs,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        out_ref[pl.dslice(f, 1), :, :] += tile[None]
-        return _
-
-    _feature_loop(body, bins_ref.shape[0], num_features, fblock_axis)
+    _feature_loop(_onehot_dots(bins_ref, out_ref, operand, jnp.bfloat16,
+                               jnp.float32, bins_minor, num_bins),
+                  runs, fblock_axis)
 
 
 def _route(v, pv_ref, thr):
@@ -852,7 +973,7 @@ def _route(v, pv_ref, thr):
 
 
 def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
-                       num_features: int, num_bins: int, num_segments: int,
+                       runs: tuple, num_bins: int, num_segments: int,
                        bins_minor: bool = False):
     """Wave histogram + ROW PARTITION in one kernel (single f-block).
 
@@ -871,9 +992,10 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
 
       pv_ref [8, chunk] f32 — per-row node fields from ONE transposed
         lookup (rows: sel, feat, thr, rank2, direct-left, lo, inv; 1 zero
-        pad): the split's column, and the range of its codes that goes
-        left, inverted where ``inv`` (``ops.members``: an EFB member's
-        split; a plain column's is lo 0, not inverted);
+        pad): the split's column (its row of ``bins``, where
+        ``prepare_wave_operands`` ordered them), and the range of its
+        codes that goes left, inverted where ``inv`` (``ops.members``: an
+        EFB member's split; a plain column's is lo 0, not inverted);
       phase 1: v = bins[feat] via a fori_loop feature select (VMEM reads,
         no HBM); go_left = ((v >= lo) & (v <= thr)) != inv (:func:`_route`);
         seg = wave rank where the row moves to its split's DIRECT
@@ -902,8 +1024,8 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
         code = bins_ref[pl.dslice(f, 1), :].astype(jnp.float32)  # [1, chunk]
         return jnp.where(feat == f, code[0, :], v)
 
-    v = lax.fori_loop(0, num_features, vbody, jnp.zeros((chunk,),
-                                                        jnp.float32))
+    v = lax.fori_loop(0, bins_ref.shape[0], vbody,
+                      jnp.zeros((chunk,), jnp.float32))
     psel = sel > 0.0
     go_left = _route(v, pv_ref, thr)
     to_direct = psel & (go_left == (dl > 0.0))
@@ -914,13 +1036,13 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
         0).reshape(1, chunk)
 
     # phase 2: standard segment-folded accumulation (see _fused_kernel)
-    _accumulate_wave(bins_ref, stats_ref, seg, out_ref,
-                     num_features=num_features, num_bins=num_bins,
-                     num_segments=w, bins_minor=bins_minor)
+    _accumulate_wave(bins_ref, stats_ref, seg, out_ref, runs=runs,
+                     num_bins=num_bins, num_segments=w,
+                     bins_minor=bins_minor)
 
 
 def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
-                          enc_ref, *, num_features: int, num_bins: int,
+                          enc_ref, *, runs: tuple, num_bins: int,
                           num_segments: int, bins_minor: bool = False):
     """Multi-feature-block variant of :func:`_fused_part_kernel`.
 
@@ -968,13 +1090,14 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
 
     # phase 2: standard segment-folded accumulation over THIS block's
     # features (see _fused_part_kernel)
-    _accumulate_wave(bins_ref, stats_ref, seg, out_ref,
-                     num_features=num_features, num_bins=num_bins,
-                     num_segments=w, bins_minor=bins_minor, fblock_axis=0)
+    _accumulate_wave(bins_ref, stats_ref, seg, out_ref, runs=runs,
+                     num_bins=num_bins, num_segments=w,
+                     bins_minor=bins_minor, fblock_axis=0)
 
 
 def prepare_wave_operands(bins: jnp.ndarray, stats: jnp.ndarray,
-                          num_bins: int, num_segments: int):
+                          num_bins: int, num_segments: int,
+                          col_order: Optional[jnp.ndarray] = None):
     """One-time (per tree) prep for :func:`hist_partition_fused_pallas`:
     transpose + row-pad the loop-invariant operands OUTSIDE the growth
     while_loop (the in-call pad/convert re-ran per wave — ~2.7 ms each at
@@ -982,7 +1105,9 @@ def prepare_wave_operands(bins: jnp.ndarray, stats: jnp.ndarray,
     blocks (F > ~45; MSLR), the feature axis is zero-padded to a whole
     number of blocks here — the kernels' feature loops skip the padded
     rows (:func:`_feature_loop`) and the wrapper trims their histogram
-    rows on the way out."""
+    rows on the way out.  ``col_order`` (:func:`onehot_order`, an operand
+    of the caller's program) puts the columns in the rows by the height of
+    their one-hot: one gather of whole columns of the codes."""
     n, num_features = bins.shape
     s = stats.shape[1]
     k = num_segments * s
@@ -990,6 +1115,10 @@ def prepare_wave_operands(bins: jnp.ndarray, stats: jnp.ndarray,
                                                  chunk_align=512)
     n_chunks = -(-n // chunk)
     pad = n_chunks * chunk - n
+    if col_order is not None:
+        # in the table's own dtype, before the 4-byte copy: a gather of
+        # the transposed copy held two of them (5.2 GB at 1M x 660)
+        bins = jnp.take(bins, col_order, axis=1)
     bins_t = bins.astype(jnp.int32).T
     stats_t = stats.T
     if pad or f_pad:
@@ -1012,10 +1141,20 @@ def hist_partition_fused_pallas(
     name: str = "lgbtpu_hist_partition_fused",
     f_blk: int | None = None,           # feature block bins_t is padded for
     bins_minor: bool | None = None,     # None: from the width (TURNED_MAX_K)
+    layout: Optional[FeatureLayout] = None,   # the rows' loops
+    row_of: jnp.ndarray | None = None,        # [F] i32 row of each column
 ):
     """Fused wave pass: histogram over the direct children PLUS the row
     partition (see _fused_part_kernel).  Returns
-    (hist f32 [num_segments, S, F, num_bins], enc i32 [n_pad]).
+    (hist f32 [num_segments, S, F, num_bins], enc i32 [n_pad]), the
+    histograms' columns in the table's order.
+
+    ``layout`` is the :class:`FeatureLayout` of ``bins_t``'s rows
+    (``None``: every one-hot ``num_bins`` tall) and ``row_of`` the row of
+    each column where :func:`prepare_wave_operands` ordered them
+    (``None``: row ``c`` holds column ``c``).  ``wfeat`` and the split's
+    column in ``pv_t`` (row 1) name ROWS of ``bins_t``: ``row_of[column]``
+    where the rows are ordered.
 
     ``f_blk`` is the feature block ``bins_t`` was padded for, where that
     was another width's (a tree's narrow passes read the operands
@@ -1061,6 +1200,9 @@ def hist_partition_fused_pallas(
                                chunk_align=512)[0]
     n_fblk = f_rows // f_blk
     assert f_rows == n_fblk * f_blk, (f_rows, n_fblk, f_blk)
+    if layout is None:
+        layout = feature_layout(num_features, f_blk, num_bins)
+    assert layout.f_blk == f_blk, (layout.f_blk, f_blk)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if bins_minor is None:
@@ -1071,7 +1213,7 @@ def hist_partition_fused_pallas(
         def one_pass(stats_arr):
             return pl.pallas_call(
                 functools.partial(_fused_part_kernel,
-                                  num_features=num_features,
+                                  runs=layout.runs,
                                   num_bins=num_bins,
                                   num_segments=num_segments,
                                   bins_minor=bins_minor),
@@ -1113,7 +1255,7 @@ def hist_partition_fused_pallas(
         def one_pass(stats_arr):
             return pl.pallas_call(
                 functools.partial(_fused_part_kernel_mb,
-                                  num_features=num_features,
+                                  runs=layout.runs,
                                   num_bins=num_bins,
                                   num_segments=num_segments,
                                   bins_minor=bins_minor),
@@ -1151,7 +1293,7 @@ def hist_partition_fused_pallas(
         out = h1 + h2
     else:
         out, enc = one_pass(stats_t)
-    out = out[:num_features]
+    out = _by_column(out, row_of, num_features)
     if bins_minor:                       # [F, W*S, B]: major axes only
         out = out.reshape(num_features, num_segments, s, num_bins)
         return out.transpose(1, 2, 0, 3), enc[0]
@@ -1222,9 +1364,9 @@ def hist_fused_pallas_batched(
 
     def one_pass(stats_arr, mode):
         return pl.pallas_call(
-            functools.partial(_fused_kernel, num_features=num_features,
-                              num_bins=num_bins, num_segments=num_segments,
-                              hist_dtype=mode, chunk_dim=2),
+            functools.partial(_fused_kernel, runs=feature_layout(
+                num_features, f_blk, num_bins).runs, num_bins=num_bins,
+                num_segments=num_segments, hist_dtype=mode, chunk_dim=2),
             grid=(e, n_fblk, n_chunks),
             in_specs=[
                 pl.BlockSpec((f_blk, chunk), lambda el, fb, c: (fb, c),

@@ -246,3 +246,62 @@ def test_a_dump_walked_on_rows_with_nan_is_the_models_prediction(nan_seen):
         assert ("NaN", False) in kinds and ("NaN", True) not in kinds
     else:
         assert {k[0] for k in kinds} == {"None"}
+
+
+def mixed_bins_table(n=3000, f=60, seed=4):
+    """``f`` dense columns of 2 to 255 distinct values (two blocks of the
+    fused kernels' feature rows at the tree's width), regression labels
+    from a few of them."""
+    rng = np.random.default_rng(seed)
+    values = np.resize(np.array([2, 9, 17, 40, 100, 255]), f)
+    X = np.stack([rng.integers(0, v, n) for v in values], 1).astype(
+        np.float32)
+    y = (X[:, 0] * 0.7 - 0.02 * X[:, 5] + 0.1 * X[:, 3] * (X[:, 1] > 4)
+         + 0.05 * X[:, 10] + rng.normal(0, 0.1, n))
+    return X, y.astype(np.float32)
+
+
+@pytest.mark.parametrize("table", ["bundled", "plain_two_blocks"])
+def test_onehot_heights_grow_the_same_trees(table, monkeypatch):
+    """The partition-fused grower (forced onto the CPU's interpret mode)
+    with its kernels' rows ordered by one-hot height grows the trees it
+    grows with every one-hot ``num_bins`` tall: the same dump, the leaf
+    values equal to the bit, the same training scores.  A bundled table
+    (one feature block, its members routed by range) and a plain one of
+    60 columns (two blocks, routed from the gathered rows)."""
+    from lightgbm_tpu.ops import histogram_pallas
+    from lightgbm_tpu.utils import profiling
+
+    X, y = stations_table() if table == "bundled" else mixed_bins_table()
+    params = dict(PARAMS, num_leaves=31, min_data_in_leaf=10,
+                  grow_policy="frontier", hist_impl="pallas")
+    if table != "bundled":
+        params["enable_bundle"] = False
+    by_height = []
+    real = histogram_pallas.hist_partition_fused_pallas
+
+    def spy(*args, **kwargs):
+        by_height.append(kwargs["row_of"] is not None)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(histogram_pallas, "hist_partition_fused_pallas", spy)
+
+    def train():
+        ds = lgb.Dataset(X, label=y, params={
+            "enable_bundle": table == "bundled"})
+        booster = lgb.train(params, ds, num_boost_round=3)
+        assert (ds.bin_mapper.bundler is not None) == (table == "bundled")
+        return booster, profiling.snapshot()["facts"]
+
+    sorted_rows, facts = train()
+    assert by_height and all(by_height)
+    assert 0 < facts["train.onehot_bin_share"] < 0.9
+    del by_height[:]
+    monkeypatch.setattr(histogram_pallas, "onehot_heights",
+                        lambda col_bins, num_bins: None)
+    full, facts = train()
+    assert by_height and not any(by_height)
+    assert facts["train.onehot_bin_share"] == 1.0
+    assert sorted_rows.dump_model() == full.dump_model()
+    np.testing.assert_array_equal(np.asarray(sorted_rows._pred_train),
+                                  np.asarray(full._pred_train))

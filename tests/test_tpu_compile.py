@@ -491,6 +491,9 @@ def test_whole_round_of_a_bundled_table(one_chip, monkeypatch):
     assert facts["train.features_raw"] == 968
     assert facts["train.features"] == facts["dataset.bundle_columns"] < 968
     assert facts["train.hist_dtype"] == "bf16"
+    # the kernels' one-hots as tall as the columns' bins: about two thirds
+    # of the table's 256 over the bundle columns
+    assert 0.5 < facts["train.onehot_bin_share"] < 0.8
     text = compiled.as_text()
     assert "%lgbtpu_hist_root" in text and "%lgbtpu_hist_narrow" in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 30
@@ -511,6 +514,8 @@ def test_round_feature_rows(monkeypatch, rows_padded, num_features,
     assert facts["train.wave_width"] == 42
     assert (facts["train.feature_blocks"], facts["train.features_padded"],
             facts["train.feature_rows_looped"]) == blocking
+    # every column of these tables uses the 255 bins
+    assert facts["train.onehot_bin_share"] == 1.0
 
 
 def test_narrow_minor_reader_sees_the_parents_buffer():

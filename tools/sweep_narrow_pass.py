@@ -1,6 +1,6 @@
 """Time one partition-fused wave pass by width and by orientation (PR 32).
 
-    python3 tools/sweep_narrow_pass.py [higgs] [epsilon] [mslr]
+    python3 tools/sweep_narrow_pass.py [higgs] [epsilon] [mslr] [bosch]
                                       [--root <checkout>]    # one TPU chip
 
 At each benchmark cell's shape (10,500,096 x 28, bfloat16, one kernel call a
@@ -11,6 +11,14 @@ dot unturned and turned: a loop of 10 calls under one ``jit``, ended by
 ``block_until_ready``, the median of the repeats, in ms a pass.  The table
 decides the kernel's ``TURNED_MAX_K`` and with it the schedule's narrow
 width (``models.spec.narrow_width_for``; PERF.md section 6, PR 32).
+
+``bosch`` (1,000,000 x 660 columns, 256 bins, bfloat16, 17 feature blocks
+of 40: the sparse cell's bundle columns) times a narrow pass (W = 16) and
+a root pass with every column's one-hot 16, 32, 64, 128 and 256 bins tall
+(``histogram_pallas.feature_layout``; codes drawn below the height): how a
+pass's time follows the height decides the rounding of
+``histogram_pallas.onehot_heights`` (PERF.md section 6).  Under
+``--root`` a checkout without layouts times the full height alone.
 
 ``mslr`` (2,270,296 x 136, bfloat16, 5 feature blocks of 32: the last one
 holds 8 features and 24 rows of padding) times the three passes a tree
@@ -44,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 import lightgbm_tpu  # noqa: F401  (puts the compile-cache rule in force)
+from lightgbm_tpu.ops import histogram_pallas
 from lightgbm_tpu.ops.histogram_pallas import (_vmem_blocking,
                                                hist_fused_prepared,
                                                hist_partition_fused_pallas,
@@ -53,27 +62,30 @@ SHAPES = {
     "higgs": dict(n=10_500_096, f=28, dtype="bf16", repeats=5),
     "epsilon": dict(n=400_128, f=2000, dtype="f32", repeats=3),
     "mslr": dict(n=2_270_296, f=136, dtype="bf16", repeats=5, floor_f=128),
+    "bosch": dict(n=1_000_000, f=660, dtype="bf16", repeats=5,
+                  heights=(16, 32, 64, 128, 256)),
 }
 WIDTHS = (1, 2, 4, 8, 16, 32, 42)
 TREE_WIDTH, NARROW_WIDTH, NUM_BINS, CALLS = 42, 16, 255, 10
 
 
-def _operands(n, f, seed=0):
-    """Random codes and statistics, prepared at the tree's width 42 as the
-    grower prepares them: ``(bins_t, stats_t, leaf, thr)``."""
+def _operands(n, f, seed=0, codes_below=NUM_BINS, num_bins=NUM_BINS):
+    """Random codes (below ``codes_below``) and statistics, prepared at the
+    tree's width 42 as the grower prepares them: ``(bins_t, stats_t, leaf,
+    thr)``."""
     key = jax.random.PRNGKey(seed)
     kb, ks, kl, kt = jax.random.split(key, 4)
-    bins = jax.random.randint(kb, (n, f), 0, NUM_BINS, jnp.int32).astype(
+    bins = jax.random.randint(kb, (n, f), 0, codes_below, jnp.int32).astype(
         jnp.uint8)
     stats = jnp.concatenate(
         [jax.random.normal(ks, (n, 2), jnp.float32),
          jnp.ones((n, 1), jnp.float32)], axis=1)
     bins_t, stats_t = jax.jit(
-        lambda b, s: prepare_wave_operands(b, s, NUM_BINS, TREE_WIDTH)[:2])(
+        lambda b, s: prepare_wave_operands(b, s, num_bins, TREE_WIDTH)[:2])(
             bins, stats)
     n_pad = bins_t.shape[1]
     leaf = jax.random.randint(kl, (n_pad,), 0, 1 << 20, jnp.int32)
-    thr = jax.random.randint(kt, (n_pad,), 0, NUM_BINS, jnp.int32)
+    thr = jax.random.randint(kt, (n_pad,), 0, codes_below, jnp.int32)
     return bins_t, stats_t, leaf, thr
 
 
@@ -184,6 +196,55 @@ def tree_passes(name, n, f, dtype, repeats, floor_f):
             "repeats": repeats, "ms_per_pass": rows}
 
 
+def height_passes(name, n, f, dtype, repeats, heights, num_bins=256):
+    """A narrow pass (W = 16) and a root pass, in ms, with every column's
+    one-hot ``h`` bins tall for each ``h`` of ``heights``."""
+    layouts = hasattr(histogram_pallas, "feature_layout")
+    f_blk, n_fblk, f_pad, chunk = _vmem_blocking(f, num_bins, 3 * TREE_WIDTH)
+    rows = {"blocking": {"f_blk": f_blk, "blocks": n_fblk, "f_pad": f_pad,
+                         "chunk": chunk}}
+    for h in (heights if layouts else (num_bins,)):
+        kw = ({"layout": histogram_pallas.feature_layout(
+            f, f_blk, num_bins, (h,) * f)} if layouts else {})
+        bins_t, stats_t, leaf, thr = _operands(n, f, codes_below=h,
+                                               num_bins=num_bins)
+        n_pad = bins_t.shape[1]
+        pv_t = _pv(leaf, thr, NARROW_WIDTH, f)
+        wfeat = (jnp.arange(NARROW_WIDTH, dtype=jnp.int32) * 7) % f
+
+        @jax.jit
+        def narrow(bins_t, stats_t, pv_t, wfeat, kw=kw):
+            def body(_, acc):
+                hist, enc = hist_partition_fused_pallas(
+                    bins_t, stats_t + acc * 0.0, pv_t, NARROW_WIDTH,
+                    num_bins, chunk, hist_dtype=dtype, wfeat=wfeat,
+                    num_features=f, f_blk=f_blk, name="lgbtpu_sweep", **kw)
+                return hist[0, 0, 0, 0] + enc[0].astype(jnp.float32)
+            return lax.fori_loop(0, CALLS, body, jnp.float32(0.0))
+
+        @jax.jit
+        def root(bins_t, stats_t, kw=kw):
+            seg = jnp.zeros((1, n_pad), jnp.int32)
+
+            def body(_, acc):
+                hist = hist_fused_prepared(
+                    bins_t, stats_t + acc * 0.0, seg, 1, num_bins, chunk,
+                    f_blk, f, hist_dtype=dtype, name="lgbtpu_sweep", **kw)
+                return hist[0, 0, 0, 0]
+            return lax.fori_loop(0, CALLS, body, jnp.float32(0.0))
+
+        for role, loop, args in (
+                ("narrow", narrow, (bins_t, stats_t, pv_t, wfeat)),
+                ("root", root, (bins_t, stats_t))):
+            rows[f"H{h}.{role}"] = _ms_per_call(loop, args, repeats)
+            print(name, f"H{h}.{role}", rows[f"H{h}.{role}"],
+                  file=sys.stderr, flush=True)
+        del bins_t, stats_t
+    return {"shape": name, "rows": n, "features": f, "num_bins": num_bins,
+            "hist_dtype": dtype, "root": ROOT, "calls_per_loop": CALLS,
+            "repeats": repeats, "ms_per_pass": rows}
+
+
 def main():
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -194,6 +255,7 @@ def main():
     for name in names:
         shape = SHAPES[name]
         res = (tree_passes(name, **shape) if "floor_f" in shape
+               else height_passes(name, **shape) if "heights" in shape
                else sweep(name, **shape))
         out["shapes"].append(res)
         print(json.dumps(res), flush=True)
