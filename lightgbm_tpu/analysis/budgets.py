@@ -473,7 +473,7 @@ def fused_train_step_recompiles(n_hyper_batches: int = 3
             hyper(0.05 * (i + 1), 0.1 * i), jnp.ones((1,), jnp.float32),
             jnp.ones((1,), jnp.float32),
             jnp.full((1,), float(n), jnp.float32), jnp.int32(0),
-            jnp.zeros((1,), jnp.float32), jax.random.PRNGKey(i))
+            jnp.zeros((1,), jnp.float32), jax.random.PRNGKey(i), None)
         jax.block_until_ready(carry.r)  # graftlint: GL002 — probe sync
     compiles = jit_cache_size(run_segment) - before
     # `before` can be nonzero when an identical static config already ran

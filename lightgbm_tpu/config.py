@@ -162,6 +162,8 @@ _ALIASES: Dict[str, str] = {
     "cat_smooth": "cat_smooth",
     "cat_l2": "cat_l2",
     "max_cat_threshold": "max_cat_threshold",
+    "max_cat_to_onehot": "max_cat_to_onehot",
+    "min_data_per_group": "min_data_per_group",
     "drop_rate": "drop_rate",
     "rate_drop": "drop_rate",
     "max_drop": "max_drop",
@@ -435,10 +437,14 @@ class Params:
     max_conflict_rate: float = 0.0
     use_missing: bool = True
     zero_as_missing: bool = False
-    # categorical subset splits (upstream cat_smooth/cat_l2/max_cat_threshold)
+    # categorical subset splits (upstream cat_smooth/cat_l2/max_cat_threshold;
+    # one-vs-rest at max_cat_to_onehot bins or fewer, min_data_per_group
+    # rows on each side of a subset split and between its candidates)
     cat_smooth: float = 10.0
     cat_l2: float = 10.0
     max_cat_threshold: int = 32
+    max_cat_to_onehot: int = 4
+    min_data_per_group: int = 100
     # DART boosting (upstream dart.hpp knobs)
     drop_rate: float = 0.1
     max_drop: int = 50

@@ -503,7 +503,7 @@ def stream_dp_goss_round(shards, mesh, obj_key: tuple, y, w, bag, pred,
     grow = make_dp_grow_step(
         mesh, dataclasses.replace(spec, row_chunk=shards[0].block_rows),
         merge_mode, 0, wire_dtype, merge_chunks)
-    tree, _ = grow(bins_g, stats_g, fmask, hyper, key)
+    tree, _ = grow(bins_g, stats_g, fmask, hyper, key, cats=None)
 
     # train-score update: one full streamed sharded traversal pass
     pred_step = _dp_goss_pred_block_step(mesh, shards[0].block_rows)

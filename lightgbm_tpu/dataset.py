@@ -350,10 +350,10 @@ class BinMapper:
     takes any table, and is what prediction, serving and ``from_blocks``
     run: codes in the original feature space, the space trees split in.
     ``Dataset.construct`` hands a float32 table of at least one row block
-    (``CODE_BLOCK_VALUES``) whose columns are all numeric to
-    :func:`device_bin_codes`, which counts on the device the bounds below
-    each value, from :meth:`device_tables`, and merges an EFB table's
-    bundles in the same program.
+    (``CODE_BLOCK_VALUES``) to :func:`device_bin_codes`, which counts on
+    the device the bounds below each value, from :meth:`device_tables`
+    (matches a categorical column's value against its categories), and
+    merges an EFB table's bundles in the same program.
     """
 
     def __init__(self, upper_bounds: List[np.ndarray], nan_bin: np.ndarray,
@@ -429,7 +429,8 @@ class BinMapper:
                 list(pool.map(np.ndarray.sort, block))          # NaNs last
                 for f, col in enumerate(block, start=f0):
                     vals = col[:np.searchsorted(col, np.nan)]
-                    has_nan = len(vals) < len(col)
+                    # a categorical column's NaN shares the overflow bin
+                    has_nan = len(vals) < len(col) and not is_cat[f]
                     ub = BinMapper._sorted_column_bounds(
                         vals, max_bin - (1 if has_nan else 0),
                         min_data_in_bin, is_cat[f])
@@ -451,17 +452,11 @@ class BinMapper:
     def _sorted_column_bounds(vals: np.ndarray, budget: int,
                               min_data_in_bin: int,
                               categorical: bool) -> np.ndarray:
-        """One column's bounds from its finite sample in ascending order."""
+        """One column's bounds from its finite sample in ascending order
+        (a categorical column's: the values of the categories it keeps,
+        :meth:`category_values`)."""
         if categorical:
-            # categorical: one bin per kept category value (exact match
-            # at transform time; unseen/rare values share the overflow
-            # bin).  The grower finds gradient-ordered k-vs-rest SUBSET
-            # splits over these bins (ops.split CatInfo path).
-            cats = np.unique(vals)
-            if len(cats) > budget - 1:
-                uniq, cnts = np.unique(vals, return_counts=True)
-                cats = np.sort(uniq[np.argsort(-cnts)[: budget - 1]])
-            ub = cats  # stores category VALUES for categorical features
+            ub = BinMapper.category_values(vals, budget, min_data_in_bin)
         else:
             # honor min_data_in_bin (LightGBM GreedyFindBin) — shared
             # with the streaming sketch builder (data.sketch), which
@@ -469,6 +464,45 @@ class BinMapper:
             ub = numeric_bin_bounds(budget, min_data_in_bin,
                                     sorted_vals=vals)
         return np.asarray(ub, dtype=np.float64)
+
+    @staticmethod
+    def category_values(vals: np.ndarray, max_bin: int,
+                        min_data_in_bin: int) -> np.ndarray:
+        """The categories a categorical column keeps, by LightGBM's
+        categorical ``BinMapper::FindBin``: the sample's distinct values
+        ranked by count (ties: the smaller value first), at most ``max_bin
+        - 1`` of them, and none past the second whose count is under
+        ``min_data_in_bin``.  Returned in ascending order: a value's bin is
+        its place among them (the exact-match lookup of
+        :meth:`_transform_unbundled` and :func:`device_bin_codes`); every
+        other value, and NaN, has the overflow bin after them, which no
+        category set of a split holds.
+
+        Departures from LightGBM:
+
+        * the cap.  LightGBM's loop goes on while the kept categories cover
+          less than 99 % of the sampled rows OR fewer than ``max_bin`` bins
+          are used (``used_cnt < cut_cnt || num_bin_ < max_bin``), so where
+          the ``max_bin - 1`` most common categories cover less than 99 %
+          it keeps more categories, past ``max_bin``, until they do (or the
+          ``min_data_in_bin`` cut stops it).  Here at most ``max_bin - 1``
+          are kept and the rest share the overflow bin, because a code is
+          one byte and the histogram kernels' one-hots are at most 256
+          bins tall.  This moves splits: a category past the cap can never
+          be sent left on its own;
+        * none of the others moves a split: its bins follow the count
+          ranking and its overflow bin is bin 0, here the bins follow the
+          values and the overflow bin is the last (a subset split does not
+          read the bins' order); it casts values to int and sends negative
+          ones to NaN, here a category is a float32 value matched
+          exactly."""
+        uniq, cnts = np.unique(vals, return_counts=True)
+        rank = np.argsort(-cnts, kind="stable")[:max(max_bin - 1, 0)]
+        rare = np.flatnonzero(cnts[rank] < min_data_in_bin)
+        rare = rare[rare > 1]
+        if len(rare):
+            rank = rank[:rare[0]]
+        return np.sort(uniq[rank])
 
     def transform(self, X: np.ndarray) -> np.ndarray:
         """Map raw features to the training layout's codes uint8[n, C]:
@@ -510,8 +544,8 @@ class BinMapper:
         return out
 
     def device_tables(self):
-        """``(edge_keys i32[F, E], nan_code i32[F])`` for
-        :func:`device_bin_codes`; numeric features only.
+        """``(edge_keys i32[F, E], nan_code i32[F], cats)`` for
+        :func:`device_bin_codes`.
 
         A float32 ``x`` is above a float64 bound ``u`` iff it is above
         ``d(u)``, ``u`` rounded to float32 toward -inf, and two float32
@@ -519,25 +553,45 @@ class BinMapper:
         "left")`` is the count of ``key(x) > key(d(u_j))``.  Rows are padded
         to the widest edge count with the largest key, which nothing is
         above.  ``nan_code`` is where :meth:`_transform_unbundled` sends
-        NaN: the NaN bin, or the bin of 0.0 where fit saw no NaN."""
-        assert not self.is_categorical.any()
+        NaN: the NaN bin, or the bin of 0.0 where fit saw no NaN (a
+        categorical column's overflow bin).
+
+        A categorical column's row holds its categories' values: the count
+        below a value is its category's place, and the value is the
+        category only where its key is one of ``cats["keys"]`` (the key of
+        a category that float32 holds exactly; the largest key, which only
+        a NaN has, where it does not), else it has the overflow bin,
+        ``cats["overflow"]`` (-1 for a numeric column).  ``cats`` is
+        ``None`` for a table with no categorical column."""
         width = max([len(ub) for ub in self.upper_bounds] + [1])
-        edge_keys = np.full((self.num_features, width),
-                            np.iinfo(np.int32).max, np.int32)
+        top = np.iinfo(np.int32).max
+        edge_keys = np.full((self.num_features, width), top, np.int32)
         nan_code = np.asarray(self.nan_bin, np.int32).copy()
+        has_cats = bool(self.is_categorical.any())
+        cat_keys = np.full_like(edge_keys, top) if has_cats else None
+        overflow = np.full(self.num_features, -1, np.int32)
         for f, ub in enumerate(self.upper_bounds):
             ub = np.asarray(ub, np.float64)
             with np.errstate(over="ignore"):
                 down = ub.astype(np.float32)
+            exact = down.astype(np.float64) == ub
             above = down.astype(np.float64) > ub
             down[above] = np.nextafter(down[above], np.float32(-np.inf))
             # a NaN bound is above every value on the host too
             edge_keys[f, :len(ub)] = np.where(
                 np.isnan(ub), edge_keys[f, :len(ub)],
                 _order_keys(down.view(np.int32)))
-            if nan_code[f] < 0:
+            if self.is_categorical[f]:
+                overflow[f] = len(ub)
+                cat_keys[f, :len(ub)] = np.where(
+                    exact, edge_keys[f, :len(ub)], top)
+                if nan_code[f] < 0:
+                    nan_code[f] = len(ub)
+            elif nan_code[f] < 0:
                 nan_code[f] = np.searchsorted(ub, 0.0, side="left")
-        return edge_keys, nan_code
+        cats = (None if not has_cats
+                else {"keys": cat_keys, "overflow": overflow})
+        return edge_keys, nan_code, cats
 
     def bin_upper_bound(self, feature: int, bin_idx: int) -> float:
         """Raw-value threshold corresponding to `bin <= bin_idx` (for model dump)."""
@@ -577,34 +631,41 @@ def code_block_rows(num_features: int) -> int:
     return max(rows // ROW_PAD_MULTIPLE, 1) * ROW_PAD_MULTIPLE
 
 
-def _block_codes(bits, edge_keys, nan_code):
+def _block_codes(bits, edge_keys, nan_code, cats=None):
     """``int32[F, B]``: the codes of one row block (``bits[B, F]``: the bit
     patterns of its float32 values).  Integer compares only; rows lie on
-    the minor axis while the edges are counted."""
+    the minor axis while the edges are counted.  ``cats``
+    (:meth:`BinMapper.device_tables`): a categorical column's value that
+    is none of its categories takes the overflow bin."""
     key = _order_keys(bits).T                                   # [F, B]
     above = key[:, None, :] > edge_keys[:, :, None]
     code = jnp.sum(above, axis=1, dtype=jnp.int32)
+    if cats is not None:
+        hit = jnp.any(key[:, None, :] == cats["keys"][:, :, None], axis=1)
+        over = cats["overflow"][:, None]
+        code = jnp.where((over >= 0) & ~hit, over, code)
     is_nan = (key > _F32_INF) | (key < -_F32_INF)
     return jnp.where(is_nan, nan_code[:, None], code)
 
 
 @functools.partial(jax.jit, donate_argnums=0)
-def _write_code_block(codes, bits, at, edge_keys, nan_code):
+def _write_code_block(codes, bits, at, edge_keys, nan_code, cats=None):
     """One row block's codes written into rows ``at...`` of ``codes``."""
-    code = _block_codes(bits, edge_keys, nan_code)
+    code = _block_codes(bits, edge_keys, nan_code, cats)
     codes = jax.lax.dynamic_update_slice(
         codes, code.T.astype(jnp.uint8), (at, 0))
     return codes, at
 
 
 @functools.partial(jax.jit, donate_argnums=0)
-def _write_bundled_block(codes, bits, at, skip, edge_keys, nan_code, slots):
+def _write_bundled_block(codes, bits, at, skip, edge_keys, nan_code, slots,
+                         cats=None):
     """:func:`_write_code_block` for a bundled table: the block's codes of
     every original feature merged into its columns in the same program,
     slot by slot of ``FeatureBundler.device_tables`` (``slots``), and the
     block's rows from ``skip`` on on which two members of one column are
     off their defaults counted (the last block overlaps the one before)."""
-    code = _block_codes(bits, edge_keys, nan_code)
+    code = _block_codes(bits, edge_keys, nan_code, cats)
 
     def slot(j, carry):
         merged, nondefault = carry
@@ -642,7 +703,10 @@ def device_bin_codes(X: np.ndarray, mapper: BinMapper, n_pad: int):
     n, num_features = X.shape
     rows = code_block_rows(num_features)
     assert X.dtype == np.float32 and X.flags.c_contiguous and n >= rows
-    edge_keys, nan_code = map(jnp.asarray, mapper.device_tables())
+    edge_keys, nan_code, cats = mapper.device_tables()
+    edge_keys, nan_code = jnp.asarray(edge_keys), jnp.asarray(nan_code)
+    if cats is not None:
+        cats = {k: jnp.asarray(v) for k, v in cats.items()}
     bundler = mapper.bundler
     slots = (None if bundler is None else
              {k: jnp.asarray(v) for k, v in bundler.device_tables().items()})
@@ -657,11 +721,12 @@ def device_bin_codes(X: np.ndarray, mapper: BinMapper, n_pad: int):
         bits = jax.device_put(X[at:at + rows].view(np.int32))
         if slots is None:
             codes, token = _write_code_block(codes, bits, at, edge_keys,
-                                             nan_code)
+                                             nan_code, cats)
         else:
             skip = starts[i - 1] + rows - at if i else 0
             codes, token = _write_bundled_block(
-                codes, bits, at, max(skip, 0), edge_keys, nan_code, slots)
+                codes, bits, at, max(skip, 0), edge_keys, nan_code, slots,
+                cats)
             conflicts.append(token)
         done.append(token)
     if conflicts is not None:
@@ -902,6 +967,9 @@ class Dataset:
                         sample_rows=len(head))
         mapper = self.bin_mapper
         self.raw_num_feature_ = num_features
+        if mapper.is_categorical.any():
+            profiling.note("dataset.categorical_features",
+                           int(mapper.is_categorical.sum()))
         if mapper.bundler is not None:
             num_features = mapper.bundler.num_columns
             self.num_feature_ = num_features
@@ -913,7 +981,6 @@ class Dataset:
         # on a CPU backend the device is the host, and its loop the faster
         # program for it: XLA:CPU counts edges at a tenth of the loop's rate
         on_device = (X.dtype == np.float32
-                     and not mapper.is_categorical.any()
                      and n >= code_block_rows(self.raw_num_feature_)
                      and jax.default_backend() != "cpu")
         path = "device" if on_device else "host"

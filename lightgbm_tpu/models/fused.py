@@ -47,8 +47,9 @@ from ..config import Params, default_metric_for_objective
 from ..metrics import get_metric
 from .gbdt import HyperScalars, _objective_static_key, _rebuild_objective
 from ..ops.lookup import lookup_values
-from .spec import (STRICT, GrowSpec, WaveSchedule, resolve_grow_spec,
-                   resolve_wave)
+from ..ops.split import CatColumns
+from .spec import (STRICT, GrowSpec, WaveSchedule, categorical_key,
+                   resolve_grow_spec, resolve_wave)
 from .tree import grower_from_spec
 
 
@@ -92,12 +93,13 @@ def _fused_cv_fn(obj_key: tuple, spec: GrowSpec,
     batch = n_configs * n_folds
 
     def one_element_round(bins, y, w, pred, bag, hyper: HyperScalars, ff,
-                          key):
+                          key, cats):
         """One boosting round for one (config, fold) batch element.
 
         ``pred`` is [n] (single-output) or [n, K] (multiclass — K trees
         grown simultaneously, the class axis vmapped over the grower
-        exactly like the host loop's round_fn_mc)."""
+        exactly like the host loop's round_fn_mc); ``cats`` the table's
+        categorical columns (``ops.split.CatColumns``, ``None``: none)."""
         num_features = bins.shape[1]
         g, h = obj.grad_hess(pred, y, w)
         fmask = _sample_features_within(jax.random.fold_in(key, 1), ff,
@@ -106,7 +108,7 @@ def _fused_cv_fn(obj_key: tuple, spec: GrowSpec,
         def grow_one(gc, hc, kc):
             stats = jnp.stack([gc * bag, hc * bag, bag], axis=-1)
             return grow(bins, stats, fmask, hyper.ctx(), hyper.max_depth,
-                        hyper.feature_fraction_bynode, kc)[:2]
+                        hyper.feature_fraction_bynode, kc, cats=cats)[:2]
 
         if num_class > 1:
             from .gbdt import mc_round_update
@@ -123,7 +125,7 @@ def _fused_cv_fn(obj_key: tuple, spec: GrowSpec,
     def run_segment(carry: FusedCVCarry, seg_end, bins, y, w, train_masks,
                     valid_masks, hyper_b: HyperScalars, bag_frac_b, ff_b,
                     n_in_fold_b, es_rounds, es_min_delta_c,
-                    base_key) -> FusedCVCarry:
+                    base_key, cats) -> FusedCVCarry:
         """Run rounds [carry.r, seg_end) — bounded per-dispatch runtime so a
         multi-minute cv batch is many short device programs, not one long
         one (long single executions can trip TPU runtime watchdogs), while
@@ -146,8 +148,8 @@ def _fused_cv_fn(obj_key: tuple, spec: GrowSpec,
 
             pred = jax.vmap(
                 one_element_round,
-                in_axes=(None, None, None, 0, 0, 0, 0, 0))(
-                    bins, y, w, c.pred, bag, hyper_b, ff_b, tkeys)
+                in_axes=(None, None, None, 0, 0, 0, 0, 0, None))(
+                    bins, y, w, c.pred, bag, hyper_b, ff_b, tkeys, cats)
 
             tpred = obj.transform(pred)
             mvals = jax.vmap(lambda p, vm: metric.fn(p, y, w * vm))(
@@ -354,10 +356,7 @@ class FusedCVProgram:
         self._num_class = num_class
         self._init_score = init
 
-        cats = np.flatnonzero(train_set.col_is_categorical)
-        cat_key = ((tuple(int(c) for c in cats), float(p0.cat_smooth),
-                    float(p0.cat_l2), int(p0.max_cat_threshold))
-                   if len(cats) else None)
+        cat_key = categorical_key(train_set.bin_mapper, p0)
         spec = resolve_grow_spec(p0, n_pad, train_set.num_bins,
                                  cat_key=cat_key)
         spec = dataclasses.replace(
@@ -376,7 +375,7 @@ class FusedCVProgram:
             jnp.asarray(n_in_fold), jnp.int32(early_stopping_rounds),
             jnp.asarray([p.early_stopping_min_delta for p in param_list],
                         jnp.float32),
-            jax.random.PRNGKey(seed))
+            jax.random.PRNGKey(seed), CatColumns.of(train_set.bin_mapper))
         self.segment_rounds = int(p0.extra.get("cv_segment_rounds", 100))
 
     def init(self) -> FusedCVCarry:

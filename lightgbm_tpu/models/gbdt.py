@@ -31,11 +31,12 @@ from ..objectives import Objective, create_objective
 from ..ops.lookup import lookup_values
 from ..ops.members import member_view, members_of
 from ..ops.predict import predict_forest_binned, predict_tree_binned
-from ..ops.split import SplitContext
+from ..ops.split import CatColumns, SplitContext
 from ..utils import profiling
-from .spec import GrowSpec, check_int8_row_limit, resolve_grow_spec
-from .tree import (Tree, grower_from_spec, pad_tree, renew_leaf_values,
-                   wave_extent)
+from .spec import (GrowSpec, categorical_key,
+                   check_int8_row_limit, resolve_grow_spec)
+from .tree import (Tree, build_cat_info,
+                   grower_from_spec, pad_tree, renew_leaf_values, wave_extent)
 
 
 class HyperScalars(NamedTuple):
@@ -160,6 +161,24 @@ def _member_scan_fn():
 
 
 @functools.lru_cache(maxsize=None)
+def _cat_scan_fn(cat_key: tuple):
+    """The jitted categorical split scan of one node's planes over its
+    categorical columns, ``(planes, ctx, cats) -> BestSplit``
+    (``cat_key``: ``spec.categorical_key``)."""
+    from ..ops.split import find_best_split
+
+    @jax.jit
+    def scan(planes, ctx, cats):
+        num_features = planes.shape[1]
+        return find_best_split(
+            planes, ctx, jnp.ones(num_features, jnp.float32),
+            jnp.bool_(True), build_cat_info(cat_key, cats),
+            bins_minor=True)
+
+    return scan
+
+
+@functools.lru_cache(maxsize=None)
 def _group_grad_fn(obj_key: tuple):
     """The jitted lambda pass alone, ``(pred, y, w, groups) -> (g, h)``:
     the replicated pass of the data-parallel learner."""
@@ -169,7 +188,7 @@ def _group_grad_fn(obj_key: tuple):
 def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
                         hyper: HyperScalars, key, g, h, goss_k,
                         renew_alpha, sample_key=None, renew_scale=None,
-                        members=None, col_order=None):
+                        members=None, col_order=None, cats=None):
     """One compacted GOSS round (shared by the per-round and scanned paths
     — the two MUST stay in RNG lockstep for fused == host training).
 
@@ -219,7 +238,8 @@ def _goss_compact_round(grow, bins, y, w, bag, pred, fmask,
     stats = jnp.stack([g[idx] * wt, h[idx] * wt, live], axis=-1)
     tree, rl_c, passes = grow(bins_c, stats, fmask, hyper.ctx(),
                               hyper.max_depth, hyper.feature_fraction_bynode,
-                              key, members=members, col_order=col_order)
+                              key, members=members, col_order=col_order,
+                              cats=cats)
     if renew_alpha is not None:
         rw = w[idx] * wt
         if renew_scale is not None:
@@ -278,7 +298,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         @jax.jit
         def round_fn_mc(bins, y, w, bag, pred, feature_mask,
                         hyper: HyperScalars, key, groups=None, members=None,
-                        col_order=None):
+                        col_order=None, cats=None):
             g, h = _grad_hess(obj, pred, y, w, groups)    # [n, K]
             if is_goss:
                 bag = goss_bag(jax.random.fold_in(key, 0x7FFFFFFF), g, bag, hyper)
@@ -288,7 +308,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
                                    (bag > 0).astype(jnp.float32)], axis=-1)
                 return grow(bins, stats, feature_mask, hyper.ctx(),
                             hyper.max_depth, hyper.feature_fraction_bynode,
-                            kc, members=members, col_order=col_order)[:2]
+                            kc, members=members, col_order=col_order,
+                            cats=cats)[:2]
 
             return mc_round_update(grow_one, g, h,
                                    jax.random.split(key, num_class), pred,
@@ -301,12 +322,12 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         @jax.jit
         def round_fn_goss(bins, y, w, bag, pred, feature_mask,
                           hyper: HyperScalars, key, groups=None,
-                          members=None, col_order=None):
+                          members=None, col_order=None, cats=None):
             g, h = _grad_hess(obj, pred, y, w, groups)
             return _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key, g, h,
                 goss_k, renew_alpha, renew_scale=renew_scale,
-                members=members, col_order=col_order)[:2]
+                members=members, col_order=col_order, cats=cats)[:2]
 
         return round_fn_goss
 
@@ -315,7 +336,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
         @jax.jit
         def round_fn_linear(bins, y, w, bag, pred, feature_mask,
-                            hyper: HyperScalars, key, xraw, groups=None):
+                            hyper: HyperScalars, key, xraw, groups=None,
+                            cats=None):
             """linear_tree round: constant-leaf growth on binned codes,
             then every leaf refits a ridge model over its path features on
             the RAW values (tree.fit_linear_leaves) — the Newton constant
@@ -325,7 +347,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
                 [g * bag, h * bag, (bag > 0).astype(jnp.float32)], axis=-1)
             tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
                                      hyper.max_depth,
-                                     hyper.feature_fraction_bynode, key)
+                                     hyper.feature_fraction_bynode, key,
+                                     cats=cats)
             tree, delta = fit_linear_leaves(
                 tree, row_leaf, xraw, g, h, bag, hyper.linear_lambda,
                 linear_k, spec.row_chunk)
@@ -336,7 +359,7 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
 
     @jax.jit
     def round_fn(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
-                 key, groups=None, members=None, col_order=None):
+                 key, groups=None, members=None, col_order=None, cats=None):
         with jax.named_scope("lgbtpu.grad"):
             g, h = _grad_hess(obj, pred, y, w, groups)
             stats = jnp.stack(
@@ -344,7 +367,8 @@ def _round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool, num_class: int,
         tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
                                  hyper.max_depth,
                                  hyper.feature_fraction_bynode, key,
-                                 members=members, col_order=col_order)
+                                 members=members, col_order=col_order,
+                                 cats=cats)
         if renew_alpha is not None:
             rw = w * bag if renew_scale is None else w * bag * renew_scale(y)
             tree = renew_leaf_values(tree, row_leaf, y - pred, rw,
@@ -375,7 +399,8 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
     fold_in(key, round_index) chain), so fused and host training produce
     identical models.  ``members`` (``ops.members.Members``) grows on an
     EFB table's bundle columns; the feature mask is over the original
-    features.
+    features.  ``cats`` (``ops.split.CatColumns``) are the table's
+    categorical columns (``None``: none).
     """
     obj = _rebuild_objective(obj_key)
     renew_alpha = getattr(obj, "renew_alpha", None)
@@ -385,7 +410,7 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
     @jax.jit
     def multi(bins, y, w, bag0, pred0, hyper: HyperScalars, round_key,
               bag_key, ff_key, row_mask, num_data, start_iter, bag_frac, ff,
-              groups=None, members=None, col_order=None):
+              groups=None, members=None, col_order=None, cats=None):
         num_features = (bins.shape[1] if members is None
                         else members.num_features)
 
@@ -414,7 +439,7 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
                 tree, new_pred, passes = _goss_compact_round(
                     grow, bins, y, w, bag, pred, fmask, hyper, rkey, g, h,
                     goss_k, renew_alpha, renew_scale=renew_scale,
-                    members=members, col_order=col_order)
+                    members=members, col_order=col_order, cats=cats)
                 return (new_pred, bag), (tree, passes)
             with jax.named_scope("lgbtpu.grad"):
                 stats = jnp.stack(
@@ -423,7 +448,8 @@ def _multi_round_fn(obj_key: tuple, spec: GrowSpec, is_rf: bool,
             tree, row_leaf, passes = grow(bins, stats, fmask, hyper.ctx(),
                                           hyper.max_depth,
                                           hyper.feature_fraction_bynode, rkey,
-                                          members=members, col_order=col_order)
+                                          members=members, col_order=col_order,
+                                          cats=cats)
             if renew_alpha is not None:
                 rw = (w * bag if renew_scale is None
                       else w * bag * renew_scale(y))
@@ -751,11 +777,10 @@ class Booster:
         # per-feature key below is over the original features
         self._members = members_of(ds.bin_mapper, self._num_bins)
         self._w_eff = ds.w  # 0 on padding rows already
-        cats = np.flatnonzero(ds.col_is_categorical)
-        self._cat_key = (
-            (tuple(int(c) for c in cats), float(p.cat_smooth),
-             float(p.cat_l2), int(p.max_cat_threshold))
-            if len(cats) else None)
+        # the categorical scan's scalars key the programs; the columns
+        # are their operand: one program for every order of the columns
+        self._cat_key = categorical_key(ds.bin_mapper, p)
+        self._cat_cols = CatColumns.of(ds.bin_mapper)
         self._mono_key = self._resolve_monotone_constraints()
         self._ic_key = self._resolve_interaction_constraints()
         # per-training-column used-bin counts bound the extra_trees draw
@@ -1866,7 +1891,7 @@ class Booster:
             fmask_p = pad_feature_mask(fmask, self._fp_width)
             tree, new_pred = fn(self._fp_bins, ds.y, self._w_eff, self._bag,
                                 self._pred_train, fmask_p, self._hyper,
-                                round_key)
+                                round_key, cats=self._cat_cols)
         elif getattr(self, "_dp2", False):
             # 2-D rows x features mesh (r10 default at D>=8, F>=64):
             # per-block histograms psum over rows, split exchange over
@@ -1880,7 +1905,7 @@ class Booster:
             fmask_p = pad_feature_mask(fmask, self._dp2_width)
             tree, new_pred = fn(self._dp_bins, self._dp_y, self._dp_w,
                                 self._bag, self._pred_train, fmask_p,
-                                self._hyper, round_key)
+                                self._hyper, round_key, cats=self._cat_cols)
         elif getattr(self, "_dp_mesh", None) is not None and \
                 getattr(self, "_dp_stats_only", False):
             from ..parallel.data_parallel import (make_dp_grow_step,
@@ -1899,7 +1924,7 @@ class Booster:
             dp_bins = (self._dp_bins if active_ids is None
                        else self._screen_view(self._dp_bins, active_ids))
             tree, row_leaf = fn(dp_bins, stats, fmask, self._hyper,
-                                round_key)
+                                round_key, cats=self._cat_cols)
             new_pred = self._pred_train + jnp.float32(p.learning_rate) \
                 * lookup_values(row_leaf, tree.leaf_value)
         elif getattr(self, "_dp_mesh", None) is not None and \
@@ -1913,7 +1938,8 @@ class Booster:
                 merge_mode, voting_k, wire_dtype, merge_chunks)
             tree, new_pred = fn(self._dp_bins, self._dp_y, self._dp_w,
                                 self._bag, self._pred_train, self._dp_xraw,
-                                fmask, self._hyper, round_key)
+                                fmask, self._hyper, round_key,
+                                cats=self._cat_cols)
         elif getattr(self, "_dp_mesh", None) is not None:
             from ..parallel.data_parallel import make_dp_train_step
 
@@ -1938,7 +1964,7 @@ class Booster:
                        else self._screen_view(self._dp_bins, active_ids))
             tree, new_pred = fn(dp_bins, self._dp_y, self._dp_w,
                                 self._bag, self._pred_train, fmask,
-                                self._hyper, round_key)
+                                self._hyper, round_key, cats=self._cat_cols)
         else:
             fn = _round_fn(self._obj_key, spec, p.boosting == "rf",
                            self._num_class, goss_k, self._linear_k)
@@ -1946,14 +1972,15 @@ class Booster:
                 tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
                                     self._hyper, round_key, self._xraw,
-                                    self._groups)
+                                    self._groups, self._cat_cols)
             else:
                 bins = (ds.X_binned if active_ids is None
                         else self._screen_view(ds.X_binned, active_ids))
                 tree, new_pred = fn(bins, ds.y, self._w_eff,
                                     self._bag, self._pred_train, fmask,
                                     self._hyper, round_key, self._groups,
-                                    self._members, self._col_order)
+                                    self._members, self._col_order,
+                                    self._cat_cols)
         if active_ids is not None:
             # the tree grew in compacted space — gather the winner ids
             # back to GLOBAL features before anything downstream
@@ -2064,15 +2091,9 @@ class Booster:
                     self._forest_cache = None
                 k -= n_rounds
 
-    def _member_scan_call(self):
-        """``(fn, args)``: an EFB table's member view and split scan ALONE
-        (``ops.members.member_view`` + ``ops.split.find_best_split``, as the
-        grower runs them on a node) on a real node histogram: the root's,
-        at the booster's current scores, taken once here; the sparse
-        cell's probe times the pair.  ``None`` for a table with no
-        bundle."""
-        if self._members is None:
-            return None
+    def _root_planes(self):
+        """The root's histogram planes ``[3, C, B]`` over the training
+        columns at the booster's current scores (the probes' node)."""
         from ..ops.histogram import compute_histograms
 
         ds = self.train_set
@@ -2084,10 +2105,36 @@ class Booster:
         hist = compute_histograms(ds.X_binned, stats,
                                   jnp.zeros(stats.shape[0], jnp.int32), 1,
                                   self._num_bins)[0]             # [C, B, 3]
-        planes = jnp.moveaxis(hist, -1, 0)
+        return jnp.moveaxis(hist, -1, 0)
+
+    def _member_scan_call(self):
+        """``(fn, args)``: an EFB table's member view and split scan ALONE
+        (``ops.members.member_view`` + ``ops.split.find_best_split``, as the
+        grower runs them on a node) on a real node histogram: the root's,
+        at the booster's current scores, taken once here; the sparse
+        cell's probe times the pair.  ``None`` for a table with no
+        bundle."""
+        if self._members is None:
+            return None
         return _member_scan_fn(), (
-            planes, self._members, self._hyper.ctx(),
+            self._root_planes(), self._members, self._hyper.ctx(),
             jnp.ones(self._num_features(), jnp.float32))
+
+    def _cat_scan_call(self):
+        """``(fn, args)``: the categorical split scan ALONE
+        (``ops.split.find_best_split``'s category-set search, as the
+        grower runs it on a node) over the categorical columns of a real
+        node histogram, the root's at the booster's current scores, taken
+        once here; the categorical cell's probe times the pair.  ``None``
+        for a table with no categorical column, or one whose columns are
+        bundled."""
+        if self._cat_key is None or self._members is not None:
+            return None
+        idx = np.flatnonzero(self.train_set.bin_mapper.is_categorical)
+        cats = CatColumns(is_cat=jnp.ones(len(idx), bool),
+                          cat_bins=self._cat_cols.cat_bins[idx])
+        return _cat_scan_fn(self._cat_key), (
+            self._root_planes()[:, idx], self._hyper.ctx(), cats)
 
     def _group_grad_call(self):
         """``(fn, args)``: a group objective's jitted lambda pass ALONE and
@@ -2162,6 +2209,11 @@ class Booster:
         # pair slots against what LightGBM's loops visit (rank_* facts)
         for fact, value in getattr(self.obj, "facts", {}).items():
             profiling.note("train." + fact, value)
+        if self._cat_cols is not None:
+            # the categorical columns; where their category sets route rows
+            # (train.cat_route) the grower notes as the program is traced
+            profiling.note("train.cat_features",
+                           int(np.sum(ds.bin_mapper.is_categorical)))
         fn = _multi_round_fn(
             self._obj_key, spec, p.boosting == "rf", n_rounds,
             p.bagging_freq if use_bagging else 0,
@@ -2174,7 +2226,7 @@ class Booster:
             ds.row_mask, jnp.float32(ds.num_data_), jnp.int32(self._iter),
             jnp.float32(p.bagging_fraction),
             jnp.float32(p.feature_fraction), self._groups, self._members,
-            self._col_order)
+            self._col_order, self._cat_cols)
 
     def _dart_round(self) -> bool:
         """One DART boosting round (upstream dart.hpp semantics).
@@ -2241,7 +2293,7 @@ class Booster:
         round_key = jax.random.fold_in(self._key, i)
         tree, new_pred = fn(ds.X_binned, ds.y, self._w_eff, self._bag, pred,
                             fmask, self._hyper, round_key, self._groups,
-                            self._members)
+                            self._members, None, self._cat_cols)
 
         if k > 0:
             # upstream Normalize(): on drop rounds the new tree's weight is
@@ -2663,7 +2715,8 @@ class Booster:
         newp = parse_params(params, base=self.params)
         static = ["num_leaves", "max_bin", "objective", "boosting",
                   "num_class", "tree_learner", "grow_policy",
-                  "max_cat_threshold", "extra_trees", "linear_tree"]
+                  "max_cat_threshold", "max_cat_to_onehot",
+                  "min_data_per_group", "extra_trees", "linear_tree"]
         if self.params.boosting == "goss":
             # GOSS sampling counts are compile-time constants (goss_k)
             static += ["top_rate", "other_rate"]

@@ -15,6 +15,8 @@ import dataclasses
 import math
 from typing import Optional
 
+import numpy as np
+
 from ..config import Params
 
 WAVE_TAILS = ("strict", "exact", "greedy", "half")
@@ -77,8 +79,12 @@ class GrowSpec:
     Nothing traced and nothing per-learner: ``is_rf``, ``num_class``,
     ``linear_k``, ``goss_k``, the round counts and the mesh learners' merge
     settings stay arguments of the builders that alone use them.
-    ``cat_key`` = (categorical column indices, cat_smooth, cat_l2,
-    max_cat_threshold); ``mono_key`` per-column monotone signs;
+    ``cat_key`` = (cat_smooth, cat_l2, max_cat_threshold,
+    max_cat_to_onehot, min_data_per_group) (:func:`categorical_key`), the
+    scan's scalars where the table has a categorical column: which columns
+    they are, and their bins, are an operand of every program that grows
+    on the table (``ops.split.CatColumns``), so every order of its columns
+    is one program; ``mono_key`` per-column monotone signs;
     ``nbins_key`` per-column used-bin counts (bounds the extra_trees
     draw); ``ic_key`` interaction-group membership rows; ``bynode_off``
     statically true when ``feature_fraction_bynode == 1.0``, so the growers
@@ -287,6 +293,15 @@ def resolve_wave(p: Params, n_rows: int) -> WaveSchedule:
     return WaveSchedule(width, "exact",
                         _exact_overgrow_target(p.num_leaves, width, over),
                         narrow)
+
+
+def categorical_key(mapper, p: Params) -> Optional[tuple]:
+    """``GrowSpec.cat_key`` of a table's ``BinMapper``: the categorical
+    scan's scalars, ``None`` where no column is categorical."""
+    if not np.any(mapper.is_categorical):
+        return None
+    return (float(p.cat_smooth), float(p.cat_l2), int(p.max_cat_threshold),
+            int(p.max_cat_to_onehot), float(p.min_data_per_group))
 
 
 def resolve_grow_spec(p: Params, n_rows: int, num_bins: int, *,

@@ -42,6 +42,7 @@ from ..ops.split import (
     find_best_split,
     leaf_output,
 )
+from ..utils import profiling
 from .spec import STRICT, GrowSpec, WaveSchedule
 
 # tools/hlo_counts.py flips this to compile the fused strict grower with
@@ -371,8 +372,8 @@ def _make_dist_scorer(axis_name: str, hist_merge: str, n_shards: int,
         # static per-feature config arrays slice ONCE; padded tail columns
         # carry mask 0 / mono 0 / is_cat False so a ragged last shard (or a
         # fully-padded shard when D > F) scores every pad slot -inf
-        cat_l = (None if cat_info is None else cat_info._replace(
-            is_cat=fslice(cat_info.is_cat, 0, False)))
+        cat_l = (None if cat_info is None else cat_info.per_feature(
+            lambda a: fslice(a, 0, 0)))
         mono_l = None if mono is None else fslice(mono, 0, 0)
         sub = f_loc // chunks           # divisible by construction
 
@@ -384,8 +385,8 @@ def _make_dist_scorer(axis_name: str, hist_merge: str, n_shards: int,
             rand_l = None if rand_s is None else fslice(rand_s, 1, 0)
             per_chunk = []
             for c in range(chunks):
-                cat_c = (None if cat_l is None else cat_l._replace(
-                    is_cat=csl(cat_l.is_cat, 0, c)))
+                cat_c = (None if cat_l is None else cat_l.per_feature(
+                    lambda a, c=c: csl(a, 0, c)))
                 mono_c = None if mono_l is None else csl(mono_l, 0, c)
                 if rand_l is None:
                     def one(h, m, d, lo, hi, po,
@@ -568,21 +569,25 @@ def pad_tree(tree: Tree, capacity: int) -> Tree:
                      else p_node2(tree.linear_coef, 0.0)))
 
 
-def build_cat_info(cat_key, num_features: int):
-    """Static ``GrowSpec.cat_key`` -> traced CatInfo (None passthrough).
-
-    The key is static so the compiled program specializes on WHICH
-    columns take subset splits.
-    """
+def build_cat_info(cat_key, cats):
+    """Static ``GrowSpec.cat_key`` and the operand ``cats``
+    (``ops.split.CatColumns``: which columns, their bins) -> traced
+    CatInfo; ``None`` where the key is.  The key holds the scan's scalars
+    alone (``models.spec.categorical_key``), so every order of a table's
+    columns is one program."""
     if cat_key is None:
         return None
-    idx, smooth, l2, mct = cat_key
-    is_cat = jnp.zeros(num_features, bool).at[jnp.asarray(idx)].set(True)
-    return CatInfo(is_cat=is_cat, cat_smooth=jnp.float32(smooth),
-                   cat_l2=jnp.float32(l2), max_cat_threshold=int(mct))
+    if cats is None:
+        raise ValueError("a categorical spec needs its columns: pass the "
+                         "table's ops.split.CatColumns as cats")
+    smooth, l2, mct, to_onehot, mdpg = cat_key
+    return CatInfo(is_cat=cats.is_cat, cat_smooth=jnp.float32(smooth),
+                   cat_l2=jnp.float32(l2), max_cat_threshold=int(mct),
+                   cat_bins=cats.cat_bins, max_cat_to_onehot=int(to_onehot),
+                   min_data_per_group=jnp.float32(mdpg))
 
 
-def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
+def grower_from_spec(spec: GrowSpec, **placement):
     """The ONE place a :class:`GrowSpec` is mapped onto :func:`grow_tree`.
 
     Returns ``grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode,
@@ -593,8 +598,9 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
     ``axis_name``, ``fp_axis``, ``fuse_partition`` and the merge settings.
     Call it where the round program is BUILT: the per-column constraint
     arrays become constants the traced bodies close over.
-    ``cat_info_for(num_features)`` replaces the CatInfo built from
-    ``spec.cat_key`` (the feature-sharded learner slices its own).
+    ``cats`` (an operand: ``ops.split.CatColumns``) are the categorical
+    columns of ``bins`` (a feature shard's, where the features are
+    sharded), given wherever ``spec.cat_key`` is.
     ``members`` (``ops.members.Members``, an operand of the caller's
     program) grows on an EFB table's bundle columns; every per-feature key
     of the spec is over the original features.  ``col_order`` (an operand
@@ -608,18 +614,15 @@ def grower_from_spec(spec: GrowSpec, cat_info_for=None, **placement):
                 else jnp.asarray(spec.nbins_key, jnp.int32))
     ic_member = (None if spec.ic_key is None
                  else jnp.asarray(spec.ic_key, bool))
-    if cat_info_for is None:
-        cat_info_for = functools.partial(build_cat_info, spec.cat_key)
 
     def grow(bins, stats, feature_mask, ctx, max_depth, ff_bynode, key,
-             members=None, col_order=None):
+             members=None, col_order=None, cats=None):
         return grow_tree_logged(
             bins, stats, feature_mask, ctx, spec.num_leaves, spec.num_bins,
             max_depth, ff_bynode=None if spec.bynode_off else ff_bynode,
             key=key, hist_impl=spec.hist_impl, row_chunk=spec.row_chunk,
             hist_dtype=spec.hist_dtype, wave=spec.wave,
-            cat_info=cat_info_for(bins.shape[1] if members is None
-                                  else members.num_features),
+            cat_info=build_cat_info(spec.cat_key, cats),
             mono=mono, extra_trees=spec.extra_trees, col_bins=col_bins,
             ic_member=ic_member, members=members,
             onehot_rows=spec.onehot_rows, col_order=col_order, **placement)
@@ -760,6 +763,9 @@ def grow_tree_logged(
             n_shards=n_shards, voting_k=voting_k, hist_wire=hist_wire,
             merge_chunks=merge_chunks, members=members,
             onehot_rows=onehot_rows, col_order=col_order)
+    if cat_info is not None:
+        # the strict grower routes category sets in XLA
+        profiling.note("train.cat_route", "xla")
     n, num_features = bins.shape
     if members is not None:
         num_features = members.num_features
@@ -1447,16 +1453,13 @@ def grow_tree_frontier(
     # call — r5 trace: ~22 ms/wave of XLA-side partition work at 11M rows
     # reads data the kernel already holds in VMEM).  Static eligibility:
     # single-model growth (callers opt in; vmapped/batched growth keeps
-    # the custom-vmap wide-segment route), no feature sharding, no
-    # categorical subset splits, and a pallas-routed dtype.  Since r7 the
+    # the custom-vmap wide-segment route), no feature sharding, and a
+    # pallas-routed dtype.  A categorical split routes inside the kernel
+    # too, by its category set (``cat_sets``).  Since r7 the
     # feature axis may span multiple VMEM blocks — routing then reads the
     # wave-gathered split-feature code rows instead of the resident bins
     # tile (_fused_part_kernel_mb), so MSLR-class shapes (F=136) get the
     # in-kernel partition too.
-    exact_dtype = hist_dtype == "f32x"
-    route_pallas = (hist_impl == "pallas"
-                    or (hist_impl == "auto" and not exact_dtype
-                        and jax.default_backend() == "tpu"))
     from ..ops.histogram_pallas import _vmem_blocking, feature_layout
 
     # more than one VMEM feature block: the kernel routes rows by wave rank
@@ -1465,15 +1468,23 @@ def grow_tree_frontier(
     wave_f_blk, wave_f_blocks = _vmem_blocking(num_cols, num_bins,
                                                3 * w_width)[:2]
     multi_block = wave_f_blocks > 1
-    fuse_part = (fuse_partition and fp_axis is None and cat_info is None
+    exact_dtype = hist_dtype == "f32x"
+    route_pallas = (hist_impl == "pallas"
+                    or (hist_impl == "auto" and not exact_dtype
+                        and jax.default_backend() == "tpu"))
+    fuse_part = (fuse_partition and fp_axis is None
                  and hist_dtype != "int8" and route_pallas
                  and w_width > 1
                  # the per-row field lookup runs at bf16 DEFAULT
                  # precision — every table value (bin, 2*rank child
-                 # offset, and the feature id where one block routes by
-                 # it) must be an exact bf16 integer
+                 # offset, the feature id where one block routes by it,
+                 # a category set's 0/1) must be an exact bf16 integer
                  and max(2 * w_width, num_bins) <= 256
                  and (multi_block or num_cols <= 256))
+    if cat_info is not None:
+        # where this grower routes category sets, noted as it is traced:
+        # inside the partition-fused kernels, or in XLA
+        profiling.note("train.cat_route", "fused" if fuse_part else "xla")
     # the fused kernels' feature rows, the columns by their one-hot's height
     by_height = (fuse_part and col_order is not None
                  and onehot_rows is not None
@@ -1778,8 +1789,10 @@ def grow_tree_frontier(
                 # values (sel/feat/thr/rank2/dl) are all <= 256 under the
                 # single-f-block gate, so the dot stays bf16-exact.
                 # a wave split's column and range (ops.members): rows 5-6
-                # carry lo and inv, zero for a plain column's ``v <= thr``
+                # carry lo and inv, zero for a plain column's ``v <= thr``;
+                # row 7 whether the split is a category set's
                 zw = jnp.zeros(width)
+                cat_w = zw if cat_info is None else prow[:, K.CAND_CAT]
                 if members is None:
                     tbl_w = jnp.stack([active_r.astype(f32),
                                        (zw if multi_block
@@ -1790,8 +1803,8 @@ def grow_tree_frontier(
                                         .astype(f32)),
                                        prow[:, K.CAND_BIN],
                                        (2 * iota_w).astype(f32),
-                                       direct_left.astype(f32), zw, zw, zw],
-                                      axis=1)                    # [W, 8]
+                                       direct_left.astype(f32), zw, zw,
+                                       cat_w], axis=1)           # [W, 8]
                 else:
                     wcol, wlo, whi, winv = split_route(
                         members, prow[:, K.CAND_FEAT].astype(jnp.int32),
@@ -1801,7 +1814,7 @@ def grow_tree_frontier(
                                         else to_row(wcol).astype(f32)),
                                        whi, (2 * iota_w).astype(f32),
                                        direct_left.astype(f32), wlo,
-                                       winv.astype(f32), zw], axis=1)
+                                       winv.astype(f32), cat_w], axis=1)
                 oh_w = (parent_r[:, None] == p[None, :])         # [W, n]
                 pv_t = lax.dot_general(
                     tbl_w.astype(f32).T, oh_w.astype(f32),
@@ -1818,7 +1831,10 @@ def grow_tree_frontier(
                     wfeat=to_row(prow[:, K.CAND_FEAT].astype(jnp.int32)
                                  if members is None else wcol),
                     num_features=num_cols, name=role, f_blk=wave_f_blk,
-                    layout=layout, row_of=row_of)
+                    layout=layout, row_of=row_of,
+                    # the wave's category sets, 0/1 by bin
+                    cat_sets=(None if cat_info is None else
+                              st.cand_catmask[parent_r].astype(f32)))
                 # the kernel's direct_hist is the LOCAL pre-merge partial,
                 # planes [W, 3, F, B]: every merge topology applies after it
                 # unchanged (voting keeps it unmerged for the scorer's

@@ -964,17 +964,45 @@ def _accumulate_wave(bins_ref, stats_ref, seg, out_ref, *, runs: tuple,
                   runs, fblock_axis)
 
 
-def _route(v, pv_ref, thr):
+def _route(v, pv_ref, thr, rank2, cset_ref=None):
     """Whether each row's code ``v`` goes left: inside ``[lo, thr]``
     (``pv`` row 5), flipped where ``inv`` (row 6) — ``ops.members.go_left``
-    in the kernels' lanes."""
+    in the kernels' lanes; with ``cset_ref``, where ``pv`` row 7 marks a
+    category set's split, whether ``v`` is in the set
+    (:func:`_in_category_set`)."""
     inside = (v >= pv_ref[5, :]) & (v <= thr)
-    return inside != (pv_ref[6, :] > 0.0)
+    go_left = inside != (pv_ref[6, :] > 0.0)
+    if cset_ref is None:
+        return go_left
+    # logic, not a select of booleans (which Mosaic does not lower)
+    cat = pv_ref[7, :] > 0.0
+    return (cat & _in_category_set(v, rank2, cset_ref)) | (~cat & go_left)
 
 
-def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
-                       runs: tuple, num_bins: int, num_segments: int,
-                       bins_minor: bool = False):
+def _in_category_set(v, rank2, cset_ref):
+    """Whether each row's code ``v`` is in the category set of its leaf's
+    split: ``cset_ref [B, W_pad]`` holds the wave's sets as 0/1 columns, one
+    a wave rank; a one-hot dot of the rows' ranks (``rank2`` = 2 x rank)
+    lays each row's set down its lane (exact: 0/1 products, one term), and
+    the bit at the row's code is read off along the sublanes."""
+    num_bins, w_pad = cset_ref.shape
+    chunk = v.shape[0]
+    oh_rank = jnp.where(
+        (2 * lax.broadcasted_iota(jnp.int32, (w_pad, chunk), 0)
+         ).astype(jnp.float32) == rank2.reshape(1, chunk), 1.0, 0.0)
+    sets = lax.dot_general(
+        cset_ref[:], oh_rank,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # [B, chunk]
+    hit = (lax.broadcasted_iota(jnp.int32, (num_bins, chunk), 0)
+           .astype(jnp.float32) == v.reshape(1, chunk))
+    bit = jnp.sum(jnp.where(hit, sets, 0.0), axis=0, keepdims=True)
+    return bit[0, :] > 0.5
+
+
+def _fused_part_kernel(bins_ref, stats_ref, pv_ref, *refs, runs: tuple,
+                       num_bins: int, num_segments: int,
+                       bins_minor: bool = False, cat_sets: bool = False):
     """Wave histogram + ROW PARTITION in one kernel (single f-block).
 
     Accumulation is ALWAYS bf16-dot into f32 here; f32-exact callers get
@@ -1004,7 +1032,14 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
         0 otherwise (the caller adds the wave's traced node base);
       phase 2: the standard segment-folded one-hot dots, with seg now
         produced in-register.
+
+    With ``cat_sets`` an operand ``cset_ref [B, W_pad]`` follows ``pv_ref``
+    (the wave's category sets, :func:`_in_category_set`), and ``pv`` row 7
+    marks the splits that route by them.
     """
+    cset_ref = refs[0] if cat_sets else None
+    out_ref, enc_ref = refs[-2:]
+
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
@@ -1027,7 +1062,7 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
     v = lax.fori_loop(0, bins_ref.shape[0], vbody,
                       jnp.zeros((chunk,), jnp.float32))
     psel = sel > 0.0
-    go_left = _route(v, pv_ref, thr)
+    go_left = _route(v, pv_ref, thr, rank2, cset_ref)
     to_direct = psel & (go_left == (dl > 0.0))
     seg = jnp.where(to_direct, (rank2 * 0.5).astype(jnp.int32),
                     jnp.int32(w)).reshape(1, chunk)
@@ -1041,9 +1076,9 @@ def _fused_part_kernel(bins_ref, stats_ref, pv_ref, out_ref, enc_ref, *,
                      bins_minor=bins_minor)
 
 
-def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
-                          enc_ref, *, runs: tuple, num_bins: int,
-                          num_segments: int, bins_minor: bool = False):
+def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, *refs,
+                          runs: tuple, num_bins: int, num_segments: int,
+                          bins_minor: bool = False, cat_sets: bool = False):
     """Multi-feature-block variant of :func:`_fused_part_kernel`.
 
     When the feature axis needs more than one VMEM block (MSLR's 136
@@ -1056,8 +1091,13 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
     routing in-register — the "cross-block winner select" is thereby a
     replicated select, not an inter-block reduction — and rewrites the
     same ``enc`` block with the same value.  Phase 2 is byte-identical
-    to the single-block kernel over this block's features.
+    to the single-block kernel over this block's features; a category
+    set's split routes as there (``cat_sets``: ``cset_ref`` after
+    ``wbins_ref``).
     """
+    cset_ref = refs[0] if cat_sets else None
+    out_ref, enc_ref = refs[-2:]
+
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
@@ -1080,7 +1120,7 @@ def _fused_part_kernel_mb(bins_ref, stats_ref, pv_ref, wbins_ref, out_ref,
 
     v = lax.fori_loop(0, w, vbody, jnp.zeros((chunk,), jnp.float32))
     psel = sel > 0.0
-    go_left = _route(v, pv_ref, thr)
+    go_left = _route(v, pv_ref, thr, rank2, cset_ref)
     to_direct = psel & (go_left == (dl > 0.0))
     seg = jnp.where(to_direct, (rank2 * 0.5).astype(jnp.int32),
                     jnp.int32(w)).reshape(1, chunk)
@@ -1143,6 +1183,7 @@ def hist_partition_fused_pallas(
     bins_minor: bool | None = None,     # None: from the width (TURNED_MAX_K)
     layout: Optional[FeatureLayout] = None,   # the rows' loops
     row_of: jnp.ndarray | None = None,        # [F] i32 row of each column
+    cat_sets: jnp.ndarray | None = None,      # [W, B] 0/1 category sets
 ):
     """Fused wave pass: histogram over the direct children PLUS the row
     partition (see _fused_part_kernel).  Returns
@@ -1180,6 +1221,11 @@ def hist_partition_fused_pallas(
     features' code rows are gathered once (``wfeat`` required) and the
     multi-block kernel routes every block from that [W_pad, n] operand
     — see :func:`_fused_part_kernel_mb`.
+
+    ``cat_sets`` (``None`` for a table with no categorical column: the
+    kernels are then built without it) holds each wave split's category
+    set, its bins 0/1; a row of a split that ``pv_t`` row 7 marks goes
+    left iff its code's bit is set (:func:`_in_category_set`).
     """
     f_rows, n_pad = bins_t.shape
     if num_features is None:
@@ -1208,6 +1254,12 @@ def hist_partition_fused_pallas(
     if bins_minor is None:
         bins_minor = k <= TURNED_MAX_K
     acc_dims = (k, num_bins) if bins_minor else (num_bins, k)
+    w_pad = -(-num_segments // 8) * 8
+    cats = []
+    if cat_sets is not None:
+        # [B, W_pad]: a wave rank's set down a column
+        cats = [jnp.pad(cat_sets.astype(jnp.float32),
+                        ((0, w_pad - num_segments), (0, 0))).T]
 
     if n_fblk == 1:
         def one_pass(stats_arr):
@@ -1216,7 +1268,8 @@ def hist_partition_fused_pallas(
                                   runs=layout.runs,
                                   num_bins=num_bins,
                                   num_segments=num_segments,
-                                  bins_minor=bins_minor),
+                                  bins_minor=bins_minor,
+                                  cat_sets=bool(cats)),
                 grid=(n_chunks,),
                 in_specs=[
                     pl.BlockSpec((num_features, chunk), lambda c: (0, c),
@@ -1225,7 +1278,9 @@ def hist_partition_fused_pallas(
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((8, chunk), lambda c: (0, c),
                                  memory_space=pltpu.VMEM),
-                ],
+                ] + [pl.BlockSpec((num_bins, w_pad), lambda c: (0, 0),
+                                  memory_space=pltpu.VMEM)
+                     for _ in cats],
                 out_specs=[
                     pl.BlockSpec((num_features,) + acc_dims,
                                  lambda c: (0, 0, 0),
@@ -1240,13 +1295,12 @@ def hist_partition_fused_pallas(
                 ],
                 interpret=interpret,
                 name=name,
-            )(bins_t, stats_arr, pv_t)
+            )(bins_t, stats_arr, pv_t, *cats)
     else:
         if wfeat is None:
             raise ValueError(
                 "multi-block partition fusion needs the wave split "
                 "features (wfeat) to gather the routing code rows")
-        w_pad = -(-num_segments // 8) * 8
         wf = jnp.clip(wfeat.astype(jnp.int32), 0, num_features - 1)
         if w_pad != num_segments:
             wf = jnp.pad(wf, (0, w_pad - num_segments))
@@ -1258,7 +1312,8 @@ def hist_partition_fused_pallas(
                                   runs=layout.runs,
                                   num_bins=num_bins,
                                   num_segments=num_segments,
-                                  bins_minor=bins_minor),
+                                  bins_minor=bins_minor,
+                                  cat_sets=bool(cats)),
                 grid=(n_fblk, n_chunks),
                 in_specs=[
                     pl.BlockSpec((f_blk, chunk), lambda f, c: (f, c),
@@ -1269,7 +1324,9 @@ def hist_partition_fused_pallas(
                                  memory_space=pltpu.VMEM),
                     pl.BlockSpec((w_pad, chunk), lambda f, c: (0, c),
                                  memory_space=pltpu.VMEM),
-                ],
+                ] + [pl.BlockSpec((num_bins, w_pad), lambda f, c: (0, 0),
+                                  memory_space=pltpu.VMEM)
+                     for _ in cats],
                 out_specs=[
                     pl.BlockSpec((f_blk,) + acc_dims,
                                  lambda f, c: (f, 0, 0),
@@ -1284,7 +1341,7 @@ def hist_partition_fused_pallas(
                 ],
                 interpret=interpret,
                 name=name,
-            )(bins_t, stats_arr, pv_t, wbins_t)
+            )(bins_t, stats_arr, pv_t, wbins_t, *cats)
 
     if hist_dtype in ("f32", "f32x"):
         hi, lo = split_hi_lo(stats_t)

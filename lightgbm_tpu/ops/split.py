@@ -15,11 +15,17 @@ configs can be vmapped without recompilation (SURVEY.md §7 sweep design).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 NEG_INF = -jnp.inf
+# gains of a categorical feature's two scan directions closer than this
+# (relative) are one tie, which the ascending direction wins
+TIE_RTOL = 1e-5
 
 
 class SplitContext(NamedTuple):
@@ -136,16 +142,52 @@ class CatInfo(NamedTuple):
     """Static-per-dataset categorical split configuration.
 
     ``is_cat`` marks the original features holding categorical codes (the
-    scan's features: an EFB table's scan reads its member view); the
+    scan's features: an EFB table's scan reads its member view);
+    ``cat_bins`` the bins of each one's kept categories (``dataset.
+    BinMapper.category_values``: the overflow bin after them, which holds
+    the rare categories and NaN, is in no category set; ``None``: every
+    bin is a category).  A column whose bins, the overflow bin counted,
+    are at most ``max_cat_to_onehot`` takes one-vs-rest splits.  The
     scalars mirror upstream ``cat_smooth`` / ``cat_l2`` /
-    ``max_cat_threshold`` (cat-specific regularization of the k-vs-rest
-    subset search).
+    ``max_cat_threshold`` / ``max_cat_to_onehot`` / ``min_data_per_group``.
     """
 
     is_cat: jnp.ndarray        # bool [F]
     cat_smooth: jnp.ndarray    # f32 []
     cat_l2: jnp.ndarray        # f32 []
     max_cat_threshold: int     # static
+    cat_bins: Optional[jnp.ndarray] = None    # i32 [F]
+    max_cat_to_onehot: int = 0                # static
+    min_data_per_group: jnp.ndarray = 0.0     # f32 []
+
+    def per_feature(self, fn) -> "CatInfo":
+        """This configuration with ``fn`` applied to each per-feature
+        array (a shard's slice of the features)."""
+        return self._replace(**{
+            k: fn(getattr(self, k)) for k in ("is_cat", "cat_bins")
+            if getattr(self, k) is not None})
+
+
+class CatColumns(NamedTuple):
+    """The categorical columns of a table, an OPERAND of every program that
+    grows on it (``models.tree.build_cat_info`` makes the CatInfo): which
+    columns, and the bins of each one's categories (0 for a numeric
+    column)."""
+
+    is_cat: jnp.ndarray        # bool [F]
+    cat_bins: jnp.ndarray      # i32 [F]
+
+    @staticmethod
+    def of(mapper) -> Optional["CatColumns"]:
+        """A ``dataset.BinMapper``'s categorical columns, each one's bins
+        but its overflow bin; ``None`` where no column is categorical."""
+        is_cat = np.asarray(mapper.is_categorical, bool)
+        if not is_cat.any():
+            return None
+        return CatColumns(
+            is_cat=jnp.asarray(is_cat),
+            cat_bins=jnp.asarray(np.where(
+                is_cat, np.asarray(mapper.n_bins) - 1, 0), jnp.int32))
 
 
 def feature_best_gains(
@@ -239,11 +281,9 @@ def find_best_split(
       feature_mask: f32/bool ``[F]`` — 1 for usable features this tree
         (feature_fraction sampling; SURVEY.md §2C "Stochasticity").
       depth_ok: bool [] — False disqualifies every split (max_depth cap).
-      cat_info: optional :class:`CatInfo`.  Categorical columns use
-        LightGBM's gradient-ordered k-vs-rest subset search (Fisher 1958
-        trick, upstream ``FindBestThresholdCategorical``): bins sort by
-        grad/(hess + cat_smooth), the usual prefix scan runs in that order,
-        and the winning prefix becomes the left-child category SET.
+      cat_info: optional :class:`CatInfo`.  Categorical columns take
+        category-SET splits by LightGBM's ``FindBestThresholdCategorical``
+        (:func:`_categorical_scan`); the set is the left child's.
       mono: optional i32 ``[F]`` per-feature monotone constraints in
         {-1, 0, +1} (upstream ``monotone_constraints``, basic method):
         candidates whose child outputs violate the required ordering are
@@ -319,74 +359,177 @@ def find_best_split(
             right_g=win_r[0], right_h=win_r[1], right_c=win_r[2],
             left_out=pick(wl), right_out=pick(wr))
 
+    with jax.named_scope("lgbtpu.wave.cat_scan"):
+        gain_c, cat_pick, cat_mask_at = _categorical_scan(
+            hist, total, ctx, feature_mask, depth_ok, cat_info, mono, lo, hi,
+            p_out, rand_bins)
     is_cat = cat_info.is_cat
-    # Fisher ordering: bins ranked by grad/(hess + cat_smooth); empty bins
-    # push to the end (+/-inf) so prefixes only accumulate populated
-    # categories and unseen-at-this-node categories fall to the RIGHT
-    # child.  Upstream scans ASCENDING and DESCENDING (each prefix capped
-    # at max_cat_threshold), which together reach small-subset partitions
-    # on either end of the ordering.
-    g_, h_, c_ = hist[0], hist[1], hist[2]
-    raw_score = g_ / (h_ + cat_info.cat_smooth)
-    pos = jnp.arange(num_bins)[None, :]
-    ctx_cat = ctx._replace(lambda_l2=ctx.lambda_l2 + cat_info.cat_l2)
-    p_out_cat = (leaf_output(tg, th, ctx_cat) if parent_out is None
-                 else parent_out)
-
-    def scan_direction(order):
-        hist_s = jnp.take_along_axis(hist, order[None], axis=2)
-        cum_s = jnp.cumsum(hist_s, axis=2)
-        slg, slh, slc = cum_s[0], cum_s[1], cum_s[2]
-        srg, srh, src = tg - slg, th - slh, tc - slc
-        gain_c, swl, swr = split_gain_scan(slg, slh, slc, srg, srh, src,
-                                           tg, th, ctx_cat, lo, hi,
-                                           p_out_cat)
-        valid_c = (
-            split_stats_valid(slc, src, slh, srh, gain_c, ctx)
-            & (feature_mask[:, None] > 0)
-            & depth_ok
-            & (pos < cat_info.max_cat_threshold)
-        )
-        if mono is not None:
-            # monotonicity is undefined over unordered category sets:
-            # constrained features take no subset splits (upstream rejects
-            # monotone_constraints on categorical columns at parse time)
-            valid_c &= mono[:, None] == 0
-        if rand_bins is not None:
-            valid_c &= pos == rand_bins[:, None]
-        return (jnp.where(valid_c, gain_c, NEG_INF),
-                (slg, slh, slc, srg, srh, src, swl, swr))
-
-    order_asc = jnp.argsort(jnp.where(c_ > 0, raw_score, jnp.inf), axis=1)
-    order_desc = jnp.argsort(jnp.where(c_ > 0, -raw_score, jnp.inf), axis=1)
-    gain_a, stats_a = scan_direction(order_asc)
-    gain_d, stats_d = scan_direction(order_desc)
-    use_desc = gain_d > gain_a
-    gain_c = jnp.maximum(gain_a, gain_d)
-    # categorical columns ONLY take subset splits; numeric only thresholds
+    # categorical columns ONLY take set splits; numeric only thresholds
     gain_all = jnp.where(is_cat[:, None], gain_c, gain)
 
     flat_idx = jnp.argmax(gain_all.reshape(-1))
     feat = (flat_idx // num_bins).astype(jnp.int32)
     bin_idx = (flat_idx % num_bins).astype(jnp.int32)
     cat_won = is_cat[feat]
-    desc_won = use_desc[feat, bin_idx]
-    order_f = jnp.where(desc_won, order_desc[feat], order_asc[feat])  # [B]
-    inv = jnp.argsort(order_f)                     # rank of each bin
-    cat_mask = cat_won & (inv <= bin_idx)          # bool [B]
+    hit = ((jnp.arange(num_features)[:, None] == feat)
+           & (jnp.arange(num_bins)[None, :] == bin_idx))          # [F, B]
 
-    def pick(ia, ib, plain):
-        cat_val = jnp.where(desc_won, ib[feat, bin_idx], ia[feat, bin_idx])
-        return jnp.where(cat_won, cat_val, plain[feat, bin_idx])
+    def pick(x):
+        return jnp.sum(jnp.where(hit, x, 0.0), axis=(-2, -1))
 
+    plain = (lg, lh, lc, rg, rh, rc, wl, wr)
+    won = [jnp.where(cat_won, pick(c), pick(jnp.broadcast_to(n, gain.shape)))
+           for c, n in zip(cat_pick, plain)]
     return BestSplit(
-        gain=gain_all.reshape(-1)[flat_idx], feature=feat, bin=bin_idx,
-        left_g=pick(stats_a[0], stats_d[0], lg),
-        left_h=pick(stats_a[1], stats_d[1], lh),
-        left_c=pick(stats_a[2], stats_d[2], lc),
-        right_g=pick(stats_a[3], stats_d[3], rg),
-        right_h=pick(stats_a[4], stats_d[4], rh),
-        right_c=pick(stats_a[5], stats_d[5], rc),
-        left_out=pick(stats_a[6], stats_d[6], wl),
-        right_out=pick(stats_a[7], stats_d[7], wr),
-        cat=cat_won, cat_mask=cat_mask)
+        gain=jnp.max(gain_all), feature=feat, bin=bin_idx,
+        left_g=won[0], left_h=won[1], left_c=won[2],
+        right_g=won[3], right_h=won[4], right_c=won[5],
+        left_out=won[6], right_out=won[7],
+        cat=cat_won, cat_mask=cat_won & cat_mask_at(hit))
+
+
+def _group_evaluated(cum_c, ok, min_data_per_group):
+    """Which positions of LightGBM's sorted categorical scan are evaluated
+    (``[F, K]``): a position whose split is allowed (``ok``) once the
+    categories added since the last evaluated one hold ``min_data_per_group``
+    rows (``cum_c``: the left side's count at each position).  The rule is
+    sequential, so it runs as a scan over the ``K`` positions."""
+    def step(last, x):
+        c, o = x
+        ev = o & (c - last >= min_data_per_group)
+        return jnp.where(ev, c, last), ev
+
+    _, ev = lax.scan(step, jnp.zeros(cum_c.shape[0], cum_c.dtype),
+                     (cum_c.T, ok.T))
+    return ev.T
+
+
+def _categorical_scan(hist, total, ctx: SplitContext, feature_mask,
+                      depth_ok, cat_info: CatInfo, mono, lo, hi, p_out,
+                      rand_bins):
+    """LightGBM's ``FindBestThresholdCategorical`` over every feature's
+    bins (planes ``hist [3, F, B]``, their sums ``total [3, F, 1]``), each
+    candidate the set of categories that goes LEFT:
+
+    * a feature of at most ``max_cat_to_onehot`` bins, the overflow bin
+      counted, tries each category alone against the rest, at
+      ``lambda_l2``;
+    * any other sorts the categories whose count reaches ``cat_smooth`` by
+      ``G / (H + cat_smooth)`` and scans the prefixes of that order from
+      both ends (the second direction is the first read backwards), at most
+      ``min(max_cat_threshold, (used + 1) // 2)`` categories, at
+      ``lambda_l2 + cat_l2``, each side holding ``min_data_in_leaf`` rows
+      and ``min_sum_hessian_in_leaf`` and the right one ``min_data_per_group``
+      rows, and a candidate evaluated only once ``min_data_per_group`` rows
+      came in since the last one (:func:`_group_evaluated`);
+    * the overflow bin (rare categories, NaN) is in no set.
+
+    The gain is the children's objective less the node's at ``lambda_l2``
+    (LightGBM's ``gain_shift``), so it ranks against numeric splits.
+    Departures from LightGBM: counts are the histogram's own (it estimates
+    them from the hessian), no ``kEpsilon`` is added to a hessian sum, and
+    its exact tie between the two directions (the ascending one wins) is
+    taken to ``TIE_RTOL``, the scale of float32 rounding.
+
+    Returns ``(gain [F, B], picks, mask_at)``: the gain of the candidate at
+    each (feature, position) (``-inf`` where none), its eight numbers
+    ``(lg, lh, lc, rg, rh, rc, wl, wr)`` as ``[F, B]`` arrays, and
+    ``mask_at(hit)``, the bins of the candidate at ``hit`` (bool ``[F, B]``,
+    one cell) as bool ``[B]``."""
+    num_features, num_bins = hist.shape[1], hist.shape[2]
+    g_, h_, c_ = hist[0], hist[1], hist[2]                        # [F, B]
+    tg, th, tc = total[0], total[1], total[2]                    # [F, 1]
+    pos = jnp.arange(num_bins)[None, :]
+    cat_bins = (jnp.full((num_features,), num_bins, jnp.int32)
+                if cat_info.cat_bins is None else cat_info.cat_bins)
+    # LightGBM counts a column's bins with the one of NaN and the rest
+    onehot = cat_bins + 1 <= cat_info.max_cat_to_onehot
+    in_range = pos < cat_bins[:, None]
+    usable = (feature_mask[:, None] > 0) & depth_ok
+    if mono is not None:
+        # monotonicity is undefined over unordered category sets:
+        # constrained features take no set splits (upstream rejects
+        # monotone_constraints on categorical columns at parse time)
+        usable &= mono[:, None] == 0
+    if rand_bins is not None:
+        usable &= pos == rand_bins[:, None]
+    parent_obj = leaf_objective_at(p_out, tg, th, ctx)
+
+    def gain_at(lg, lh, lc, c):
+        rg, rh, rc = tg - lg, th - lh, tc - lc
+        wl = constrained_leaf_output(lg, lh, lc, c, lo, hi, p_out)
+        wr = constrained_leaf_output(rg, rh, rc, c, lo, hi, p_out)
+        gain = (leaf_objective_at(wl, lg, lh, c)
+                + leaf_objective_at(wr, rg, rh, c) - parent_obj)
+        return gain, (lg, lh, lc, rg, rh, rc, wl, wr)
+
+    # one-vs-rest: bin t alone goes left
+    gain_o, stats_o = gain_at(g_, h_, c_, ctx)
+    valid_o = (split_stats_valid(c_, stats_o[5], h_, stats_o[4], gain_o, ctx)
+               & in_range & usable)
+    gain_o = jnp.where(valid_o, gain_o, NEG_INF)
+
+    # the sorted scan over the categories that hold cat_smooth rows
+    ctx_cat = ctx._replace(lambda_l2=ctx.lambda_l2 + cat_info.cat_l2)
+    elig = in_range & (c_ >= cat_info.cat_smooth)
+    used = jnp.sum(elig, axis=1, dtype=jnp.int32)                 # [F]
+    ratio = g_ / (h_ + cat_info.cat_smooth)
+    order_asc = jnp.argsort(jnp.where(elig, ratio, jnp.inf), axis=1,
+                            stable=True)
+    back = used[:, None] - 1 - pos
+    order_desc = jnp.where(
+        back >= 0,
+        jnp.take_along_axis(order_asc, jnp.maximum(back, 0), axis=1),
+        order_asc)
+    span = jnp.minimum(used, jnp.minimum(cat_info.max_cat_threshold,
+                                         (used + 1) // 2))
+    k = min(cat_info.max_cat_threshold, num_bins)
+    mdpg = cat_info.min_data_per_group
+
+    def direction(order):
+        # plane by plane: a gather of the three planes at once is laid out
+        # with the 3-wide axis minor, 128 lanes wide on the chip
+        cum = [jnp.cumsum(jnp.take_along_axis(x, order, axis=1), axis=1)
+               for x in (g_, h_, c_)]
+        gain_d, stats_d = gain_at(cum[0], cum[1], cum[2], ctx_cat)
+        lc, rg, rh, rc = stats_d[2], stats_d[3], stats_d[4], stats_d[5]
+        ok = ((lc >= ctx.min_data_in_leaf)
+              & (stats_d[1] >= ctx.min_sum_hessian)
+              & (rc >= ctx.min_data_in_leaf) & (rc >= mdpg)
+              & (rh >= ctx.min_sum_hessian) & (pos < span[:, None]))
+        ev = _group_evaluated(lc[:, :k], ok[:, :k], mdpg)
+        ev = jnp.pad(ev, ((0, 0), (0, num_bins - k)))
+        valid = ev & (gain_d > ctx.min_gain_to_split) & usable
+        return jnp.where(valid, gain_d, NEG_INF), stats_d
+
+    gain_a, stats_a = direction(order_asc)
+    gain_d, stats_d = direction(order_desc)
+    # LightGBM scans ascending first and keeps a later candidate only where
+    # it gains more.  The two ends reach one partition of a node's
+    # categories as mirror sets (left and right swapped), whose gains differ
+    # by float32 rounding alone; a descending set wins only where it beats
+    # every ascending one by more than TIE_RTOL, or rounding would decide
+    # which side the categories that no row holds go to
+    best_a = jnp.max(gain_a, axis=1, keepdims=True)
+    beat = jnp.where(jnp.isfinite(best_a),
+                     best_a + TIE_RTOL * jnp.abs(best_a), NEG_INF)
+    gain_d = jnp.where(gain_d > beat, gain_d, NEG_INF)
+    use_desc = gain_d > gain_a
+    gain_s = jnp.maximum(gain_a, gain_d)
+    oh = onehot[:, None]
+    gain = jnp.where(oh, gain_o, gain_s)
+    picks = tuple(jnp.where(oh, o, jnp.where(use_desc, d, a))
+                  for o, a, d in zip(stats_o, stats_a, stats_d))
+
+    def mask_at(hit):
+        row = jnp.any(hit, axis=1)                                # [F]
+        at = jnp.sum(jnp.where(hit, pos, 0))
+        desc = jnp.any(hit & use_desc)
+        order_f = jnp.sum(jnp.where(row[:, None],
+                                    jnp.where(desc, order_desc, order_asc),
+                                    0), axis=0)                   # [B]
+        rank = jnp.argsort(order_f)          # each bin's place in the order
+        one = jnp.any(row & onehot)
+        return jnp.where(one, pos[0] == at, rank <= at)
+
+    return gain, picks, mask_at

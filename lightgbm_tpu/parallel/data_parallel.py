@@ -82,6 +82,17 @@ def shard_rows(mesh: Mesh, *arrays):
     return out if len(out) > 1 else out[0]
 
 
+def jit_with_cats(sharded):
+    """``sharded`` (a mesh step whose last operand is the table's
+    categorical columns, ``ops.split.CatColumns``) jitted with that operand
+    given by keyword: ``cats=None`` for a table with none."""
+    @functools.wraps(sharded)       # its name and operands name the program
+    def step(*args, cats=None):
+        return sharded(*args, cats)
+
+    return jax.jit(step)
+
+
 def _mesh_grower(spec: GrowSpec, mesh: Mesh, merge_mode: str,
                  voting_k: int, wire_dtype: str, merge_chunks: int, **kw):
     """``grower_from_spec`` placed on the row mesh: histograms merge over
@@ -103,8 +114,10 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
                        wire_dtype: str = "f32", merge_chunks: int = 4):
     """Build the jitted data-parallel round step for a mesh.
 
-    Returns step(bins, y, w, bag, pred, feature_mask, hyper) ->
-    (tree [replicated], new_pred [row-sharded]).
+    Returns step(bins, y, w, bag, pred, feature_mask, hyper, key, cats) ->
+    (tree [replicated], new_pred [row-sharded]); ``cats``
+    (``ops.split.CatColumns``, replicated) the table's categorical columns,
+    ``None`` for none.
 
     The entire per-round body — gradients, bagged stats, the full best-first
     growth loop with merged histograms, and the train-score update — runs
@@ -133,7 +146,7 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
                         merge_chunks, fuse_partition=num_class == 1)
 
     def step_mc(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars,
-                key):
+                key, cats):
         """Multiclass: one tree per class per round, the class axis vmapped
         over the grower INSIDE the shard_map — the per-class histogram
         psums batch into one collective.  GOSS (when requested) becomes
@@ -156,14 +169,15 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
                                (bag > 0).astype(jnp.float32)], axis=-1)
             return grow(bins, stats, feature_mask, hyper.ctx(),
                         hyper.max_depth, hyper.feature_fraction_bynode,
-                        kc)[:2]
+                        kc, cats=cats)[:2]
 
         from ..models.gbdt import mc_round_update
         return mc_round_update(grow_one, g, h,
                                jax.random.split(key, num_class), pred,
                                hyper.learning_rate)
 
-    def step(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars, key):
+    def step(bins, y, w, bag, pred, feature_mask, hyper: HyperScalars, key,
+             cats):
         g, h = obj.grad_hess(pred, y, w)
         if goss_k_shard is not None:
             from ..models.gbdt import _goss_compact_round
@@ -177,12 +191,13 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
                 key, lax.axis_index(DATA_AXIS))
             tree, new_pred, _ = _goss_compact_round(
                 grow, bins, y, w, bag, pred, feature_mask, hyper, key,
-                g, h, goss_k_shard, None, sample_key=sample_key)
+                g, h, goss_k_shard, None, sample_key=sample_key, cats=cats)
             return tree, new_pred
         stats = jnp.stack([g * bag, h * bag, bag], axis=-1)
         tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
                                  hyper.max_depth,
-                                 hyper.feature_fraction_bynode, key)
+                                 hyper.feature_fraction_bynode, key,
+                                 cats=cats)
         shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
         new_pred = pred + shrink * lookup_values(row_leaf, tree.leaf_value)
         return tree, new_pred
@@ -191,11 +206,11 @@ def make_dp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
         step_mc if num_class > 1 else step,
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
-                  P(DATA_AXIS), P(), P(), P()),
+                  P(DATA_AXIS), P(), P(), P(), P()),
         out_specs=(P(), P(DATA_AXIS)),
         check_vma=False,  # tree is replicated by construction via psum
     )
-    return jax.jit(sharded)
+    return jit_with_cats(sharded)
 
 
 @functools.lru_cache(maxsize=None)
@@ -212,7 +227,7 @@ def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
     training exactly (tested vs serial on the CPU mesh).
 
     step(bins_sh, y_sh, w_sh, bag_sh, pred_sh, xraw_sh, fmask, hyper,
-    key) -> (tree [replicated], new_pred [row-sharded]).
+    key, cats) -> (tree [replicated], new_pred [row-sharded]).
     """
     from ..models.tree import fit_linear_leaves
 
@@ -221,12 +236,13 @@ def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
                         merge_chunks, fuse_partition=True)
 
     def step(bins, y, w, bag, pred, xraw, feature_mask,
-             hyper: HyperScalars, key):
+             hyper: HyperScalars, key, cats):
         g, h = obj.grad_hess(pred, y, w)
         stats = jnp.stack([g * bag, h * bag, bag], axis=-1)
         tree, row_leaf, _ = grow(bins, stats, feature_mask, hyper.ctx(),
                                  hyper.max_depth,
-                                 hyper.feature_fraction_bynode, key)
+                                 hyper.feature_fraction_bynode, key,
+                                 cats=cats)
         tree, delta = fit_linear_leaves(
             tree, row_leaf, xraw, g, h, bag, hyper.linear_lambda,
             linear_k, spec.row_chunk, axis_name=DATA_AXIS)
@@ -237,11 +253,11 @@ def make_dp_linear_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
         step,
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
-                  P(DATA_AXIS), P(DATA_AXIS), P(), P(), P()),
+                  P(DATA_AXIS), P(DATA_AXIS), P(), P(), P(), P()),
         out_specs=(P(), P(DATA_AXIS)),
         check_vma=False,  # tree replicated by construction via psum
     )
-    return jax.jit(sharded)
+    return jit_with_cats(sharded)
 
 
 @functools.lru_cache(maxsize=None)
@@ -256,7 +272,7 @@ def make_dp_grow_step(mesh: Mesh, spec: GrowSpec,
     histograms (upstream's data-parallel ranking keeps whole queries per
     machine; here the query pass is replicated instead, same result).
 
-    step(bins_sharded, stats_sharded, feature_mask, hyper, key) ->
+    step(bins_sharded, stats_sharded, feature_mask, hyper, key, cats) ->
     (tree [replicated], row_leaf [row-sharded]) — callers update train
     predictions with one ``leaf_value[row_leaf]`` gather instead of
     re-traversing the tree (code-review r2).
@@ -264,15 +280,15 @@ def make_dp_grow_step(mesh: Mesh, spec: GrowSpec,
     grow = _mesh_grower(spec, mesh, merge_mode, voting_k, wire_dtype,
                         merge_chunks, fuse_partition=True)
 
-    def step(bins, stats, feature_mask, hyper: HyperScalars, key):
+    def step(bins, stats, feature_mask, hyper: HyperScalars, key, cats):
         return grow(bins, stats, feature_mask, hyper.ctx(), hyper.max_depth,
-                    hyper.feature_fraction_bynode, key)[:2]
+                    hyper.feature_fraction_bynode, key, cats=cats)[:2]
 
     sharded = shard_map(
         step,
         mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(), P(), P()),
+        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(), P(), P(), P()),
         out_specs=(P(), P(DATA_AXIS)),
         check_vma=False,  # tree replicated by construction via psum
     )
-    return jax.jit(sharded)
+    return jit_with_cats(sharded)

@@ -34,8 +34,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.gbdt import HyperScalars, _rebuild_objective
 from ..ops.lookup import lookup_values
+from ..ops.split import CatColumns
 from ..models.spec import GrowSpec
-from ..models.tree import build_cat_info, grower_from_spec
+from ..models.tree import grower_from_spec
+from .data_parallel import jit_with_cats
 
 FEATURE_AXIS = "feature"
 
@@ -126,37 +128,30 @@ def make_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
                        is_rf: bool = False, num_class: int = 1):
     """Build the jitted feature-parallel round step for a mesh.
 
-    step(bins_fsharded, y, w, bag, pred, fmask_fsharded, hyper, key) ->
-    (tree [replicated], new_pred [replicated]).
+    step(bins_fsharded, y, w, bag, pred, fmask_fsharded, hyper, key,
+    cats) -> (tree [replicated], new_pred [replicated]); ``cats``
+    (``ops.split.CatColumns``, replicated) the table's categorical columns,
+    ``None`` for none.
 
     ``num_class > 1`` vmaps the class axis over the grower INSIDE the
     shard_map (one tree per class per round, exactly like the dp
     learner's step_mc — the per-class split-exchange all_gathers batch
     into one collective).  ``spec.cat_key`` enables categorical k-vs-rest
-    splits: the static global is_cat mask is sliced to each shard's
-    column range (cat_key indices are GLOBAL training columns), the
-    winning subset mask rides the split exchange like any other
-    BestSplit field, and the partition's category-membership test runs
-    on the psum-broadcast global column.
+    splits: the global categorical columns are sliced to each shard's
+    column range (:func:`_local_cats`), the winning subset mask rides the
+    split exchange like any other BestSplit field, and the partition's
+    category-membership test runs on the psum-broadcast global column.
     """
     obj = _rebuild_objective(obj_key)
     n_shards = mesh.shape[FEATURE_AXIS]
-
-    def local_cat_info(f_local):
-        if spec.cat_key is None:
-            return None
-        full = build_cat_info(spec.cat_key, f_local * n_shards)
-        shard = jax.lax.axis_index(FEATURE_AXIS)
-        return full._replace(is_cat=jax.lax.dynamic_slice(
-            full.is_cat, (shard * f_local,), (f_local,)))
-
     # wave growth composes with the split exchange since r5 (categorical
     # datasets drop to the strict fp path inside grow_tree)
-    grow = grower_from_spec(spec, cat_info_for=local_cat_info,
-                            fp_axis=FEATURE_AXIS)
+    grow = grower_from_spec(spec, fp_axis=FEATURE_AXIS)
 
-    def step(bins_l, y, w, bag, pred, fmask_l, hyper: HyperScalars, key):
+    def step(bins_l, y, w, bag, pred, fmask_l, hyper: HyperScalars, key,
+             cats):
         g, h = obj.grad_hess(pred, y, w)          # [n] or [n, K]
+        cats = _local_cats(cats, bins_l.shape[1], n_shards)
 
         def grow_one(gc, hc, kc):
             stats = jnp.stack([gc * bag, hc * bag,
@@ -164,7 +159,7 @@ def make_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
             # the Booster gate guarantees bynode == 1.0 on the fp path (it
             # would sample per SHARD): no per-node threefry draw
             return grow(bins_l, stats, fmask_l, hyper.ctx(),
-                        hyper.max_depth, None, kc)[:2]
+                        hyper.max_depth, None, kc, cats=cats)[:2]
 
         if num_class > 1:
             from ..models.gbdt import mc_round_update
@@ -180,11 +175,26 @@ def make_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
         step,
         mesh=mesh,
         in_specs=(P(None, FEATURE_AXIS), P(), P(), P(), P(),
-                  P(FEATURE_AXIS), P(), P()),
+                  P(FEATURE_AXIS), P(), P(), P()),
         out_specs=(P(), P()),
         check_vma=False,  # tree replicated by construction via all_gather
     )
-    return jax.jit(sharded)
+    return jit_with_cats(sharded)
+
+
+def _local_cats(cats, f_local: int, n_shards: int):
+    """This feature shard's slice of the table's categorical columns
+    (``ops.split.CatColumns`` over the global columns, padded here to the
+    mesh's ``f_local * n_shards``); ``None`` for none."""
+    if cats is None:
+        return None
+    shard = jax.lax.axis_index(FEATURE_AXIS)
+
+    def local(a):
+        a = jnp.pad(a, (0, f_local * n_shards - a.shape[0]))
+        return jax.lax.dynamic_slice(a, (shard * f_local,), (f_local,))
+
+    return CatColumns(*(local(a) for a in cats))
 
 
 def make_mesh_2d(n_data: int, n_feature: int, devices=None) -> Mesh:
@@ -214,7 +224,7 @@ def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
     psum — both collectives ride the same mesh.
 
     step(bins_2dsharded, y, w, bag, pred [all row-sharded],
-    fmask_fsharded, hyper, key) -> (tree [replicated],
+    fmask_fsharded, hyper, key, cats [replicated]) -> (tree [replicated],
     new_pred [row-sharded]).
 
     r10 promotes this topology to the data learner's default at D>=8,
@@ -227,13 +237,14 @@ def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
     grow = grower_from_spec(spec, axis_name=DATA_AXIS, fp_axis=FEATURE_AXIS)
 
     def step(bins_b, y_l, w_l, bag_l, pred_l, fmask_l, hyper: HyperScalars,
-             key):
+             key, cats):
         g, h = obj.grad_hess(pred_l, y_l, w_l)
+        cats = _local_cats(cats, bins_b.shape[1], mesh.shape[FEATURE_AXIS])
         stats = jnp.stack([g * bag_l, h * bag_l,
                            (bag_l > 0).astype(jnp.float32)], axis=-1)
         # (Booster._dp2_shape admits only bynode == 1.0: no per-node draw)
         tree, row_leaf, _ = grow(bins_b, stats, fmask_l, hyper.ctx(),
-                                 hyper.max_depth, None, key)
+                                 hyper.max_depth, None, key, cats=cats)
         shrink = jnp.where(is_rf, 1.0, hyper.learning_rate)
         new_pred = pred_l + shrink * lookup_values(row_leaf, tree.leaf_value)
         return tree, new_pred
@@ -242,8 +253,8 @@ def make_dp_fp_train_step(mesh: Mesh, obj_key: tuple, spec: GrowSpec,
         step,
         mesh=mesh,
         in_specs=(P("data", FEATURE_AXIS), P("data"), P("data"), P("data"),
-                  P("data"), P(FEATURE_AXIS), P(), P()),
+                  P("data"), P(FEATURE_AXIS), P(), P(), P()),
         out_specs=(P(), P("data")),
         check_vma=False,  # tree replicated via psum + all_gather
     )
-    return jax.jit(sharded)
+    return jit_with_cats(sharded)

@@ -168,9 +168,13 @@ def dump_booster_dict(booster, num_iteration: Optional[int] = None,
     the model with RAW-VALUE thresholds (bin bounds resolved through the
     training bin mapper), traversable without any lightgbm_tpu code.
 
-    Categorical subset splits dump ``decision_type: '=='`` with the LEFT
-    category bin set; numeric splits dump ``decision_type: '<='`` with the
-    raw threshold.  Every split is on one ORIGINAL feature (EFB bundles are
+    Categorical splits dump ``decision_type: '=='`` with the LEFT set as
+    LightGBM writes it, the raw category values joined by ``||`` (``"3||7||
+    12"``; a value that is a whole number as an integer): a row goes left
+    iff its raw value is one of them, and NaN, a value never seen or one
+    of the rare categories that share the overflow bin goes right
+    (``default_left: false``).  Numeric splits dump ``decision_type:
+    '<='`` with the raw threshold.  Every split is on one ORIGINAL feature (EFB bundles are
     a training-time layout), so every threshold is a raw value.  Missing
     values are told as the codes route them: where fit saw NaN in a feature
     (``missing_type: "NaN"``) NaN has the last bin and goes right
@@ -228,7 +232,8 @@ def dump_booster_dict(booster, num_iteration: Optional[int] = None,
             }
             if cat:
                 out["decision_type"] = "=="
-                out["threshold"] = [int(b) for b in np.flatnonzero(cm[node])]
+                out["threshold"] = category_set_text(
+                    mapper.upper_bounds[feat], np.flatnonzero(cm[node]))
             else:
                 out["decision_type"] = "<="
                 out["threshold"] = float(
@@ -272,6 +277,19 @@ def dump_booster_dict(booster, num_iteration: Optional[int] = None,
         "feature_names": booster.feature_name(),
         "tree_info": trees_info,
     }
+
+
+def category_set_text(categories, bins) -> str:
+    """A category set as the dump writes it: the values of the set's bins
+    (``categories``: a categorical column's kept values, its bins in
+    order; the overflow bin, past them, holds no one value) joined by
+    ``||``, each a whole number as an integer."""
+    out = []
+    for b in bins:
+        if b < len(categories):
+            v = float(categories[b])
+            out.append(str(int(v)) if v.is_integer() else repr(v))
+    return "||".join(out)
 
 
 def load_booster_into(booster, model_file: Optional[str] = None,

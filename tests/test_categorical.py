@@ -103,3 +103,308 @@ def test_frontier_grower_supports_categoricals(cat_data):
     r_strict = float(np.sqrt(np.mean((b_strict.predict(X) - y) ** 2)))
     assert r_wave < r_strict * 1.2, (r_wave, r_strict)
     assert any(bool(np.asarray(t.is_cat_split).any()) for t in b_wave.trees)
+
+
+# -- LightGBM's categorical split rules, held to the plain reference ---------
+#
+# ``benchmark/reference/cat_check.best_cat_gain`` is LightGBM's
+# FindBestThresholdCategorical written as its loops, in float64 over the raw
+# codes; ``find_best_split`` has to find the same best gain on the
+# histogram of the same rows, and each rule has to matter: the reference
+# with the rule switched off finds another answer.
+
+CAT_HYPER = {"lambda_l2": 0.0, "min_sum_hessian_in_leaf": 1e-3,
+             "min_data_in_leaf": 1, "cat_smooth": 10.0, "cat_l2": 10.0,
+             "max_cat_threshold": 32, "max_cat_to_onehot": 4,
+             "min_data_per_group": 100}
+
+
+def _scan(code, g, h, levels, hyper=CAT_HYPER, bins=64):
+    """The program's best category set on one categorical column whose
+    codes ``code`` hold ``levels`` kept categories and the overflow bin
+    ``levels``: ``(gain, bins of the set)``."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.models.tree import build_cat_info
+    from lightgbm_tpu.ops.split import CatColumns, SplitContext, find_best_split
+
+    planes = np.stack([np.bincount(code, weights=w, minlength=bins)
+                       for w in (g, h, np.ones_like(g))])[:, None, :]
+    key = (hyper["cat_smooth"], hyper["cat_l2"], hyper["max_cat_threshold"],
+           hyper["max_cat_to_onehot"], float(hyper["min_data_per_group"]))
+    cats = CatColumns(is_cat=jnp.ones(1, bool),
+                      cat_bins=jnp.asarray([levels], jnp.int32))
+    ctx = SplitContext(jnp.float32(0.0), jnp.float32(hyper["lambda_l2"]),
+                       jnp.float32(hyper["min_data_in_leaf"]),
+                       jnp.float32(hyper["min_sum_hessian_in_leaf"]),
+                       jnp.float32(0.0))
+    bs = find_best_split(jnp.asarray(planes, jnp.float32), ctx,
+                         jnp.ones(1), jnp.bool_(True),
+                         build_cat_info(key, cats), bins_minor=True)
+    return float(bs.gain), set(np.flatnonzero(np.asarray(bs.cat_mask)))
+
+
+def _reference(code, g, h, levels, hyper=CAT_HYPER):
+    from benchmark.reference.cat_check import best_cat_gain
+
+    return best_cat_gain(code, np.arange(levels), g, h, hyper, 1.0)
+
+
+def _rows(counts, means, seed=0):
+    """Rows of categories ``0..len(counts)-1``, ``counts[k]`` of category
+    ``k`` with gradients about ``means[k]``, shuffled; unit hessians."""
+    rng = np.random.default_rng(seed)
+    code = np.repeat(np.arange(len(counts)), counts)
+    g = np.repeat(np.asarray(means, np.float64), counts) \
+        + rng.normal(0, 0.05, len(code))
+    perm = rng.permutation(len(code))
+    return code[perm], g[perm], np.ones(len(code))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_category_scan_finds_the_references_best_gain(seed):
+    """Random columns of 2 to 40 categories, some rarer than cat_smooth or
+    min_data_per_group, the overflow bin holding a share of the rows."""
+    rng = np.random.default_rng(100 + seed)
+    levels = int(rng.integers(2, 41))
+    counts = rng.integers(1, 400, levels + 1)
+    counts[rng.random(levels + 1) < 0.2] = rng.integers(1, 12)
+    code, g, h = _rows(counts, rng.normal(0, 1, levels + 1), seed)
+    got, _ = _scan(code, g, h, levels)
+    want = _reference(code, g, h, levels)
+    assert np.isfinite(want)
+    assert got == pytest.approx(want, rel=2e-4)
+
+
+def test_few_categories_split_one_against_the_rest():
+    """At max_cat_to_onehot bins or fewer (3 categories and the overflow:
+    4), one category goes left alone, at lambda_l2, though two against
+    two gains more."""
+    code, g, h = _rows([300, 300, 300, 300], [-1.0, -1.0, 1.0, 1.0])
+    got, left = _scan(code, g, h, 3)
+    assert len(left) == 1 and 3 not in left
+    assert got == pytest.approx(_reference(code, g, h, 3), rel=1e-5)
+    more = dict(CAT_HYPER, max_cat_to_onehot=1)
+    got_sorted, left_sorted = _scan(code, g, h, 3, more)
+    assert left_sorted == {0, 1} and got_sorted > 2 * got
+    assert got_sorted == pytest.approx(_reference(code, g, h, 3, more),
+                                       rel=1e-5)
+
+
+def test_min_data_per_group_keeps_a_small_category_from_going_alone():
+    """A category of 60 rows, the strongest: alone it holds fewer than
+    min_data_per_group rows, so the first set evaluated is it and its
+    neighbour."""
+    code, g, h = _rows([60, 500, 500, 500, 500], [-3.0, -0.5, 0.0, 0.5, 0.2])
+    got, left = _scan(code, g, h, 4)
+    assert got == pytest.approx(_reference(code, g, h, 4), rel=1e-5)
+    assert left != {0}
+    alone = dict(CAT_HYPER, min_data_per_group=1)
+    assert _scan(code, g, h, 4, alone)[0] != pytest.approx(got, rel=1e-3)
+    assert _reference(code, g, h, 4, alone) == pytest.approx(
+        _scan(code, g, h, 4, alone)[0], rel=1e-5)
+
+
+def test_cat_smooth_keeps_rare_categories_out_of_the_sorted_scan():
+    """Two categories of 6 rows, under cat_smooth's 10, carry the most
+    extreme gradients: they are in no set, and with cat_smooth 1 they
+    are."""
+    code, g, h = _rows([6, 6, 400, 400, 400, 400],
+                       [-5.0, 5.0, -0.5, 0.0, 0.3, 0.6])
+    got, left = _scan(code, g, h, 6)
+    assert not {0, 1} & left
+    assert got == pytest.approx(_reference(code, g, h, 6), rel=1e-5)
+    loose = dict(CAT_HYPER, cat_smooth=1.0, min_data_per_group=1)
+    got_loose, left_loose = _scan(code, g, h, 6, loose)
+    assert {0, 1} & left_loose
+    assert got_loose == pytest.approx(_reference(code, g, h, 6, loose),
+                                      rel=1e-5)
+
+
+def test_at_most_half_the_used_categories_go_left():
+    """Five usable categories: a set holds at most (5 + 1) / 2 = 3, from
+    either end of the order, though four of them against the fifth and
+    the overflow bin gains more."""
+    code, g, h = _rows([300] * 6, [-1.0, -1.0, -1.0, -1.0, 1.0, 1.0])
+    got, left = _scan(code, g, h, 5)
+    assert len(left) <= 3
+    assert got == pytest.approx(_reference(code, g, h, 5), rel=1e-5)
+    uncapped = dict(CAT_HYPER, max_cat_threshold=64)
+    # the cap is (used + 1) / 2 whatever max_cat_threshold says
+    assert _scan(code, g, h, 5, uncapped)[0] == pytest.approx(got)
+
+
+# -- the partition-fused kernels route category sets -------------------------
+
+@pytest.mark.parametrize("num_features,num_bins", [(6, 64), (60, 256)],
+                         ids=["single_block", "multi_block"])
+def test_fused_kernels_route_category_sets_as_xla_does(num_features,
+                                                        num_bins):
+    """Interpret mode: a wave grower whose partition-fused kernels route
+    every split, category sets included, grows the trees (splits, sets,
+    leaf values, counts) and the row-to-leaf map of the XLA route."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.models.spec import WaveSchedule
+    from lightgbm_tpu.models.tree import build_cat_info, grow_tree_logged
+    from lightgbm_tpu.ops.histogram_pallas import _vmem_blocking
+    from lightgbm_tpu.ops.split import CatColumns, SplitContext
+    from lightgbm_tpu.utils import profiling
+
+    rng = np.random.default_rng(num_features)
+    n = 4000
+    cats = [0, 2, num_features - 1]
+    nb = rng.integers(3, 60, num_features)
+    codes = np.stack([rng.integers(0, b, n) for b in nb], 1)
+    eff = rng.normal(0, 1, 64)
+    g = (eff[codes[:, 0]] + 0.5 * eff[codes[:, 2]] * (codes[:, 1] > 20)
+         + eff[codes[:, -1]] + rng.normal(0, .3, n)).astype(np.float32)
+    stats = jnp.asarray(np.stack([g, np.ones(n, np.float32),
+                                  np.ones(n, np.float32)], -1))
+    bins = jnp.asarray(codes.astype(np.uint8))
+    key = (10.0, 10.0, 32, 4, 100.0)
+    is_cat = np.isin(np.arange(num_features), cats)
+    columns = CatColumns(is_cat=jnp.asarray(is_cat),
+                         cat_bins=jnp.asarray(np.where(is_cat, nb - 1, 0),
+                                              jnp.int32))
+    ctx = SplitContext(jnp.float32(0), jnp.float32(0), jnp.float32(20),
+                       jnp.float32(1e-3), jnp.float32(0))
+    wave = WaveSchedule(8, "greedy", narrow_width=4)
+    assert (_vmem_blocking(num_features, num_bins, 24)[1] > 1) == (
+        num_features == 60)
+
+    def grow(fused):
+        return jax.jit(lambda: grow_tree_logged(
+            bins, stats, jnp.ones(num_features), ctx, 31, num_bins, 0,
+            wave=wave, cat_info=build_cat_info(key, columns),
+            hist_impl="pallas", hist_dtype="f32",
+            fuse_partition=fused))()
+
+    # the grower notes where it routes the sets as it is traced
+    tf, rf, _ = grow(True)
+    assert profiling.snapshot()["facts"]["train.cat_route"] == "fused"
+    tx, rx, _ = grow(False)
+    assert profiling.snapshot()["facts"]["train.cat_route"] == "xla"
+    assert np.asarray(tf.is_cat_split).sum() >= 3
+    assert np.array_equal(np.asarray(rf), np.asarray(rx))
+    for field in ("split_feature", "split_bin", "left", "right", "is_leaf",
+                  "is_cat_split", "cat_mask", "count", "leaf_value"):
+        assert np.array_equal(np.asarray(getattr(tf, field)),
+                              np.asarray(getattr(tx, field))), field
+
+
+# -- codes, dump and the reference ------------------------------------------
+
+def test_device_codes_of_categorical_columns_are_the_hosts(monkeypatch):
+    """``device_bin_codes`` of categorical columns: kept categories, the
+    rare ones past max_bin - 1 (overflow), NaN, values the fit never saw
+    and non-integer category values, beside a numeric column."""
+    from lightgbm_tpu import dataset as dataset_mod
+    from lightgbm_tpu.dataset import (ROW_PAD_MULTIPLE, BinMapper,
+                                      device_bin_codes)
+
+    rng = np.random.default_rng(3)
+    n = 3 * ROW_PAD_MULTIPLE + 17
+    X = np.empty((n, 3), np.float32)
+    X[:, 0] = rng.zipf(1.3, n) % 300                 # many levels: overflow
+    X[:, 1] = rng.normal(size=n)
+    X[:, 2] = rng.choice([-2.5, 0.0, 0.1, 7.0, 1e6], n)
+    X[rng.random(n) < 0.05, 0] = np.nan
+    X[rng.random(n) < 0.05, 2] = np.nan
+    mapper = BinMapper.fit(X, max_bin=31, categorical=[0, 2])
+    assert mapper.n_bins[0] == 31 and mapper.nan_bin[0] == -1
+    Q = X.copy()
+    Q[:40, 0] = [1e4, -1.0, -0.0, 0.5] * 10           # never seen at fit
+    Q[40:50, 2] = -0.0                                # the category 0.0
+    monkeypatch.setattr(dataset_mod, "CODE_BLOCK_VALUES",
+                        ROW_PAD_MULTIPLE * 3)
+    n_pad = -(-n // ROW_PAD_MULTIPLE) * ROW_PAD_MULTIPLE
+    codes, _, _ = device_bin_codes(Q, mapper, n_pad)
+    host = mapper.transform(Q)
+    assert np.asarray(codes)[:n].tobytes() == host.tobytes()
+    over = len(mapper.upper_bounds[0])
+    assert (host[np.isnan(Q[:, 0]), 0] == over).all()
+    assert (host[:40:4, 0] == over).all() and (host[40:50, 2] ==
+                                               host[Q[:, 2] == 0.0, 2][0]).all()
+
+
+@pytest.fixture(scope="module")
+def raw_cat_booster():
+    """Categorical columns of non-contiguous raw values (negative,
+    fractional, large) with NaN, beside numeric ones."""
+    rng = np.random.default_rng(8)
+    n = 6000
+    vals = np.array([-2.5, 3.0, 17.0, 250.0, 1e6, 0.75, 42.0, 9.0])
+    a = rng.choice(vals, n)
+    b = rng.integers(0, 12, n).astype(np.float64)
+    b[rng.random(n) < 0.1] = np.nan
+    x = rng.normal(size=n)
+    effect = dict(zip(vals, rng.normal(0, 1.5, len(vals))))
+    y = (np.vectorize(effect.get)(a) + np.where(np.nan_to_num(b) % 3 == 0,
+                                                1.0, -0.5)
+         + 0.3 * x + rng.normal(0, 0.2, n)).astype(np.float32)
+    X = np.column_stack([a, x, b]).astype(np.float32)
+    params = {"objective": "regression", "num_leaves": 15,
+              "learning_rate": 0.2, "verbosity": -1, "min_data_in_leaf": 5,
+              "lambda_l2": 0.0, "hist_dtype": "f32"}
+    ds = lgb.Dataset(X, label=y, categorical_feature=[0, 2])
+    booster = lgb.Booster(params, ds)
+    scores = []
+    for _ in range(3):
+        booster.update()
+        scores.append(np.asarray(booster._pred_train)[:n].copy())
+    return booster, X, y, scores
+
+
+def test_dump_walks_raw_values_back_to_the_programs_scores(raw_cat_booster):
+    from benchmark.reference import cat_check
+
+    booster, X, y, _ = raw_cat_booster
+    dump = booster.dump_model()
+    sets = []
+
+    def collect(node):
+        if "left_child" in node:
+            if node["decision_type"] == "==":
+                assert isinstance(node["threshold"], str)
+                assert node["default_left"] is False
+                sets.append(cat_check.parse_set(node["threshold"]))
+            collect(node["left_child"])
+            collect(node["right_child"])
+
+    for t in dump["tree_info"]:
+        collect(t["tree_structure"])
+    assert sets and all(len(s) for s in sets)
+    # raw values, not bin numbers: every value is one of the column's
+    raw = set(X[:, 0].tolist()) | set(X[~np.isnan(X[:, 2]), 2].tolist())
+    assert set(np.concatenate(sets).tolist()) <= raw
+    table = cat_check.Table(X, [0, 2])
+    walk = np.zeros(len(X))
+    for t in dump["tree_info"]:
+        tree = cat_check.flatten_tree(t["tree_structure"], table)
+        walk += t["shrinkage"] * tree["value"][cat_check.route(table, tree)]
+    got = booster.predict(X, raw_score=True)
+    np.testing.assert_allclose(walk, got - got.mean() + walk.mean(),
+                               atol=1e-5)
+
+
+def test_reference_holds_the_programs_trees(raw_cat_booster):
+    """``cat_check`` recomputes the checked trees' leaves from the raw
+    rows, routed by the dumped sets, and finds the program's values,
+    counts and scores."""
+    from benchmark.reference import cat_check
+
+    booster, X, y, scores = raw_cat_booster
+    table = cat_check.Table(X, [0, 2])
+    trees = [cat_check.flatten_tree(t["tree_structure"], table)
+             for t in booster.dump_model()["tree_info"]]
+    hyper = {"learning_rate": 0.2, "lambda_l2": 0.0, "cat_l2": 10.0,
+             "max_cat_to_onehot": 4, "max_bin": 255}
+    # the regression objective's gradients: the reference's are binary
+    # logloss's, so its leaf values are not compared here, only the rows
+    # each leaf holds and the walk of the scores
+    out = cat_check.check_rounds(table, y, trees, scores, 0.0, hyper)
+    for rd in out["rounds"]:
+        assert rd["leaf_count_off"] == 0 and rd["root_count_off"] == 0
+        assert rd["cat_splits"] > 0

@@ -103,11 +103,23 @@ def test_dataset_pandas_feature_names():
 
 
 def test_categorical_binning():
-    X = np.array([[0.0], [1.0], [2.0], [2.0], [7.0]])
+    """LightGBM's categorical rule: categories ranked by count, none past
+    the second seen fewer than ``min_data_in_bin`` times; the rest (and
+    NaN) share the overflow bin after the kept ones, which lie in the
+    order of their values."""
+    X = np.array([[0.0], [1.0], [2.0], [2.0], [7.0], [np.nan]])
     bm = BinMapper.fit(X, max_bin=255, categorical=[0])
     codes = bm.transform(X)
-    assert codes[:, 0].tolist() == [0, 1, 2, 2, 3]
-    assert bm.is_categorical[0]
+    # 2 (twice) and 0 (the first of the ones seen once) are kept
+    assert codes[:, 0].tolist() == [0, 2, 1, 1, 2, 2]
+    assert bm.is_categorical[0] and bm.n_bins[0] == 3
+    assert bm.nan_bin[0] == -1
+    every = BinMapper.fit(X, max_bin=255, min_data_in_bin=1, categorical=[0])
+    assert every.transform(X)[:, 0].tolist() == [0, 1, 2, 2, 3, 4]
+    # at most max_bin - 1 categories: the most frequent
+    few = BinMapper.fit(X, max_bin=2, min_data_in_bin=1, categorical=[0])
+    assert few.upper_bounds[0].tolist() == [2.0]
+    assert few.transform(X)[:, 0].tolist() == [1, 1, 0, 0, 1, 1]
 
 
 # -- bin codes assigned on the device ---------------------------------------
@@ -247,7 +259,7 @@ def _bundling(n, num_features, dtype):
 @pytest.mark.parametrize("case,make,kwargs,backend,patched,path", [
     ("float64", _dense, {}, "tpu", True, "host"),
     ("categorical_column", _dense, {"categorical_feature": [1]}, "tpu", True,
-     "host"),
+     "device"),
     ("bundling_sparse", _bundling, {}, "tpu", True, "device"),
     ("bundling_off_sparse", _bundling, {"params": {"enable_bundle": False}},
      "tpu", True, "device"),
@@ -343,12 +355,19 @@ def _oracle_fit(X, max_bin=255, min_data_in_bin=3, categorical=(),
         vals = col[~np.isnan(col)]
         budget = max_bin - (1 if has_nan else 0)
         if f in cat:
+            # LightGBM's categorical rule: by count, at most max_bin - 1,
+            # none past the second under min_data_in_bin; NaN shares the
+            # overflow bin
             is_cat[f] = True
-            cats = np.unique(vals)
-            if len(cats) > budget - 1:
-                uniq, cnts = np.unique(vals, return_counts=True)
-                cats = np.sort(uniq[np.argsort(-cnts)[: budget - 1]])
-            ub = cats
+            has_nan = False
+            uniq, cnts = np.unique(vals, return_counts=True)
+            keep = []
+            for i in sorted(range(len(uniq)), key=lambda i: (-cnts[i], i)):
+                if len(keep) == max_bin - 1 or (
+                        len(keep) > 1 and cnts[i] < min_data_in_bin):
+                    break
+                keep.append(uniq[i])
+            ub = np.sort(np.asarray(keep, np.float64))
         elif len(vals) == 0:
             ub = np.zeros(0)
         else:

@@ -416,11 +416,13 @@ def test_two_boosters_on_equal_shapes_share_one_round_program():
     fn_b, args_b = b._fused_segment(1)
     assert fn_a is fn_b
     # the operands end with the layout's tensors, the table's bundles
-    # (none: this table has no bundle) and the order of its columns by
-    # the height of their one-hots (an operand: any order is one program)
-    assert args_a[-3] is a._groups and args_b[-3] is b._groups
-    assert args_a[-2] is None and args_b[-2] is None
-    assert args_a[-1] is a._col_order and args_b[-1] is b._col_order
+    # (none: this table has no bundle), the order of its columns by the
+    # height of their one-hots (an operand: any order is one program) and
+    # its categorical columns (none: this table has no categorical column)
+    assert args_a[-4] is a._groups and args_b[-4] is b._groups
+    assert args_a[-3] is None and args_b[-3] is None
+    assert args_a[-2] is a._col_order and args_b[-2] is b._col_order
+    assert args_a[-1] is None and args_b[-1] is None
     before = fn_a._cache_size()
     a.update_many(1)
     b.update_many(1)

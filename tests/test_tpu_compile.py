@@ -104,6 +104,36 @@ def test_hist_partition_fused(one_chip, num_features):
              S((w,), jnp.int32))
 
 
+@pytest.mark.parametrize("num_features,width", [(32, 42), (32, 16),
+                                                 (136, 42)],
+                         ids=["single_block_f32", "narrow_f32",
+                              "multi_block_f136"])
+def test_hist_partition_fused_category_sets(one_chip, num_features, width):
+    """The fused kernels with the wave's category sets as an operand (the
+    one-hot dot that lays each row's set down its lane, the bit read off
+    along the sublanes), as a table with categorical columns builds
+    them."""
+    from lightgbm_tpu.ops.histogram_pallas import (
+        hist_partition_fused_pallas, prepare_wave_operands)
+
+    b = 256
+
+    def wave(bins, stats, pv, wfeat, sets):
+        bins_t, stats_t, chunk = prepare_wave_operands(bins, stats, b, 42)
+        pv_t = jnp.pad(pv, ((0, 0), (0, bins_t.shape[1] - pv.shape[1])))
+        return hist_partition_fused_pallas(
+            bins_t, stats_t, pv_t, width, b, chunk, interpret=False,
+            hist_dtype="bf16", wfeat=wfeat, num_features=num_features,
+            f_blk=None if width == 42 else bins_t.shape[0],
+            cat_sets=sets)
+
+    rows = 2_000_128
+    _compile(wave, one_chip,
+             S((rows, num_features), jnp.uint8),
+             S((rows, 3), jnp.float32), S((8, rows), jnp.float32),
+             S((width,), jnp.int32), S((width, b), jnp.float32))
+
+
 @pytest.mark.parametrize("num_bins", [255, 256])
 def test_split_iter(one_chip, num_bins):
     # 255 is what max_bin=255 data has: the wrapper pads the bin axis to
@@ -498,6 +528,73 @@ def test_whole_round_of_a_bundled_table(one_chip, monkeypatch):
     assert "%lgbtpu_hist_root" in text and "%lgbtpu_hist_narrow" in text
     assert compiled.memory_analysis().temp_size_in_bytes <= 8 << 30
     assert _narrow_minor_f32(text, 64 << 20) == []
+
+
+def test_whole_round_of_a_categorical_table(one_chip, monkeypatch):
+    """The categorical cell's round (13,184,384 rows of an Allstate-shaped
+    table, 16 of its 32 columns categorical) lowers for the described
+    chip with the category sets routed inside the partition-fused
+    kernels, the narrow passes and the one-hot heights engaged, under a
+    bound on the temporaries."""
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from benchmark.datagen_cat import CATEGORICAL, allstate_like
+    from lightgbm_tpu.utils import profiling
+
+    rows_padded = 13_184_384
+    X, y = allstate_like(60_000, 2144000001)
+    booster = lgb.Booster(
+        dict(objective="binary", num_leaves=255, learning_rate=0.1,
+             max_bin=255, min_data_in_leaf=1, min_sum_hessian_in_leaf=100.0,
+             verbosity=-1),
+        lgb.Dataset(X, label=y, categorical_feature=list(CATEGORICAL)))
+    ds = booster.train_set
+    small = int(ds.row_mask.shape[0])
+    ds.row_mask = jax.ShapeDtypeStruct((rows_padded,), ds.row_mask.dtype)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = booster._fused_segment(1)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            tuple(rows_padded if d == small else d for d in a.shape),
+            a.dtype, sharding=one_chip), args)
+    lowered = fn.lower(*shapes)
+    # the grower notes where it routes category sets as it is traced
+    facts = dict(profiling.snapshot()["facts"])
+    assert facts["train.cat_route"] == "fused"
+    assert facts["train.cat_features"] == 16
+    assert facts["train.hist_dtype"] == "bf16"
+    assert facts["train.wave_narrow_width"] == 16
+    assert 0.3 < facts["train.onehot_bin_share"] < 0.8
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for kernel in ("%lgbtpu_hist_root", "%lgbtpu_hist_wave",
+                   "%lgbtpu_hist_narrow"):
+        assert kernel in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6 << 30
+    assert _narrow_minor_f32(text, 64 << 20) == []
+
+
+def test_bin_code_block_program_of_categorical_columns(one_chip):
+    """The device's bin-code pass with the categorical columns' exact
+    match (``dataset.BinMapper.device_tables``) at the categorical cell's
+    width."""
+    from lightgbm_tpu import dataset
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f = 32
+    block = dataset.code_block_rows(f)
+    compiled = dataset._write_code_block.lower(
+        on_chip((13_184_384, f), jnp.uint8), on_chip((block, f), jnp.int32),
+        on_chip((), jnp.int32), on_chip((f, 254), jnp.int32),
+        on_chip((f,), jnp.int32),
+        {"keys": on_chip((f, 254), jnp.int32),
+         "overflow": on_chip((f,), jnp.int32)}).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes > 0          # codes written in place
+    assert memory.temp_size_in_bytes < 128 << 20
 
 
 @pytest.mark.parametrize("rows_padded,num_features,blocking", [
