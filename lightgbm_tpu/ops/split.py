@@ -474,23 +474,26 @@ def _categorical_scan(hist, total, ctx: SplitContext, feature_mask,
     elig = in_range & (c_ >= cat_info.cat_smooth)
     used = jnp.sum(elig, axis=1, dtype=jnp.int32)                 # [F]
     ratio = g_ / (h_ + cat_info.cat_smooth)
-    order_asc = jnp.argsort(jnp.where(elig, ratio, jnp.inf), axis=1,
-                            stable=True)
+    # Each order is one sort that carries the bin numbers and the three
+    # planes with its key, so the planes come out in order: a gather of a
+    # plane by the order cost ~10 ns an element on the chip.  Ascending:
+    # the eligible categories by ratio, ties by bin, the rest after them.
+    bins = lax.broadcasted_iota(jnp.int32, g_.shape, 1)
+    _, *asc = lax.sort((jnp.where(elig, ratio, jnp.inf), bins, g_, h_, c_),
+                       dimension=1, num_keys=1, is_stable=True)
+    # Descending: the ascending order's eligible prefix read backwards, the
+    # rest in place; the key is each sorted place's place in that order
     back = used[:, None] - 1 - pos
-    order_desc = jnp.where(
-        back >= 0,
-        jnp.take_along_axis(order_asc, jnp.maximum(back, 0), axis=1),
-        order_asc)
+    _, *desc = lax.sort((jnp.where(back >= 0, back, pos), *asc),
+                        dimension=1, num_keys=1)
+    order_asc, order_desc = asc[0], desc[0]
     span = jnp.minimum(used, jnp.minimum(cat_info.max_cat_threshold,
                                          (used + 1) // 2))
     k = min(cat_info.max_cat_threshold, num_bins)
     mdpg = cat_info.min_data_per_group
 
-    def direction(order):
-        # plane by plane: a gather of the three planes at once is laid out
-        # with the 3-wide axis minor, 128 lanes wide on the chip
-        cum = [jnp.cumsum(jnp.take_along_axis(x, order, axis=1), axis=1)
-               for x in (g_, h_, c_)]
+    def direction(planes):
+        cum = [jnp.cumsum(x, axis=1) for x in planes]
         gain_d, stats_d = gain_at(cum[0], cum[1], cum[2], ctx_cat)
         lc, rg, rh, rc = stats_d[2], stats_d[3], stats_d[4], stats_d[5]
         ok = ((lc >= ctx.min_data_in_leaf)
@@ -502,8 +505,8 @@ def _categorical_scan(hist, total, ctx: SplitContext, feature_mask,
         valid = ev & (gain_d > ctx.min_gain_to_split) & usable
         return jnp.where(valid, gain_d, NEG_INF), stats_d
 
-    gain_a, stats_a = direction(order_asc)
-    gain_d, stats_d = direction(order_desc)
+    gain_a, stats_a = direction(asc[1:])
+    gain_d, stats_d = direction(desc[1:])
     # LightGBM scans ascending first and keeps a later candidate only where
     # it gains more.  The two ends reach one partition of a node's
     # categories as mirror sets (left and right swapped), whose gains differ

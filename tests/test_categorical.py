@@ -408,3 +408,244 @@ def test_reference_holds_the_programs_trees(raw_cat_booster):
     for rd in out["rounds"]:
         assert rd["leaf_count_off"] == 0 and rd["root_count_off"] == 0
         assert rd["cat_splits"] > 0
+
+
+# -- the scan orders its categories by sorting the planes themselves ---------
+
+def _gather_scan(hist, total, ctx, feature_mask, depth_ok, cat_info, mono,
+                 lo, hi, p_out, rand_bins):
+    """The reference: ``ops.split._categorical_scan`` as it was written
+    with an ``argsort`` and ``take_along_axis`` gathers of the planes by
+    the order.  Same contract, same downstream arithmetic."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.split import (NEG_INF, TIE_RTOL, _group_evaluated,
+                                        constrained_leaf_output,
+                                        leaf_objective_at, split_stats_valid)
+
+    num_features, num_bins = hist.shape[1], hist.shape[2]
+    g_, h_, c_ = hist[0], hist[1], hist[2]
+    tg, th, tc = total[0], total[1], total[2]
+    pos = jnp.arange(num_bins)[None, :]
+    cat_bins = (jnp.full((num_features,), num_bins, jnp.int32)
+                if cat_info.cat_bins is None else cat_info.cat_bins)
+    onehot = cat_bins + 1 <= cat_info.max_cat_to_onehot
+    in_range = pos < cat_bins[:, None]
+    usable = (feature_mask[:, None] > 0) & depth_ok
+    if mono is not None:
+        usable &= mono[:, None] == 0
+    if rand_bins is not None:
+        usable &= pos == rand_bins[:, None]
+    parent_obj = leaf_objective_at(p_out, tg, th, ctx)
+
+    def gain_at(lg, lh, lc, c):
+        rg, rh, rc = tg - lg, th - lh, tc - lc
+        wl = constrained_leaf_output(lg, lh, lc, c, lo, hi, p_out)
+        wr = constrained_leaf_output(rg, rh, rc, c, lo, hi, p_out)
+        gain = (leaf_objective_at(wl, lg, lh, c)
+                + leaf_objective_at(wr, rg, rh, c) - parent_obj)
+        return gain, (lg, lh, lc, rg, rh, rc, wl, wr)
+
+    gain_o, stats_o = gain_at(g_, h_, c_, ctx)
+    valid_o = (split_stats_valid(c_, stats_o[5], h_, stats_o[4], gain_o, ctx)
+               & in_range & usable)
+    gain_o = jnp.where(valid_o, gain_o, NEG_INF)
+    ctx_cat = ctx._replace(lambda_l2=ctx.lambda_l2 + cat_info.cat_l2)
+    elig = in_range & (c_ >= cat_info.cat_smooth)
+    used = jnp.sum(elig, axis=1, dtype=jnp.int32)
+    ratio = g_ / (h_ + cat_info.cat_smooth)
+    order_asc = jnp.argsort(jnp.where(elig, ratio, jnp.inf), axis=1,
+                            stable=True)
+    back = used[:, None] - 1 - pos
+    order_desc = jnp.where(
+        back >= 0,
+        jnp.take_along_axis(order_asc, jnp.maximum(back, 0), axis=1),
+        order_asc)
+    span = jnp.minimum(used, jnp.minimum(cat_info.max_cat_threshold,
+                                         (used + 1) // 2))
+    k = min(cat_info.max_cat_threshold, num_bins)
+    mdpg = cat_info.min_data_per_group
+
+    def direction(order):
+        cum = [jnp.cumsum(jnp.take_along_axis(x, order, axis=1), axis=1)
+               for x in (g_, h_, c_)]
+        gain_d, stats_d = gain_at(cum[0], cum[1], cum[2], ctx_cat)
+        lc, rg, rh, rc = stats_d[2], stats_d[3], stats_d[4], stats_d[5]
+        ok = ((lc >= ctx.min_data_in_leaf)
+              & (stats_d[1] >= ctx.min_sum_hessian)
+              & (rc >= ctx.min_data_in_leaf) & (rc >= mdpg)
+              & (rh >= ctx.min_sum_hessian) & (pos < span[:, None]))
+        ev = _group_evaluated(lc[:, :k], ok[:, :k], mdpg)
+        ev = jnp.pad(ev, ((0, 0), (0, num_bins - k)))
+        valid = ev & (gain_d > ctx.min_gain_to_split) & usable
+        return jnp.where(valid, gain_d, NEG_INF), stats_d
+
+    gain_a, stats_a = direction(order_asc)
+    gain_d, stats_d = direction(order_desc)
+    best_a = jnp.max(gain_a, axis=1, keepdims=True)
+    beat = jnp.where(jnp.isfinite(best_a),
+                     best_a + TIE_RTOL * jnp.abs(best_a), NEG_INF)
+    gain_d = jnp.where(gain_d > beat, gain_d, NEG_INF)
+    use_desc = gain_d > gain_a
+    gain_s = jnp.maximum(gain_a, gain_d)
+    oh = onehot[:, None]
+    gain = jnp.where(oh, gain_o, gain_s)
+    picks = tuple(jnp.where(oh, o, jnp.where(use_desc, d, a))
+                  for o, a, d in zip(stats_o, stats_a, stats_d))
+
+    def mask_at(hit):
+        row = jnp.any(hit, axis=1)
+        at = jnp.sum(jnp.where(hit, pos, 0))
+        desc = jnp.any(hit & use_desc)
+        order_f = jnp.sum(jnp.where(row[:, None],
+                                    jnp.where(desc, order_desc, order_asc),
+                                    0), axis=0)
+        rank = jnp.argsort(order_f)
+        one = jnp.any(row & onehot)
+        return jnp.where(one, pos[0] == at, rank <= at)
+
+    return gain, picks, mask_at
+
+
+def _scan_case(case, seed, children=3, num_features=7, num_bins=40):
+    """Planes ``[children, 3, F, B]`` of categorical columns whose sorted
+    scans meet exact ties: bins of one ratio (equal gradient and hessian,
+    other counts), categories of zero gradient (``+0`` and ``-0`` ratios),
+    empty bins; and the scan's inputs for ``case``."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.split import CatInfo, SplitContext
+
+    rng = np.random.default_rng(seed)
+    shape = (children, num_features, num_bins)
+    c = rng.integers(0, 300, shape).astype(np.float32)
+    c[rng.random(shape) < 0.15] = 0.0
+    h = np.round(c * rng.uniform(0.1, 0.3, shape)).astype(np.float32)
+    g = np.round(rng.normal(0, 1, shape) * 8 * np.sqrt(c + 1)) / 4
+    g[rng.random(shape) < 0.15] = 0.0
+    g = np.where(rng.random(shape) < 0.5, g, -g).astype(np.float32)
+    tie = rng.integers(0, num_bins, (children, num_features, 6))
+    src = tie[..., :1]
+    for arr in (g, h):
+        np.put_along_axis(arr, tie, np.take_along_axis(arr, src, 2), 2)
+    assert (g == 0).any() and np.signbit(g[g == 0]).any()
+    hyper = dict(cat_smooth=10.0, cat_l2=10.0, max_cat_threshold=32,
+                 max_cat_to_onehot=4, min_data_per_group=0.0)
+    cat_bins = np.full(num_features, num_bins, np.int32)
+    mono = rand_bins = None
+    if case == "cat_smooth_and_short_columns":
+        hyper["cat_smooth"] = 150.0
+        cat_bins = rng.integers(2, num_bins + 1, num_features).astype(
+            np.int32)
+    elif case == "onehot_columns":
+        hyper["max_cat_to_onehot"] = 12
+        cat_bins = rng.choice([2, 3, 6, 11, num_bins], num_features).astype(
+            np.int32)
+    elif case == "min_data_per_group":
+        hyper["min_data_per_group"] = 400.0
+        hyper["max_cat_threshold"] = 8
+    elif case == "rand_bins":
+        rand_bins = jnp.asarray(rng.integers(0, num_bins, (children,
+                                                           num_features)))
+    elif case == "mono":
+        mono = jnp.asarray(rng.choice([-1, 0, 0, 1], num_features))
+    info = CatInfo(is_cat=jnp.ones(num_features, bool),
+                   cat_smooth=jnp.float32(hyper["cat_smooth"]),
+                   cat_l2=jnp.float32(hyper["cat_l2"]),
+                   max_cat_threshold=hyper["max_cat_threshold"],
+                   cat_bins=jnp.asarray(cat_bins),
+                   max_cat_to_onehot=hyper["max_cat_to_onehot"],
+                   min_data_per_group=jnp.float32(
+                       hyper["min_data_per_group"]))
+    ctx = SplitContext(jnp.float32(0.0), jnp.float32(0.0), jnp.float32(1.0),
+                       jnp.float32(5.0), jnp.float32(0.0))
+    planes = jnp.asarray(np.stack([g, h, c], 1))
+    return planes, ctx, info, mono, rand_bins
+
+
+def _same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+SCAN_CASES = ["ties_and_signed_zeros", "cat_smooth_and_short_columns",
+              "onehot_columns", "min_data_per_group", "rand_bins", "mono"]
+
+
+@pytest.mark.parametrize("case", SCAN_CASES)
+def test_sorted_planes_scan_as_the_gathered_ones(case, monkeypatch):
+    """``_categorical_scan`` sorts the planes with their key; the gather
+    formulation it replaced is the reference: the same gain, the same
+    eight numbers and the same category set at every (feature, position),
+    to the bit, alone and inside ``find_best_split`` vmapped over a wave's
+    children in ``bins_minor`` planes."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops import split
+
+    planes, ctx, info, mono, rand_bins = _scan_case(case, seed=45)
+    n, _, num_features, num_bins = planes.shape
+    lo, hi = jnp.float32(-jnp.inf), jnp.float32(jnp.inf)
+    mask = jnp.ones(num_features, jnp.float32)
+    hits = jnp.eye(num_features * num_bins, dtype=bool).reshape(
+        -1, num_features, num_bins)
+    rb0 = None if rand_bins is None else rand_bins[0]
+
+    def scan(fn, hist):
+        total = jnp.cumsum(hist, axis=2)[:, :, -1:]
+        p_out = split.leaf_output(total[0], total[1], ctx)
+        gain, picks, mask_at = fn(hist, total, ctx, mask, jnp.bool_(True),
+                                  info, mono, lo, hi, p_out, rb0)
+        return gain, picks, jax.vmap(mask_at)(hits)
+
+    for child in range(n):
+        got = scan(split._categorical_scan, planes[child])
+        want = scan(_gather_scan, planes[child])
+        assert _same_bits(got[0], want[0]), "gain"
+        assert np.isfinite(np.asarray(want[0])).any()
+        for i, (a, b) in enumerate(zip(got[1], want[1])):
+            assert _same_bits(a, b), f"pick {i}"
+        assert _same_bits(got[2], want[2]), "mask_at"
+
+    def wave():
+        def score(h, rb=None):
+            return split.find_best_split(h, ctx, mask, jnp.bool_(True), info,
+                                         mono, rand_bins=rb, bins_minor=True)
+        if rand_bins is None:
+            return jax.vmap(score)(planes)
+        return jax.vmap(score)(planes, rand_bins)
+
+    got = wave()
+    monkeypatch.setattr(split, "_categorical_scan", _gather_scan)
+    want = wave()
+    assert np.asarray(want.cat).any()
+    for field in want._fields:
+        assert _same_bits(getattr(got, field), getattr(want, field)), field
+
+
+def test_wave_scan_gathers_no_plane():
+    """The wave's vmapped scan over categorical columns holds no gather
+    whose result has F x B elements or more: the categories are ordered by
+    sorting the planes, not by gathering them."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops import split
+
+    planes, ctx, info, _, _ = _scan_case("ties_and_signed_zeros", seed=1,
+                                         children=6)
+    num_features, num_bins = planes.shape[2], planes.shape[3]
+
+    def score(h):
+        return split.find_best_split(h, ctx, jnp.ones(num_features),
+                                     jnp.bool_(True), info, bins_minor=True)
+
+    text = jax.jit(jax.vmap(score)).lower(planes).as_text()
+    assert "stablehlo.sort" in text
+    gathers = re.findall(
+        r'"stablehlo\.gather".*-> tensor<((?:\d+x)*\d+)x\w+>', text)
+    sizes = [int(np.prod([int(d) for d in m.split("x")])) for m in gathers]
+    assert not [s for s in sizes if s >= num_features * num_bins], sizes
